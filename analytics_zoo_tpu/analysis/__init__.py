@@ -2,7 +2,7 @@
 
 Eight PRs of hard-won invariants — one placement site, one injected
 clock, seeded-RNG-only determinism, donated step buffers, no host work
-inside jitted hot paths, a complete error taxonomy — were enforced by
+inside jitted hot paths, a complete error classification — were enforced by
 one grep test, convention, and reviewer memory.  This package turns
 them into machine-checked rules (Clockwork's thesis restated for a
 codebase: predictable systems come from *consolidating choice* and

@@ -8,8 +8,8 @@ recursively (pjit bodies, scan/while/cond sub-jaxprs, shard_map
 bodies, custom_vjp calls) for four properties:
 
 - **no-callbacks-in-hot-program** — ``pure_callback``/``io_callback``/
-  ``debug_callback`` inside a jitted train/eval/serving program is a
-  host round-trip per step hiding where no profiler attributes it (and
+  ``debug_callback``/``debug_print`` inside a jitted train/eval/serving
+  program is a host round-trip per step hiding where no profiler attributes it (and
   pins the program to the host, breaking async dispatch overlap).
 - **donation-materialized** — the train step's ``TrainState`` arg must
   actually reach the pjit with every leaf marked donated.  Donation is
@@ -44,16 +44,17 @@ from typing import Any, Callable, Iterator, List, Optional, Sequence, Set, Tuple
 
 import jax
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from analytics_zoo_tpu.analysis.base import Violation
 
 #: host-callback primitives banned from hot programs
 CALLBACK_PRIMS = frozenset({"pure_callback", "io_callback",
-                            "debug_callback"})
+                            "debug_callback", "debug_print"})
 
 #: named-axis collective primitives whose axes must be declared
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmax", "pmin", "pbroadcast", "ppermute",
+    "psum", "psum_invariant", "pmax", "pmin", "pbroadcast", "ppermute",
     "all_gather", "all_gather_invariant", "reduce_scatter",
     "all_to_all", "pgather", "axis_index",
 })
@@ -91,11 +92,11 @@ class AuditProgram:
 
 def _sub_jaxprs(params: dict) -> Iterator[Any]:
     for v in params.values():
-        if isinstance(v, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+        if isinstance(v, (Jaxpr, ClosedJaxpr)):
             yield v
         elif isinstance(v, (tuple, list)):
             for item in v:
-                if isinstance(item, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+                if isinstance(item, (Jaxpr, ClosedJaxpr)):
                     yield item
 
 
@@ -103,7 +104,7 @@ def iter_eqns(jaxpr) -> Iterator[Any]:
     """Every equation of ``jaxpr`` and (recursively) of every sub-jaxpr
     carried in equation params — pjit bodies, scan/while/cond branches,
     shard_map bodies, custom_jvp/vjp call jaxprs."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
@@ -134,7 +135,7 @@ def collective_inventory(jaxpr) -> Set[str]:
 
 
 def _avals(jaxpr) -> Iterator[Any]:
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for v in jaxpr.invars + jaxpr.outvars:
         if hasattr(v, "aval"):
@@ -173,13 +174,15 @@ def audit_program(target: AuditProgram) -> List[Violation]:
 
     if built.donate_state is not None:
         n_state = len(jax.tree_util.tree_leaves(built.donate_state))
+        # a jitted call traces as one "jit" equation carrying the
+        # donation vector
         pjit_eqns = [e for e in closed.jaxpr.eqns
-                     if e.primitive.name == "pjit"
+                     if e.primitive.name == "jit"
                      and "donated_invars" in e.params]
         if not pjit_eqns:
             out.append(Violation(
                 rule="donation-materialized", file=where, line=0,
-                message="no pjit equation found at the top level — the "
+                message="no jit equation found at the top level — the "
                         "step is not the single jitted program the "
                         "donation contract assumes"))
         else:

@@ -342,14 +342,14 @@ class NoHostSyncInHotPath:
 
 
 @dataclasses.dataclass
-class TaxonomyComplete:
+class ErrorClassesComplete:
     """Every exception class in ``resilience/errors.py`` must appear in
     exactly one of ``_RETRYABLE_CLASSES``/``FATAL_ERRORS`` — an error
     class outside both falls through ``run_resilient``'s retry filter
     with unconsidered semantics (the PR-3 contract, now static: the
     check runs without importing the module)."""
 
-    name: str = "taxonomy-complete"
+    name: str = "error-classes-complete"
     target: str = "resilience/errors.py"
     registries: Sequence[str] = ("_RETRYABLE_CLASSES", "FATAL_ERRORS")
 
@@ -507,7 +507,7 @@ class RegisteredMetricNames:
 
 def default_rules() -> List:
     return [OneClock(), OnePlacementSite(), SeededRngOnly(),
-            NoHostSyncInHotPath(), TaxonomyComplete(),
+            NoHostSyncInHotPath(), ErrorClassesComplete(),
             RegisteredMetricNames()]
 
 

@@ -299,10 +299,12 @@ class Recurrent(nn.Module):
     compatibility.  ``engine="pallas"`` runs ``ops.pallas_rnn``'s
     persistent kernel (h2h weights VMEM-resident across all timesteps);
     if the geometry exceeds the VMEM budget (``pallas_vmem_limit``,
-    default ``ops.pallas_rnn.VMEM_BUDGET_BYTES`` — checked only when the
+    default ``ops.vmem.VMEM_BUDGET_BYTES`` — checked only when the
     kernel would actually compile for a TPU, interpret mode has no VMEM)
     or the cell kind is not ported, it warns and falls back to the
     blocked scan, bit-identical results either way.
+    :meth:`resolved_engine` says which engine a geometry runs, so a
+    caller that asked for the kernel can check it got it.
     """
 
     cell: nn.Module
@@ -337,10 +339,21 @@ class Recurrent(nn.Module):
             raise ValueError(f"engine={eng!r} not in {ENGINES}")
         return eng
 
+    def resolved_engine(self, batch: int, dtype=jnp.float32) -> str:
+        """The engine that runs for a ``[batch, T, D]`` input of
+        ``dtype`` in this process: ``engine="pallas"`` resolves to
+        ``"blocked"`` when the kernel does not apply (cell kind not
+        ported, or over the VMEM budget on a TPU)."""
+        engine = self._resolve_engine()
+        if engine == "pallas" and self._pallas_or_fallback(batch,
+                                                           dtype) is None:
+            return "blocked"
+        return engine
+
     def _pallas_or_fallback(self, batch: int, dtype) -> Optional[str]:
         """Cell kind if the persistent kernel applies, else None (warn +
         blocked-scan fallback)."""
-        from analytics_zoo_tpu.ops import pallas_rnn
+        from analytics_zoo_tpu.ops import pallas_rnn, vmem
 
         kind = _pallas_cell_kind(self.cell)
         if kind is None:
@@ -353,7 +366,11 @@ class Recurrent(nn.Module):
         if limit is None:
             if interp:          # interpret mode discharges to XLA: no VMEM
                 return kind
-            limit = pallas_rnn.VMEM_BUDGET_BYTES
+            # what the kernel will ask Mosaic for (declared buffers plus
+            # the compiler's working set) against the chip's budget
+            limit, ask = vmem.VMEM_BUDGET_BYTES, vmem.limit_bytes
+        else:
+            ask = int               # an explicit cap on the declared bytes
         # budget against the dtype that will actually compile (fp32 by
         # default, bf16 under make_train_step(compute_dtype='bf16')
         # casting) and the PER-DEVICE batch: a pre-sharded global batch
@@ -368,11 +385,11 @@ class Recurrent(nn.Module):
         size_kwargs = dict(batch=-(-batch // shards),
                            time_block=self.pallas_time_block,
                            weight_bytes=jnp.dtype(dtype).itemsize)
-        need = {"forward": pallas_rnn.persistent_vmem_bytes(
-            self.cell.hidden_size, kind, **size_kwargs)}
+        need = {"forward": ask(pallas_rnn.persistent_vmem_bytes(
+            self.cell.hidden_size, kind, **size_kwargs))}
         if self.pallas_grad and self.pallas_backward == "pallas":
-            need["backward"] = pallas_rnn.persistent_vmem_bytes(
-                self.cell.hidden_size, kind, backward=True, **size_kwargs)
+            need["backward"] = ask(pallas_rnn.persistent_vmem_bytes(
+                self.cell.hidden_size, kind, backward=True, **size_kwargs))
         over = {p: nb for p, nb in need.items() if nb > limit}
         if over:
             detail = ", ".join(f"{p} ~{nb / 2**20:.1f} MB"

@@ -176,8 +176,8 @@ def overlap_window(items, dispatch, consume, max_inflight: int = 4) -> None:
 
     ``dispatch(item)`` must be async (a jit call returning a token);
     ``consume(token)`` forces the result to host and processes it.  Up to
-    ``max_inflight`` items are in flight, so the remote device's fixed
-    per-call latency overlaps with the next items' host prep WITHOUT
+    ``max_inflight`` items are in flight, so device execution and
+    readback overlap with the next items' host prep WITHOUT
     letting the whole dataset's input buffers accumulate in HBM.  Used by
     the serving predictors, the Validator, and the ASR pipeline."""
     from collections import deque

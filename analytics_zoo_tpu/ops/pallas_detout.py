@@ -41,9 +41,10 @@ ordering (int8-quantized confidences) because both tie-break rules
 reduce to lowest-flat-index-first.
 
 ``interpret=True`` (automatic off-TPU) discharges the kernel to XLA so
-CPU tier-1 runs the fused semantics; geometries whose planning
-estimate exceeds :data:`VMEM_BUDGET_BYTES` warn and fall back to the
-unfused pallas path (see ``detection_output``) — never an error.
+CPU tier-1 runs the fused semantics.  ``detection_output`` selects this
+kernel only for geometries whose :func:`fused_vmem_bytes` fits
+``ops.vmem.VMEM_BUDGET_BYTES``, and the same figure is the VMEM limit
+the kernel requests from Mosaic.
 
 ``stage`` builds prefix programs of the same kernel ("decode" →
 "select" → "full") so ``tools/profile_serve.py`` can ladder the fused
@@ -63,29 +64,29 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from analytics_zoo_tpu.ops.pallas_nms import _round_up
-
-#: VMEM the fused program may plan against: 16 MB/core on v4/v5 minus
-#: headroom for Mosaic's own buffers (the ``pallas_rnn`` convention).
-#: Module attribute on purpose — tests shrink it to force the fallback.
-VMEM_BUDGET_BYTES = 14 * (1 << 20)
+from analytics_zoo_tpu.ops.vmem import compiler_params, padded_bytes
 
 #: prefix programs for the profile ladder (each includes the previous)
 STAGES = ("decode", "select", "full")
 
 
 def fused_vmem_bytes(n_priors: int, n_classes: int, keep_topk: int) -> int:
-    """Planning estimate of the fused program's VMEM residency: the
-    per-class keep scratch (C_fg rows × padded priors), the seven f32
-    work vectors (4 box planes + active/remaining/current-keep), the
-    double-buffered input blocks (scores + loc/priors/variances at 4
-    sublanes each) and the output block.  Used by ``detection_output``
-    to warn-and-fall-back to the unfused pallas path."""
+    """VMEM the fused program's buffers occupy, priced the way Mosaic
+    lays them out (``ops.vmem.padded_bytes``): every ``(1, 1, P)`` lane
+    vector and every ``(1, 4, P)`` block pads to 8 sublanes, so the
+    figure is ~8× the logical bytes.  Counted: the per-class keep
+    scratch (C_fg rows), the seven work vectors, the double-buffered
+    score and loc blocks, the single-buffered prior/variance blocks
+    (whole-array windows are not double-buffered) and the
+    double-buffered output block.  ``detection_output`` selects on it and
+    ``fused_detection_output`` hands it to Mosaic as the VMEM limit."""
     ppad = _round_up(n_priors, 128)
     n_fg = max(n_classes - 1, 1)
-    vec = 4 * ppad                      # one f32 lane vector
-    scratch = (n_fg + 7) * vec          # allkeep rows + 7 work vectors
-    blocks = 2 * (vec + 3 * 4 * vec)    # double-buffered in-blocks
-    return scratch + blocks + keep_topk * 6 * 4
+    vec = padded_bytes((1, 1, ppad), np.float32)
+    quad = padded_bytes((1, 4, ppad), np.float32)
+    scratch = (n_fg + 7) * vec
+    blocks = 2 * vec + 2 * quad + 2 * quad
+    return scratch + blocks + 2 * padded_bytes((keep_topk, 6), np.float32)
 
 
 def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
@@ -251,8 +252,8 @@ def fused_detection_output(loc: jax.Array, conf: jax.Array,
     ``stage``: "full" (the product), or the "decode"/"select" prefix
     programs for the profile ladder (their outputs are probes, not
     detections).  Callers normally go through ``detection_output``
-    with ``DetectionOutputParam(backend="fused")``, which adds the
-    VMEM-budget fallback."""
+    with ``DetectionOutputParam(backend="fused")``, which checks the
+    VMEM budget first."""
     if stage not in STAGES:
         raise ValueError(f"stage must be one of {STAGES}, got {stage!r}")
     B, P, C = conf.shape
@@ -306,5 +307,7 @@ def fused_detection_output(loc: jax.Array, conf: jax.Array,
         scratch_shapes=(
             [pltpu.VMEM((1, 1, ppad), jnp.float32) for _ in range(7)]
             + [pltpu.VMEM((n_fg, 1, ppad), jnp.float32)]),
+        compiler_params=compiler_params(
+            fused_vmem_bytes(P, C, int(param.keep_topk))),
         interpret=interpret,
     )(scores, loc_t, pr, vr)

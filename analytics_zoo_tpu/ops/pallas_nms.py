@@ -29,6 +29,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from analytics_zoo_tpu.ops.vmem import round_up as _round_up
+
 
 def _nms_kernel(x1_ref, y1_ref, x2_ref, y2_ref, valid_ref, keep_ref,
                 active_ref, *, iou_threshold: float, k: int,
@@ -110,10 +112,6 @@ def nms_sweep(x1, y1, x2, y2, valid, iou_threshold: float = 0.45,
       x2.astype(jnp.float32)[:, None, :], y2.astype(jnp.float32)[:, None, :],
       valid.astype(jnp.float32)[:, None, :])
     return out[:, 0, :]
-
-
-def _round_up(n: int, m: int) -> int:
-    return ((n + m - 1) // m) * m
 
 
 @functools.partial(

@@ -17,8 +17,8 @@ no JAX equivalent, so this module supplies it TPU-natively:
   failure (device/runtime error, stall, preemption) it rebuilds the
   whole program via the caller's factory and resumes from the latest
   orbax checkpoint, up to ``max_restarts`` times.  Rebuilding matters on
-  TPU: after a device reset or relay drop the old compiled executables
-  and live buffers are garbage; a fresh ``Optimizer`` re-traces and
+  TPU: after a device reset the old compiled executables and live
+  buffers are garbage; a fresh ``Optimizer`` re-traces and
   re-replicates from the restored host-side state.
 
 Fault injection for tests: :class:`FaultInjector` wraps a dataset and

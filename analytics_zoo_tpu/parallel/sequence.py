@@ -32,10 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from analytics_zoo_tpu.parallel.mesh import SEQUENCE_AXIS
@@ -141,12 +138,8 @@ def full_attention(q, k, v, causal: bool = False,
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    try:
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax uses check_rep
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return shard_map(body, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 def halo_exchange(x, axis_name: str, left: int, right: int, time_axis: int = 1):

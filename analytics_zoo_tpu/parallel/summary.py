@@ -9,8 +9,10 @@ since the loss/metrics come out of the psum'd step).
 
 from __future__ import annotations
 
+import glob
 import os
-from typing import Dict, Optional
+import struct
+from typing import Dict, List, Optional, Tuple
 
 import jax
 
@@ -49,7 +51,7 @@ class _Summary:
     def add_scalar(self, tag: str, value, iteration: int) -> None:
         """``value`` may be a device array: it is only forced to a host
         float AFTER the trigger gate, so gated-off iterations never pay a
-        device→host sync (expensive when the accelerator is remote)."""
+        device→host sync (which stalls the async dispatch pipeline)."""
         if self.writer is not None and self._gated(tag, iteration):
             self.writer.add_scalar(tag, float(value), iteration)
 
@@ -71,3 +73,30 @@ class TrainSummary(_Summary):
 class ValidationSummary(_Summary):
     def __init__(self, log_dir: str, app_name: str):
         super().__init__(log_dir, app_name, "validation")
+
+
+def read_scalars(summary_dir: str) -> Dict[str, List[Tuple[int, float, float]]]:
+    """Read back what a summary wrote: ``{tag: [(iteration, value,
+    wall_time), ...]}`` in write order, from the event files under
+    ``summary_dir`` (a ``_Summary.log_dir``).  The reading half of the
+    summaries — how ``chip_smoke.py`` sees the per-step loss of a run
+    driven through ``train_ssd``."""
+    from tensorboardX.proto import event_pb2
+
+    out: Dict[str, List[Tuple[int, float, float]]] = {}
+    for path in sorted(glob.glob(os.path.join(summary_dir,
+                                              "events.out.tfevents.*"))):
+        with open(path, "rb") as f:
+            data = f.read()
+        pos = 0
+        # TFRecord framing: u64 length, u32 crc, payload, u32 crc
+        while pos + 12 <= len(data):
+            (n,) = struct.unpack_from("<Q", data, pos)
+            event = event_pb2.Event.FromString(data[pos + 12:pos + 12 + n])
+            pos += 12 + n + 4
+            for v in event.summary.value:
+                if v.HasField("simple_value"):
+                    out.setdefault(v.tag, []).append(
+                        (int(event.step), float(v.simple_value),
+                         float(event.wall_time)))
+    return out

@@ -160,9 +160,10 @@ class DeepSpeech2Pipeline:
         """ONE jitted program: device featurize → DS2 forward → per-frame
         argmax.  Features never round-trip to host (the split path reads
         them back only to re-upload), and the readback is (B, T) int ids
-        — ~30× fewer bytes than (B, T, C) log-probs.  Serving on a
-        remote accelerator is dispatch/transfer bound, so the greedy
-        path must be a single call per batch (docs/PERFORMANCE.md)."""
+        — ~30× fewer bytes than (B, T, C) log-probs.  Each extra
+        dispatch pays a launch and an HBM round-trip of its operands, so
+        the greedy path is a single call per batch
+        (docs/PERFORMANCE.md)."""
         if self._fused_asr is None:
             import jax
 

@@ -1,11 +1,11 @@
-"""Resilience layer: failure taxonomy, stall detection, graceful
+"""Resilience layer: failure classification, stall detection, graceful
 preemption, and chaos fault injection.
 
 The reference delegated its whole failure story to Spark task retry and
 lineage (``ssd/example/Train.scala:153``); a TPU-native system owns it
 itself.  The pieces (see docs/RESILIENCE.md):
 
-- :mod:`errors` — retryable vs fatal taxonomy (:func:`retryable_errors`,
+- :mod:`errors` — retryable vs fatal classification (:func:`retryable_errors`,
   :data:`FATAL_ERRORS`, :func:`is_retryable`)
 - :mod:`watchdog` — :class:`StallWatchdog` (hung step → StallError)
 - :mod:`preempt` — :class:`PreemptionHandler` (SIGTERM → checkpoint →

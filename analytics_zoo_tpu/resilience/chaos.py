@@ -9,8 +9,8 @@ global batch index:
 kind                 effect
 ===================  ======================================================
 ``crash``            raise :class:`InjectedFault` (generic lost task)
-``xla_transient``    raise ``jaxlib...XlaRuntimeError`` (device/runtime
-                     error — what a real TPU relay drop surfaces as)
+``xla_transient``    raise ``jax.errors.JaxRuntimeError`` (device/runtime
+                     error — what a lost TPU surfaces as)
 ``sigterm``          deliver SIGTERM to this process (graceful-preemption
                      path: checkpoint at the boundary, ``Preempted``)
 ``mid_save_kill``    arm a one-shot hook that crashes the NEXT checkpoint
@@ -165,14 +165,11 @@ def mutate_batch(kind: str, batch: Dict[str, Any], seed: int) -> Dict[str, Any]:
 
 
 def transient_xla_error(msg: str = "injected transient device error"):
-    """An exception of the real jaxlib runtime-error type when available
-    (so the retry filter is exercised against the genuine class)."""
-    try:
-        import jaxlib.xla_extension as xe
+    """An exception of the real JAX runtime-error type, so the retry
+    filter is exercised against the genuine class."""
+    from jax.errors import JaxRuntimeError
 
-        return xe.XlaRuntimeError(msg)
-    except Exception:  # pragma: no cover - jaxlib always present in-image
-        return InjectedFault(msg)
+    return JaxRuntimeError(msg)
 
 
 def corrupt_snapshot(checkpoint_path: str) -> Tuple[str, str]:

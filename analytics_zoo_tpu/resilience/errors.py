@@ -1,4 +1,4 @@
-"""Failure taxonomy for the resilience layer.
+"""Failure classification for the resilience layer.
 
 Every error class here is dependency-free on purpose: the data layer
 (``data/prefetch.py``, ``data/records.py``), the checkpoint layer
@@ -16,9 +16,9 @@ The split that matters operationally is *retryable* vs *fatal*:
   ``ValueError``, shape mismatches).  Restarting cannot fix these; they
   propagate on the first attempt so the bug surfaces immediately.
 
-``retryable_errors()`` assembles the canonical retryable tuple, pulling
-in the jaxlib runtime error type when available (transient XLA/device
-errors — the TPU-native analogue of a lost Spark executor).
+``retryable_errors()`` assembles the canonical retryable tuple, including
+``jax.errors.JaxRuntimeError`` (transient XLA/device errors — the
+TPU-native analogue of a lost Spark executor).
 """
 
 from __future__ import annotations
@@ -113,7 +113,7 @@ class ReplicaWedged(RuntimeError):
       once, and only if THAT dispatch also fails do the requests fail
       with this error (at which point the client may retry elsewhere).
 
-    Classified retryable in the taxonomy because the error object only
+    Registered retryable because the error object only
     ever escapes to request/supervisor scope — replica fencing is
     handled internally by ``serving.replica.ReplicaPool``."""
 
@@ -159,7 +159,7 @@ class ElasticPlacementError(ValueError):
 
 #: Explicit classification registries.  EVERY exception class defined in
 #: this module must appear in exactly one of the two tuples below — the
-#: taxonomy completeness test (tests/test_anomaly.py) enforces it, so a
+#: classification completeness test (tests/test_anomaly.py) enforces it, so a
 #: future error class cannot silently fall through ``run_resilient``'s
 #: retry filter with unconsidered semantics.
 _RETRYABLE_CLASSES: Tuple[Type[BaseException], ...] = (
@@ -186,18 +186,16 @@ FATAL_ERRORS: Tuple[Type[BaseException], ...] = (
 
 def retryable_errors() -> Tuple[Type[BaseException], ...]:
     """The canonical tuple of transient, restart-recoverable failures."""
-    errs = _RETRYABLE_CLASSES
-    try:  # transient device/runtime errors (lost TPU, relay drop, OOM)
-        import jaxlib.xla_extension as _xe
+    # imported here, not at module scope: this module sits at the bottom
+    # of the import graph and stays importable without touching jax
+    from jax.errors import JaxRuntimeError
 
-        errs = errs + (_xe.XlaRuntimeError,)
-    except Exception:  # pragma: no cover - jaxlib always present in-image
-        pass
-    return errs
+    # transient device/runtime errors (lost TPU, HBM OOM)
+    return _RETRYABLE_CLASSES + (JaxRuntimeError,)
 
 
 def is_retryable(exc: BaseException) -> bool:
-    """Classify one failure instance against the taxonomy.  Fatal classes
+    """Classify one failure instance as retryable or fatal.  Fatal classes
     win over retryable bases (``TrainingDiverged`` is a ``RuntimeError``
     subclass, but divergence must never be restart-masked)."""
     if isinstance(exc, FATAL_ERRORS):

@@ -173,12 +173,8 @@ def make_audit_fn(mesh):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax spelling
-        from jax.experimental.shard_map import shard_map
-
     from analytics_zoo_tpu.parallel import mesh as mesh_lib
+    from analytics_zoo_tpu.parallel.sequence import _shard_map
 
     if len(mesh.axis_names) != 1:
         raise ValueError(
@@ -194,10 +190,9 @@ def make_audit_fn(mesh):
         word = tree_fingerprint(params, flip=(element, bit, on))
         return word[None]                   # (1,) per device → (W,)
 
-    fn = shard_map(per_device, mesh=mesh,
-                   in_specs=(P(), P(), P(), P()),
-                   out_specs=P(axis), check_rep=False)
-    return jax.jit(fn)
+    return jax.jit(_shard_map(per_device, mesh,
+                              in_specs=(P(), P(), P(), P()),
+                              out_specs=P(axis)))
 
 
 def make_shadow_fn(module, forward_fn=None):

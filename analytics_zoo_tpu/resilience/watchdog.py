@@ -1,6 +1,6 @@
 """Host-side stall detection.
 
-A hung device step (relay drop, deadlocked collective) or a dead input
+A hung device step (lost device, deadlocked collective) or a dead input
 pipeline does not raise — it blocks the host loop forever, which is the
 worst failure mode for a supervised job: no error, no restart, no
 progress.  :class:`StallWatchdog` turns "no progress past a deadline"

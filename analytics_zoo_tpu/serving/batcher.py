@@ -1,10 +1,9 @@
 """Deadline-aware dynamic batch assembly over compiled geometries.
 
-Batching amortizes dispatch overhead — decisive on a remote accelerator
-where every dispatch pays a fixed round-trip — but waiting to fill a
-batch spends the queued requests' deadline slack.  The classic dynamic-
-batching compromise (Clipper's adaptive batch sizing): flush a bucket
-when it is FULL, or when its most urgent request can no longer afford
+Batching amortizes the fixed cost of a dispatch and fills the MXU, but
+waiting to fill a batch spends the queued requests' deadline slack.
+The classic dynamic-batching compromise (Clipper's adaptive batch
+sizing): flush a bucket when it is FULL, or when its most urgent request can no longer afford
 to wait for more arrivals.
 
 Geometry discipline: an online path must never hand XLA a shape it has

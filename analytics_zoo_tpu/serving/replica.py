@@ -64,7 +64,8 @@ import logging
 from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
                     Tuple)
 
-from analytics_zoo_tpu.resilience.errors import ReplicaWedged, StallError
+from analytics_zoo_tpu.resilience.errors import (ReplicaWedged, StallError,
+                                                 is_retryable)
 from analytics_zoo_tpu.resilience.watchdog import StallWatchdog
 from analytics_zoo_tpu.serving.batcher import AssembledBatch
 from analytics_zoo_tpu.serving.request import DEFAULT_MODEL
@@ -141,6 +142,11 @@ class Replica:
         #: per-replica store (``ServingTier.evict_session``)
         self.tier_objs: Dict[str, List[Any]] = {}
         self._fence_t: Optional[float] = None
+        #: geometries whose forward has COMPLETED here at least once.  A
+        #: geometry's first forward traces, lowers and compiles it, so
+        #: its failure is the program's (a lowering/Mosaic error every
+        #: replica would repeat), not this replica's
+        self._ran: Set[GeometryKey] = set()
         # one time-source convention (utils.clock): the watchdog takes
         # the Clock object itself since PR 7, no .now unwrapping
         self.watchdog = StallWatchdog(
@@ -202,23 +208,44 @@ class Replica:
         # compile leaves the geometry cold for the restarted replica)
         self.warm_keys.add((batch.model, batch.edge, batch.tier))
 
+    def warm(self, batch: AssembledBatch) -> float:
+        """Run one geometry OFF the dispatch path, so its compile happens
+        before traffic does: no watchdog, deadline or fence (a compile is
+        not a stall) and nothing caught (a compile error is the
+        caller's to see).  Returns the wall seconds, compile included."""
+        t0 = self.clock.now()
+        self._fn_for(batch)(batch.batch)
+        self._ran.add((batch.model, batch.edge, batch.tier))
+        return self.clock.now() - t0
+
     def forward(self, batch: AssembledBatch,
                 fault: Optional[Callable[["Replica"], None]] = None) -> Any:
         """Run one batch under stall supervision.  ``fault`` (chaos) runs
         just before the model fn — it may raise (crash) or advance the
         virtual clock (slow forward).  Raises :class:`ReplicaWedged` on
-        crash or deadline overrun; the POOL owns fencing/failover."""
+        a retryable crash or deadline overrun; the POOL owns
+        fencing/failover.  A PROGRAM error is not a replica fault and
+        propagates as itself: anything the failure classification calls fatal,
+        and anything the model fn raises the first time a geometry runs
+        here (that call compiles it) — fencing and failing over would
+        only repeat the same compile error on the next replica."""
         self.watchdog.beat()
         self.dispatches += 1
         self.inflight += 1
         t0 = self.clock.now()
         self._fence_t = (t0 + self.fence_budget_s
                          if self.fence_budget_s is not None else None)
+        key = (batch.model, batch.edge, batch.tier)
+        compiling = False
         try:
             if fault is not None:
                 fault(self)
             self._maybe_cold_compile(batch)
-            out = self._fn_for(batch)(batch.batch)
+            fn = self._fn_for(batch)
+            compiling = key not in self._ran
+            out = fn(batch.batch)
+            compiling = False
+            self._ran.add(key)
             if self.service_hook is not None:
                 # virtual time: the hook says how long this forward took
                 self.sleep_guarded(float(self.service_hook(batch,
@@ -226,6 +253,8 @@ class Replica:
         except ReplicaWedged:
             raise
         except Exception as e:
+            if compiling or not is_retryable(e):
+                raise
             raise ReplicaWedged(
                 f"replica {self.rid}: forward crashed mid-batch "
                 f"({type(e).__name__}: {e})") from e

@@ -776,6 +776,37 @@ class ServingRuntime:
             batch.batch["session"][i] = -1
             batch.batch["final"][i] = 0
 
+    # -- warm-up -------------------------------------------------------------
+    def warm(self, payload: Any, model: Optional[str] = None
+             ) -> Dict[Tuple[str, Any, int], float]:
+        """Compile every (edge, tier) geometry of ``model`` on every
+        replica before traffic arrives, by running each once on a batch
+        built from ``payload`` (an example request payload; a bucketed
+        model's variable axis is padded/cut to each edge) —
+        :meth:`Replica.warm`, off the dispatch path.  On a real clock
+        the first dispatch of a cold geometry would otherwise compile
+        for seconds under the wedge watchdog and the request deadlines.
+        Compile errors propagate.  Returns ``{(model, edge, tier):
+        slowest replica's seconds}``."""
+        cfg = self._resolve_model(model)
+        if cfg.streaming:
+            raise ValueError(
+                f"model {cfg.name!r} is a streaming session model — its "
+                f"forward mutates session state, there is no dry run")
+        now = self.clock.now()
+        took: Dict[Tuple[str, Any, int], float] = {}
+        for key in self._geometry_plan():
+            name, edge, tier = key
+            if name != cfg.name:
+                continue
+            req = Request(rid=-1, payload=payload, arrival_t=now,
+                          deadline_t=float("inf"),
+                          length=None if edge is FIXED else int(edge),
+                          model=name)
+            batch = self.batcher._collate([req], edge, tier, model=name)
+            took[key] = max(r.warm(batch) for r in self.pool.replicas)
+        return took
+
     # -- scheduler -----------------------------------------------------------
     def _tier_arg(self):
         if self._multi:

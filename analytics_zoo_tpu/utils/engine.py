@@ -50,6 +50,15 @@ def init(config: Optional[EngineConfig] = None) -> None:
     _initialized = True
 
 
+def on_tpu() -> bool:
+    """THE device predicate: whether this process's default JAX backend
+    is a TPU.  Pallas kernels compile through Mosaic when it holds and
+    run in interpret mode when it does not; ``backend="auto"``
+    selections key on it.  Asked in one place so no call site can grow
+    its own notion of "a TPU"."""
+    return jax.default_backend() == "tpu"
+
+
 def node_number() -> int:
     """Number of participating hosts (reference ``Engine.nodeNumber``)."""
     return jax.process_count()

@@ -18,7 +18,7 @@ This example runs BOTH paths on one long utterance:
 
 Without real multi-chip hardware, run on the virtual CPU mesh::
 
-    AZ_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/long_audio_asr.py --seconds 30
 """
 

@@ -45,7 +45,7 @@ from analytics_zoo_tpu.analysis.source import (
     OnePlacementSite,
     RegisteredMetricNames,
     SeededRngOnly,
-    TaxonomyComplete,
+    ErrorClassesComplete,
     default_rules,
     run_source_engine,
 )
@@ -202,8 +202,8 @@ class TestNoHostSyncInHotPathRule:
         assert got == []
 
 
-class TestTaxonomyCompleteRule:
-    RULES = [TaxonomyComplete(target="errors.py")]
+class TestErrorClassesCompleteRule:
+    RULES = [ErrorClassesComplete(target="errors.py")]
 
     def test_fires_on_unclassified_class_and_ghost_registration(
             self, tmp_path):
@@ -216,7 +216,7 @@ class TestTaxonomyCompleteRule:
         assert any("Orphan" in v.message and v.line == 3 for v in got)
         assert any("Ghost" in v.message for v in got)
 
-    def test_clean_on_fully_classified_taxonomy(self, tmp_path):
+    def test_clean_on_fully_classified_errors(self, tmp_path):
         got = _scan(tmp_path, "errors.py", (
             "from typing import Tuple, Type\n"
             "class A(RuntimeError):\n    pass\n"
@@ -437,7 +437,7 @@ class TestProgramEngine:
         assert _audit_one(jax.jit(f), (np.ones(3, np.float32),)) == []
 
     def test_collective_inventory_catches_misdeclared_specset(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         from analytics_zoo_tpu.parallel.specs import SpecSet
@@ -515,10 +515,10 @@ class TestRepoClean:
 
     def test_az_analyze_all_clean_within_budget(self, capsys):
         """``tools/az_analyze.py --all`` in-process: exit 0, the full
-        audit surface covered, inside the ≤30 s tier-1 budget (the 20 s
-        pin covered the 25-program surface; ISSUE 17 grew it to 32 —
-        rec/sentiment train+eval+serve — so the budget scales with it;
-        measured ~9 s on the 2-core CPU host)."""
+        audit surface covered, inside its tier-1 budget.  The budget is
+        sized for the installed JAX: tracing the 36-program surface takes
+        34 s under jax 0.9.0 on the 8-core host (PR 21), so 90 s flags a
+        surface that doubled, not a loaded machine."""
         import tools.az_analyze as az
         from analytics_zoo_tpu.analysis.targets import repo_audit_suite
 
@@ -527,7 +527,7 @@ class TestRepoClean:
         dt = time.time() - t0
         out = capsys.readouterr().out
         assert rc == 0, out
-        assert dt < 30.0, f"az-analyze --all took {dt:.1f}s (budget 30s)"
+        assert dt < 90.0, f"az-analyze --all took {dt:.1f}s (budget 90s)"
         assert "0 violation(s)" in out
         n = len(repo_audit_suite())
         assert n >= 21  # 6 pipelines × train+eval, ≥3+3+2+2 serving tiers
@@ -627,5 +627,5 @@ class TestRepoClean:
         assert az.main(["--list-rules"]) == 0
         out = capsys.readouterr().out
         for rule in ("one-clock", "one-placement-site", "seeded-rng-only",
-                     "no-host-sync-in-hot-path", "taxonomy-complete"):
+                     "no-host-sync-in-hot-path", "error-classes-complete"):
             assert rule in out

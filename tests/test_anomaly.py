@@ -1,5 +1,5 @@
 """Training anomaly sentinel: in-graph health word, skip → rollback →
-diverge ladder, forensics replay, and taxonomy completeness.
+diverge ladder, forensics replay, and classification completeness.
 
 The reference's only numerical guard is the MultiBoxLoss loss>50 skip
 (``MultiBoxLoss.scala:546``); everything here is new surface (see
@@ -185,7 +185,7 @@ class TestSentinel:
         assert AnomalyPolicy(reseek_batches=9).reseek == 9
 
 
-class TestTaxonomyCompleteness:
+class TestErrorClassCompleteness:
     def test_every_error_class_is_classified(self):
         """Every exception class defined in resilience.errors must be
         EXPLICITLY retryable or fatal — a new class can't silently fall
@@ -198,7 +198,7 @@ class TestTaxonomyCompleteness:
             and issubclass(obj, BaseException)
             and obj.__module__ == E.__name__
         }
-        assert declared, "taxonomy module defines no error classes?"
+        assert declared, "errors module defines no error classes?"
         classified = set(E._RETRYABLE_CLASSES) | set(E.FATAL_ERRORS)
         missing = {c.__name__ for c in declared - classified}
         assert not missing, f"unclassified error classes: {missing}"
@@ -225,7 +225,7 @@ class TestTaxonomyCompleteness:
         assert not is_retryable(ValueError("v"))
 
     def test_serving_classes_pinned_retryable(self):
-        """The serving-side taxonomy (PR 5): ServerOverloaded is the
+        """The serving-side classification (PR 5): ServerOverloaded is the
         explicit bounded-queue rejection (retry WITH backoff — a blind
         immediate retry re-creates the overload), RequestTimeout is a
         shed-before-dispatch (resubmit with a fresh deadline), and

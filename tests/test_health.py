@@ -1,6 +1,6 @@
 """Device-health sentinel tests (ISSUE 20): fingerprint sensitivity,
 minority-vote attribution, straggler hysteresis, chaos ``bit_flip``
-arming, detail-key validation, the error taxonomy pins, and the serving
+arming, detail-key validation, the error classification pins, and the serving
 pool's quarantine path.
 
 The full multi-device story (bit-flip detected within one audit
@@ -345,7 +345,7 @@ class TestFaultSpecDetailValidation:
         FaultSpec("burst_load", 1, batches=9, detail={"rate_x": 4.0})
 
 
-class TestTaxonomy:
+class TestErrorClasses:
     def test_device_quarantine_retryable_with_suspect(self):
         from analytics_zoo_tpu.resilience.errors import (
             _RETRYABLE_CLASSES, DeviceQuarantine, is_retryable)

@@ -54,6 +54,49 @@ def test_pallas_nms_max_output_truncates():
     assert (np.diff(kept_scores) <= 1e-6).all()  # score-ranked
 
 
+@pytest.mark.pallas(device=True)
+@pytest.mark.parametrize("n_rows", [8 * 20, 32 * 20])
+def test_compiled_sweep_matches_interpret_at_serving_geometry(n_rows):
+    """The sweep as the unfused serve path calls it, compiled by Mosaic:
+    one grid row per (image, foreground class) — B x 20 for SSD at B = 8
+    and 32 — over K = 512 lanes (nms_topk 400 rounded up to the lane
+    multiple), ragged valid prefixes.  Skipped off TPU."""
+    from analytics_zoo_tpu.ops.pallas_nms import nms_sweep
+
+    K = 512
+    rng = np.random.RandomState(1)
+    cx = rng.rand(n_rows, K, 2).astype(np.float32)
+    wh = (rng.rand(n_rows, K, 2) * 0.2 + 0.05).astype(np.float32)
+    x1, y1 = cx[..., 0] - wh[..., 0] / 2, cx[..., 1] - wh[..., 1] / 2
+    x2, y2 = cx[..., 0] + wh[..., 0] / 2, cx[..., 1] + wh[..., 1] / 2
+    valid = (np.arange(K)[None] < rng.randint(0, 400, (n_rows, 1))).astype(
+        np.float32)
+    got = np.asarray(nms_sweep(x1, y1, x2, y2, valid, interpret=False))
+    ref = np.asarray(nms_sweep(x1, y1, x2, y2, valid, interpret=True))
+    np.testing.assert_array_equal(got, ref)
+    assert got.sum() > n_rows          # the sweep kept and suppressed
+
+
+@pytest.mark.pallas(device=True)
+@pytest.mark.parametrize("resolution", [300, 512])
+def test_compiled_pallas_backend_matches_xla_at_ssd_geometry(resolution):
+    """backend="pallas" end to end at P = 8732 / 24564, C = 21, B = 8 —
+    the path "auto" falls to when the fused kernel does not fit."""
+    import dataclasses
+
+    from test_pallas_detout import _assert_rows_match, _ssd_inputs
+
+    from analytics_zoo_tpu.ops.detection_output import (
+        DetectionOutputParam, detection_output)
+
+    loc, conf, priors, variances = _ssd_inputs(resolution, 8)
+    p = DetectionOutputParam(backend="pallas")
+    got = np.asarray(detection_output(loc, conf, priors, variances, p))
+    ref = np.asarray(detection_output(
+        loc, conf, priors, variances, dataclasses.replace(p, backend="xla")))
+    _assert_rows_match(got, ref, atol=1e-4)
+
+
 class TestDetectionOutputPallasBackend:
     """The serving-path wiring: DetectionOutputParam(backend='pallas')
     must agree with the XLA backend end to end (VERDICT round-1 item 6)."""

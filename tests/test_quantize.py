@@ -413,7 +413,7 @@ class TestServingArtifact:
         model_file = str(tmp_path / "m.flax")
         m.save(model_file)
         out = str(tmp_path / "m_int8.npz")
-        env = dict(_os.environ, AZ_PLATFORM="cpu", PYTHONPATH=repo)
+        env = dict(_os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
         r = subprocess.run(
             [_sys.executable, _os.path.join(repo, "tools/export_serving.py"),
              "--model-file", model_file, "--arch", "ds2", "--hidden", "64",

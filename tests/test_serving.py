@@ -272,6 +272,107 @@ class TestFailover:
         assert rt.accounting()["by_state"] == {"done": 8}
 
 
+class TestWarmAndProgramErrors:
+    """The real-clock seams (ISSUE 21): geometries compile before traffic
+    through ``ServingRuntime.warm``, and a program error — the failure of
+    a geometry's FIRST forward on a replica, or anything the failure
+    classification calls fatal — propagates as itself instead of being
+    laundered into ``ReplicaWedged`` + fence + failover."""
+
+    def test_warm_runs_every_geometry_on_every_replica_off_the_books(self):
+        calls = []
+
+        def fwd(tag):
+            def forward(batch):
+                calls.append((tag, batch["input"].shape))
+                return _fwd(batch)
+            return forward
+
+        tiers = [ServingTier("fp", fwd("fp"), 1.0),
+                 ServingTier("int8", fwd("int8"), 0.6)]
+        rt = _runtime(VirtualClock(), tiers=tiers, bucket_edges=[4, 8])
+        took = rt.warm({"input": np.ones((3, 2), np.float32)})
+        # 2 edges x 2 tiers, each padded to its compiled geometry
+        # (max_batch rows, edge frames), on both replicas
+        assert sorted(took) == [("default", 4, 0), ("default", 4, 1),
+                                ("default", 8, 0), ("default", 8, 1)]
+        assert sorted(set(calls)) == [("fp", (4, 4, 2)), ("fp", (4, 8, 2)),
+                                      ("int8", (4, 4, 2)),
+                                      ("int8", (4, 8, 2))]
+        assert len(calls) == 8
+        # nothing was submitted, dispatched or supervised
+        assert rt.accounting()["submitted"] == 0
+        assert all(r.dispatches == 0 for r in rt.pool.replicas)
+
+    def test_warm_refuses_streaming_models(self):
+        from analytics_zoo_tpu.serving.runtime import ModelConfig
+
+        cfg = ModelConfig(name="asr", tiers=_tiers(1), streaming=True,
+                          tier_factory=lambda rid: _tiers(1))
+        rt = ServingRuntime(models=[cfg], n_replicas=1,
+                            clock=VirtualClock(),
+                            service_time=lambda m, e, n, t: 0.05)
+        with pytest.raises(ValueError, match="streaming"):
+            rt.warm({"input": np.ones((1, 2), np.float32)})
+
+    def test_first_forward_failure_of_a_geometry_is_not_a_wedge(self):
+        """A compile error surfaces at a geometry's first forward; here a
+        retryable-CLASS error stands in for it (Mosaic failures raise
+        ``JaxRuntimeError``).  No fence, no failover: it propagates."""
+        import jax
+
+        def broken(batch):
+            raise jax.errors.JaxRuntimeError(
+                "INTERNAL: Mosaic failed to compile TPU kernel")
+
+        rt = _runtime(VirtualClock(), tiers=[ServingTier("fp", broken, 1.0)])
+        with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+            rt.warm({"input": np.ones((1, 2), np.float32)})
+        rt.submit({"input": np.ones((1, 2), np.float32)})
+        with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+            rt.drain()
+        assert not [e for e in rt.pool.events
+                    if e["kind"] in ("replica_fenced", "failover")]
+
+    def test_runtime_error_after_a_geometry_ran_is_a_replica_fault(self):
+        """Once a geometry has completed a forward on a replica, a
+        retryable error from it is the replica's: fence + fail over."""
+        import jax
+
+        state = {"calls": 0}
+
+        def flaky(batch):
+            state["calls"] += 1
+            if state["calls"] == 3:        # after both replicas warmed
+                raise jax.errors.JaxRuntimeError("device lost")
+            return _fwd(batch)
+
+        rt = _runtime(VirtualClock(), tiers=[ServingTier("fp", flaky, 1.0)])
+        rt.warm({"input": np.ones((1, 2), np.float32)})
+        rt.submit({"input": np.ones((1, 2), np.float32)})
+        rt.drain()
+        assert rt.accounting()["by_state"] == {"done": 1}
+        assert [e["kind"] for e in rt.pool.events
+                if e["kind"] in ("replica_fenced", "failover")] \
+            == ["replica_fenced", "failover"]
+
+    def test_fatal_class_errors_always_propagate(self):
+        """A TypeError is a bug in the program, whenever it happens."""
+        state = {"calls": 0}
+
+        def buggy(batch):
+            state["calls"] += 1
+            if state["calls"] > 2:
+                raise TypeError("unsupported operand")
+            return _fwd(batch)
+
+        rt = _runtime(VirtualClock(), tiers=[ServingTier("fp", buggy, 1.0)])
+        rt.warm({"input": np.ones((1, 2), np.float32)})
+        rt.submit({"input": np.ones((1, 2), np.float32)})
+        with pytest.raises(TypeError, match="unsupported operand"):
+            rt.drain()
+
+
 class TestDegradationLadder:
     def test_hysteresis_down_and_up(self):
         ladder = DegradationLadder(3, LadderPolicy(down_after=2,

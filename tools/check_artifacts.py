@@ -54,13 +54,9 @@ EXTRA_STAMPED = frozenset({
 #: cannot be regenerated from this environment).  CLOSED SET — do not
 #: add to it; new artifacts must stamp obs.run_metadata().
 LEGACY = frozenset({
-    "BENCH_r01.json",
-    "BENCH_r03.json",
     "BENCH_r05.json",
-    "BENCH_r06.json",
     "BENCH_r07.json",
     "MFU_CEILING_r4mining.json",
-    "MULTICHIP_r01.json",
     "MULTICHIP_r02.json",
     "MULTICHIP_r03.json",
     "MULTICHIP_r04.json",
