@@ -262,13 +262,13 @@ class NoHostSyncInHotPath:
     ``block_until_ready``/``.item()`` is a full device round-trip that
     serializes the async dispatch pipeline (the overlap PR 2/PR 5 built),
     and ``np.asarray``/``np.array`` inside a jit-bound function either
-    fails on tracers or silently constant-folds a batch.  The ONE
-    sanctioned sync point is ``obs/probe.py`` — syncing is its
-    measurement, by design."""
+    fails on tracers or silently constant-folds a batch.  No module is
+    exempt: the step's input-wait / dispatch / fence split is measured
+    by the ``obs.stage``s round what the loop does anyway, without a
+    fence of its own."""
 
     name: str = "no-host-sync-in-hot-path"
     hot_modules: FrozenSet[str] = _HOT_MODULES
-    allowed: FrozenSet[str] = frozenset({"obs/probe.py"})
     _HOST_MATERIALIZE = frozenset({"numpy.asarray", "numpy.array",
                                    "jax.device_get"})
 
@@ -313,7 +313,7 @@ class NoHostSyncInHotPath:
         return spans
 
     def check(self, ctx: ModuleContext) -> Iterator[Violation]:
-        if ctx.rel in self.allowed or ctx.rel not in self.hot_modules:
+        if ctx.rel not in self.hot_modules:
             return
         spans = self._jit_bound_spans(ctx)
         for call in _calls(ctx.tree):
@@ -322,8 +322,8 @@ class NoHostSyncInHotPath:
                 yield Violation(
                     rule=self.name, file=ctx.display, line=call.lineno,
                     message="block_until_ready in a hot-path module — "
-                            "syncing belongs to obs/probe.py (or waive "
-                            "with the reason the sync is load-bearing)")
+                            "waive it with the reason the sync is "
+                            "load-bearing, or drop it")
                 continue
             if last == "item" and not call.args and not call.keywords:
                 yield Violation(

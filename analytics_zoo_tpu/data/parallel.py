@@ -62,7 +62,9 @@ import random
 import shutil
 import struct
 import tempfile
+import time
 import warnings
+from multiprocessing.sharedctypes import RawArray
 from typing import Any, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -71,12 +73,15 @@ from analytics_zoo_tpu.data.transformer import (ChainedTransformer,
                                                 ParallelTransformer,
                                                 Transformer,
                                                 walk_rngs)
+from analytics_zoo_tpu.obs import span as obs_span
 from analytics_zoo_tpu.resilience.errors import PrefetchWorkerDied
 
 logger = logging.getLogger("analytics_zoo_tpu")
 
 _DEFAULT_SLOT_BYTES = 32 << 20
 _POLL_S = 0.2
+# a worker's counters: indices into ``_Ring.counters``
+_CHAIN_S, _PUT_S, _WALK_S, _GROUPS, _ALIVE_S = range(5)
 
 
 # ---------------------------------------------------------------------------
@@ -301,6 +306,14 @@ class _Ring:
         self.free = ctx.Semaphore(slots)
         self.items = ctx.Semaphore(0)
         self.seq = 0            # producer- and consumer-side slot cursor
+        # what the producer spent, cumulative, for the parent to record
+        # when the pool closes (a forked worker can open no obs.stage):
+        # seconds in the per-sample chain, in put_group, reading groups
+        # that are another worker's; groups shipped; seconds alive.
+        # Single writer, so no lock.
+        self.counters = RawArray("d", 5)
+        self.born_t = obs_span.now()
+        self.spilled = 0        # consumer side: groups read from a file
 
     def close(self) -> None:
         try:
@@ -448,12 +461,16 @@ def _worker_main(worker_id: int, num_workers: int, epoch: int,
                  stream_keys: List[str],
                  chain: List[Transformer], group_size: int,
                  base_seed: int) -> None:
-    """Producer body (runs in a forked child; must never touch jax).
+    """Producer body (runs in a forked child; must never touch jax — so
+    it opens no ``obs.stage`` either: what it spends goes into
+    ``ring.counters``).
 
     Iterates the full raw stream (cheap), transforms only the groups
     owned by this shard, and ships them through the ring.  All
     randomness is pinned: worker-level RNGs from ``(base_seed, epoch,
     shard)``, per-sample RNGs folded in from the global stream index."""
+    clock, counters = time.perf_counter, ring.counters
+    t_born = clock()
     try:
         # per-worker base PRNG: worker-local decisions (none on the hot
         # path today, but the contract is part of the API)
@@ -473,7 +490,10 @@ def _worker_main(worker_id: int, num_workers: int, epoch: int,
 
         def flush() -> bool:
             if mine:
+                t = clock()
                 ok, spilled = ring.put_group(g, group, stop_event)
+                counters[_PUT_S] += clock() - t
+                counters[_GROUPS] += ok
                 if spilled and not warned[0]:
                     warned[0] = True
                     logger.warning(
@@ -483,26 +503,36 @@ def _worker_main(worker_id: int, num_workers: int, epoch: int,
                 return ok
             return True
 
+        t_group = clock()
         for sample in it:
             if stop_event.is_set():
                 return
             if mine:
+                t = clock()
                 seed_sample(chain, base_seed, epoch, idx)
                 out = _apply_chain(chain, sample)
+                counters[_CHAIN_S] += clock() - t
                 if out is not None:
                     group.append(out)
             idx += 1
             if idx % group_size == 0:
+                if not mine:
+                    counters[_WALK_S] += clock() - t_group
                 if not flush():
                     return
                 group = []
                 g += 1
                 mine = ((g % num_workers == worker_id)
                         and g >= start_group)
+                t_group = clock()
+                counters[_ALIVE_S] = t_group - t_born
         if idx % group_size:
+            if not mine:
+                counters[_WALK_S] += clock() - t_group
             if not flush():
                 return
             g += 1
+        counters[_ALIVE_S] = clock() - t_born
         ring.put(_KIND_END, g, b"", (), (), stop_event)
     except BaseException as e:  # noqa: BLE001 - shipped to the parent
         import traceback
@@ -681,6 +711,16 @@ class ParallelLoader:
             proc.start()
         return ring, proc
 
+    @staticmethod
+    def _record_worker(w: int, ring: _Ring) -> None:
+        """One ``az/input/worker`` record from what worker ``w`` counted
+        over its life."""
+        chain_s, put_s, walk_s, groups, alive_s = ring.counters
+        obs_span.record_stage(
+            "az/input/worker", ring.born_t, ring.born_t + alive_s,
+            worker=w, chain_s=chain_s, put_s=put_s, walk_s=walk_s,
+            groups=int(groups), spills=ring.spilled)
+
     def _merged_samples(self, epoch: int) -> Iterator[Any]:
         ctx = mp.get_context("fork")
         stop_event = ctx.Event()
@@ -693,13 +733,17 @@ class ParallelLoader:
         rings: List[_Ring] = []
         procs: List[mp.Process] = []
         respawns_left = self.max_respawns
-        for w in range(W):
-            ring, proc = self._spawn(ctx, w, epoch, 0, stop_event,
-                                     spill_dir)
-            rings.append(ring)
-            procs.append(proc)
         self._procs = procs
+        # open until the first group has arrived, so inside the
+        # consumer's first next() of the epoch — never across a yield
+        starting = obs_span.stage("az/input/pool_start",
+                                  workers=W).__enter__()
         try:
+            for w in range(W):
+                ring, proc = self._spawn(ctx, w, epoch, 0, stop_event,
+                                         spill_dir)
+                rings.append(ring)
+                procs.append(proc)
             g = 0
             total_groups: Optional[int] = None
             while total_groups is None or g < total_groups:
@@ -713,10 +757,15 @@ class ParallelLoader:
                 if kind == "end":
                     total_groups = payload
                     continue   # re-check the loop condition (g == total)
+                if starting is not None:
+                    starting.__exit__(None, None, None)
+                    starting = None
                 for sample in payload:
                     yield sample
                 g += 1
         finally:
+            if starting is not None:        # no sample ever arrived
+                starting.__exit__(None, None, None)
             # pool cleanup FIRST (a failing source advance must never
             # leave workers spinning on live rings)...
             stop_event.set()
@@ -725,7 +774,8 @@ class ParallelLoader:
                 if proc.is_alive():
                     proc.terminate()
                     proc.join(timeout=5.0)
-            for ring in rings:
+            for w, ring in enumerate(rings):
+                self._record_worker(w, ring)
                 ring.close()
             shutil.rmtree(spill_dir, ignore_errors=True)
             self._procs = []
@@ -766,6 +816,7 @@ class ParallelLoader:
                         "input worker %d died (exitcode %s); respawning "
                         "from group %d (%d respawns left)", w,
                         procs[w].exitcode, g, respawns_left - 1)
+                    self._record_worker(w, rings[w])
                     rings[w].close()
                     ring, proc = self._spawn(ctx, w, epoch, g, stop_event,
                                              spill_dir)
@@ -795,6 +846,7 @@ class ParallelLoader:
                     f"\n{tb}")
             if kind == _KIND_SPILL:
                 self.spills += 1
+                rings[w].spilled += 1
                 kind = _KIND_GRP
             if kind == _KIND_END:
                 if idx > g:  # pragma: no cover - protocol bug

@@ -12,6 +12,7 @@ import queue
 import threading
 from typing import Any, Iterable, Iterator, Optional
 
+from analytics_zoo_tpu.obs.span import stage
 from analytics_zoo_tpu.parallel import mesh as mesh_lib
 from analytics_zoo_tpu.resilience.errors import PrefetchWorkerDied
 
@@ -26,24 +27,31 @@ def _drain(q: "queue.Queue", stop: object, err: list, worker,
     timeout instead and, when the queue is empty AND the worker is dead,
     raise a descriptive error: the worker's recorded exception if it left
     one, else :class:`PrefetchWorkerDied`."""
-    while True:
-        try:
-            item = q.get(timeout=poll_s)
-        except queue.Empty:
-            if worker.is_alive():
-                continue
-            # worker is gone, so nothing more can be enqueued — but it
-            # may have delivered its tail (and the sentinel) between our
-            # timeout and the liveness check: drain before declaring death
+    def get():
+        while True:
             try:
-                item = q.get_nowait()
+                return q.get(timeout=poll_s)
             except queue.Empty:
-                if err:
-                    raise err[0]
-                raise PrefetchWorkerDied(
-                    "prefetch worker thread died without delivering its "
-                    "stop sentinel (no exception recorded) — input "
-                    "pipeline is gone; restart the attempt")
+                if worker.is_alive():
+                    continue
+                # worker is gone, so nothing more can be enqueued — but
+                # it may have delivered its tail (and the sentinel)
+                # between our timeout and the liveness check: drain
+                # before declaring death
+                try:
+                    return q.get_nowait()
+                except queue.Empty:
+                    if err:
+                        raise err[0]
+                    raise PrefetchWorkerDied(
+                        "prefetch worker thread died without delivering "
+                        "its stop sentinel (no exception recorded) — "
+                        "input pipeline is gone; restart the attempt")
+
+    while True:
+        # closed before the yield: a stage is never held across one
+        with stage("az/input/get_wait"):
+            item = get()
         if item is stop:
             if err:
                 raise err[0]
@@ -79,14 +87,21 @@ def device_prefetch(batches: Iterable[Any], mesh, size: int = 2,
 
     def worker():
         try:
-            for b in batches:
-                item = mesh_lib.shard_batch(b, mesh)
-                while not cancelled.is_set():
-                    try:
-                        q.put(item, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
+            source = iter(batches)
+            while True:
+                with stage("az/input/next"):
+                    b = next(source, stop)
+                if b is stop:
+                    break
+                with stage("az/input/place"):
+                    item = mesh_lib.shard_batch(b, mesh)
+                with stage("az/input/put_wait"):
+                    while not cancelled.is_set():
+                        try:
+                            q.put(item, timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
                 if cancelled.is_set():
                     return
         except BaseException as e:  # propagate to consumer

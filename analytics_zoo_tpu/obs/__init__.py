@@ -10,15 +10,16 @@ instrumentation are the pattern sources):
 - :mod:`span` — :class:`Span`/:class:`Tracer`: trace-ids threaded
   end-to-end (loader epoch/batch → train step → checkpoint; serving
   submit → queue → batch → dispatch → response) plus the
-  :func:`span_conservation` structural check;
+  :func:`span_conservation` structural check; and :func:`stage` /
+  :func:`stages`: always-on host stages of a batch, step or epoch, in
+  the profiler's trace and in one bounded ring (the input-wait /
+  dispatch / fence split of a train step, the stages of a serve batch);
 - :mod:`registry` — :class:`MetricRegistry`: counters, gauges,
   bounded-reservoir histograms, one snapshot schema;
 - :mod:`recorder` — :class:`FlightRecorder`: bounded ring buffer,
   deterministic JSONL black-box dump on terminal conditions;
 - :mod:`exporters` — JSONL dump, Prometheus text rendering,
   :class:`SummaryBridge` into the TensorBoard writers;
-- :mod:`probe` — :class:`StepProbe`: the dispatch / device /
-  input-wait step decomposition as a reusable API;
 - :mod:`runmeta` — :func:`run_metadata`: the artifact-stamping block
   ``tools/check_artifacts.py`` lints for;
 - :mod:`trace` — :class:`TraceStore`: indexed span trees over a flight
@@ -30,12 +31,13 @@ instrumentation are the pattern sources):
   item-1 autoscaler hook;
 - :mod:`names` — :data:`CATALOG`: every registry metric name declared
   once (the ``registered-metric-names`` az-analyze rule pins usage
-  against it).
+  against it); :data:`STAGES`: every stage name, likewise.
 
-Everything runs on the injected clock (``utils.clock``), so drills on a
-``VirtualClock`` produce byte-identical traces from a seed
-(``OBS_r01.json`` pins the sha256), and the layer's hot-path cost is
-banked, not assumed (``bench.py obs_overhead``).  Docs:
+Everything but the stages runs on the injected clock
+(``utils.clock``), so drills on a ``VirtualClock`` produce
+byte-identical traces from a seed (``OBS_r01.json`` pins the sha256),
+and the layer's hot-path cost is banked, not assumed (``bench.py
+obs_overhead``).  Docs:
 ``docs/OBSERVABILITY.md``.
 """
 
@@ -46,11 +48,10 @@ from typing import Optional
 from analytics_zoo_tpu.obs.exporters import (SummaryBridge,
                                              dump_flight_jsonl,
                                              render_prometheus)
-from analytics_zoo_tpu.obs.probe import StepProbe
 from analytics_zoo_tpu.obs.recorder import DEFAULT_CAPACITY, FlightRecorder
 from analytics_zoo_tpu.obs.registry import (Counter, Gauge, MetricRegistry,
                                             ReservoirHistogram)
-from analytics_zoo_tpu.obs.names import CATALOG
+from analytics_zoo_tpu.obs.names import CATALOG, STAGES
 from analytics_zoo_tpu.obs.runmeta import run_metadata
 from analytics_zoo_tpu.obs.slo import (SLO, SloDecision, SloEvaluator,
                                        deadline_miss_slo,
@@ -58,7 +59,8 @@ from analytics_zoo_tpu.obs.slo import (SLO, SloDecision, SloEvaluator,
                                        model_deadline_miss_slo,
                                        model_shed_rate_slo, model_slos,
                                        p99_latency_slo, shed_rate_slo)
-from analytics_zoo_tpu.obs.span import Span, Tracer, span_conservation
+from analytics_zoo_tpu.obs.span import (Span, StageRecord, Tracer,
+                                        span_conservation, stage, stages)
 from analytics_zoo_tpu.obs.trace import (SEGMENTS, TraceStore,
                                          attribution_rows,
                                          format_critical_path)
@@ -120,7 +122,8 @@ __all__ = [
     "SloDecision",
     "SloEvaluator",
     "Span",
-    "StepProbe",
+    "STAGES",
+    "StageRecord",
     "SummaryBridge",
     "TraceStore",
     "Tracer",
@@ -137,4 +140,6 @@ __all__ = [
     "run_metadata",
     "shed_rate_slo",
     "span_conservation",
+    "stage",
+    "stages",
 ]

@@ -183,14 +183,67 @@ CATALOG: Dict[str, str] = {
         "gauge · undecodable records dropped (skip-and-count)",
     "data/read/skipped_shards":
         "gauge · whole shards dropped after retry exhaustion",
-    # -- step decomposition probe (obs.StepProbe) ---------------------------
-    "probe/input_wait_s":
-        "histogram · per-step blocking time on the input pipeline",
-    "probe/dispatch_s":
-        "histogram · per-step host dispatch time (call until return)",
-    "probe/device_s":
-        "histogram · per-step device wait (return until "
-        "block_until_ready)",
+}
+
+#: Every :func:`analytics_zoo_tpu.obs.stage` name, declared once with
+#: the thread it runs on and the code it brackets.  Stages land in the
+#: profiler's trace and in the process's stage ring (``obs.stages()``),
+#: not in a registry, so they have no kind.  ``az/input/worker`` is the
+#: one record that no ``stage`` writes: a forked worker must never touch
+#: JAX, so it counts seconds into shared memory and the parent records
+#: them when the epoch's pool closes.
+STAGES: Dict[str, str] = {
+    "az/input/next":
+        "prefetch thread · the loader's next(): one host batch",
+    "az/input/place":
+        "prefetch thread · shard_batch: the batch's transfer to the mesh",
+    "az/input/put_wait":
+        "prefetch thread · the put into the bounded prefetch queue "
+        "(blocked = the loader is ahead of the train loop)",
+    "az/input/get_wait":
+        "main thread · the get from the prefetch queue up to the item's "
+        "arrival (blocked = the train loop is starved)",
+    "az/input/pool_start":
+        "prefetch thread · ParallelLoader epoch start: forking the "
+        "workers until the first sample is ready to yield (inside the "
+        "epoch's first az/input/next)",
+    "az/input/worker":
+        "record, one a worker an epoch · the worker's lifetime; attrs: "
+        "chain_s (decode + augment of its own samples), put_s (copy "
+        "into the ring + blocked on full slots), walk_s (reading "
+        "samples that are another worker's), groups shipped, spills",
+    "az/train/prepare":
+        "main thread · from the batch's arrival to the step's call: its "
+        "size, the choice of step program, and place_batch where neither "
+        "the prefetch thread nor the jit places it",
+    "az/train/dispatch":
+        "main thread · the train step's call (asynchronous dispatch)",
+    "az/train/summary":
+        "main thread · TrainSummary.add_scalar of loss and learning "
+        "rate: the loss's float() is the step's fence",
+    "az/train/boundary":
+        "main thread · _boundary_checks (validation, checkpoint, stall, "
+        "preemption) and end_when",
+    "az/serve/pump":
+        "main thread · ServingRuntime.pump(), whole",
+    "az/serve/collate":
+        "main thread · DynamicBatcher._collate: pad and np.stack the "
+        "batch's payloads",
+    "az/serve/forward":
+        "main thread · ReplicaPool.dispatch of one batch (replica "
+        "choice, watchdog, the tier's forward, failover)",
+    "az/serve/h2d":
+        "main thread · the SSD tier's jnp.asarray of the host batch: "
+        "the host's side of the transfer (staging and enqueue)",
+    "az/serve/dispatch":
+        "main thread · the SSD tier's detect_normalized call "
+        "(asynchronous dispatch of the serve program)",
+    "az/serve/result_wait":
+        "main thread · the SSD tier's np.asarray of the answer: waits "
+        "for the program and copies the detections to the host",
+    "az/serve/handout":
+        "main thread · _dispatch after the pool returns: canary, "
+        "finishing each request, accounting, _after_dispatch",
 }
 
 

@@ -11,15 +11,104 @@ Spans are recorded into the flight recorder when they END (one event
 per span, carrying start/end/duration), which keeps the hot path to two
 clock reads and one deque append — the cost the ``bench.py
 obs_overhead`` phase banks.
+
+:func:`stage` is the other kind of interval: a host STAGE of a batch, a
+step or an epoch (collate, transfer, dispatch, the wait on a queue), on
+the profiler's clock.  It needs no ``Observability`` bundle and is
+always on — see its docstring for what that forbids.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
-from typing import Any, Dict, List, Optional
+import threading
+from typing import Any, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 from analytics_zoo_tpu.obs.recorder import FlightRecorder
 from analytics_zoo_tpu.utils.clock import TimeSource, as_now_fn
+
+
+class StageRecord(NamedTuple):
+    """One closed :func:`stage`: ``t0``/``t1`` are ``time.monotonic()``
+    seconds, ``thread`` the ``threading.get_ident()`` it ran on."""
+
+    name: str
+    t0: float
+    t1: float
+    thread: int
+    attrs: Dict[str, Any]
+
+
+#: the stages' clock: real monotonic seconds, whatever clock a drill
+#: injects elsewhere — a stage is laid against the profiler's trace and a
+#: benchmark's own ``time.monotonic()`` stamps, not against modelled time
+now = as_now_fn(None)
+
+#: every closed stage of the process, oldest dropped first
+_STAGES: "collections.deque[StageRecord]" = collections.deque(maxlen=1 << 16)
+
+
+class stage:
+    """``with stage(name, **attrs):`` — one fully nested, per-thread host
+    stage.  On entry it opens a ``jax.profiler.TraceAnnotation(name)``:
+    with a profiler session running that is an event on the
+    ``/host:CPU`` plane, on the same clock as the device's ``XLA Ops``
+    line; with none it is a no-op of about a microsecond.  On exit (also
+    on an exception) it appends a :class:`StageRecord` to the process's
+    one bounded ring, which :func:`stages` reads.
+
+    Always on, because nothing outside the program can switch it on.
+    So: at batch / step / epoch granularity only — never per request,
+    per sample or inside a jitted function; never with a fence, a
+    ``block_until_ready`` or a device read that the code it brackets
+    does not make anyway; never held across a ``yield`` (annotations
+    close last-in-first-out on their thread).  Names are declared in
+    ``obs/names.py::STAGES``.
+
+    A generator that cannot use ``with`` (the stage ends before its
+    first ``yield``) calls ``__enter__`` / ``__exit__`` itself."""
+
+    __slots__ = ("name", "attrs", "_annotation", "_t0")
+
+    def __init__(self, name: str, **attrs: Any):
+        self.name, self.attrs = name, attrs
+
+    def __enter__(self) -> "stage":
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self._t0 = now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = now()
+        self._annotation.__exit__(*exc)
+        _STAGES.append(StageRecord(self.name, self._t0, t1,
+                                   threading.get_ident(), self.attrs))
+
+
+def record_stage(name: str, t0: float, t1: float, **attrs: Any) -> None:
+    """A stage measured where no :func:`stage` can go (a forked loader
+    worker reports its counters through shared memory; the parent
+    records them when the pool closes)."""
+    _STAGES.append(StageRecord(name, t0, t1, threading.get_ident(), attrs))
+
+
+def stages(since: Optional[float] = None) -> List[StageRecord]:
+    """The ring's records, oldest first; with ``since`` (a
+    ``time.monotonic()`` reading, e.g. a benchmark window's opening)
+    only the stages that began at or after it."""
+    while True:
+        try:
+            out = list(_STAGES)
+            break
+        except RuntimeError:        # another thread appended meanwhile
+            continue
+    if since is not None:
+        out = [r for r in out if r.t0 >= since]
+    return out
 
 
 class Span:

@@ -29,6 +29,7 @@ import optax
 from flax import struct
 
 from analytics_zoo_tpu.core.module import Model, accepted_kwargs
+from analytics_zoo_tpu.obs.span import stage
 from analytics_zoo_tpu.parallel import mesh as mesh_lib
 from analytics_zoo_tpu.parallel.optim import (
     Adam,
@@ -694,9 +695,10 @@ class Optimizer:
         latency, not device wall time (with the anomaly sentinel armed
         its per-step health fetch makes it ≈wall).  A deliberate
         choice: fencing every step to measure it would serialize the
-        pipeline the PR-2 work overlapped.  For the fenced
-        dispatch/device/input-wait decomposition use
-        :class:`analytics_zoo_tpu.obs.StepProbe` on a probe run.
+        pipeline the PR-2 work overlapped.  The input-wait / dispatch /
+        fence split of a step needs no arming: it is the always-on
+        stages ``az/input/get_wait`` / ``az/train/dispatch`` /
+        ``az/train/summary`` (``obs.stages()``, docs/OBSERVABILITY.md).
         Cost is banked by ``bench.py obs_overhead`` (≤ 3 % per step);
         ``None`` builds a default bundle."""
         from analytics_zoo_tpu.obs import Observability
@@ -892,7 +894,6 @@ class Optimizer:
                 epoch_iter = iter(epoch_batches)
                 try:
                     for batch in epoch_iter:
-                        n = _batch_size(batch)
                         # prefetch path: already sharded on the worker
                         # thread.  jit fast path: the annotated step's
                         # in_shardings place the HOST batch (one
@@ -903,18 +904,21 @@ class Optimizer:
                         # un-annotated-batch step (the data-axis prefix
                         # is invalid for rank-0; place_batch replicates
                         # scalars, preserving the shard_batch contract).
-                        step_fn = train_step
-                        if batch_annotated and _has_scalar_leaf(batch):
-                            if scalar_step[0] is None:
-                                scalar_step[0] = build_step(
-                                    annotate_batches=False)
-                            step_fn = scalar_step[0]
-                            dev_batch = (batch if self.prefetch
-                                         else self.specs.place_batch(batch))
-                        else:
-                            dev_batch = (batch if (self.prefetch
-                                                   or jit_places)
-                                         else self.specs.place_batch(batch))
+                        with stage("az/train/prepare"):
+                            n = _batch_size(batch)
+                            step_fn = train_step
+                            if batch_annotated and _has_scalar_leaf(batch):
+                                if scalar_step[0] is None:
+                                    scalar_step[0] = build_step(
+                                        annotate_batches=False)
+                                step_fn = scalar_step[0]
+                                dev_batch = (
+                                    batch if self.prefetch
+                                    else self.specs.place_batch(batch))
+                            else:
+                                dev_batch = (
+                                    batch if (self.prefetch or jit_places)
+                                    else self.specs.place_batch(batch))
                         # device_transform is fused INSIDE train_step
                         step_span = None
                         if tracer is not None:
@@ -930,14 +934,16 @@ class Optimizer:
                                 epoch=loop.epoch,
                                 batch=self._iter_in_epoch)
                         try:
-                            if step_timer is None:
-                                state, metrics = step_fn(
-                                    state, dev_batch, self.optim.lr_scale)
-                            else:
-                                with step_timer.step(n):
+                            with stage("az/train/dispatch"):
+                                if step_timer is None:
                                     state, metrics = step_fn(
                                         state, dev_batch,
                                         self.optim.lr_scale)
+                                else:
+                                    with step_timer.step(n):
+                                        state, metrics = step_fn(
+                                            state, dev_batch,
+                                            self.optim.lr_scale)
                         except BaseException as e:
                             # an exception escaping the step (XLA error,
                             # watchdog interrupt) must still CLOSE the
@@ -1015,15 +1021,19 @@ class Optimizer:
                             step_span.end(status="ok")
                         if self.train_summary is not None:
                             # device arrays on purpose: add_scalar floats them
-                            # only when the tag's trigger fires
-                            self.train_summary.add_scalar(
-                                "Loss", metrics["loss"], loop.iteration)
-                            self.train_summary.add_scalar(
-                                "LearningRate", metrics["lr"], loop.iteration)
-                        self._boundary_checks(loop, state, eval_step,
-                                              wd, ph)
-                        if self.end_when(loop):
-                            stop = True
+                            # only when the tag's trigger fires (the loss's
+                            # float() is then the step's fence)
+                            with stage("az/train/summary"):
+                                self.train_summary.add_scalar(
+                                    "Loss", metrics["loss"], loop.iteration)
+                                self.train_summary.add_scalar(
+                                    "LearningRate", metrics["lr"],
+                                    loop.iteration)
+                        with stage("az/train/boundary"):
+                            self._boundary_checks(loop, state, eval_step,
+                                                  wd, ph)
+                            stop = bool(self.end_when(loop))
+                        if stop:
                             break
                 finally:
                     # early exit (end_when break / detector raise): release

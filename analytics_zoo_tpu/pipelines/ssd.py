@@ -30,6 +30,7 @@ from analytics_zoo_tpu.data import (
     overlap_window,
     pad_ragged,
 )
+from analytics_zoo_tpu.obs.span import stage
 from analytics_zoo_tpu.models import SSDVgg, build_priors, ssd300_config, ssd512_config
 from analytics_zoo_tpu.ops import (
     DetectionOutputParam,
@@ -890,7 +891,16 @@ def ssd_serving_tiers(model: Model, param: PreProcessParam,
 
     def fwd(pred: SSDPredictor) -> Callable[[Dict], np.ndarray]:
         def forward(batch: Dict) -> np.ndarray:
-            return np.asarray(pred.detect_normalized(batch["input"]))
+            # three stages of one batch: the host's side of the transfer
+            # (staging and enqueue), the program's asynchronous
+            # dispatch (detect_normalized's own jnp.asarray of a device
+            # array is free), and the wait for the answer
+            with stage("az/serve/h2d"):
+                x = jnp.asarray(batch["input"])
+            with stage("az/serve/dispatch"):
+                out = pred.detect_normalized(x)
+            with stage("az/serve/result_wait"):
+                return np.asarray(out)
         return forward
 
     def audit(pred: SSDPredictor) -> Callable[[], tuple]:

@@ -48,6 +48,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from analytics_zoo_tpu.data.bucket import edge_for
+from analytics_zoo_tpu.obs.span import stage
 from analytics_zoo_tpu.serving.request import (DEFAULT_MODEL,
                                                AdmissionQueue, Request)
 
@@ -269,8 +270,9 @@ class DeadlineBatcher:
                                  and self.bucket_of(r) == edge),
             limit=self.model_batch(model))
         m_tier = tiers.get(model, 0) if tiers is not None else int(tier)
-        return self._collate(taken, edge, m_tier, model=model,
-                             affinity=affinity)
+        with stage("az/serve/collate"):
+            return self._collate(taken, edge, m_tier, model=model,
+                                 affinity=affinity)
 
     def _collate(self, reqs: List[Request], edge: Any, tier: int,
                  model: str = DEFAULT_MODEL,

@@ -66,6 +66,7 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
 
 import numpy as np
 
+from analytics_zoo_tpu.obs.span import stage
 from analytics_zoo_tpu.resilience.errors import (ReplicaWedged,
                                                  ServerOverloaded)
 from analytics_zoo_tpu.serving.batcher import (AssembledBatch,
@@ -819,24 +820,28 @@ class ServingRuntime:
         assemble and dispatch every flush-ready batch.  Returns the
         number of batches dispatched.  Call after submits and after
         advancing the clock."""
-        self._swap_tick()
-        dispatched = 0
-        while True:
-            if self.parallel and not force \
-                    and not self.pool.any_free(self.clock.now()):
-                # every replica is serving concurrently — assembling a
-                # batch now would only burn its members' slack; expiry
-                # still ran on the previous iteration's next_batch
-                self.queue.expire()
-                break
-            batch = self.batcher.next_batch(self._tier_arg(), force=force)
-            if batch is None:
-                # no batch is flush-ready; expiry may still have shed —
-                # that counts toward the current decision window
-                break
-            self._dispatch(batch)
-            dispatched += 1
-        return dispatched
+        with stage("az/serve/pump"):
+            self._swap_tick()
+            dispatched = 0
+            while True:
+                if self.parallel and not force \
+                        and not self.pool.any_free(self.clock.now()):
+                    # every replica is serving concurrently — assembling
+                    # a batch now would only burn its members' slack;
+                    # expiry still ran on the previous iteration's
+                    # next_batch
+                    self.queue.expire()
+                    break
+                batch = self.batcher.next_batch(self._tier_arg(),
+                                                force=force)
+                if batch is None:
+                    # no batch is flush-ready; expiry may still have
+                    # shed — that counts toward the current decision
+                    # window
+                    break
+                self._dispatch(batch)
+                dispatched += 1
+            return dispatched
 
     def next_event_t(self) -> Optional[float]:
         """Parallel mode: the next virtual instant the pool changes
@@ -1293,46 +1298,51 @@ class ServingRuntime:
                     parent=spans["root"], tier=batch.tier,
                     batch=self._dispatch_idx)
         try:
-            out = self.pool.dispatch(batch, fault_for=self._fault_for)
+            with stage("az/serve/forward"):
+                out = self.pool.dispatch(batch, fault_for=self._fault_for)
         except ReplicaWedged as err:
-            now = self.clock.now()
-            for req in batch.requests:
-                if req.finished:        # scrubbed dead-session row
-                    continue
-                req.finish("failed", now, error=err)
-                self._account_terminal(req)
-                self.metrics.on_fail(model=model_label)
-                self._end_request_spans(req, "failed",
-                                        attempts=req.attempts)
-                if req.session is not None:
-                    # affine dispatch lost its replica (or wedged): the
-                    # session's carry state is gone — honest state loss
-                    self._kill_session(req, str(err))
-            if batch_span is not None:
-                batch_span.end(status="failed",
-                               redispatched=batch.redispatched)
-            self._after_dispatch(batch, t0, failed=True)
+            with stage("az/serve/handout"):
+                now = self.clock.now()
+                for req in batch.requests:
+                    if req.finished:        # scrubbed dead-session row
+                        continue
+                    req.finish("failed", now, error=err)
+                    self._account_terminal(req)
+                    self.metrics.on_fail(model=model_label)
+                    self._end_request_spans(req, "failed",
+                                            attempts=req.attempts)
+                    if req.session is not None:
+                        # affine dispatch lost its replica (or wedged):
+                        # the session's carry state is gone — honest
+                        # state loss
+                        self._kill_session(req, str(err))
+                if batch_span is not None:
+                    batch_span.end(status="failed",
+                                   redispatched=batch.redispatched)
+                self._after_dispatch(batch, t0, failed=True)
             return
-        now = self.clock.now()
-        rows = np.asarray(out)
-        self._maybe_canary(batch, rows, now)
-        for i, req in enumerate(batch.requests):
-            if req.finished:            # scrubbed dead-session row
-                continue
-            req.tier = batch.tier
-            req.finish("done", now,
-                       result=rows[i] if self.retain_requests else None)
-            self._account_terminal(req)
-            missed = now > req.deadline_t
-            self.metrics.on_complete(now - req.arrival_t, batch.tier,
-                                     missed=missed, model=model_label)
-            self._end_request_spans(req, "done", attempts=req.attempts,
-                                    missed=missed)
-            if req.final and req.session is not None:
-                self._release_session(req.session)
-        if batch_span is not None:
-            batch_span.end(status="done", redispatched=batch.redispatched)
-        self._after_dispatch(batch, t0, failed=False)
+        with stage("az/serve/handout"):
+            now = self.clock.now()
+            rows = np.asarray(out)
+            self._maybe_canary(batch, rows, now)
+            for i, req in enumerate(batch.requests):
+                if req.finished:            # scrubbed dead-session row
+                    continue
+                req.tier = batch.tier
+                req.finish("done", now,
+                           result=rows[i] if self.retain_requests else None)
+                self._account_terminal(req)
+                missed = now > req.deadline_t
+                self.metrics.on_complete(now - req.arrival_t, batch.tier,
+                                         missed=missed, model=model_label)
+                self._end_request_spans(req, "done", attempts=req.attempts,
+                                        missed=missed)
+                if req.final and req.session is not None:
+                    self._release_session(req.session)
+            if batch_span is not None:
+                batch_span.end(status="done",
+                               redispatched=batch.redispatched)
+            self._after_dispatch(batch, t0, failed=False)
 
     def _parallel_fault(self, replica: Replica) -> Tuple[bool, float, float]:
         """Chaos windows for the current dispatch index against
