@@ -52,6 +52,10 @@ CATALOG: Dict[str, str] = {
         "histogram · dispatched-batch fill fraction (n_valid/max_batch)",
     "serve/queue_depth":
         "histogram · admission-queue depth sampled at each dispatch",
+    "serve/staging_alloc":
+        "counter · batches (warm() included) whose staging buffer was "
+        "allocated or replaced instead of reused: stays at the number "
+        "of geometries when every model's rows keep their shape",
     # -- multiplexed fleet (ServingRuntime(models=...), ISSUE 14) -----------
     "serve/submitted/model=*":
         "counter · requests submitted per multiplexed model",
@@ -227,8 +231,10 @@ STAGES: Dict[str, str] = {
     "az/serve/pump":
         "main thread · ServingRuntime.pump(), whole",
     "az/serve/collate":
-        "main thread · DynamicBatcher._collate: pad and np.stack the "
-        "batch's payloads",
+        "main thread · DeadlineBatcher._collate: the batch's payloads "
+        "copied, padded, into the staging buffer kept for the geometry; "
+        "attrs: reused (False when the buffer was allocated or replaced "
+        "for this batch)",
     "az/serve/forward":
         "main thread · ReplicaPool.dispatch of one batch (replica "
         "choice, watchdog, the tier's forward, failover)",

@@ -83,7 +83,27 @@ class AssembledBatch:
     ``batch`` dict, the geometry it compiled under, and the dispatch
     bookkeeping the failover path reads (``redispatched``).  ``model``
     keys the replica's per-model forward table; ``affinity`` (set for
-    session batches) pins the dispatch to one replica."""
+    session batches) pins the dispatch to one replica.
+
+    **Who owns the bytes.**  ``batch[pad_key]`` is the batcher's staging
+    buffer of this ``(model, edge)``, filled in place: it is valid until
+    the NEXT batch of that geometry is assembled, and no longer.  A
+    forward must have finished reading it when it returns, and must copy
+    whatever it keeps (a row, a view, a zero-copy device alias).  The
+    runtime keeps its side: ``pump`` assembles the next batch only after
+    ``_dispatch`` has handed the answers out, and the hand-out copies an
+    answer that shares memory with the buffer before a request retains
+    it.  The shipped tiers keep theirs by ONE fence, the fetch of the
+    answer (``np.asarray(out)``): on the chip the runtime may read the
+    host buffer until the transfer ``jnp.asarray`` started completes,
+    and on a CPU backend ``jnp.asarray`` of an aligned array may alias
+    it with no copy at all — either way the program that consumed the
+    input has run when its answer is on the host.  The SSD tier relies
+    on that fetch (``az/serve/result_wait``), not on the transfer's
+    completion, which it never waits for by itself.  The small vectors
+    (``length_key``, ``session``, ``final``) are new for every batch.
+    ``staging_reused`` is False when the buffer was allocated or
+    replaced for this batch (``serve/staging_alloc`` counts those)."""
 
     requests: List[Request]
     batch: Dict[str, Any]
@@ -93,6 +113,7 @@ class AssembledBatch:
     redispatched: bool = False      # exactly-once failover latch
     model: str = DEFAULT_MODEL
     affinity: Optional[int] = None
+    staging_reused: bool = True
 
     @property
     def earliest_deadline(self) -> float:
@@ -152,6 +173,13 @@ class DeadlineBatcher:
         #: feeds these from the SLO burn rates each decision window
         self._weights: Dict[str, float] = {}
         self._weighted = False
+        #: per (model, edge): (staging buffer of shape (cap,) + row
+        #: shape, rows the last batch assembled in it left non-zero).
+        #: Tier is no part of the key (every tier of a model takes the
+        #: same input), and a batch of another row shape or dtype
+        #: REPLACES its geometry's buffer, so what is held is bounded by
+        #: the geometries, never by traffic.
+        self._staging: Dict[Tuple[str, Any], Tuple[np.ndarray, int]] = {}
 
     def _plan(self, model: str) -> ModelPlan:
         try:
@@ -270,39 +298,65 @@ class DeadlineBatcher:
                                  and self.bucket_of(r) == edge),
             limit=self.model_batch(model))
         m_tier = tiers.get(model, 0) if tiers is not None else int(tier)
-        with stage("az/serve/collate"):
-            return self._collate(taken, edge, m_tier, model=model,
-                                 affinity=affinity)
+        with stage("az/serve/collate") as collate:
+            batch = self._collate(taken, edge, m_tier, model=model,
+                                  affinity=affinity)
+            collate.attrs["reused"] = batch.staging_reused
+        return batch
 
     def _collate(self, reqs: List[Request], edge: Any, tier: int,
                  model: str = DEFAULT_MODEL,
                  affinity: Optional[int] = None) -> AssembledBatch:
         """Pad rows to the bucket edge and the batch axis to the model's
-        batch size — both geometries already compiled."""
+        batch size — both geometries already compiled — in the staging
+        buffer this batcher keeps for ``(model, edge)``: a new array of
+        a batch's size costs its page faults again for every batch, the
+        kept one only the copy.  The bytes are those ``np.stack`` of the
+        padded rows gave (its promoted dtype, its ``ValueError`` for
+        rows of different shapes); see :class:`AssembledBatch` for how
+        long they stay valid."""
         plan = self._plan(model)
         cap = self.model_batch(model)
-        rows, lengths = [], []
-        for r in reqs:
-            arr = np.asarray(r.payload[plan.pad_key]
-                             if isinstance(r.payload, dict) else r.payload)
-            if edge is not FIXED:
-                n = min(int(r.length if r.length is not None
-                            else arr.shape[0]), int(edge), arr.shape[0])
-                padded = np.zeros((int(edge),) + arr.shape[1:], arr.dtype)
-                padded[:n] = arr[:n]
-                rows.append(padded)
-                lengths.append(n)
-            else:
-                rows.append(arr)
-                lengths.append(arr.shape[0] if arr.ndim else 0)
-        n_valid = len(rows)
+        arrs = [np.asarray(r.payload[plan.pad_key]
+                           if isinstance(r.payload, dict) else r.payload)
+                for r in reqs]
+        # everything that can refuse the rows comes before the first
+        # write, so a refused batch leaves the buffer as it was; a row of
+        # another shape would broadcast into its slot silently
+        skip = 0 if edge is FIXED else 1
+        row_shape = arrs[0].shape[skip:]
+        if any(a.shape[skip:] != row_shape for a in arrs):
+            raise ValueError("all input arrays must have the same shape")
+        lengths = []
+        if edge is not FIXED:
+            row_shape = (int(edge),) + row_shape
+            lengths = [min(int(r.length if r.length is not None
+                               else arr.shape[0]), int(edge), arr.shape[0])
+                       for r, arr in zip(reqs, arrs)]
+        shape = (cap,) + row_shape
+        dtype = np.result_type(*dict.fromkeys(a.dtype for a in arrs))
+        buf, dirty = self._staging.get((model, edge), (None, 0))
+        reused = buf is not None and buf.shape == shape \
+            and buf.dtype == dtype
+        if not reused:      # zeroed, so no row of it is dirty
+            buf, dirty = np.zeros(shape, dtype), 0
+        zero = np.zeros((), dtype)      # '' for strings, where 0 reads '0'
+        if edge is FIXED:
+            for i, arr in enumerate(arrs):
+                buf[i] = arr
+        else:
+            for i, (arr, n) in enumerate(zip(arrs, lengths)):
+                buf[i, :n] = arr[:n]
+                buf[i, n:] = zero
+        n_valid = len(arrs)
         pad = cap - n_valid
-        if pad:
-            rows.extend(np.zeros_like(rows[0]) for _ in range(pad))
-            lengths.extend(0 for _ in range(pad))
-        batch: Dict[str, Any] = {plan.pad_key: np.stack(rows)}
+        if dirty > n_valid:
+            buf[n_valid:dirty] = zero
+        self._staging[(model, edge)] = (buf, n_valid)
+        batch: Dict[str, Any] = {plan.pad_key: buf}
         if edge is not FIXED and plan.length_key:
-            batch[plan.length_key] = np.asarray(lengths, np.int32)
+            batch[plan.length_key] = np.asarray(lengths + [0] * pad,
+                                                np.int32)
         if plan.streaming:
             sess = [(-1 if r.session is None else int(r.session))
                     for r in reqs] + [-1] * pad
@@ -311,4 +365,4 @@ class DeadlineBatcher:
             batch["final"] = np.asarray(fin, np.int8)
         return AssembledBatch(requests=reqs, batch=batch, edge=edge,
                               n_valid=n_valid, tier=tier, model=model,
-                              affinity=affinity)
+                              affinity=affinity, staging_reused=reused)

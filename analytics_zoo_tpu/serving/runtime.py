@@ -805,6 +805,7 @@ class ServingRuntime:
                           length=None if edge is FIXED else int(edge),
                           model=name)
             batch = self.batcher._collate([req], edge, tier, model=name)
+            self._count_staging(batch)
             took[key] = max(r.warm(batch) for r in self.pool.replicas)
         return took
 
@@ -1264,8 +1265,25 @@ class ServingRuntime:
         self._fill_ewma[batch.model] = (
             fill if prev is None else 0.8 * prev + 0.2 * fill)
 
+    def _count_staging(self, batch: AssembledBatch) -> None:
+        if not batch.staging_reused:
+            self.metrics.registry.counter("serve/staging_alloc").inc()
+
+    def _answer_rows(self, batch: AssembledBatch, out: Any) -> np.ndarray:
+        """The tier's answer as rows to hand out.  The batch's input is
+        the batcher's staging buffer, which the next batch of the
+        geometry overwrites (:class:`AssembledBatch`): an answer that
+        shares memory with it — a tier that returns its input, or a
+        view of it — is copied before a request retains a row of it."""
+        rows = np.asarray(out)
+        if self.retain_requests and np.shares_memory(
+                rows, batch.batch[self.models[batch.model].pad_key]):
+            rows = rows.copy()
+        return rows
+
     def _dispatch(self, batch: AssembledBatch) -> None:
         self._scrub_dead_session_rows(batch)
+        self._count_staging(batch)
         if self.parallel:
             self._dispatch_parallel(batch)
             return
@@ -1323,7 +1341,7 @@ class ServingRuntime:
             return
         with stage("az/serve/handout"):
             now = self.clock.now()
-            rows = np.asarray(out)
+            rows = self._answer_rows(batch, out)
             self._maybe_canary(batch, rows, now)
             for i, req in enumerate(batch.requests):
                 if req.finished:            # scrubbed dead-session row
@@ -1452,7 +1470,7 @@ class ServingRuntime:
                 # irreversible — a replica paying warm taxes for new
                 # (model, edge, tier) keys must not be flagged for it
                 self._note_device_health(replica, service)
-            rows = np.asarray(out)
+            rows = self._answer_rows(batch, out)
             self._maybe_canary(batch, rows, now)
             for i, req in enumerate(batch.requests):
                 if req.finished:        # scrubbed dead-session row

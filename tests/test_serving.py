@@ -143,6 +143,308 @@ class TestBatchAssembly:
         assert all(r.state == "done" for r in rt.requests)
 
 
+def _reference_batch(reqs, edge, cap, plan):
+    """What ``_collate`` built before it kept a staging buffer, kept as
+    the reference: every row padded into a new array of its own, pad
+    rows of zeros, ``np.stack`` of them all."""
+    rows, lengths = [], []
+    for r in reqs:
+        arr = np.asarray(r.payload[plan.pad_key])
+        if edge is FIXED:
+            rows.append(arr)
+            continue
+        n = min(int(r.length if r.length is not None else arr.shape[0]),
+                int(edge), arr.shape[0])
+        padded = np.zeros((int(edge),) + arr.shape[1:], arr.dtype)
+        padded[:n] = arr[:n]
+        rows.append(padded)
+        lengths.append(n)
+    pad = cap - len(rows)
+    rows.extend(np.zeros_like(rows[0]) for _ in range(pad))
+    batch = {plan.pad_key: np.stack(rows)}
+    if edge is not FIXED and plan.length_key:
+        batch[plan.length_key] = np.asarray(lengths + [0] * pad, np.int32)
+    if plan.streaming:
+        batch["session"] = np.asarray(
+            [r.session for r in reqs] + [-1] * pad, np.int64)
+        batch["final"] = np.asarray(
+            [int(r.final) for r in reqs] + [0] * pad, np.int8)
+    return batch
+
+
+def _rows(rng, shapes, dtype=np.float32, **request):
+    """One request a shape, payloads without a zero in them (so a stale
+    byte of an earlier batch cannot pass for padding)."""
+    none = [None] * len(shapes)
+    return [Request(rid=i, arrival_t=0.0, deadline_t=1.0,
+                    payload={"input": np.asarray(rng.rand(*shape) + 1,
+                                                 dtype)},
+                    length=request.get("lengths", none)[i],
+                    session=request.get("sessions", none)[i],
+                    final=bool(request.get("finals", none)[i]))
+            for i, shape in enumerate(shapes)]
+
+
+#: name → (plan, [(edge, request shapes, request keywords), ...]): the
+#: batches of one geometry in the order one batcher assembles them
+STAGING_SCRIPTS = {
+    "fixed_full": (
+        {}, [(FIXED, [(5, 3)] * 4, {})]),
+    "fixed_short_after_full": (
+        {}, [(FIXED, [(5, 3)] * 4, {}), (FIXED, [(5, 3)], {}),
+             (FIXED, [(5, 3)] * 3, {}), (FIXED, [(5, 3)] * 2, {})]),
+    "bucketed_lengths_shrink": (
+        {"bucket_edges": [8]},
+        [(8, [(8, 2), (7, 2), (8, 2)], {"lengths": [8, 7, 8]}),
+         (8, [(3, 2), (6, 2), (1, 2)], {"lengths": [3, 6, 1]}),
+         # the declared length cuts a longer payload; one longer than
+         # the edge is cut to the edge
+         (8, [(6, 2), (11, 2)], {"lengths": [2, 11]})]),
+    "streaming": (
+        {"bucket_edges": [6], "length_key": "n_samples", "streaming": True},
+        [(6, [(6,), (6,), (4,)], {"lengths": [6, 6, 4],
+                                  "sessions": [7, 9, 11],
+                                  "finals": [False, False, True]}),
+         (6, [(2,)], {"lengths": [2], "sessions": [9], "finals": [True]})]),
+    "scalar_rows": (
+        {}, [(FIXED, [()] * 3, {}), (FIXED, [()], {})]),
+}
+
+
+class TestStagingBuffer:
+    """ISSUE 27: ``_collate`` fills the staging buffer the batcher keeps
+    for the geometry.  The bytes are those ``np.stack`` gave; the buffer
+    is one a geometry; nobody who kept an answer sees the next batch."""
+
+    CAP = 4
+
+    def _batcher(self, **plan):
+        from analytics_zoo_tpu.serving.batcher import ModelPlan
+
+        clock = VirtualClock()
+        b = DeadlineBatcher(AdmissionQueue(64, clock), max_batch=self.CAP,
+                            plans={"default": ModelPlan(**plan)})
+        return b, b.plans["default"]
+
+    @pytest.mark.parametrize("script", sorted(STAGING_SCRIPTS))
+    def test_bytes_are_those_np_stack_gave(self, script):
+        plan_kw, batches = STAGING_SCRIPTS[script]
+        b, plan = self._batcher(**plan_kw)
+        rng = np.random.RandomState(27)
+        for k, (edge, shapes, kw) in enumerate(batches):
+            reqs = _rows(rng, shapes, **kw)
+            got = b._collate(reqs, edge, 0)
+            want = _reference_batch(reqs, edge, self.CAP, plan)
+            assert sorted(got.batch) == sorted(want), (script, k)
+            for key, ref in want.items():
+                assert got.batch[key].dtype == ref.dtype, (script, k, key)
+                assert got.batch[key].tobytes() == ref.tobytes(), (
+                    script, k, key)
+            assert got.n_valid == len(reqs)
+            assert got.staging_reused == (k > 0)
+
+    @pytest.mark.parametrize("dtypes,promoted", [
+        ((np.float32, np.float64, np.float32), np.float64),
+        ((np.int8, np.uint8), np.int16),
+        ((np.int32, np.float32), np.float64),
+        ((np.float16,), np.float16),
+    ])
+    def test_rows_of_mixed_dtype_take_the_promoted_one(self, dtypes,
+                                                       promoted):
+        b, plan = self._batcher()
+        rng = np.random.RandomState(3)
+        reqs = [r for d in dtypes for r in _rows(rng, [(2, 3)], d)]
+        got = b._collate(reqs, FIXED, 0).batch["input"]
+        want = _reference_batch(reqs, FIXED, self.CAP, plan)["input"]
+        assert got.dtype == want.dtype == promoted
+        assert got.tobytes() == want.tobytes()
+        # the same rows in one dtype next: the buffer is replaced, not
+        # filled through a cast
+        again = b._collate(_rows(rng, [(2, 3)] * 2, dtypes[0]), FIXED, 0)
+        assert again.batch["input"].dtype == dtypes[0]
+        assert again.staging_reused == (dtypes[0] == promoted)
+
+    @pytest.mark.parametrize("edge,shapes", [
+        (FIXED, [(5, 3), (5, 1)]),        # would broadcast silently
+        (FIXED, [(5, 3), (3,)]),          # would broadcast silently
+        (FIXED, [(5, 3), (4, 3)]),
+        (8, [(6, 2), (6, 1)]),
+        (8, [(6, 2), (6,)]),
+    ])
+    def test_a_row_of_another_shape_is_refused_as_np_stack_does(
+            self, edge, shapes):
+        b, plan = self._batcher(bucket_edges=None if edge is FIXED else [8])
+        rng = np.random.RandomState(5)
+        full = _rows(rng, [shapes[0]] * self.CAP)
+        first = b._collate(full, edge, 0).batch["input"].copy()
+        reqs = _rows(rng, shapes)
+        with pytest.raises(ValueError, match="same shape") as theirs:
+            _reference_batch(reqs, edge, self.CAP, plan)
+        with pytest.raises(ValueError, match="same shape") as ours:
+            b._collate(reqs, edge, 0)
+        assert str(ours.value) == str(theirs.value)
+        # refused before a byte was written: the buffer's zero-padding
+        # bookkeeping still holds for the next batch
+        np.testing.assert_array_equal(
+            b._staging[("default", edge)][0], first)
+        ok = _rows(rng, [shapes[0]])
+        assert (b._collate(ok, edge, 0).batch["input"].tobytes()
+                == _reference_batch(ok, edge, self.CAP, plan)["input"]
+                .tobytes())
+
+    def test_one_buffer_a_geometry_replaced_when_the_rows_change(self):
+        from analytics_zoo_tpu.serving.batcher import ModelPlan
+
+        clock = VirtualClock()
+        plans = {"a": ModelPlan(bucket_edges=[4, 8]),
+                 "b": ModelPlan(max_batch=2)}
+        b = DeadlineBatcher(AdmissionQueue(64, clock), max_batch=self.CAP,
+                            plans=plans)
+        geometries = [("a", 4), ("a", 8), ("b", FIXED)]
+        rng = np.random.RandomState(11)
+        kept = {}
+        for round_ in range(3):
+            for tier in (0, 1):                 # tier is no part of the key
+                for model, edge in geometries:
+                    shape = (3, 2) if model == "a" else (5,)
+                    got = b._collate(_rows(rng, [shape] * 2), edge, tier,
+                                     model=model).batch["input"]
+                    first = kept.setdefault((model, edge), got)
+                    assert got is first and np.shares_memory(got, first)
+                    assert len(b._staging) <= len(geometries)
+        assert sorted(b._staging, key=str) == sorted(geometries, key=str)
+        assert kept[("b", FIXED)].shape == (2, 5)
+        # rows of another shape: the buffer of that geometry alone goes
+        wider = b._collate(_rows(rng, [(6,)]), FIXED, 0, model="b")
+        assert wider.staging_reused is False
+        assert wider.batch["input"].shape == (2, 6)
+        assert not np.shares_memory(wider.batch["input"], kept[("b", FIXED)])
+        assert b._staging[("a", 4)][0] is kept[("a", 4)]
+        assert len(b._staging) == len(geometries)
+        assert b._collate(_rows(rng, [(6,)]), FIXED, 1,
+                          model="b").batch["input"] is wider.batch["input"]
+
+    @pytest.mark.parametrize("parallel", [False, True],
+                             ids=["serial", "parallel"])
+    @pytest.mark.parametrize("answer", [
+        lambda batch: batch["input"],
+        lambda batch: batch["input"][:, :1],
+        lambda batch: batch["input"].reshape(-1, 6)[:, ::2],
+    ], ids=["input", "view", "strided"])
+    def test_a_tier_that_returns_its_input_cannot_leak_the_buffer(
+            self, answer, parallel):
+        clock = VirtualClock()
+        rt = ServingRuntime([ServingTier("echo", answer)], n_replicas=1,
+                            clock=clock, queue_capacity=16, max_batch=2,
+                            default_deadline_s=10.0, parallel_replicas=parallel,
+                            service_time=lambda e, n, t: 0.01)
+        rng = np.random.RandomState(2)
+        pictures = [rng.rand(3, 2).astype(np.float32) for _ in range(4)]
+        first = [rt.submit({"input": p}) for p in pictures[:2]]
+        rt.pump()
+        clock.advance(0.05)
+        rt.pump()
+        assert all(r.state == "done" for r in first)
+        before = [np.array(r.result) for r in first]
+        second = [rt.submit({"input": p}) for p in pictures[2:]]
+        clock.advance(0.05)
+        rt.drain()
+        assert all(r.state == "done" for r in second)
+        staging = rt.batcher._staging[("default", FIXED)][0]
+        for req, was, pic in zip(first, before, pictures):
+            np.testing.assert_array_equal(req.result, was)
+            np.testing.assert_array_equal(
+                req.result, answer({"input": pic[None]})[0])
+            assert not np.shares_memory(req.result, staging)
+        # the second batch did overwrite the rows the first one used
+        np.testing.assert_array_equal(staging[0], pictures[2])
+
+    def test_collate_stage_says_reused_and_the_registry_counts_allocs(self):
+        import time
+
+        from analytics_zoo_tpu import obs
+
+        clock = VirtualClock()
+        rt = _runtime(clock)
+        t0 = time.monotonic()
+        for _ in range(2):
+            for _ in range(4):
+                rt.submit({"input": np.ones((1, 2), np.float32)})
+            assert rt.pump() == 1
+        collates = [r for r in obs.stages(since=t0)
+                    if r.name == "az/serve/collate"]
+        assert [r.attrs for r in collates] == [{"reused": False},
+                                               {"reused": True}]
+        reg = rt.metrics.registry
+        assert reg.counter("serve/staging_alloc").value == 1
+        # warm() allocates off the books of no one: a warmed runtime's
+        # first batch reuses, and the counter says how many it took
+        warmed = _runtime(VirtualClock())
+        warmed.warm({"input": np.ones((1, 2), np.float32)})
+        for _ in range(4):
+            warmed.submit({"input": np.ones((1, 2), np.float32)})
+        t1 = time.monotonic()
+        assert warmed.pump() == 1
+        assert [r.attrs["reused"] for r in obs.stages(since=t1)
+                if r.name == "az/serve/collate"] == [True]
+        assert warmed.metrics.registry.counter(
+            "serve/staging_alloc").value == 1
+
+    def test_streaming_session_through_kept_rows_matches_offline(self):
+        """Chunks of shrinking lengths from two sessions share the rows
+        of one kept buffer over four batches: what ``StreamingDS2``
+        buffers across chunks must be its own (the tier hands it a view
+        of the staging row), and a row's tail the zeros of THIS chunk."""
+        import jax.numpy as jnp
+
+        from analytics_zoo_tpu.core.module import Model
+        from analytics_zoo_tpu.models import DeepSpeech2
+        from analytics_zoo_tpu.pipelines.deepspeech2 import (
+            StreamingDS2, ds2_streaming_tiers)
+        from analytics_zoo_tpu.serving import ModelConfig
+
+        model = Model(DeepSpeech2(hidden=16, n_rnn_layers=1,
+                                  bidirectional=False))
+        model.build(0, jnp.zeros((1, 50, 13), jnp.float32))
+        EDGE = 6000
+        cfg = ModelConfig(
+            name="ds2-stream", streaming=True,
+            tiers=ds2_streaming_tiers(model, chunk_frames=20),
+            tier_factory=lambda rid: ds2_streaming_tiers(
+                model, chunk_frames=20),
+            pad_key="input", length_key="n_samples",
+            bucket_edges=[EDGE], chunk_deadline_s=2.0)
+        clock = VirtualClock()
+        rt = ServingRuntime(models=[cfg], n_replicas=1, clock=clock,
+                            queue_capacity=32, max_batch=2,
+                            service_time=lambda m, e, n, t: 0.02)
+        rng = np.random.RandomState(4)
+        cuts = {0: [6000, 4100, 2900, 700], 1: [5000, 6000, 1300, 3300]}
+        utts = {s: (rng.randn(sum(c)) * 0.1).astype(np.float32)
+                for s, c in cuts.items()}
+        sids = {s: rt.open_session("ds2-stream") for s in cuts}
+        reqs = {s: [] for s in cuts}
+        buffers = set()
+        for k in range(4):
+            for s, c in cuts.items():
+                chunk = utts[s][sum(c[:k]):sum(c[:k + 1])]
+                reqs[s].append(rt.submit_chunk(
+                    sids[s], {"input": chunk}, length=len(chunk),
+                    final=(k == 3)))
+            clock.advance(0.1)
+            assert rt.pump() == 1
+            buffers.add(id(rt.batcher._staging[("ds2-stream", EDGE)][0]))
+        assert len(buffers) == 1
+        assert rt.accounting()["by_state"] == {"done": 8}
+        for s, c in cuts.items():
+            direct = StreamingDS2(model, chunk_frames=20)
+            pieces = [direct.accept(utts[s][sum(c[:k]):sum(c[:k + 1])])
+                      for k in range(4)]
+            pieces.append(direct.flush())
+            assert "".join(str(r.result) for r in reqs[s]) \
+                == "".join(pieces), s
+
+
 class TestEdfShedding:
     def test_edf_order_and_expiry(self):
         clock = VirtualClock()
