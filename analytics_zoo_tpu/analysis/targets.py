@@ -617,6 +617,35 @@ def _sentiment_serving(mesh) -> List[AuditProgram]:
     return _tier_targets("sentiment", tiers, specs)
 
 
+def _lm_serving(mesh) -> List[AuditProgram]:
+    """ISSUE 28: the decoder LM's session tier — its decode step (one
+    jitted call a batch of rows of any sessions) under the rung's own
+    name, and the prefill program of each chunk edge beside it."""
+    from analytics_zoo_tpu.models import lm
+    from analytics_zoo_tpu.parallel import pipeline_specs
+    from analytics_zoo_tpu.pipelines.lm import LMModel, lm_serving_tiers
+
+    cfg = lm.LMConfig(
+        d=32, kinds=(lm.FULL, lm.SLIDING), dense_layers=1,
+        full=lm.MLADims(4, 16, 8, 8, 4, 8, 8e7),
+        swa=lm.MLADims(2, 16, 12, 12, 4, 8, 5e4), window=5, idx_heads=4,
+        idx_dim=8, topk=4, f_dense=48, f_expert=16, f_shared=16, experts=8,
+        held=4, first_held=0, per_tok=2, route_scale=1.0, vocab=40,
+        eps=1e-5, dtype="float32")
+    model = LMModel(cfg, filled(lm.param_shapes(cfg)))
+    specs = pipeline_specs("lm", mesh=mesh)
+    tiers = lm_serving_tiers(model, cache_tokens=64, max_sessions=4,
+                             max_batch=4, page=4, max_len=32)
+    out = _tier_targets("lm", tiers, specs)
+    for edge in (4, 8):
+        def build(thunk=tiers[0].device_program_for(edge)) -> BuiltProgram:
+            fn, args, static = thunk()
+            return BuiltProgram(fn=fn, args=args, static_argnums=static,
+                                specs=specs)
+        out.append(AuditProgram(f"lm/serve:prefill{edge}", build))
+    return out
+
+
 def _fraud_swapped_serving(mesh) -> List[AuditProgram]:
     """ISSUE 18 (live weights): ``ServingRuntime.hot_swap`` rebuilds a
     family's tier stack from a RESTORED checkpoint pytree — plain
@@ -696,4 +725,7 @@ def repo_audit_suite(mesh=None) -> List[AuditProgram]:
                               mesh)
     targets += _guarded_tiers("rec", _rec_serving, mesh)
     targets += _guarded_tiers("sentiment", _sentiment_serving, mesh)
+    # ISSUE 28: the decoder LM is served, not trained: its session
+    # tier's decode and prefill programs are its whole audit surface
+    targets += _guarded_tiers("lm", _lm_serving, mesh)
     return targets

@@ -1,0 +1,494 @@
+"""A causal decoder LM of the ``dots3_note`` family, for serving through
+device-resident session caches (pipelines/lm.py): RMS-norm pre-norm
+residual blocks; multi-head latent attention (MLA) in two shapes — full
+layers with a learned sparse indexer that selects the ``index_topk``
+positions a token attends to, sliding layers over a window — each with a
+headwise sigmoid gate on the heads' outputs; a gated dense MLP in the
+leading layers, then sparse experts (sigmoid router of the published
+width, bias-corrected top-k, a shared expert) of which THIS CHIP HOLDS A
+SHARE (parallel/expert.py); embedding and head over a slice of the
+vocabulary.
+
+Functional, not flax: the step functions take the parameters and the
+cache and return the new cache, so one jitted call is a whole batch of
+rows and the cache is donated from call to call.
+
+``LMConfig.from_dict`` reads a HuggingFace-style ``config.json`` with the
+share beside it (``expert_share``); the parameter tree's names are the
+benchmark reference's (benchmarks/reference/lm.py), which is how the same
+weights reach both.
+
+Two step programs:
+
+- :func:`decode_step` — B rows of any sessions, one token each;
+- :func:`prefill_step` — one session's chunk of T tokens (chunked
+  prefill: a chunk of any length up to T, at any position).
+
+Both write the new tokens' cache entries, and both return float32 logits
+over the vocabulary slice at each row's last position, the tokens each held
+expert got, and the step's discrete CHOICES: ``{"selected": a full layer's
+selected positions (ops/lm_attention.py), "routed": (MoE layers, tokens,
+k) expert ids}``.  They stay on the device unless somebody fetches them
+(pipelines/lm.py records them for the sessions it is asked to).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from analytics_zoo_tpu.ops import lm_attention as att
+from analytics_zoo_tpu.parallel.expert import moe_held_experts
+
+F32 = jnp.float32
+FULL, SLIDING = "full_attention", "sliding_attention"
+#: a cache entry's width is rounded up to this many elements: the TPU
+#: tiles the minor axis by 128, and for a width of 576 its default layout
+#: puts the PAGE axis minor, so that every gather and scatter of a token's
+#: row first copies the whole pool into the other layout and back (seen
+#: in the compiled decode step: four 1.4 GB copies a step)
+LANE = 128
+#: heads a step of prefill's Pallas attention (ops/pallas_lm_prefill.py):
+#: at 4 the kernel's blocks and scratch take 25 MiB of VMEM at the
+#: published widths and 1,024 queries
+PREFILL_HEADS_PER_STEP = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class MLADims:
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+
+    @property
+    def scale(self) -> float:
+        return 1.0 / math.sqrt(self.nope + self.rope)
+
+    @property
+    def entry(self) -> int:
+        """Width of a token's cache entry: latent, rotary key, padding."""
+        return -(-(self.kv_rank + self.rope) // LANE) * LANE
+
+
+@dataclasses.dataclass(frozen=True)
+class LMConfig:
+    d: int
+    kinds: Tuple[str, ...]            # per layer: FULL or SLIDING
+    dense_layers: int                 # leading layers with a dense MLP
+    full: MLADims
+    swa: MLADims
+    window: int
+    idx_heads: int
+    idx_dim: int
+    topk: int
+    f_dense: int
+    f_expert: int
+    f_shared: int
+    experts: int                      # the router's (published) width
+    held: int                         # experts held here
+    first_held: int
+    per_tok: int
+    route_scale: float
+    vocab: int                        # rows of the vocabulary held here
+    eps: float
+    dtype: str = "bfloat16"
+
+    @classmethod
+    def from_dict(cls, cfg: Dict) -> "LMConfig":
+        n = int(cfg["num_hidden_layers"])
+        share = cfg.get("expert_share") or {
+            "published_experts": cfg["n_routed_experts"], "index": 0}
+        held = int(cfg["n_routed_experts"])
+        mla = lambda p, theta: MLADims(          # noqa: E731
+            heads=int(cfg[p + "num_attention_heads"]),
+            q_rank=int(cfg[p + "q_lora_rank"]),
+            kv_rank=int(cfg[p + "kv_lora_rank"]),
+            nope=int(cfg[p + "qk_nope_head_dim"]),
+            rope=int(cfg[p + "qk_rope_head_dim"]),
+            v=int(cfg[p + "v_head_dim"]), theta=float(cfg[theta]))
+        return cls(
+            d=int(cfg["hidden_size"]), kinds=tuple(cfg["layer_types"][:n]),
+            dense_layers=int(cfg["first_k_dense_replace"]),
+            full=mla("", "rope_theta"), swa=mla("swa_", "swa_rope_theta"),
+            window=int(cfg["sliding_window_size"]),
+            idx_heads=int(cfg["index_n_heads"]),
+            idx_dim=int(cfg["index_head_dim"]),
+            topk=int(cfg["index_topk"]),
+            f_dense=int(cfg["intermediate_size"]),
+            f_expert=int(cfg["moe_intermediate_size"]),
+            f_shared=int(cfg["moe_intermediate_size"])
+            * int(cfg["n_shared_experts"]),
+            experts=int(share["published_experts"]), held=held,
+            first_held=int(share["index"]) * held,
+            per_tok=int(cfg["num_experts_per_tok"]),
+            route_scale=float(cfg["routed_scaling_factor"]),
+            vocab=int(cfg["vocab_size"]), eps=float(cfg["rms_norm_eps"]),
+            dtype={"bf16": "bfloat16"}.get(cfg.get("compute_dtype",
+                                                   "bfloat16"),
+                                           cfg.get("compute_dtype",
+                                                   "bfloat16")))
+
+    @property
+    def n_full(self) -> int:
+        return sum(k == FULL for k in self.kinds)
+
+    @property
+    def n_sliding(self) -> int:
+        return sum(k == SLIDING for k in self.kinds)
+
+    def mla(self, kind: str) -> MLADims:
+        return self.full if kind == FULL else self.swa
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+def param_shapes(cfg: LMConfig) -> Dict:
+    """The parameter tree as shapes: {"layers": [...], "ends": {...}}."""
+    dt = jnp.dtype(cfg.dtype)
+    S = lambda *s: jax.ShapeDtypeStruct(s, dt)          # noqa: E731
+
+    def mlp(f, *lead):
+        return {"w_gate": S(*lead, cfg.d, f), "w_up": S(*lead, cfg.d, f),
+                "w_down": S(*lead, f, cfg.d)}
+
+    layers = []
+    for i, kind in enumerate(cfg.kinds):
+        a = cfg.mla(kind)
+        attn = {"wq_a": S(cfg.d, a.q_rank), "q_norm": S(a.q_rank),
+                "wq_b": S(a.q_rank, a.heads, a.nope + a.rope),
+                "wkv_a": S(cfg.d, a.kv_rank + a.rope),
+                "kv_norm": S(a.kv_rank),
+                "wkv_b": S(a.kv_rank, a.heads, a.nope + a.v),
+                "wo": S(a.heads, a.v, cfg.d), "w_gate": S(cfg.d, a.heads)}
+        if kind == FULL:
+            attn.update({"idx_wq_b": S(a.q_rank, cfg.idx_heads, cfg.idx_dim),
+                         "idx_wk": S(cfg.d, cfg.idx_dim),
+                         "idx_k_norm_w": S(cfg.idx_dim),
+                         "idx_k_norm_b": S(cfg.idx_dim),
+                         "idx_w": S(cfg.d, cfg.idx_heads)})
+        layer = {"attn_norm": S(cfg.d), "mlp_norm": S(cfg.d), "attn": attn}
+        if i < cfg.dense_layers:
+            layer["mlp"] = mlp(cfg.f_dense)
+        else:
+            layer["moe"] = {
+                "router_w": S(cfg.d, cfg.experts),
+                "router_b": jax.ShapeDtypeStruct((cfg.experts,), F32),
+                "experts": mlp(cfg.f_expert, cfg.held),
+                "shared": mlp(cfg.f_shared)}
+        layers.append(layer)
+    return {"layers": layers,
+            "ends": {"embed": S(cfg.vocab, cfg.d), "final_norm": S(cfg.d),
+                     "head": S(cfg.d, cfg.vocab)}}
+
+
+def init_params(cfg: LMConfig, seed: int = 0) -> Dict:
+    """Random parameters: normal of variance 1/fan_in (the first of a
+    matrix's contracted axes sets it), norm weights one, router bias 0."""
+    shapes = param_shapes(cfg)
+    leaves, tree = jax.tree_util.tree_flatten_with_path(shapes)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    out = []
+    for key, (path, s) in zip(keys, leaves):
+        name = jax.tree_util.keystr(path)
+        if "norm" in name and "_b" not in name:
+            out.append(jnp.ones(s.shape, s.dtype))
+        elif name.endswith("router_b']") or "norm_b" in name:
+            out.append(jnp.zeros(s.shape, s.dtype))
+        else:
+            fan = s.shape[-2] if len(s.shape) > 1 else s.shape[0]
+            if "wq_b" in name or "wkv_b" in name:
+                fan = s.shape[0]
+            elif name.endswith("wo']"):
+                fan = s.shape[0] * s.shape[1]
+            elif name.endswith("embed']"):
+                fan = 1
+            out.append((jax.random.normal(key, s.shape, F32)
+                        / math.sqrt(fan)).astype(s.dtype))
+    return jax.tree_util.tree_unflatten(tree, out)
+
+
+# ---------------------------------------------------------------------------
+# the cache
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class CacheGeometry:
+    """``n_pages`` pages of ``page`` tokens for the full layers (page 0 is
+    nobody's), ``max_pages`` pages a session at most, ``n_slots`` sessions'
+    rings for the sliding layers."""
+    n_pages: int
+    page: int
+    max_pages: int
+    n_slots: int
+
+    @property
+    def max_len(self) -> int:
+        return self.max_pages * self.page
+
+
+def cache_shapes(cfg: LMConfig, geo: CacheGeometry) -> Dict:
+    """One array a layer (a layer's pool is never sliced out of a stack):
+    ``kv`` and ``ik`` for the full layers, ``ring`` for the sliding ones."""
+    dt = jnp.dtype(cfg.dtype)
+    f, s = cfg.full, cfg.swa
+    S = jax.ShapeDtypeStruct
+    return {
+        "kv": [S((geo.n_pages, geo.page, f.entry), dt)
+               for _ in range(cfg.n_full)],
+        "ik": [S((geo.n_pages, geo.page, cfg.idx_dim), dt)
+               for _ in range(cfg.n_full)],
+        "ring": [S((geo.n_slots, cfg.window, s.entry), dt)
+                 for _ in range(cfg.n_sliding)]}
+
+
+def new_cache(cfg: LMConfig, geo: CacheGeometry) -> Dict:
+    return jax.tree_util.tree_map(lambda v: jnp.zeros(v.shape, v.dtype),
+                                  cache_shapes(cfg, geo))
+
+
+# ---------------------------------------------------------------------------
+# shared pieces
+# ---------------------------------------------------------------------------
+
+def rms_norm(x, w, eps):
+    xf = x.astype(F32)
+    return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+            * w.astype(F32)).astype(x.dtype)
+
+
+def layer_norm(x, w, b, eps):
+    xf = x.astype(F32)
+    mu = jnp.mean(xf, -1, keepdims=True)
+    var = jnp.mean((xf - mu) ** 2, -1, keepdims=True)
+    return ((xf - mu) * jax.lax.rsqrt(var + eps) * w.astype(F32)
+            + b.astype(F32)).astype(x.dtype)
+
+
+def gated_mlp(x, w):
+    return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
+
+
+def latents(cfg: LMConfig, a: MLADims, w: Dict, x, pos):
+    """(c_q (N, q_rank), c (N, a.entry) the cache entry — latent, rotary
+    key, zeros up to the entry's width — gate (N, H)) of normed inputs ``x`` (N, d) at positions ``pos`` (N,).
+    ``apply_mla_qkv_lora_rescale``: sqrt(hidden/rank) after the norms."""
+    c_q = rms_norm(x @ w["wq_a"], w["q_norm"], cfg.eps) \
+        * jnp.asarray(math.sqrt(cfg.d / a.q_rank), x.dtype)
+    kv = x @ w["wkv_a"]
+    c_kv = rms_norm(kv[:, :a.kv_rank], w["kv_norm"], cfg.eps) \
+        * jnp.asarray(math.sqrt(cfg.d / a.kv_rank), x.dtype)
+    k_r = att.rope(kv[:, a.kv_rank:], pos, a.theta)
+    gate = jax.nn.sigmoid((x @ w["w_gate"]).astype(F32))
+    pad = jnp.zeros((x.shape[0], a.entry - a.kv_rank - a.rope), x.dtype)
+    return c_q, jnp.concatenate([c_kv, k_r, pad], -1), gate
+
+
+def queries(a: MLADims, w: Dict, c_q, pos):
+    """(q_nope (N, H, nope), q_rope (N, H, rope) rotated)."""
+    q = jnp.einsum("nr,rhe->nhe", c_q, w["wq_b"])
+    return q[..., :a.nope], att.rope(q[..., a.nope:], pos, a.theta)
+
+
+def indexer(cfg: LMConfig, w: Dict, x, c_q, pos):
+    """(q_idx (N, Hi, Di), k_idx (N, Di), w_idx (N, Hi) float32)."""
+    a = cfg.full
+    q = att.rope_head(jnp.einsum("nr,rhi->nhi", c_q, w["idx_wq_b"]), pos,
+                      a.theta, a.rope)
+    k = layer_norm(x @ w["idx_wk"], w["idx_k_norm_w"], w["idx_k_norm_b"],
+                   cfg.eps)
+    k = att.rope_head(k, pos, a.theta, a.rope)
+    wt = (x @ w["idx_w"]).astype(F32) \
+        * (cfg.idx_heads ** -0.5) * (cfg.idx_dim ** -0.5)
+    return q, k, wt
+
+
+def finish_attention(w: Dict, o, gate):
+    """Headwise gate, then the output projection: ``o`` (N, H, v)."""
+    o = o * gate[..., None].astype(o.dtype)
+    return jnp.einsum("nhv,hvd->nd", o, w["wo"])
+
+
+def feed_forward(cfg: LMConfig, layer: Dict, x):
+    """The layer's MLP or its share of the expert layer; the tokens each
+    held expert got (zeros for a dense layer); the experts each token was
+    routed to (``None`` for a dense layer)."""
+    if "mlp" in layer:
+        with jax.named_scope("lm/dense_mlp"):
+            return gated_mlp(x, layer["mlp"]), jnp.zeros((cfg.held,),
+                                                         jnp.int32), None
+    y, chosen, counts = moe_held_experts(x, layer["moe"], cfg.first_held,
+                                         cfg.per_tok, cfg.route_scale)
+    return y, counts, chosen
+
+
+def choices(selected, routed) -> Dict:
+    return {"selected": selected,
+            "routed": jnp.stack([c for c in routed if c is not None])}
+
+
+def head(cfg: LMConfig, ends: Dict, h):
+    with jax.named_scope("lm/head"):
+        x = rms_norm(h, ends["final_norm"], cfg.eps)
+        return jnp.einsum("nd,dv->nv", x, ends["head"],
+                          preferred_element_type=F32)
+
+
+# ---------------------------------------------------------------------------
+# decode: B rows of any sessions, one token each
+# ---------------------------------------------------------------------------
+
+def decode_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
+                cache: Dict, tokens, slots, pos, tables, owner):
+    """One token for each of B rows.  ``tokens`` (B,) ids; ``slots`` (B,)
+    the rows' ring slots (−1: a padding row); ``pos`` (B,) the positions
+    the tokens stand at; ``tables`` (B, max_pages) the rows' page tables;
+    ``owner`` (n_pages,) which row owns each page (−1: none).
+    → (cache, logits (B, vocab) float32, expert tokens (n_layers, held),
+    choices: ``selected`` (B, topk) positions a full layer, −1 where a row
+    has fewer)."""
+    B = tokens.shape[0]
+    live = slots >= 0
+    slot = jnp.maximum(slots, 0)
+    # a padding row writes to page 0 and to no ring slot
+    page = jnp.where(live, tables[jnp.arange(B), pos // geo.page], 0)
+    off = pos % geo.page
+    lengths = jnp.where(live, pos + 1, 0)
+    h = params["ends"]["embed"][tokens]
+    kv, ik, ring = (list(cache[k]) for k in ("kv", "ik", "ring"))
+    counts, selected, routed = [], [], []
+    i_full = i_slide = 0
+    for layer, kind in zip(params["layers"], cfg.kinds):
+        a, w = cfg.mla(kind), layer["attn"]
+        x = rms_norm(h, layer["attn_norm"], cfg.eps)
+        c_q, c, gate = latents(cfg, a, w, x, pos)
+        q_nope, q_rope = queries(a, w, c_q, pos)
+        if kind == FULL:
+            with jax.named_scope("lm/indexer"):
+                q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
+                kv[i_full] = kv[i_full].at[page, off].set(c)
+                ik[i_full] = ik[i_full].at[page, off].set(k_idx)
+                by_page = att.index_scores_paged(q_idx, w_idx, ik[i_full],
+                                                 owner)
+                scores = by_page[tables].reshape(B, geo.max_len)
+            with jax.named_scope("lm/select"):
+                idx, valid = att.select_topk(scores, lengths, cfg.topk)
+                selected.append(jnp.where(valid, idx, -1))
+                phys = tables[jnp.arange(B)[:, None], idx // geo.page] \
+                    * geo.page + idx % geo.page
+                chosen = kv[i_full].reshape(
+                    geo.n_pages * geo.page, -1)[phys]
+            with jax.named_scope("lm/mla_full"):
+                o = att.mla_absorbed(q_nope, q_rope, chosen, valid,
+                                     w["wkv_b"], a.nope, a.rope, a.scale)
+                h = h + finish_attention(w, o, gate)
+            i_full += 1
+        else:
+            with jax.named_scope("lm/mla_window"):
+                at = jnp.where(live, pos % cfg.window, cfg.window)
+                ring[i_slide] = ring[i_slide].at[slot, at].set(
+                    c, mode="drop")
+                mine = ring[i_slide][slot]                 # (B, W, rank+r)
+                age = (pos[:, None] - jnp.arange(cfg.window)[None, :]) \
+                    % cfg.window
+                valid = (age <= pos[:, None]) & live[:, None]
+                o = att.mla_absorbed(q_nope, q_rope, mine, valid,
+                                     w["wkv_b"], a.nope, a.rope, a.scale)
+                h = h + finish_attention(w, o, gate)
+            i_slide += 1
+        x = rms_norm(h, layer["mlp_norm"], cfg.eps)
+        y, n, chosen = feed_forward(cfg, layer, x)
+        h = h + y
+        counts.append(n)
+        routed.append(chosen)
+    logits = head(cfg, params["ends"], h)
+    return ({"kv": kv, "ik": ik, "ring": ring}, logits, jnp.stack(counts),
+            choices(selected, routed))
+
+
+# ---------------------------------------------------------------------------
+# prefill: one session's chunk of T tokens
+# ---------------------------------------------------------------------------
+
+def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
+                 cache: Dict, tokens, slot, start, n_valid, table,
+                 pages_per_step: int = 1, q_block: int = 512):
+    """A chunk of one session: ``tokens`` (T,) ids of which the first
+    ``n_valid`` are real, standing at positions ``start ..``; ``slot`` the
+    session's ring slot; ``table`` (max_pages,) its page table.
+    → (cache, logits (1, vocab) float32 at the last real token, expert
+    tokens (n_layers, held), choices: ``selected`` uint8 (T, max_len / 8)
+    bit-packed rows a full layer)."""
+    T = tokens.shape[0]
+    pos = start + jnp.arange(T)
+    real = jnp.arange(T) < n_valid
+    page = jnp.where(real, table[pos // geo.page], 0)
+    off = pos % geo.page
+    h = params["ends"]["embed"][tokens]
+    kv, ik, ring = (list(cache[k]) for k in ("kv", "ik", "ring"))
+    W = cfg.window
+    counts, selected, routed = [], [], []
+    i_full = i_slide = 0
+    for layer, kind in zip(params["layers"], cfg.kinds):
+        a, w = cfg.mla(kind), layer["attn"]
+        x = rms_norm(h, layer["attn_norm"], cfg.eps)
+        c_q, c, gate = latents(cfg, a, w, x, pos)
+        q_nope, q_rope = queries(a, w, c_q, pos)
+        if kind == FULL:
+            with jax.named_scope("lm/indexer"):
+                q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
+                kv[i_full] = kv[i_full].at[page, off].set(c)
+                ik[i_full] = ik[i_full].at[page, off].set(k_idx)
+            with jax.named_scope("lm/mla_full"):
+                o, sets = att.prefill_full_attention(
+                    q_nope, q_rope, q_idx, w_idx, kv[i_full], ik[i_full],
+                    table, start, n_valid, w["wkv_b"], a.nope, a.rope,
+                    a.scale, cfg.topk, pages_per_step,
+                    flash=PREFILL_HEADS_PER_STEP)
+                selected.append(sets)
+                h = h + finish_attention(w, o, gate)
+            i_full += 1
+        else:
+            with jax.named_scope("lm/mla_window"):
+                prev_pos = start - (W - 1) + jnp.arange(W - 1)
+                prev = ring[i_slide][slot, prev_pos % W]
+                o = att.prefill_window_attention(
+                    jnp.concatenate([q_nope, q_rope], -1), c, prev,
+                    prev_pos, start, n_valid, w["wkv_b"], a.nope, a.rope,
+                    a.scale, W, min(q_block, T))
+                h = h + finish_attention(w, o, gate)
+                # the chunk's last W real tokens go into the ring; the
+                # others (overwritten within the chunk, or padding) are
+                # dropped by an index past the end
+                keep = real & (jnp.arange(T) >= n_valid - W)
+                ring[i_slide] = ring[i_slide].at[
+                    slot, jnp.where(keep, pos % W, W)].set(c, mode="drop")
+            i_slide += 1
+        x = rms_norm(h, layer["mlp_norm"], cfg.eps)
+        y, n, chosen = feed_forward(cfg, layer, x)
+        h = h + y
+        counts.append(n)
+        routed.append(chosen)
+    last = jax.lax.dynamic_slice_in_dim(h, jnp.maximum(n_valid - 1, 0), 1, 0)
+    logits = head(cfg, params["ends"], last)
+    return ({"kv": kv, "ik": ik, "ring": ring}, logits, jnp.stack(counts),
+            choices(selected, routed))
+
+
+#: the two step programs, jitted once for the process: configuration and
+#: geometry are static (two tiers of one model share the compiled step),
+#: the cache is donated.  The device trace names them ``jit_decode_step``
+#: and ``jit_prefill_step``.
+decode_jit = jax.jit(decode_step, static_argnums=(0, 1), donate_argnums=(3,))
+prefill_jit = jax.jit(prefill_step, static_argnums=(0, 1),
+                      static_argnames=("pages_per_step", "q_block"),
+                      donate_argnums=(3,))

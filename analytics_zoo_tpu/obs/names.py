@@ -56,6 +56,18 @@ CATALOG: Dict[str, str] = {
         "counter · batches (warm() included) whose staging buffer was "
         "allocated or replaced instead of reused: stays at the number "
         "of geometries when every model's rows keep their shape",
+    # -- decoder-LM session tier (pipelines/lm.py, ISSUE 28) ----------------
+    "lm/cache_tokens":
+        "gauge · tokens the replica's live sessions hold in the paged "
+        "cache",
+    "lm/cache_fill":
+        "gauge · share of the paged pool's pages that sessions hold",
+    "lm/sessions_live":
+        "gauge · sessions that hold a slot (and pages) on the replica",
+    "lm/expert_tokens/stat=*":
+        "histogram · tokens a held expert of an expert layer got in one "
+        "decode step: stat=mean over the held experts of the step's "
+        "expert layers, stat=max the busiest of them",
     # -- multiplexed fleet (ServingRuntime(models=...), ISSUE 14) -----------
     "serve/submitted/model=*":
         "counter · requests submitted per multiplexed model",
@@ -239,14 +251,22 @@ STAGES: Dict[str, str] = {
         "main thread · ReplicaPool.dispatch of one batch (replica "
         "choice, watchdog, the tier's forward, failover)",
     "az/serve/h2d":
-        "main thread · the SSD tier's jnp.asarray of the host batch: "
-        "the host's side of the transfer (staging and enqueue)",
+        "main thread · a tier's jnp.asarray of the host batch (SSD: the "
+        "pictures; LM: the token ids, positions and page tables): the "
+        "host's side of the transfer (staging and enqueue)",
     "az/serve/dispatch":
-        "main thread · the SSD tier's detect_normalized call "
-        "(asynchronous dispatch of the serve program)",
+        "main thread · a tier's call of its serve program (SSD: "
+        "detect_normalized; LM: the decode or prefill step; "
+        "asynchronous dispatch)",
     "az/serve/result_wait":
-        "main thread · the SSD tier's np.asarray of the answer: waits "
-        "for the program and copies the detections to the host",
+        "main thread · a tier's np.asarray of the answer: waits for the "
+        "program and copies the answer (detections, logits) to the host",
+    "az/lm/step":
+        "main thread · the LM tier's forward of one batch, whole; attrs: "
+        "rows (live rows), edge, phase (prefill or decode)",
+    "az/lm/cache_admit":
+        "main thread · the LM tier's admission of a batch's chunks into "
+        "the session cache: slots and pages for the new tokens",
     "az/serve/handout":
         "main thread · _dispatch after the pool returns: canary, "
         "finishing each request, accounting, _after_dispatch",

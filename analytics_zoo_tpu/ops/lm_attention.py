@@ -1,0 +1,324 @@
+"""Attention over device-resident session caches for the decoder LM
+(models/lm.py): multi-head latent attention (MLA) in the absorbed form
+over a PAGED pool of latents, the learned sparse indexer with its
+top-k selection, and windowed MLA over per-session rings.  XLA only.
+
+Cache layout (one replica's, all sessions'):
+
+- ``kv``  a full layer: (n_pages, page, entry) — per token the normed kv
+  latent, the rotated shared key, and zeros up to ``entry`` (a multiple
+  of the TPU's 128 lanes, so a token's row is the minor axis);
+- ``ik``  a full layer: (n_pages, page, idx_dim) — the indexer's key;
+- ``ring`` a sliding layer: (n_slots, window, swa entry) — the last
+  ``window`` tokens of a session at ``position % window``.
+
+A session's tokens live in the pages its row of the page table names
+(position ``p`` at page ``table[p // page]``, offset ``p % page``); page
+0 belongs to no session and takes the writes of padding rows.  The page
+table, the pages' owners and the positions come from the host with every
+call (pipelines/lm.py owns the allocation); the arrays here hold data
+only.
+
+Two shapes of work:
+
+- **decode** — a batch of rows, one new token each, rows of any sessions:
+  the indexer scores every page once against its owner's query (each key
+  is read once, whatever the mix of lengths), the scores are laid out by
+  session, the ``topk`` largest are selected and only those latents are
+  gathered for the attention;
+- **prefill** — one session's chunk of T tokens: two loops over the
+  session's pages (as many as it has, not as many as the longest could
+  have): the first fills the index scores, from which each query's
+  selection threshold is found exactly by bisection on the scores' bits;
+  the second is attention with an online softmax over the selected keys.
+  The selected SET is the same either way: the ``topk`` largest scores.
+
+Both shapes hand the selected set back beside their result — decode the
+positions (−1 where a row has fewer), prefill a bit-packed row a query —
+so that a comparison with another implementation can be made on EQUAL
+sets: past ``topk`` tokens a rounding flips members of the set, and what
+the flip does to the output says nothing about either side's arithmetic
+(benchmarks/reference/lm.py ``follow``).
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from analytics_zoo_tpu.ops import pallas_lm_prefill
+
+F32 = jnp.float32
+NEG = -1e30
+#: prefill's selection (the threshold's 32 counting passes, the sets) works
+#: on blocks of about this many columns of a chunk's scores, as many as the
+#: session has: a pass over all ``max_len`` columns of 1,024 queries is
+#: 285 MB, and 32 of them took 16 ms on a v5e whatever the session (PR 28)
+SELECT_BLOCK = 4096
+
+
+def rope(x, pos, theta: float):
+    """Rotary embedding of the last axis in interleaved pairs; ``pos``
+    has ``x``'s leading axes up to where it stops (``x`` (..., [h,] r))."""
+    r = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, r, 2, dtype=F32) / r)
+    ang = pos.astype(F32).reshape(pos.shape + (1,) * (x.ndim - pos.ndim)) \
+        * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(F32)
+    x0, x1 = xf[..., 0::2], xf[..., 1::2]
+    return jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos],
+                     -1).reshape(x.shape).astype(x.dtype)
+
+
+def rope_head(x, pos, theta: float, r: int):
+    """Rotary on the first ``r`` dims of the last axis."""
+    return jnp.concatenate([rope(x[..., :r], pos, theta), x[..., r:]], -1)
+
+
+def ordered_bits(x):
+    """float32 → uint32 whose unsigned order is the floats' order."""
+    b = lax.bitcast_convert_type(x.astype(F32), jnp.int32)
+    b = b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+    return lax.bitcast_convert_type(b, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+def kth_largest(count, k: int, rows: int):
+    """Per row the k-th largest of its uint32 values, exactly: 32 counting
+    passes build it bit by bit from the top.  ``count(above)`` → (rows,)
+    says for how many of a row's values ``above(values)`` holds."""
+    def bit(i, tau):
+        cand = tau | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        return jnp.where(count(lambda v: v >= cand[:, None]) >= k, cand, tau)
+    return lax.fori_loop(0, 32, bit, jnp.zeros((rows,), jnp.uint32))
+
+
+# ---------------------------------------------------------------------------
+# decode: B rows, one token each
+# ---------------------------------------------------------------------------
+
+def index_scores_paged(q_idx, w_idx, ik_pool, owner):
+    """Indexer scores of every page against its owner's query.
+    ``q_idx`` (B, Hi, Di), ``w_idx`` (B, Hi) float32, ``ik_pool``
+    (n_pages, page, Di), ``owner`` (n_pages,) row of the batch or −1.
+    → (n_pages, page) float32."""
+    row = jnp.maximum(owner, 0)
+    q = q_idx[row]                                    # (n_pages, Hi, Di)
+    s = jnp.einsum("pti,phi->pth", ik_pool, q,
+                   preferred_element_type=F32)
+    return jnp.einsum("pth,ph->pt", jax.nn.relu(s), w_idx[row])
+
+
+def select_topk(scores, lengths, k: int):
+    """The ``k`` largest of each row's first ``lengths`` scores: (indices
+    (B, k), valid (B, k)).  Rows shorter than ``k`` select all they have.
+    ``where(valid, indices, -1)`` is the selected set as decode reports it."""
+    n = scores.shape[1]
+    scores = jnp.where(jnp.arange(n)[None, :] < lengths[:, None], scores, NEG)
+    if n <= k:
+        idx = jnp.broadcast_to(jnp.arange(n)[None, :], scores.shape)
+        return idx, idx < lengths[:, None]
+    top, idx = lax.top_k(scores, k)
+    return idx, top > NEG / 2
+
+
+def mla_absorbed(q_nope, q_rope, latents, valid, wkv_b, nope: int,
+                 r: int, scale: float):
+    """MLA over gathered cache entries in the absorbed form.  ``q_nope``
+    (B, H, nope), ``q_rope`` (B, H, r), ``latents`` (B, S, entry),
+    ``valid`` (B, S), ``wkv_b`` (kv_rank, H, nope + v) → (B, H, v)."""
+    rank = wkv_b.shape[0]
+    q_abs = jnp.einsum("bhn,rhn->bhr", q_nope, wkv_b[..., :nope])
+    s = (jnp.einsum("bhr,bsr->bhs", q_abs, latents[..., :rank],
+                    preferred_element_type=F32)
+         + jnp.einsum("bhe,bse->bhs", q_rope, latents[..., rank:rank + r],
+                      preferred_element_type=F32)) * scale
+    s = jnp.where(valid[:, None, :], s, NEG)
+    p = jax.nn.softmax(s, -1).astype(latents.dtype)
+    o = jnp.einsum("bhs,bsr->bhr", p, latents[..., :rank])
+    return jnp.einsum("bhr,rhv->bhv", o, wkv_b[..., nope:])
+
+
+# ---------------------------------------------------------------------------
+# prefill: one session's chunk of T tokens
+# ---------------------------------------------------------------------------
+
+def prefill_full_attention(q_nope, q_rope, q_idx, w_idx, kv_pool, ik_pool,
+                           table, start, n_valid, wkv_b, nope: int,
+                           r: int, scale: float, topk: int,
+                           pages_per_step: int = 2, flash: int = 0):
+    """Sparse-selected MLA of a chunk against its session's pages, the
+    chunk's own tokens (already written to the pool) included.
+
+    ``q_nope`` (T, H, nope), ``q_rope`` (T, H, r), ``q_idx`` (T, Hi, Di),
+    ``w_idx`` (T, Hi) float32; ``kv_pool`` (n_pages, page, entry),
+    ``ik_pool`` (n_pages, page, Di); ``table`` (max_pages,) the session's
+    pages; the chunk's tokens stand at ``start + arange(T)``, the first
+    ``n_valid`` of them real → ((T, H, v), the selected sets: uint8
+    (T, max_len / 8), bit s of row t — in ``numpy.packbits``'s order — set
+    where query t attends to position s).  ``flash``: how many heads a step
+    of the Pallas form of pass 2 takes (ops/pallas_lm_prefill.py; 0, or
+    widths it does not take: the XLA loop)."""
+    T, H, _ = q_nope.shape
+    page = kv_pool.shape[1]
+    rank = wkv_b.shape[0]
+    # the selection works on blocks of ``blk`` columns of the scores: whole
+    # pages, whole bytes of the selected sets, whole steps of the XLA
+    # attention loop, and no fewer than ``topk`` (a session's first block
+    # then holds a k-th largest, be it a masked column's)
+    pages_per_step *= 8 // math.gcd(8, pages_per_step * page)
+    step = pages_per_step * page
+    blk = step * -(-max(topk, SELECT_BLOCK) // step)
+    max_len = -(-table.shape[0] * page // blk) * blk
+    end = start + n_valid
+    n_pages = (end + page - 1) // page
+    n_blk = (end + blk - 1) // blk
+    q_pos = start + jnp.arange(T)
+
+    def seen(first, n):
+        key_pos = first + jnp.arange(n)
+        return (key_pos[None, :] <= q_pos[:, None]) & (key_pos[None, :] < end)
+
+    # pass 1: the index scores of every (query, key), page by page
+    def fill(j, buf):
+        ik = ik_pool[table[j]]                                 # (page, Di)
+        s = jnp.einsum("thi,si->ths", q_idx, ik,
+                       preferred_element_type=F32)
+        s = jnp.einsum("ths,th->ts", jax.nn.relu(s), w_idx)
+        s = jnp.where(seen(j * page, page), s, NEG)
+        return lax.dynamic_update_slice(buf, ordered_bits(s), (0, j * page))
+
+    neg_bits = ordered_bits(jnp.full((), NEG, F32))
+    bits = lax.fori_loop(0, n_pages, fill,
+                         jnp.full((T, max_len), neg_bits, jnp.uint32))
+
+    def count(above):
+        """Per query, how many of the session's scores ``above`` holds
+        for, a block of columns at a time: as many blocks as the session
+        has, not as many as the longest could have."""
+        def body(b, acc):
+            blk_bits = lax.dynamic_slice(bits, (0, b * blk), (T, blk))
+            return acc + jnp.sum(above(blk_bits), 1, dtype=jnp.int32)
+        return lax.fori_loop(0, n_blk, body, jnp.zeros((T,), jnp.int32))
+
+    # each query's threshold: the topk-th largest of its scores (a row
+    # with fewer than topk keys ends at a masked column's NEG and keeps
+    # all it has)
+    tau = kth_largest(count, topk, T)
+    # of the scores equal to the threshold only as many as still fit are
+    # selected, the earlier positions first (as top_k orders equal scores,
+    # so that decode and prefill select the same set)
+    room = topk - count(lambda b: b > tau[:, None])
+
+    # the selected sets, a block at a time: as an additive bias for the
+    # attention (0 selected, NEG not) and bit-packed for the caller
+    def mark(b, carry):
+        ties, bias, sets = carry
+        blk_bits = lax.dynamic_slice(bits, (0, b * blk), (T, blk))
+        ok = seen(b * blk, blk)
+        tie = (blk_bits == tau[:, None]) & ok
+        nth = ties[:, None] + jnp.cumsum(tie, 1, dtype=jnp.int32)
+        keep = ((blk_bits > tau[:, None])
+                | (tie & (nth <= room[:, None]))) & ok
+        bias = lax.dynamic_update_slice(
+            bias, jnp.where(keep, 0.0, NEG).astype(bias.dtype), (0, b * blk))
+        sets = lax.dynamic_update_slice(sets, jnp.packbits(keep, axis=1),
+                                        (0, b * (blk // 8)))
+        return nth[:, -1], bias, sets
+
+    _, bias, sets = lax.fori_loop(
+        0, n_blk, mark,
+        (jnp.zeros((T,), jnp.int32),
+         jnp.full((T, max_len), NEG, kv_pool.dtype),
+         jnp.zeros((T, max_len // 8), jnp.uint8)))
+
+    entry = kv_pool.shape[2]
+    if flash and pallas_lm_prefill.supported(nope, wkv_b.shape[2] - nope,
+                                             rank, entry, r, H, flash):
+        # pass 2 as one Pallas program: the pages decompressed in VMEM,
+        # the plain form, nothing of the scores in HBM
+        q = jnp.concatenate(
+            [q_nope, q_rope.astype(q_nope.dtype),
+             jnp.zeros((T, H, entry - rank - r), q_nope.dtype)], -1)
+        o = pallas_lm_prefill.flash_mla_prefill(
+            q.transpose(1, 0, 2), wkv_b, kv_pool, bias, table, n_pages,
+            nope=nope, scale=scale, steps=max_len // page,
+            heads_per_step=flash)
+        return o.transpose(1, 0, 2), sets
+
+    # pass 2 in XLA: attention over the selected keys, online softmax, the
+    # absorbed form; ``pages_per_step`` pages an iteration, because every
+    # iteration reads and writes the whole (T, H, rank) accumulator once
+    # (268 MB at 1,024 queries: with 256 keys an iteration that was half
+    # of the loop's time on a v5e, PR 28).  Absorbed queries and rotary
+    # queries side by side: ONE product a step writes the scores once
+    q_abs = jnp.einsum("thn,rhn->thr", q_nope, wkv_b[..., :nope])
+    q_cat = jnp.concatenate([q_abs, q_rope.astype(q_abs.dtype)], -1)
+    span = pages_per_step * page
+
+    def attend(i, carry):
+        m, l, acc = carry
+        pages = [jnp.where(i * pages_per_step + b < n_pages,
+                           table[jnp.minimum(i * pages_per_step + b,
+                                             table.shape[0] - 1)], 0)
+                 for b in range(pages_per_step)]
+        kv = jnp.concatenate([kv_pool[p] for p in pages], 0)  # (span, entry)
+        keep_blk = lax.dynamic_slice(bias, (0, i * span), (T, span)) == 0
+        s = jnp.einsum("the,se->ths", q_cat, kv[:, :rank + r],
+                       preferred_element_type=F32) * scale
+        s = jnp.where(keep_blk[:, None, :], s, NEG)
+        m_new = jnp.maximum(m, jnp.max(s, -1))
+        p = jnp.where(keep_blk[:, None, :], jnp.exp(s - m_new[..., None]),
+                      0.0)
+        fix = jnp.exp(m - m_new)
+        l = l * fix + jnp.sum(p, -1)
+        acc = acc * fix[..., None] + jnp.einsum(
+            "ths,sr->thr", p.astype(kv.dtype), kv[:, :rank],
+            preferred_element_type=F32)
+        return m_new, l, acc
+
+    m0 = jnp.full((T, H), NEG, F32)
+    _, l, acc = lax.fori_loop(
+        0, (n_pages + pages_per_step - 1) // pages_per_step, attend,
+        (m0, jnp.zeros((T, H), F32), jnp.zeros((T, H, rank), F32)))
+    o = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(kv_pool.dtype)
+    return jnp.einsum("thr,rhv->thv", o, wkv_b[..., nope:]), sets
+
+
+def prefill_window_attention(q, c_new, prev, prev_pos, start, n_valid,
+                             wkv_b, nope: int, r: int, scale: float,
+                             window: int, q_block: int):
+    """Windowed MLA of a chunk: keys are the session's last ``window − 1``
+    tokens before the chunk (``prev`` (window − 1, entry) in position
+    order, at positions ``prev_pos``, negative where there is none) and
+    the chunk's own (``c_new`` (T, entry)).  Decompressed keys: a
+    window is short, so the plain form is the cheaper one here.
+    ``q`` (T, H, nope + r) with its rotary part rotated → (T, H, v)."""
+    T, H, _ = q.shape
+    rank = wkv_b.shape[0]
+    lat = jnp.concatenate([prev, c_new], 0)              # (W-1+T, rank+r)
+    pos = jnp.concatenate([prev_pos, start + jnp.arange(T)])
+    ok_key = jnp.concatenate([prev_pos >= 0, jnp.arange(T) < n_valid])
+    kv = jnp.einsum("sr,rhe->she", lat[:, :rank], wkv_b)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(lat[:, None, rank:rank + r],
+                          (lat.shape[0], H, r))], -1)
+    v = kv[..., nope:]
+    out = []
+    for lo in range(0, T, q_block):
+        hi = min(T, lo + q_block)
+        # queries lo..hi see keys (lo .. hi + window - 1) of ``lat``
+        ks = slice(lo, hi + window - 1)
+        q_pos = start + jnp.arange(lo, hi)
+        ok = (ok_key[ks][None, :] & (pos[ks][None, :] <= q_pos[:, None])
+              & (pos[ks][None, :] > q_pos[:, None] - window))
+        s = jnp.einsum("the,she->hts", q[lo:hi], k[ks],
+                       preferred_element_type=F32) * scale
+        s = jnp.where(ok[None], s, NEG)
+        p = jax.nn.softmax(s, -1).astype(v.dtype)
+        out.append(jnp.einsum("hts,she->the", p, v[ks]))
+    return jnp.concatenate(out, 0)

@@ -52,9 +52,13 @@ from analytics_zoo_tpu.parallel.specs import (
 from analytics_zoo_tpu.parallel.summary import TrainSummary, ValidationSummary
 from analytics_zoo_tpu.parallel import checkpoint
 from analytics_zoo_tpu.parallel.expert import (
+    held_experts_apply,
     moe_apply_dense,
     moe_apply_expert_parallel,
+    moe_held_experts,
+    moe_held_experts_parallel,
     route_top1,
+    route_topk_sigmoid,
 )
 from analytics_zoo_tpu.parallel.pipeline import (
     carrier_decay_mask,

@@ -23,6 +23,19 @@ scaling):
 
 Everything is static-shape: capacity ``C`` is a Python int, dropped
 tokens are zeros, so both paths jit cleanly.
+
+**Held experts (ISSUE 28).**  The second family below is the layer a
+wide-expert-parallel deployment runs: the router keeps its PUBLISHED
+width (:func:`route_topk_sigmoid`: sigmoid scores, bias-corrected top-k,
+normalised weights), and a chip is TOLD which experts it holds
+(``first_held`` and the leading axis of its expert weights), routes over
+all of them and computes its own experts' part of the result for the
+tokens routed to them (:func:`held_experts_apply`) — no capacity, no
+dropped token.  On one chip that is the whole layer's local share, run
+without its exchange (:func:`moe_held_experts`);
+:func:`moe_held_experts_parallel` runs every share on an ``expert`` mesh
+axis with the exchange (tokens all-gathered over the axis, the shares'
+parts reduce-scattered back), and equals the sum of the shares.
 """
 
 from __future__ import annotations
@@ -37,6 +50,14 @@ from jax.sharding import Mesh, PartitionSpec as P
 from analytics_zoo_tpu.parallel.sequence import _shard_map
 
 EXPERT_AXIS = "expert"
+#: :func:`held_experts_apply` runs a batch of at most this many tokens
+#: through every held expert, a larger one through the grouped product.
+#: On a v5e at the published widths (32 held experts of 5,120 x 1,536,
+#: 8 of 256 a token; PR 28, ms a layer, dense / grouped): 64 tokens 2.04 /
+#: 2.86, 256 2.34 / 5.80, 512 4.65 / 6.81, 1,024 10.1 / 8.86.  Reading the
+#: weights once is the cost of a small batch, and the dense form's N·H
+#: products overtake it between 512 and 1,024 tokens
+DENSE_BELOW = 512
 
 
 def route_top1(x: jax.Array, gate_kernel: jax.Array, capacity: int
@@ -143,3 +164,156 @@ def moe_apply_expert_parallel(
                     in_specs=(param_spec, P(), tok_spec),
                     out_specs=tok_spec)
     return fn(stacked_params, gate_kernel, x)
+
+
+# ---------------------------------------------------------------------------
+# held experts: a published-width router, this chip's share of the experts
+# ---------------------------------------------------------------------------
+
+def route_topk_sigmoid(x: jax.Array, router_w: jax.Array,
+                       router_b: jax.Array, top_k: int,
+                       scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """``noaux_tc`` routing in one group: scores ``sigmoid(x W_r)`` over
+    the router's whole width, the ``top_k`` largest of score + bias chosen
+    (ties to the lower expert id), weights the chosen SCORES over their
+    sum, times ``scale``.  → (chosen (N, k) int32, weights (N, k) f32)."""
+    s = jax.nn.sigmoid(jnp.einsum("nd,de->ne", x, router_w,
+                                  preferred_element_type=jnp.float32))
+    _, chosen = jax.lax.top_k(s + router_b.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, chosen, 1)
+    return chosen, scale * picked / jnp.sum(picked, 1, keepdims=True)
+
+
+def _gated(x, w_gate, w_up, w_down):
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def held_experts_apply(x: jax.Array, experts: Any, chosen: jax.Array,
+                       weights: jax.Array, first_held: Any
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """This share's part of the routed result: ``Σ_{e chosen ∩ held} w_e ·
+    E_e(x)`` for tokens ``x`` (N, d), with ``experts`` the HELD experts'
+    gated-MLP weights (``w_gate``/``w_up`` (H, d, f), ``w_down`` (H, f, d))
+    for expert ids ``first_held .. first_held + H``.  Every routed pair is
+    computed; none is dropped.  → (y (N, d), tokens per held expert (H,)).
+
+    The number of tokens alone decides the form: up to ``DENSE_BELOW``
+    every token runs through every held expert and the results are weighed
+    (weights read once, N·H products: a decode batch, where reading the
+    weights is the cost); beyond, the (token, expert) pairs are sorted by
+    expert and run through one grouped product (N·k rows whatever H: a
+    prefill chunk)."""
+    h = experts["w_gate"].shape[0]
+    local = chosen - first_held
+    held = (local >= 0) & (local < h)
+    counts = jnp.sum(jax.nn.one_hot(jnp.where(held, local, h), h + 1,
+                                    dtype=jnp.int32), (0, 1))[:h]
+    form = _held_dense if x.shape[0] <= DENSE_BELOW else _held_grouped
+    return form(x, experts, jnp.where(held, local, h), weights, counts), counts
+
+
+def _held_dense(x, experts, local, weights, counts):
+    """``local`` (N, k): the held expert's index, or H for an absent one."""
+    h = experts["w_gate"].shape[0]
+    combine = jnp.sum(jax.nn.one_hot(local, h + 1, dtype=jnp.float32)
+                      * weights[..., None], 1)[:, :h]           # (N, H)
+    g = jnp.einsum("nd,hdf->hnf", x, experts["w_gate"])
+    u = jnp.einsum("nd,hdf->hnf", x, experts["w_up"])
+    out = jnp.einsum("hnf,hfd->hnd", jax.nn.silu(g) * u, experts["w_down"])
+    return jnp.einsum("hnd,nh->nd", out, combine.astype(x.dtype),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _held_grouped(x, experts, local, weights, counts):
+    n, k = local.shape
+    h = experts["w_gate"].shape[0]
+    key = local.reshape(-1)                                     # (N*k,)
+    order = jnp.argsort(key, stable=True)       # this share's pairs first
+    wt = jnp.where(local < h, weights, 0.0).reshape(-1)
+
+    def grouped(rows_max: int):
+        """The first ``rows_max`` sorted pairs through the grouped
+        product, back in token order."""
+        take = order[:rows_max]
+        rows = x[take // k]
+        g = jax.lax.ragged_dot(rows, experts["w_gate"], counts)
+        u = jax.lax.ragged_dot(rows, experts["w_up"], counts)
+        out = jax.lax.ragged_dot(jax.nn.silu(g) * u, experts["w_down"],
+                                 counts, preferred_element_type=jnp.float32)
+        out = jnp.where((key[take] < h)[:, None], out * wt[take][:, None],
+                        0.0).astype(x.dtype)
+        # a token's pairs summed by a 0/1 product (exact: the rounded rows
+        # are added in float32); a scatter of the rows into an (N·k, d)
+        # buffer took 3.8 of a layer's 9.1 ms at 1,024 tokens on a v5e
+        mine = (take[None, :] // k == jnp.arange(n)[:, None]).astype(x.dtype)
+        return jnp.dot(mine, out,
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+
+    # a uniform router sends N·k·H/E pairs here, N at the published
+    # ratio; the buffer takes twice the tokens, and the step that gets
+    # more than that (the router may send all N·k) runs the full one: a
+    # buffer's rows cost their time filled or not (12.2 ms for 16 k rows
+    # at 1,024 tokens on a v5e, PR 28)
+    small = min(n * k, max(2 * n, 256))
+    if small == n * k:
+        return grouped(n * k)
+    return jax.lax.cond(jnp.sum(counts) <= small, lambda: grouped(small),
+                        lambda: grouped(n * k))
+
+
+def moe_held_experts(x: jax.Array, params: Any, first_held: Any,
+                     top_k: int, scale: float = 1.0, shared: bool = True):
+    """One share of the layer on one chip, without its exchange: route
+    over the router's whole width, the held experts' part, plus the shared
+    expert.  ``params``: ``router_w`` (d, E), ``router_b`` (E,),
+    ``experts`` (held, stacked), ``shared`` (a gated MLP).
+    → (y (N, d), chosen (N, k), tokens per held expert (H,))."""
+    with jax.named_scope("lm/route"):
+        chosen, weights = route_topk_sigmoid(
+            x, params["router_w"], params["router_b"], top_k, scale)
+    with jax.named_scope("lm/experts"):
+        y, counts = held_experts_apply(x, params["experts"], chosen,
+                                       weights, first_held)
+    if shared:
+        with jax.named_scope("lm/shared_mlp"):
+            sh = params["shared"]
+            y = y + _gated(x, sh["w_gate"], sh["w_up"], sh["w_down"])
+    return y, chosen, counts
+
+
+def moe_held_experts_parallel(x: jax.Array, params: Any, mesh: Mesh,
+                              top_k: int, scale: float = 1.0,
+                              axis_name: str = EXPERT_AXIS) -> jax.Array:
+    """Every share of the layer on an ``expert`` mesh axis, with the
+    exchange: ``params["experts"]`` holds ALL experts stacked and sharded
+    over the axis (device i holds experts ``i·H .. (i+1)·H``), ``x``
+    (N, d) is sharded over it by tokens.  Each device all-gathers the
+    tokens, computes its held experts' part for all of them, and the
+    parts are reduce-scattered back to the tokens' owners; the shared
+    expert runs on the local tokens, once.  Equals the sum of the shares
+    :func:`moe_held_experts` gives, the shared expert counted once."""
+    n = mesh.shape[axis_name]
+    n_experts = params["experts"]["w_gate"].shape[0]
+    if n_experts % n or x.shape[0] % n:
+        raise ValueError(f"{n_experts} experts / {x.shape[0]} tokens do not "
+                         f"divide over {n} devices of {axis_name!r}")
+    held = n_experts // n
+    spec = {"router_w": P(), "router_b": P(),
+            "experts": jax.tree_util.tree_map(lambda _: P(axis_name),
+                                              params["experts"]),
+            "shared": jax.tree_util.tree_map(lambda _: P(),
+                                             params["shared"])}
+
+    def local(p, x_l):
+        x_all = jax.lax.all_gather(x_l, axis_name, axis=0, tiled=True)
+        first = jax.lax.axis_index(axis_name) * held
+        part, _, _ = moe_held_experts(x_all, p, first, top_k, scale,
+                                      shared=False)
+        y = jax.lax.psum_scatter(part, axis_name, scatter_dimension=0,
+                                 tiled=True)
+        sh = p["shared"]
+        return y + _gated(x_l, sh["w_gate"], sh["w_up"], sh["w_down"])
+
+    fn = _shard_map(local, mesh, in_specs=(spec, P(axis_name, None)),
+                    out_specs=P(axis_name, None))
+    return fn(params, x)

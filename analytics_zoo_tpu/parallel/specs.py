@@ -364,3 +364,21 @@ def _sentiment_specs(mesh: Mesh, shard_tables: bool = True) -> SpecSet:
 
     rules = tensor_lib.embedding_row_rules() if shard_tables else None
     return SpecSet(mesh, rules=rules)
+
+
+@register_pipeline("lm")
+def _lm_specs(mesh: Mesh) -> SpecSet:
+    """Decoder-LM serving (models/lm.py): on a mesh with an ``expert``
+    axis the stacked routed experts are sharded over it by their leading
+    (expert) dim — what ``expert.moe_held_experts_parallel`` expects —
+    and everything else (attention, router, shared expert, the ends) is
+    replicated; on any other mesh all of it is replicated (one chip
+    serves its own share and its own sessions)."""
+    from analytics_zoo_tpu.parallel import tensor as tensor_lib
+    from analytics_zoo_tpu.parallel.expert import EXPERT_AXIS
+
+    if EXPERT_AXIS not in mesh.axis_names:
+        return SpecSet(mesh)
+    return SpecSet(mesh, rules=[
+        (r".*moe/experts/w_(gate|up|down)$",
+         tensor_lib._row_dim(EXPERT_AXIS))])

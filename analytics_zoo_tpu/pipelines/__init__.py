@@ -79,5 +79,12 @@ from analytics_zoo_tpu.pipelines.deepspeech2 import (
     ds2_streaming_tiers,
     make_ds2_model,
 )
+from analytics_zoo_tpu.pipelines.lm import (
+    CacheExhausted,
+    LMModel,
+    SessionCache,
+    lm_serving_tiers,
+    make_lm_model,
+)
 
 __all__ = [k for k in dir() if not k.startswith("_")]

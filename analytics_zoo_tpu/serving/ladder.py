@@ -53,7 +53,14 @@ class ServingTier:
     session's carry state from this tier instance's store — the
     runtime calls it on the pinned replica when a session dies without
     its final chunk ever being served (killed, shed, replica loss), so
-    failed sessions don't leak their state on the replica."""
+    failed sessions don't leak their state on the replica.
+
+    ``pads_session_rows`` (streaming session tiers): this tier's forward
+    takes a row whose session is −1 for padding — it runs its program of
+    that geometry and reads and writes no session's state.  Only then has
+    a session model a dry run, and ``ServingRuntime.warm`` (whose batch is
+    all such rows) compiles it; a tier that steps whatever state its rows
+    name must leave it False."""
 
     name: str
     forward: Callable[[Dict[str, Any]], Any]
@@ -61,6 +68,7 @@ class ServingTier:
     quality_note: str = ""
     device_program: Optional[Callable[[], tuple]] = None
     evict_session: Optional[Callable[[int], None]] = None
+    pads_session_rows: bool = False
 
 
 @dataclasses.dataclass

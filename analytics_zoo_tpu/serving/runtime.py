@@ -109,6 +109,12 @@ class ModelConfig:
     ladder and its weighted-EDF dispatch weight.  ``streaming`` marks a
     session-type model (``open_session``/``submit_chunk``) with
     ``chunk_deadline_s`` as the per-chunk incremental deadline.
+    ``serial_chunks`` (streaming models): a session may have ONE chunk
+    in flight — ``submit_chunk`` refuses the next until the last is
+    answered, as a caller that needs the answer to go on (a decoder LM's
+    next token) behaves anyway.  With it chunk order needs no help from
+    the buckets, so the plan may declare several ``bucket_edges`` (a
+    prefill chunk and a decoded token are different geometries).
 
     ``weights_to_tiers``: ``(placed_variables, replica_rid) ->
     [ServingTier]`` — how :meth:`ServingRuntime.hot_swap` turns a
@@ -131,6 +137,7 @@ class ModelConfig:
     streaming: bool = False
     chunk_deadline_s: float = 0.5
     ladder_policy: Optional[LadderPolicy] = None
+    serial_chunks: bool = False
 
     def __post_init__(self):
         if not self.tiers:
@@ -140,8 +147,8 @@ class ModelConfig:
                 f"streaming model {self.name!r} needs a tier_factory — "
                 f"session carry state must live per replica for session "
                 f"affinity to mean anything")
-        if self.streaming and self.bucket_edges \
-                and len(self.bucket_edges) > 1:
+        if self.streaming and not self.serial_chunks \
+                and self.bucket_edges and len(self.bucket_edges) > 1:
             # chunk order relies on EDF within ONE (model, affinity,
             # edge) group: with several edges a session's later chunk
             # could land in a bucket that flushes first and decode out
@@ -151,7 +158,8 @@ class ModelConfig:
                 f"streaming model {self.name!r} may declare at most one "
                 f"bucket edge — multiple edges would let a later chunk's "
                 f"bucket flush before an earlier chunk's, breaking "
-                f"in-order decode")
+                f"in-order decode (serial_chunks=True lifts this: one "
+                f"chunk of a session in flight)")
 
     def plan(self) -> ModelPlan:
         return ModelPlan(bucket_edges=self.bucket_edges,
@@ -203,7 +211,9 @@ class ServingRuntime:
     compile cost for the pre-warm / cold-compile modeling (0 disables).
     ``retain_requests=False`` drops per-request objects once terminal
     (accounting stays exact via incremental counters) — the
-    million-request drill's memory bound.
+    million-request drill's memory bound.  Either way an answer is handed
+    to its ``Request``, which the caller holds and the runtime then does
+    not.
 
     ``specs``: the pipeline's declared
     :class:`~analytics_zoo_tpu.parallel.specs.SpecSet` — pass the SAME
@@ -658,6 +668,12 @@ class ServingRuntime:
         if not sess["open"]:
             raise RuntimeError(f"session {sid} is closed")
         cfg = self.models[sess["model"]]
+        if cfg.serial_chunks and sess.get("last") is not None \
+                and not sess["last"].finished:
+            raise RuntimeError(
+                f"session {sid}: chunk {sess['chunks']} is not answered "
+                f"yet and model {cfg.name!r} takes one chunk of a session "
+                f"at a time (serial_chunks)")
         if deadline_s is None:
             deadline_s = cfg.chunk_deadline_s
         # chunk deadlines must stay MONOTONE within the session — EDF
@@ -676,6 +692,8 @@ class ServingRuntime:
             affinity=sess["replica"], final=final)
         sess["chunks"] += 1
         sess["last_deadline_t"] = req.deadline_t
+        if cfg.serial_chunks:
+            sess["last"] = req
         if final:
             self._close_session_books(sess)
         return req
@@ -788,12 +806,19 @@ class ServingRuntime:
         the first dispatch of a cold geometry would otherwise compile
         for seconds under the wedge watchdog and the request deadlines.
         Compile errors propagate.  Returns ``{(model, edge, tier):
-        slowest replica's seconds}``."""
+        slowest replica's seconds}``.
+
+        A streaming session model has a dry run only if every tier of it
+        says so (``ServingTier.pads_session_rows``): the warm-up batch's
+        rows all carry session −1, which such a tier takes for padding."""
         cfg = self._resolve_model(model)
-        if cfg.streaming:
+        if cfg.streaming and not all(t.pads_session_rows
+                                     for t in cfg.tiers):
             raise ValueError(
                 f"model {cfg.name!r} is a streaming session model — its "
-                f"forward mutates session state, there is no dry run")
+                f"forward mutates session state, there is no dry run "
+                f"(a tier that takes rows of session -1 for padding says "
+                f"so: ServingTier.pads_session_rows)")
         now = self.clock.now()
         took: Dict[Tuple[str, Any, int], float] = {}
         for key in self._geometry_plan():
@@ -1276,7 +1301,7 @@ class ServingRuntime:
         shares memory with it — a tier that returns its input, or a
         view of it — is copied before a request retains a row of it."""
         rows = np.asarray(out)
-        if self.retain_requests and np.shares_memory(
+        if np.shares_memory(
                 rows, batch.batch[self.models[batch.model].pad_key]):
             rows = rows.copy()
         return rows
@@ -1347,8 +1372,7 @@ class ServingRuntime:
                 if req.finished:            # scrubbed dead-session row
                     continue
                 req.tier = batch.tier
-                req.finish("done", now,
-                           result=rows[i] if self.retain_requests else None)
+                req.finish("done", now, result=rows[i])
                 self._account_terminal(req)
                 missed = now > req.deadline_t
                 self.metrics.on_complete(now - req.arrival_t, batch.tier,
@@ -1476,9 +1500,7 @@ class ServingRuntime:
                 if req.finished:        # scrubbed dead-session row
                     continue
                 req.tier = batch.tier
-                req.finish("done", completion,
-                           result=rows[i] if self.retain_requests
-                           else None)
+                req.finish("done", completion, result=rows[i])
                 self._account_terminal(req)
                 missed = completion > req.deadline_t
                 self.metrics.on_complete(completion - req.arrival_t,

@@ -542,9 +542,17 @@ class TestRepoClean:
         from analytics_zoo_tpu.parallel import registered_pipelines
 
         names = {t.name for t in repo_audit_suite()}
+        # a pipeline that is served and never trained (ISSUE 28: the
+        # decoder LM) joins with its serving programs alone
+        serve_only = {"lm"}
         for pipe in registered_pipelines():
+            if pipe in serve_only:
+                assert any(n.startswith(f"{pipe}/serve:") for n in names)
+                continue
             assert f"{pipe}/train" in names, names
             assert f"{pipe}/eval" in names, names
+        assert {"lm/serve:bf16", "lm/serve:prefill4",
+                "lm/serve:prefill8"} <= names
         assert {"ssd/serve:fp", "ssd/serve:int8"} <= names
         # ISSUE 13: the persistent-RNN TRAIN program (pallas engine,
         # transposed persistent backward) is audited alongside the
@@ -583,7 +591,7 @@ class TestRepoClean:
         silently."""
         from analytics_zoo_tpu.analysis.targets import (
             _ds2_serving, _ds2_streaming_serving, _fraud_serving,
-            _frcnn_serving, _rec_serving, _sentiment_serving,
+            _frcnn_serving, _lm_serving, _rec_serving, _sentiment_serving,
             _ssd_serving)
         from analytics_zoo_tpu.parallel import mesh as mesh_lib
 
@@ -591,7 +599,8 @@ class TestRepoClean:
         for target in (_ssd_serving(mesh) + _ds2_serving(mesh)
                        + _ds2_streaming_serving(mesh)
                        + _frcnn_serving(mesh) + _fraud_serving(mesh)
-                       + _rec_serving(mesh) + _sentiment_serving(mesh)):
+                       + _rec_serving(mesh) + _sentiment_serving(mesh)
+                       + _lm_serving(mesh)):
             built = target.build()      # raises if the hook is missing
             assert callable(built.fn)
 

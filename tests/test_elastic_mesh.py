@@ -171,7 +171,7 @@ class TestWidthChangeMatrix:
 
     def test_registry_is_the_expected_zoo(self):
         assert set(registered_pipelines()) == {
-            "ssd", "frcnn", "ds2", "fraud", "rec", "sentiment"}
+            "ssd", "frcnn", "ds2", "fraud", "rec", "sentiment", "lm"}
 
     @pytest.mark.parametrize("name", sorted(registered_pipelines()))
     def test_save_at_4_restore_at_narrower_bitexact(self, name, tmp_path):

@@ -1,0 +1,81 @@
+"""The Pallas form of prefill's attention (ops/pallas_lm_prefill.py):
+against the XLA loop at small lane-aligned widths in interpret mode, and
+compiled for a v5e at the published widths (no chip needed: the TPU's
+compiler is described, not attached)."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from analytics_zoo_tpu.ops import lm_attention as att
+from analytics_zoo_tpu.ops import pallas_lm_prefill as pf
+
+H, NOPE, ROPE, V, RANK, ENTRY, PAGE = 4, 128, 64, 128, 128, 256, 8
+
+
+def chunk(seed=0, T=16, start=19, n_valid=13, topk=8, pool_pages=12):
+    rng = np.random.RandomState(seed)
+    f = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)      # noqa: E731
+    kv = np.zeros((pool_pages, PAGE, ENTRY), np.float32)
+    kv[:, :, :RANK + ROPE] = rng.randn(pool_pages, PAGE, RANK + ROPE)
+    table = jnp.asarray([3, 7, 1, 9, 5, 0, 0, 0], jnp.int32)
+    args = (f(T, H, NOPE), f(T, H, ROPE), f(T, 2, 16), jnp.abs(f(T, 2)),
+            jnp.asarray(kv), f(pool_pages, PAGE, 16), table,
+            jnp.asarray(start, jnp.int32), jnp.asarray(n_valid, jnp.int32),
+            f(RANK, H, NOPE + V) / np.sqrt(RANK))
+    return args, dict(nope=NOPE, r=ROPE, scale=float(1 / np.sqrt(NOPE + ROPE)),
+                      topk=topk)
+
+
+@pytest.mark.parametrize("start,n_valid,topk", [(19, 13, 8), (0, 16, 8),
+                                                (24, 16, 64), (0, 0, 8)])
+def test_kernel_equals_the_xla_loop(start, n_valid, topk):
+    assert pf.supported(NOPE, V, RANK, ENTRY, ROPE, H, 2)
+    args, kw = chunk(start=start, n_valid=n_valid, topk=topk)
+    want, sets_x = att.prefill_full_attention(*args, **kw, flash=0)
+    got, sets_k = att.prefill_full_attention(*args, **kw, flash=2)
+    assert np.array_equal(np.asarray(sets_x), np.asarray(sets_k))
+    real = slice(0, n_valid)      # a padding row's output is nobody's
+    # float32 on both sides: two orders of summation
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real],
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_widths_the_kernel_does_not_take_run_the_xla_loop():
+    assert not pf.supported(16, 16, 32, 128, 8, 4, 4)        # the toy's
+    assert not pf.supported(128, 128, 512, 640, 64, 128, 3)  # heads % step
+    assert pf.supported(128, 128, 512, 640, 64, 128, 4)      # published
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("T", [256, 2048])
+def test_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, T,
+                                                           monkeypatch):
+    # the code asks the backend whether to interpret: steered here
+    monkeypatch.setattr(pf.engine, "on_tpu", lambda: True)
+    S = lambda s, d=jnp.bfloat16: jax.ShapeDtypeStruct(      # noqa: E731
+        s, d, sharding=one_chip)
+    heads, rank, entry, page, steps = 128, 512, 640, 512, 136
+    fn = lambda q, w, kv, b, tab, n: pf.flash_mla_prefill(   # noqa: E731
+        q, w, kv, b, tab, n, nope=128, scale=0.07, steps=steps,
+        heads_per_step=4)
+    compiled = jax.jit(fn).lower(
+        S((heads, T, 128 + entry - rank)), S((rank, heads, 256)),
+        S((2401, page, entry)), S((T, steps * page)),
+        S((steps,), jnp.int32), S((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert pf.declared_vmem_bytes(T, page, entry, rank, 128, 128, 4) \
+        < 64 * (1 << 20)

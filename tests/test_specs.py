@@ -81,6 +81,18 @@ def _small_model_for(name: str) -> Model:
                                    hidden=8, head="gru"))
         model.build(0, jnp.zeros((1, 12), jnp.int32))
         return model
+    if name == "lm":
+        # functional, not flax: the parameter tree is the whole model
+        from analytics_zoo_tpu.models import lm
+
+        cfg = lm.LMConfig(
+            d=16, kinds=(lm.FULL, lm.SLIDING), dense_layers=1,
+            full=lm.MLADims(2, 8, 8, 4, 4, 4, 8e7),
+            swa=lm.MLADims(2, 8, 8, 4, 4, 4, 5e4), window=5, idx_heads=2,
+            idx_dim=8, topk=4, f_dense=16, f_expert=8, f_shared=8,
+            experts=4, held=4, first_held=0, per_tok=2, route_scale=1.0,
+            vocab=16, eps=1e-5, dtype="float32")
+        return Model(None, {"params": lm.init_params(cfg, 0)})
     raise AssertionError(
         f"pipeline {name!r} registered in parallel.specs but this test "
         f"has no model factory for it — add one so the structure-match "
@@ -96,6 +108,7 @@ _VARIANTS = {
     "fraud": [{}],
     "rec": [{}, {"shard_tables": False}],
     "sentiment": [{}, {"shard_tables": False}],
+    "lm": [{}],
 }
 
 
