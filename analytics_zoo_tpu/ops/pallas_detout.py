@@ -33,6 +33,20 @@ same math as ONE kernel over a ``(batch, class)`` grid:
 Candidates never leave VMEM between the stages; the only HBM traffic
 is streaming the inputs once and writing the (B, keep_topk, 6) result.
 
+**Layout.**  Mosaic tiles a buffer's last two dims in ``(8, 128)``
+registers, so every per-prior vector (scores, the four box rows, the
+sweep's two masks, each class's keeps) is a dense ``(P_pad / 128, 128)``
+tile with ``P_pad`` a multiple of 1,024: SSD512's 24,564 priors are 192
+rows, 24 full registers (as ``(1, P)`` rows they were 192 registers with
+one sublane of eight in use).  Prior ``p`` sits at ``[p // 128,
+p % 128]`` and ``p`` is the index every tie-break is stated on.  A pass
+over a vector (max, index of the max, the IoU row) costs 24 register
+operations; whatever concerns ONE candidate — clearing its ``remaining``
+bit, reading its ``active`` bit and its box, writing its keep score,
+zeroing it in the merge, writing an output row — loads, selects in and
+stores the one aligned register that holds it, and a class's keeps are
+written in place into its own ``allkeep[c]`` tile.
+
 Semantics contract: bit-for-bit the same detections as
 ``detection_output_single`` (and therefore the xla/pallas backends) up
 to float associativity — pinned ≤1e-5 (measured exact on the test
@@ -63,64 +77,80 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from analytics_zoo_tpu.ops.pallas_nms import _round_up
-from analytics_zoo_tpu.ops.vmem import compiler_params, padded_bytes
+from analytics_zoo_tpu.ops.vmem import (compiler_params, padded_bytes,
+                                        round_up)
 
 #: prefix programs for the profile ladder (each includes the previous)
 STAGES = ("decode", "select", "full")
 
 
+def _padded_priors(n_priors: int) -> int:
+    """Priors padded to whole ``(8, 128)`` float32 registers: a per-prior
+    vector is then a dense ``(ppad / 128, 128)`` tile."""
+    return round_up(n_priors, 8 * 128)
+
+
 def fused_vmem_bytes(n_priors: int, n_classes: int, keep_topk: int) -> int:
     """VMEM the fused program's buffers occupy, priced the way Mosaic
-    lays them out (``ops.vmem.padded_bytes``): every ``(1, 1, P)`` lane
-    vector and every ``(1, 4, P)`` block pads to 8 sublanes, so the
-    figure is ~8× the logical bytes.  Counted: the per-class keep
-    scratch (C_fg rows), the seven work vectors, the double-buffered
-    score and loc blocks, the single-buffered prior/variance blocks
-    (whole-array windows are not double-buffered) and the
-    double-buffered output block.  ``detection_output`` selects on it and
-    ``fused_detection_output`` hands it to Mosaic as the VMEM limit."""
-    ppad = _round_up(n_priors, 128)
+    lays them out (``ops.vmem.padded_bytes``).  Every per-prior vector is
+    a dense ``(R, 128)`` tile with ``R`` a multiple of 8, so the figure is
+    the logical bytes of the padded priors; only the ``(keep_topk, 6)``
+    output block pads (6 lanes to 128).  Counted: the per-class keep
+    scratch (C_fg tiles), the decoded boxes (4) and the two sweep masks,
+    the double-buffered score and loc blocks, the single-buffered
+    prior/variance blocks (whole-array windows are not double-buffered)
+    and the double-buffered output block.  ``detection_output`` selects on
+    it and ``fused_detection_output`` hands it to Mosaic as the VMEM
+    limit."""
+    rows = _padded_priors(n_priors) // 128
     n_fg = max(n_classes - 1, 1)
-    vec = padded_bytes((1, 1, ppad), np.float32)
-    quad = padded_bytes((1, 4, ppad), np.float32)
-    scratch = (n_fg + 7) * vec
+    vec = padded_bytes((rows, 128), np.float32)
+    quad = padded_bytes((4, rows, 128), np.float32)
+    scratch = (n_fg + 2) * vec + quad
     blocks = 2 * vec + 2 * quad + 2 * quad
     return scratch + blocks + 2 * padded_bytes((keep_topk, 6), np.float32)
 
 
 def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
-                  bx1, by1, bx2, by2, active, remaining, curkeep, allkeep,
-                  *, n_fg: int, n_priors: int, ppad: int, kout: int,
+                  boxes, active, remaining, allkeep,
+                  *, n_fg: int, n_priors: int, rows: int, kout: int,
                   conf_thresh: float, nms_thresh: float, nms_topk: int,
                   bg_id: int, clip: bool, stage: str):
-    """One (image, class) grid step.  All per-candidate reads/writes are
-    masked full-row VPU ops (TPU VMEM has no scalar stores — the
-    ``pallas_nms`` convention); scratch persists across the class grid,
-    which is what lets decode run once per image and the global merge
-    see every class's keeps without an HBM round-trip."""
+    """One (image, class) grid step.  A per-prior vector is an
+    ``(rows, 128)`` tile and prior ``p`` sits at ``[p // 128, p % 128]``.
+    Whole-vector work (max, index of the max, the IoU row) runs over
+    ``rows / 8`` full registers; everything that touches ONE candidate
+    loads, selects in and stores the one aligned ``(8, 128)`` register
+    that holds it (TPU VMEM has no scalar stores).  Scratch persists
+    across the class grid, which is what lets decode run once per image
+    and the global merge see every class's keeps without an HBM
+    round-trip."""
     c = pl.program_id(1)
     f32 = jnp.float32
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, ppad), 2)
+    ppad = rows * 128
+    # a prior's flat index: what every tie-break below is stated on
+    flat = (jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0) * 128
+            + jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1))
+    flat8 = flat[:8]
 
-    def pick(vec_, is_):
-        return jnp.sum(jnp.where(is_, vec_, 0.0))
+    def register_of(p):
+        """The aligned 8-row window holding prior ``p`` and ``p``'s
+        place in it."""
+        r0 = pl.multiple_of((p // 1024) * 8, 8)
+        return pl.ds(r0, 8), flat8 + r0 * 128 == p
+
+    def pick(reg, hit):
+        return jnp.sum(jnp.where(hit, reg, 0.0))
+
+    def box_of(win, hit):
+        return [pick(boxes[i, win, :], hit) for i in range(4)]
 
     # -- stage 1: box decode, once per image (class-constant blocks) ------
     @pl.when(c == 0)
     def _decode():
-        r4 = jax.lax.broadcasted_iota(jnp.int32, (1, 4, ppad), 1)
-
-        def row(ref, i):
-            # masked cross-sublane reduce: sublane i of the (1,4,ppad)
-            # block as a (1,1,ppad) lane vector (static sublane slices
-            # at non-8-aligned offsets are not a Mosaic-legal load)
-            return jnp.sum(jnp.where(r4 == i, ref[...], 0.0), axis=1,
-                           keepdims=True)
-
-        dx, dy, dw, dh = (row(loc_ref, i) for i in range(4))
-        px1, py1, px2, py2 = (row(priors_ref, i) for i in range(4))
-        v0, v1, v2, v3 = (row(var_ref, i) for i in range(4))
+        dx, dy, dw, dh = (loc_ref[0, i] for i in range(4))
+        px1, py1, px2, py2 = (priors_ref[i] for i in range(4))
+        v0, v1, v2, v3 = (var_ref[i] for i in range(4))
         # exact decode_bbox math (ops/bbox.py): center-size deltas
         pw = px2 - px1
         ph = py2 - py1
@@ -135,16 +165,16 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
         if clip:
             x1, y1 = jnp.clip(x1, 0.0, 1.0), jnp.clip(y1, 0.0, 1.0)
             x2, y2 = jnp.clip(x2, 0.0, 1.0), jnp.clip(y2, 0.0, 1.0)
-        bx1[:], by1[:], bx2[:], by2[:] = x1, y1, x2, y2
+        boxes[0], boxes[1], boxes[2], boxes[3] = x1, y1, x2, y2
 
     # -- stage 2: per-class filter + selection + suppression, fused -------
     if stage in ("select", "full"):
-        s = scores_ref[...][0]                          # (1, 1, ppad)
-        valid = ((lane < n_priors)
-                 & (s > conf_thresh)).astype(f32)
+        keep_c = allkeep.at[c]                          # this class's keeps
+        valid = ((flat < n_priors)
+                 & (scores_ref[0, 0] > conf_thresh)).astype(f32)
         active[:] = valid
         remaining[:] = valid
-        curkeep[:] = jnp.zeros_like(curkeep)
+        keep_c[:] = jnp.zeros((rows, 128), f32)
         # pop order IS descending-score order (ties: lowest prior index,
         # lax.top_k's stable order), and the pop INDEX is the sorted
         # rank — so stopping at nms_topk pops reproduces the reference's
@@ -154,26 +184,24 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
         bound = jnp.minimum(jnp.sum(valid).astype(jnp.int32), nms_topk)
 
         def body(i, _):
-            vals = jnp.where(remaining[:] > 0, s, -jnp.inf)
+            vals = jnp.where(remaining[:] > 0, scores_ref[0, 0], -jnp.inf)
             m = jnp.max(vals)
-            p = jnp.min(jnp.where(vals == m, lane, ppad))
-            is_p = lane == p
-            remaining[:] = jnp.where(is_p, 0.0, remaining[:])
+            p = jnp.min(jnp.where(vals == m, flat, ppad))
+            win, hit = register_of(p)
+            remaining[win, :] = jnp.where(hit, 0.0, remaining[win, :])
 
-            @pl.when(pick(active[:], is_p) > 0.0)
+            @pl.when(pick(active[win, :], hit) > 0.0)
             def _keep():
-                curkeep[:] = jnp.where(is_p, s, curkeep[:])
-                x1 = pick(bx1[:], is_p)
-                y1 = pick(by1[:], is_p)
-                x2 = pick(bx2[:], is_p)
-                y2 = pick(by2[:], is_p)
-                ix1 = jnp.maximum(bx1[:], x1)
-                iy1 = jnp.maximum(by1[:], y1)
-                ix2 = jnp.minimum(bx2[:], x2)
-                iy2 = jnp.minimum(by2[:], y2)
+                keep_c[win, :] = jnp.where(hit, m, keep_c[win, :])
+                x1, y1, x2, y2 = box_of(win, hit)
+                bx1, by1, bx2, by2 = (boxes[i] for i in range(4))
+                ix1 = jnp.maximum(bx1, x1)
+                iy1 = jnp.maximum(by1, y1)
+                ix2 = jnp.minimum(bx2, x2)
+                iy2 = jnp.minimum(by2, y2)
                 inter = (jnp.maximum(ix2 - ix1, 0.0)
                          * jnp.maximum(iy2 - iy1, 0.0))
-                area = (bx2[:] - bx1[:]) * (by2[:] - by1[:])
+                area = (bx2 - bx1) * (by2 - by1)
                 area_p = (x2 - x1) * (y2 - y1)
                 union = jnp.maximum(area + area_p - inter, 1e-12)
                 # deactivate everything overlapping the kept box
@@ -184,47 +212,46 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
             return 0
 
         jax.lax.fori_loop(0, bound, body, 0)
-        ci = jax.lax.broadcasted_iota(jnp.int32, (n_fg, 1, ppad), 0)
-        allkeep[:] = jnp.where(ci == c, curkeep[:], allkeep[:])
 
     # -- stage 3: global cross-class top-K, last class step ---------------
     if stage == "full":
         @pl.when(c == n_fg - 1)
         def _merge():
-            rowi = jax.lax.broadcasted_iota(jnp.int32, (1, kout, 6), 1)
-            coli = jax.lax.broadcasted_iota(jnp.int32, (1, kout, 6), 2)
-            out_ref[:] = jnp.where(coli == 0, -1.0, 0.0)  # empty rows
-            ci = jax.lax.broadcasted_iota(jnp.int32, (n_fg, 1, ppad), 0)
-            li = jax.lax.broadcasted_iota(jnp.int32, (n_fg, 1, ppad), 2)
-            flat = ci * ppad + li
+            coli = jax.lax.broadcasted_iota(jnp.int32, (8, 6), 1)
+            rowi = jax.lax.broadcasted_iota(jnp.int32, (8, 6), 0)
+            out_ref[0] = jnp.where(             # empty rows
+                jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 1)
+                == 0, -1.0, 0.0)
+            ci = jax.lax.broadcasted_iota(jnp.int32, (n_fg, rows, 128), 0)
             n_kept = jnp.sum((allkeep[:] > 0).astype(f32)).astype(jnp.int32)
             npop = jnp.minimum(n_kept, kout)
 
             def body(j, _):
                 ak = allkeep[:]
-                m = jnp.max(ak)
+                m = jnp.max(jnp.max(ak, axis=0))
                 # tie-break: lowest flattened (class, prior) index ==
                 # lax.top_k's stable order over the reference's
-                # class-major candidate layout
-                idx = jnp.min(jnp.where(ak == m, flat, n_fg * ppad))
+                # class-major candidate layout; per prior the lowest
+                # class that holds the max, then the lowest such pair
+                cls_at = jnp.min(jnp.where(ak == m, ci, n_fg), axis=0)
+                idx = jnp.min(cls_at * ppad + flat)
                 cstar = idx // ppad
-                pstar = idx - cstar * ppad
-                is_p = lane == pstar
+                win, hit = register_of(idx - cstar * ppad)
                 # foreground row → original class id (the background
                 # column was dropped before the kernel)
                 cls = (cstar
                        + (cstar >= bg_id).astype(jnp.int32)).astype(f32)
-                x1 = pick(bx1[:], is_p)
-                y1 = pick(by1[:], is_p)
-                x2 = pick(bx2[:], is_p)
-                y2 = pick(by2[:], is_p)
+                x1, y1, x2, y2 = box_of(win, hit)
                 vals = jnp.where(coli == 0, cls,
                        jnp.where(coli == 1, m,
                        jnp.where(coli == 2, x1,
                        jnp.where(coli == 3, y1,
                        jnp.where(coli == 4, x2, y2)))))
-                out_ref[:] = jnp.where(rowi == j, vals, out_ref[:])
-                allkeep[:] = jnp.where(flat == idx, 0.0, ak)
+                j0 = pl.multiple_of((j // 8) * 8, 8)
+                out_ref[0, pl.ds(j0, 8), :] = jnp.where(
+                    rowi == j - j0, vals, out_ref[0, pl.ds(j0, 8), :])
+                keep_s = allkeep.at[cstar]
+                keep_s[win, :] = jnp.where(hit, 0.0, keep_s[win, :])
                 return 0
 
             jax.lax.fori_loop(0, npop, body, 0)
@@ -234,9 +261,9 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
         # interpret-mode emulation dead-code the measured work)
         @pl.when(c == n_fg - 1)
         def _touch():
-            probe = (jnp.sum(bx1[:]) + jnp.sum(by2[:])
+            probe = (jnp.sum(boxes[0]) + jnp.sum(boxes[3])
                      + (jnp.sum(allkeep[:]) if stage == "select" else 0.0))
-            out_ref[:] = jnp.zeros((1, kout, 6), f32) + probe
+            out_ref[:] = jnp.zeros(out_ref.shape, f32) + probe
 
 
 @functools.partial(jax.jit, static_argnames=("param", "interpret", "stage"))
@@ -263,51 +290,57 @@ def fused_detection_output(loc: jax.Array, conf: jax.Array,
     if not n_fg:
         raise ValueError("fused DetectionOutput needs >= 1 foreground "
                          "class")
-    ppad = _round_up(P, 128)
-    pad = ppad - P
+    ppad = _padded_priors(P)
+    rows = ppad // 128
+    kout = int(param.keep_topk)
+    # the merge writes an answer's row through the aligned 8-row window
+    # that holds it: the output block is whole registers, cut after
+    kpad = round_up(kout, 8)
+
+    def tiles(x):
+        """(..., P) → (..., rows, 128): prior p at [p // 128, p % 128]."""
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, ppad - P)])
+        return x.reshape(x.shape[:-1] + (rows, 128))
 
     # background dropped HERE (layout, not masking): only foreground
     # rows enter the (batch, class) grid
-    scores = jnp.swapaxes(conf.astype(jnp.float32)[..., fg_ids], 1, 2)
-    scores = jnp.pad(scores, ((0, 0), (0, 0), (0, pad)))[:, :, None, :]
-    loc_t = jnp.pad(jnp.swapaxes(loc.astype(jnp.float32), 1, 2),
-                    ((0, 0), (0, 0), (0, pad)))
-    pr = jnp.pad(jnp.swapaxes(jnp.asarray(priors, jnp.float32), 0, 1),
-                 ((0, 0), (0, pad)))[None]
-    vr = jnp.pad(jnp.swapaxes(jnp.asarray(variances, jnp.float32), 0, 1),
-                 ((0, 0), (0, pad)))[None]
+    scores = tiles(jnp.swapaxes(conf.astype(jnp.float32)[..., fg_ids], 1, 2))
+    loc_t = tiles(jnp.swapaxes(loc.astype(jnp.float32), 1, 2))
+    pr = tiles(jnp.swapaxes(jnp.asarray(priors, jnp.float32), 0, 1))
+    vr = tiles(jnp.swapaxes(jnp.asarray(variances, jnp.float32), 0, 1))
 
     kernel = functools.partial(
-        _fused_kernel, n_fg=n_fg, n_priors=P, ppad=ppad,
-        kout=int(param.keep_topk), conf_thresh=float(param.conf_thresh),
+        _fused_kernel, n_fg=n_fg, n_priors=P, rows=rows, kout=kout,
+        conf_thresh=float(param.conf_thresh),
         nms_thresh=float(param.nms_thresh), nms_topk=int(param.nms_topk),
         bg_id=int(param.background_id), clip=bool(param.clip_boxes),
         stage=stage)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(B, n_fg),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, ppad), lambda b, c: (b, c, 0, 0),
+            pl.BlockSpec((1, 1, rows, 128), lambda b, c: (b, c, 0, 0),
                          memory_space=pltpu.VMEM),
             # loc / priors / variances: class-constant index maps keep
             # the blocks VMEM-resident across the inner class grid
-            pl.BlockSpec((1, 4, ppad), lambda b, c: (b, 0, 0),
+            pl.BlockSpec((1, 4, rows, 128), lambda b, c: (b, 0, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 4, ppad), lambda b, c: (0, 0, 0),
+            pl.BlockSpec((4, rows, 128), lambda b, c: (0, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 4, ppad), lambda b, c: (0, 0, 0),
+            pl.BlockSpec((4, rows, 128), lambda b, c: (0, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         # one output block per image, revisited across the class grid
-        out_specs=pl.BlockSpec((1, int(param.keep_topk), 6),
-                               lambda b, c: (b, 0, 0),
+        out_specs=pl.BlockSpec((1, kpad, 6), lambda b, c: (b, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B, int(param.keep_topk), 6),
-                                       jnp.float32),
-        scratch_shapes=(
-            [pltpu.VMEM((1, 1, ppad), jnp.float32) for _ in range(7)]
-            + [pltpu.VMEM((n_fg, 1, ppad), jnp.float32)]),
-        compiler_params=compiler_params(
-            fused_vmem_bytes(P, C, int(param.keep_topk))),
+        out_shape=jax.ShapeDtypeStruct((B, kpad, 6), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((4, rows, 128), jnp.float32),        # boxes
+            pltpu.VMEM((rows, 128), jnp.float32),           # active
+            pltpu.VMEM((rows, 128), jnp.float32),           # remaining
+            pltpu.VMEM((n_fg, rows, 128), jnp.float32),     # allkeep
+        ],
+        compiler_params=compiler_params(fused_vmem_bytes(P, C, kout)),
         interpret=interpret,
     )(scores, loc_t, pr, vr)
+    return out[:, :kout]

@@ -1,7 +1,9 @@
 """The Pallas form of prefill's attention (ops/pallas_lm_prefill.py):
 against the XLA loop at small lane-aligned widths in interpret mode, and
 compiled for a v5e at the published widths (no chip needed: the TPU's
-compiler is described, not attached)."""
+compiler is described, not attached).  The repo's other compile-for-a-v5e
+tests live here too (fused DetectionOutput, last): only the one xdist
+worker that is given this file loads the TPU's library."""
 
 import numpy as np
 import pytest
@@ -79,3 +81,29 @@ def test_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, T,
     assert "tpu_custom_call" in compiled.as_text()
     assert pf.declared_vmem_bytes(T, page, entry, rank, 128, 128, 4) \
         < 64 * (1 << 20)
+
+
+@pytest.mark.parametrize("batch,n_priors,stage,keep_topk", [
+    (32, 8732, "full", 200), (64, 24564, "decode", 200),
+    (64, 24564, "select", 200), (64, 24564, "full", 200),
+    (64, 24564, "full", 50)])
+def test_fused_detection_output_compiles_for_a_v5e(one_chip, batch,
+                                                   n_priors, stage,
+                                                   keep_topk):
+    """``ops/pallas_detout.py`` at SSD300's and SSD512's priors (the
+    serve cell's batch), each prefix program, and the ``int8_topk50``
+    tier's ``keep_topk``: Mosaic takes the dense (rows, 128) tiles, the
+    dynamic aligned register windows and the dynamic first-axis class
+    index, inside the VMEM the kernel asks for."""
+    from analytics_zoo_tpu.ops.detection_output import DetectionOutputParam
+    from analytics_zoo_tpu.ops.pallas_detout import fused_detection_output
+
+    S = lambda *s: jax.ShapeDtypeStruct(                     # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+    fn = lambda l, c, p, v: fused_detection_output(          # noqa: E731
+        l, c, p, v, param=DetectionOutputParam(keep_topk=keep_topk),
+        stage=stage)
+    compiled = jax.jit(fn).lower(
+        S(batch, n_priors, 4), S(batch, n_priors, 21), S(n_priors, 4),
+        S(n_priors, 4)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -76,43 +76,51 @@ def _assert_rows_match(got, ref, atol=1e-5):
 
 BASE = dict(n_classes=6, nms_topk=64, keep_topk=32)
 
+#: the kernel pads P to whole (8, 128) registers (a multiple of 1,024):
+#: under one register; a multiple of 128 but not of 1,024 (padded rows,
+#: no padded lane); neither (padded lanes in the last row, and padded rows)
+PRIOR_COUNTS = [160, 1152, 1300]
 
+
+@pytest.mark.parametrize("priors_n", PRIOR_COUNTS)
 class TestFusedParity:
     @pytest.mark.parametrize("seed", [0, 7])
-    def test_trained_like_conf(self, seed):
+    def test_trained_like_conf(self, seed, priors_n):
         """The serving distribution: background bias +7 makes conf
         sparse exactly like a trained SSD's softmax (the SERVE_PROFILE
         methodology), a few re-boosted hot priors carry detections."""
-        loc, conf, priors, variances = _inputs(seed, bg_bias=7.0,
-                                               hot_frac=0.05)
+        loc, conf, priors, variances = _inputs(
+            seed, priors_n=priors_n, bg_bias=7.0, hot_frac=0.05)
         assert (np.asarray(conf)[..., 1:] > 0.01).mean() < 0.15
         p = DetectionOutputParam(**BASE)
         _assert_rows_match(_fused(loc, conf, priors, variances, p),
                            _reference(loc, conf, priors, variances, p))
 
     @pytest.mark.parametrize("seed", [0, 3])
-    def test_dense_untrained_conf(self, seed):
+    def test_dense_untrained_conf(self, seed, priors_n):
         """Dense near-uniform conf (untrained init): every class row
         saturates the nms_topk pop bound — the opposite regime."""
-        loc, conf, priors, variances = _inputs(seed)
+        loc, conf, priors, variances = _inputs(seed, priors_n=priors_n)
         p = DetectionOutputParam(**BASE)
         _assert_rows_match(_fused(loc, conf, priors, variances, p),
                            _reference(loc, conf, priors, variances, p))
 
-    def test_ragged_valid_candidate_rows(self):
+    def test_ragged_valid_candidate_rows(self, priors_n):
         """Per-class candidate populations from dense to empty: the
         dynamic pop bound must handle every row width in ONE grid."""
         loc, conf, priors, variances = _inputs(
-            11, bg_bias=6.0, per_class_hot=[0.5, 0.1, 0.02, 0.002, 0.0])
+            11, priors_n=priors_n, bg_bias=6.0,
+            per_class_hot=[0.5, 0.1, 0.02, 0.002, 0.0])
         p = DetectionOutputParam(**BASE)
         _assert_rows_match(_fused(loc, conf, priors, variances, p),
                            _reference(loc, conf, priors, variances, p))
 
-    def test_all_background_and_empty_classes(self):
+    def test_all_background_and_empty_classes(self, priors_n):
         """No foreground score above conf_thresh → every output row is
         the empty convention (class -1, score 0, zero box), matching
         the reference exactly."""
-        loc, conf, priors, variances = _inputs(5, bg_bias=20.0)
+        loc, conf, priors, variances = _inputs(5, priors_n=priors_n,
+                                               bg_bias=20.0)
         p = DetectionOutputParam(**BASE)
         got = _fused(loc, conf, priors, variances, p)
         ref = _reference(loc, conf, priors, variances, p)
@@ -120,40 +128,41 @@ class TestFusedParity:
         assert (got[..., 0] == -1).all() and (got[..., 1] == 0).all()
         assert (got[..., 2:] == 0).all()
 
-    def test_int8_quantized_conf_ties_agree(self):
+    def test_int8_quantized_conf_ties_agree(self, priors_n):
         """Int8-quantized score grids (the int8 serving tiers' regime)
         create massive exact TIES; the fused kernel's lowest-flat-index
         pop order must reproduce lax.top_k's stable order both per
         class and in the global merge — row-for-row equality, not just
         set equality."""
-        loc, conf, priors, variances = _inputs(2, bg_bias=5.0,
-                                               hot_frac=0.08)
+        loc, conf, priors, variances = _inputs(
+            2, priors_n=priors_n, bg_bias=5.0, hot_frac=0.08)
         qconf = jnp.asarray(
             np.round(np.asarray(conf) * 127.0) / 127.0)
         p = DetectionOutputParam(**BASE)
         _assert_rows_match(_fused(loc, qconf, priors, variances, p),
                            _reference(loc, qconf, priors, variances, p))
 
-    def test_clip_boxes(self):
-        loc, conf, priors, variances = _inputs(4, bg_bias=4.0,
-                                               hot_frac=0.1)
+    def test_clip_boxes(self, priors_n):
+        loc, conf, priors, variances = _inputs(
+            4, priors_n=priors_n, bg_bias=4.0, hot_frac=0.1)
         p = DetectionOutputParam(**BASE, clip_boxes=True)
         _assert_rows_match(_fused(loc, conf, priors, variances, p),
                            _reference(loc, conf, priors, variances, p))
 
-    def test_nonzero_background_id(self):
+    def test_nonzero_background_id(self, priors_n):
         """The foreground-row → class-id mapping when background is not
         class 0 (the discard-at-selection layout must skip the right
         column)."""
-        loc, conf, priors, variances = _inputs(6, hot_frac=0.05)
+        loc, conf, priors, variances = _inputs(
+            6, priors_n=priors_n, hot_frac=0.05)
         p = DetectionOutputParam(**BASE, background_id=3)
         _assert_rows_match(_fused(loc, conf, priors, variances, p),
                            _reference(loc, conf, priors, variances, p))
 
-    def test_matches_unfused_pallas_backend(self):
+    def test_matches_unfused_pallas_backend(self, priors_n):
         """Backend triple-point: fused == pallas == xla on one batch."""
-        loc, conf, priors, variances = _inputs(8, bg_bias=6.0,
-                                               hot_frac=0.05)
+        loc, conf, priors, variances = _inputs(
+            8, priors_n=priors_n, bg_bias=6.0, hot_frac=0.05)
         outs = {}
         for backend in ("xla", "pallas", "fused"):
             p = DetectionOutputParam(**BASE, backend=backend)
@@ -162,16 +171,143 @@ class TestFusedParity:
         _assert_rows_match(outs["fused"], outs["pallas"])
         _assert_rows_match(outs["fused"], outs["xla"])
 
-    def test_keep_topk_exceeds_kept_count(self):
+    @pytest.mark.parametrize("keep_topk", [5, 13, 50])
+    def test_keep_topk_off_a_multiple_of_eight(self, priors_n, keep_topk):
+        """The merge writes a row through the aligned 8-row window that
+        holds it; a ``keep_topk`` that ends inside a window (50 is the
+        ``int8_topk50`` serving tier's) gets exactly its rows."""
+        loc, conf, priors, variances = _inputs(
+            1, priors_n=priors_n, bg_bias=5.0, hot_frac=0.1)
+        p = DetectionOutputParam(n_classes=6, nms_topk=64,
+                                 keep_topk=keep_topk)
+        got = _fused(loc, conf, priors, variances, p)
+        assert got.shape == (2, keep_topk, 6)
+        assert (got[..., 0] >= 0).all()         # more kept than asked for
+        _assert_rows_match(got, _reference(loc, conf, priors, variances, p))
+
+    def test_keep_topk_exceeds_kept_count(self, priors_n):
         """keep_topk far above the surviving-candidate count: the tail
         rows are the empty convention and the head rows still match."""
-        loc, conf, priors, variances = _inputs(9, bg_bias=8.0,
-                                               hot_frac=0.01)
+        loc, conf, priors, variances = _inputs(
+            9, priors_n=priors_n, bg_bias=8.0, hot_frac=0.01)
         p = DetectionOutputParam(n_classes=6, nms_topk=64, keep_topk=120)
         got = _fused(loc, conf, priors, variances, p)
         ref = _reference(loc, conf, priors, variances, p)
         _assert_rows_match(got, ref)
         assert (got[..., 1] > 0).sum() < got.shape[0] * 120
+
+
+def _placed(priors_n, scores, overlaps=(), classes=4):
+    """One image of hand-placed candidates.  Prior ``i`` is a small box in
+    its own cell of a 64-wide grid and ``loc`` is zero, so a decoded box
+    IS its prior's box and no two overlap — but for ``overlaps`` pairs
+    ``(a, b)``, where ``b``'s box is ``a``'s moved by a tenth of its
+    width (IoU 0.82).  ``scores``: {(class, prior): score}; every other
+    score is 0."""
+    i = np.arange(priors_n)
+    x1 = (i % 64) / 64.0
+    y1 = (i // 64) / 32.0
+    priors = np.stack([x1, y1, x1 + 0.01, y1 + 0.02], 1).astype(np.float32)
+    for a, b in overlaps:
+        priors[b] = priors[a] + np.float32([0.001, 0.0, 0.001, 0.0])
+    variances = np.tile(np.float32([0.1, 0.1, 0.2, 0.2]), (priors_n, 1))
+    conf = np.zeros((1, priors_n, classes), np.float32)
+    for (cls, prior), score in scores.items():
+        conf[0, prior, cls] = score
+    loc = np.zeros((1, priors_n, 4), np.float32)
+    return loc, conf, priors, variances
+
+
+def _expected_rows(priors, rows, keep_topk):
+    """(class, score, prior) triples → the (1, keep_topk, 6) answer."""
+    out = np.zeros((1, keep_topk, 6), np.float32)
+    out[..., 0] = -1.0
+    for k, (cls, score, prior) in enumerate(rows):
+        out[0, k] = [cls, score, *priors[prior]]
+    return out
+
+
+class TestFlatIndexOrder:
+    """A prior's flat index is ``row * 128 + lane`` of the kernel's
+    ``(rows, 128)`` tiles.  Every tie-break is stated on it, and nothing
+    may depend on where a row or a register ends: hand-placed candidates,
+    each case against the xla reference AND against the answer written
+    out by hand."""
+
+    PARAM = dict(n_classes=4, nms_topk=64, keep_topk=8)
+
+    def _check(self, case, rows, **param):
+        loc, conf, priors, variances = case
+        p = DetectionOutputParam(**{**self.PARAM, **param})
+        got = _fused(loc, conf, priors, variances, p)
+        _assert_rows_match(got, _reference(loc, conf, priors, variances, p))
+        _assert_rows_match(got, _expected_rows(priors, rows, p.keep_topk))
+
+    def test_equal_scores_in_two_rows_and_two_classes(self):
+        """The sweep: of two overlapping candidates with one score, in
+        rows 0 and 3, the lower prior is popped first and suppresses
+        the other.  The merge: equal scores in two classes come out
+        class-major (class 2's prior 700 before class 3's prior 7), and
+        within a class by prior (130, row 1, before 900, row 7)."""
+        case = _placed(1300, {(1, 5): 0.9, (1, 389): 0.9,
+                              (2, 700): 0.8, (3, 7): 0.8,
+                              (2, 130): 0.7, (2, 900): 0.7},
+                       overlaps=[(5, 389)])
+        self._check(case, [(1, 0.9, 5), (2, 0.8, 700), (3, 0.8, 7),
+                           (2, 0.7, 130), (2, 0.7, 900)])
+
+    def test_higher_prior_wins_on_score_not_on_place(self):
+        """The same pair with the higher prior scoring higher: it is
+        kept and the lower one suppressed."""
+        case = _placed(1300, {(1, 5): 0.8, (1, 389): 0.9},
+                       overlaps=[(5, 389)])
+        self._check(case, [(1, 0.9, 389)])
+
+    @pytest.mark.parametrize("priors_n", PRIOR_COUNTS)
+    def test_last_valid_prior_is_the_top_candidate(self, priors_n):
+        """The last lane that holds a prior (the lanes after it are
+        padding) pops first, and is found again by the merge."""
+        last = priors_n - 1
+        case = _placed(priors_n, {(1, last): 0.95, (1, 0): 0.5,
+                                  (3, last): 0.6})
+        self._check(case, [(1, 0.95, last), (3, 0.6, last), (1, 0.5, 0)])
+
+    @pytest.mark.parametrize("first,second", [(127, 128), (128, 127),
+                                              (1023, 1024), (1024, 1023)])
+    def test_suppression_crosses_a_row_and_a_register(self, first, second):
+        """A candidate in the last lane of a row suppresses its
+        neighbour in the first lane of the next row (127 | 128), the
+        same across two registers (1,023 | 1,024), and the other way
+        round; a third candidate far away is untouched."""
+        lo, hi = min(first, second), max(first, second)
+        case = _placed(1300, {(2, first): 0.9, (2, second): 0.8,
+                              (2, 640): 0.3}, overlaps=[(lo, hi)])
+        self._check(case, [(2, 0.9, first), (2, 0.3, 640)])
+
+    @pytest.mark.parametrize("conf_thresh", [0.0, -1.0])
+    @pytest.mark.parametrize("priors_n", PRIOR_COUNTS)
+    def test_padding_is_never_a_candidate(self, priors_n, conf_thresh):
+        """With ``conf_thresh`` <= 0 a padding lane's score of 0 is over
+        the threshold (at -1) or ties with real priors' (at 0): only the
+        flat index keeps it out.  Three real candidates and ``nms_topk``
+        larger than the priors, so every lane that counts as valid is
+        popped: the answer holds the three, in order, and no box of a
+        padding lane (all zeros)."""
+        last = priors_n - 1
+        case = _placed(priors_n, {(1, last): 0.4, (2, 3): 0.6,
+                                  (3, 129): 0.5})
+        self._check(case, [(2, 0.6, 3), (3, 0.5, 129), (1, 0.4, last)],
+                    conf_thresh=conf_thresh, nms_topk=2048)
+
+    @pytest.mark.parametrize("conf_thresh", [0.0, -1.0])
+    def test_dense_conf_at_a_threshold_of_zero(self, conf_thresh):
+        """Every real prior of every class is a candidate (dense
+        untrained conf, all scores > 0): ``nms_topk`` pops a class, none
+        of them a padding lane."""
+        loc, conf, priors, variances = _inputs(3, priors_n=1300)
+        p = DetectionOutputParam(**BASE, conf_thresh=conf_thresh)
+        _assert_rows_match(_fused(loc, conf, priors, variances, p),
+                           _reference(loc, conf, priors, variances, p))
 
 
 class TestBackendResolution:
@@ -225,12 +361,11 @@ class TestBackendResolution:
                              DetectionOutputParam(**BASE, backend="mosaic"))
 
     def test_estimate_counts_tile_padding(self):
-        """Every (1, 1, P) lane vector and (1, 4, P) block occupies 8
-        sublanes in VMEM: the estimate is ~8x the logical bytes, and it
-        matches what the v5e accepted (PR 21 chip run: SSD300 compiled
-        inside its 21.6 MiB request; SSD512 needed 33.3 MiB, more than
-        its 24.9 MiB of declared buffers, less than the 45.4 MiB it now
-        asks for)."""
+        """A per-prior vector is a dense (P_pad / 128, 128) tile, P padded
+        to whole (8, 128) registers: the scratch and the input blocks
+        cost their logical bytes, and only the (keep_topk, 6) output
+        block pads (6 lanes to 128).  The one-sublane layout before PR 30
+        cost 8x: 9.1 MiB at SSD300 and 24.9 MiB at SSD512."""
         from analytics_zoo_tpu.ops import vmem
         from analytics_zoo_tpu.ops.pallas_detout import fused_vmem_bytes
 
@@ -238,13 +373,19 @@ class TestBackendResolution:
         ssd300 = fused_vmem_bytes(8732, 21, 200)
         ssd512 = fused_vmem_bytes(24564, 21, 200)
         assert small < ssd300 < ssd512
-        ppad = 8832
-        logical = (20 + 7) * ppad * 4        # scratch rows, unpadded
-        assert ssd300 > 8 * logical
-        assert 9.0 < ssd300 / 2**20 < 9.2
-        assert 24.9 < ssd512 / 2**20 < 25.0
-        assert vmem.limit_bytes(ssd512) > 33.34 * 2**20
+        out = 2 * 200 * 128 * 4              # two (200, 6) blocks, padded
+        # 20 keep tiles + 4 box tiles + 2 masks of scratch; 2 score,
+        # 2 x 4 loc, 4 prior and 4 variance tiles of input blocks
+        vectors = (20 + 4 + 2) + (2 + 8 + 4 + 4)
+        assert ssd300 == vectors * 9216 * 4 + out       # 72 rows of 128
+        assert ssd512 == vectors * 24576 * 4 + out      # 192 rows
+        assert small == (5 + 6 + 18) * 1024 * 4 + 2 * 32 * 128 * 4
+        assert 1.74 < ssd300 / 2**20 < 1.75
+        assert 4.32 < ssd512 / 2**20 < 4.33
+        assert vmem.limit_bytes(ssd512) < 15 * 2**20
         assert vmem.fits(ssd300) and vmem.fits(ssd512)
+        # a geometry four times SSD512's priors at 81 classes still fits
+        assert vmem.fits(fused_vmem_bytes(4 * 24564, 81, 200))
 
     def test_param_is_static_arg_usable(self):
         p = DetectionOutputParam(backend="fused")
@@ -276,10 +417,12 @@ class TestFusedDeviceTwins:
 
     @pytest.mark.pallas(device=True)
     @pytest.mark.parametrize("resolution,batch",
-                             [(300, 8), (300, 32), (512, 8)])
+                             [(300, 8), (300, 32), (512, 8),
+                              (512, 64)])
     def test_compiled_kernel_at_zoo_geometries(self, resolution, batch):
         """The geometries serving runs: SSD300 at the runtime's and the
-        trainer's batch, SSD512 — default DetectionOutputParam
+        trainer's batch, SSD512 at 8 and at the benchmark's serve cell's
+        64 — default DetectionOutputParam
         (keep_topk=200, nms_topk=400), through ``"auto"``, which must
         resolve to the compiled fused kernel."""
         from analytics_zoo_tpu.ops.detection_output import resolve_backend
@@ -295,13 +438,14 @@ class TestFusedDeviceTwins:
         _assert_rows_match(got, ref, atol=1e-4)
 
     @pytest.mark.pallas(device=True)
-    def test_compiled_kernel_matches_reference(self):
+    @pytest.mark.parametrize("keep_topk", [32, 50])
+    def test_compiled_kernel_matches_reference(self, keep_topk):
         from analytics_zoo_tpu.ops.pallas_detout import (
             fused_detection_output)
 
         loc, conf, priors, variances = _inputs(0, bg_bias=7.0,
                                                hot_frac=0.05)
-        p = DetectionOutputParam(**BASE)
+        p = DetectionOutputParam(**{**BASE, "keep_topk": keep_topk})
         got = np.asarray(fused_detection_output(
             loc, conf, priors, variances, param=p, interpret=False))
         _assert_rows_match(got, _reference(loc, conf, priors, variances,
