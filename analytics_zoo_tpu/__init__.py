@@ -24,8 +24,8 @@ import os as _os
 
 import jax as _jax
 
-# The ONE compile-cache site.  Every entry point (chip_smoke.py, bench.py
-# phase children, the tools) gets the persistent XLA compile cache by
+# The ONE compile-cache site.  Every entry point (chip_smoke.py, the
+# benchmark's drivers, the tools) gets the persistent XLA compile cache by
 # importing the package.  JAX itself reads JAX_COMPILATION_CACHE_DIR, so
 # when the caller places the cache from outside nothing is set here;
 # otherwise it lives at a FIXED path under the checkout — the directory

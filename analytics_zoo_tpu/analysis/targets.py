@@ -283,8 +283,7 @@ def _ds2(mesh) -> List[AuditProgram]:
         # kernel since r10, so the jaxpr audit must trace the
         # pallas-engine training pipeline — not just the default
         # blocked-scan one — or the kernel path (fwd AND bwd pallas
-        # calls, the programs bench.py ds2_persistent measures) sits
-        # outside the audit surface.  Traces interpret-mode off-TPU,
+        # calls) sits outside the audit surface.  Traces interpret-mode off-TPU,
         # same as the program the CPU tier dispatches.
         from analytics_zoo_tpu.models import DeepSpeech2
         from analytics_zoo_tpu.parallel import (Adam, make_train_step,

@@ -30,7 +30,7 @@ restructuring (persistent/fused RNNs à la Deep Speech 2, Amodei et al.
 
 ``hoist=False`` keeps the original per-step ``nn.scan`` body (one tiny
 latency-bound matmul per timestep per gate) — retained as the equivalence
-reference and the A/B baseline of ``bench.py bench_ds2_train``.
+reference.
 
 **Engines.**  ``Recurrent(engine=...)`` names the recurrence schedule
 explicitly; all three share ONE parameter tree (checkpoints move freely):

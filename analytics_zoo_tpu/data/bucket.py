@@ -17,7 +17,7 @@ samples into a small FIXED set of padded-length buckets instead:
   is a stream (trailing) stage: it always runs in the parent process.
 - **Waste accounting**: each batch carries per-row ``n_frames``; the
   train step reports ``padding_efficiency`` (valid / padded frames) in
-  its metrics, and ``bench.py bench_ds2_train`` banks it per line.
+  its metrics.
 
 Samples are dicts with ``pad_key`` holding a ``(n, D)`` array and
 ``length_key`` its true length ``n``.  A sample longer than the last
@@ -116,8 +116,7 @@ def edge_for(n: int, edges: Sequence[int]) -> int:
 
 def padding_efficiency(n_frames, padded_len: int) -> float:
     """valid frames / padded frames for rows padded to ``padded_len`` —
-    the host-side waste metric (``bench.py ds2_ragged`` banks it for the
-    pad-to-max discipline).  The in-graph step metric re-derives the
+    the host-side waste metric.  The in-graph step metric re-derives the
     same ratio in jnp (``pipelines.deepspeech2.ds2_padding_metric``)."""
     n = np.asarray(n_frames)
     return float(n.sum()) / float(max(n.shape[0] * padded_len, 1))

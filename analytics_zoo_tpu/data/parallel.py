@@ -1,8 +1,7 @@
 """Multiprocess host input pipeline: decode + augmentation fan-out.
 
-Every committed train sweep is host-bound (``bench.py``
-host_bound_fraction 0.81-0.88): the device step waits on ONE Python
-thread doing decode + augment + collate.  The reference got its input
+A serial loader makes the device step wait on ONE Python thread doing
+decode + augment + collate.  The reference got its input
 throughput from Spark's coarse-grained executor parallelism (SURVEY §0);
 the JAX-native equivalent here is a process pool feeding the device
 asynchronously — the same host/accelerator split tf.data and Grain use.

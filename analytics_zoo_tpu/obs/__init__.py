@@ -35,9 +35,8 @@ instrumentation are the pattern sources):
 
 Everything but the stages runs on the injected clock
 (``utils.clock``), so drills on a ``VirtualClock`` produce
-byte-identical traces from a seed (``OBS_r01.json`` pins the sha256),
-and the layer's hot-path cost is banked, not assumed (``bench.py
-obs_overhead``).  Docs:
+byte-identical traces from a seed (``OBS_r01.json`` pins the sha256).
+What a stage costs on the chip: PERF.md (PR 26).  Docs:
 ``docs/OBSERVABILITY.md``.
 """
 

@@ -9,8 +9,7 @@ same trace ids and the flight-recorder dump replays byte-identically.
 
 Spans are recorded into the flight recorder when they END (one event
 per span, carrying start/end/duration), which keeps the hot path to two
-clock reads and one deque append — the cost the ``bench.py
-obs_overhead`` phase banks.
+clock reads and one deque append.
 
 :func:`stage` is the other kind of interval: a host STAGE of a batch, a
 step or an epoch (collate, transfer, dispatch, the wait on a queue), on

@@ -44,8 +44,9 @@ class MultiBoxLossParam:
     background_id: int = 0
     neg_pos_ratio: float = 3.0
     neg_overlap: float = 0.5
-    # Hard-negative selection engine (MFU_CEILING.md: mining is ~20% of
-    # the SSD300 train step at 1.3% of its FLOPs).  "sort": one value
+    # Hard-negative selection engine (docs/MFU_CEILING.md: mining was
+    # ~20% of the SSD300 train step at 1.3% of its FLOPs on a v5e in
+    # round 4).  "sort": one value
     # sort of the (P,) negative losses — exact reference semantics up to
     # float ties (the former double-argsort rank trick cost two sorts
     # for the same selection).  "topk": lax.top_k over a static window

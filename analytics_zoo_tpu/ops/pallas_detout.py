@@ -6,9 +6,8 @@ is four XLA/Pallas stages with materialized intermediates between them:
 decode (B,P,4) → per-class ``lax.top_k`` + gathers (B,C,K scores, idx,
 boxes) → the ``pallas_nms.nms_sweep`` kernel (B·C,K) → a global
 ``lax.top_k`` over (B, C·K).  Every arrow is an HBM round-trip and a
-stage boundary the serve-profile decomposition could not attribute
-(SERVE_PROFILE.json's pre-r9 −423 ms residual).  This module is the
-same math as ONE kernel over a ``(batch, class)`` grid:
+stage boundary.  This module is the same math as ONE kernel over a
+``(batch, class)`` grid:
 
 - **decode** runs in-kernel at the first class step of each image (loc
   and prior blocks have constant-over-class index maps, so Pallas
@@ -61,10 +60,9 @@ kernel only for geometries whose :func:`fused_vmem_bytes` fits
 the kernel requests from Mosaic.
 
 ``stage`` builds prefix programs of the same kernel ("decode" →
-"select" → "full") so ``tools/profile_serve.py`` can ladder the fused
-cost into parts that sum to the whole BY CONSTRUCTION (each rung is a
-prefix; rung deltas are stage costs) — the coherence the pre-r9
-decomposition lacked.
+"select" → "full") so a caller can ladder the fused cost into parts
+that sum to the whole BY CONSTRUCTION (each rung is a prefix; rung
+deltas are stage costs; the chip's ladder: PERF.md, PR 30).
 """
 
 from __future__ import annotations

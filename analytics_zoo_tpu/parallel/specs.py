@@ -302,8 +302,9 @@ def _ssd_specs(mesh: Mesh, tp: Optional[str] = None,
                resolution: int = 300) -> SpecSet:
     """SSD detection training/serving.  ``tp=None``: pure data parallel
     (params replicated).  ``tp="spatial"``: image HEIGHT over ``model``
-    — the conv-trunk mode that measured 2.1× faster than channel
-    sharding (TP_MICROBENCH.json).  ``tp="megatron"``: paired col/row
+    — the conv-trunk mode that exchanges halo rows where channel
+    sharding all-reduces whole activation maps
+    (``tensor.spatial_input_spec``).  ``tp="megatron"``: paired col/row
     weight sharding (``tensor.ssd_tp_rules``)."""
     from analytics_zoo_tpu.parallel import tensor as tensor_lib
 

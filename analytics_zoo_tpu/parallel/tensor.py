@@ -161,8 +161,8 @@ def spatial_input_spec(axis: str = MODEL_AXIS,
     parallelism that actually pays on TPU.
 
     Channel (Megatron) sharding of a VGG-style trunk all-reduces FULL
-    spatial activation maps once per col/row pair — measured 2.1× slower
-    than this mode on the virtual-mesh microbench (TP_MICROBENCH.json).
+    spatial activation maps once per col/row pair (neither mode's speed
+    is measured on the chip: PERF.md §7, collectives).
     With H sharded and weights replicated, XLA's SPMD partitioner inserts
     only halo exchanges of kernel_h/2 edge rows per conv (communication
     O(B·W·C·halo), not O(B·H·W·C)), so each device convolves a horizontal

@@ -699,7 +699,6 @@ class Optimizer:
         fence split of a step needs no arming: it is the always-on
         stages ``az/input/get_wait`` / ``az/train/dispatch`` /
         ``az/train/summary`` (``obs.stages()``, docs/OBSERVABILITY.md).
-        Cost is banked by ``bench.py obs_overhead`` (≤ 3 % per step);
         ``None`` builds a default bundle."""
         from analytics_zoo_tpu.obs import Observability
         self.obs = obs or Observability()

@@ -362,8 +362,8 @@ def train_ds2(model: Model, dataset, epochs: int = 10, lr: float = 3e-4,
     step metrics gain ``padding_efficiency``.  The recurrence engine is
     the model's: build with ``make_ds2_model(rnn_engine="pallas")`` to
     train on the persistent-RNN kernel (h2h weights VMEM-resident —
-    the docs/MFU_CEILING.md roofline lever; ``bench.py ds2_persistent``
-    banks the A/B against the blocked scan).
+    the docs/MFU_CEILING.md roofline lever; its speed against the
+    blocked scan is not measured on the chip: PERF.md §7).
     ``param_rules`` enables tensor-parallel weight sharding
     (``parallel.tensor.default_tp_rules``) on a data×model mesh.
 

@@ -284,8 +284,8 @@ def load_train_set_device(pattern: str, param: PreProcessParam,
 def _warn_host_chain_ignores_wire(param: PreProcessParam, fn: str) -> None:
     # The host-aug chains always ship plain bgr float batches; silently
     # dropping a requested yuv420/packed wire would make callers believe
-    # they benched the thin wire (bench.py's hostaug phase did exactly
-    # that).  Mirror the FrcnnPredictor guard: loud, not fatal.
+    # they measured the thin wire.  Mirror the FrcnnPredictor guard:
+    # loud, not fatal.
     if param.wire_format != "bgr" or param.pack_staging:
         import warnings
         warnings.warn(
@@ -844,9 +844,9 @@ def ssd_serving_tiers(model: Model, param: PreProcessParam,
     program, cheapest last.
 
     - tier 0 ``fp``: full-precision weights, full NMS ``keep_topk``;
-    - tier 1 ``int8``: weight-only int8 via ``quantize_params`` (the
-      banked readings: ~4× less HBM traffic, 1.3× conv speedup,
-      mAP delta +0.0001 — INT8_MAP_PARITY.json);
+    - tier 1 ``int8``: weight-only int8 via ``quantize_params`` (~4×
+      fewer parameter bytes; mAP delta +0.0001 —
+      INT8_MAP_PARITY.json);
     - tier 2 ``int8_topk``: int8 plus ``keep_topk=degraded_topk`` — a
       bounded, explicit post-processing cut (reference ``setTopK``).
 
@@ -865,8 +865,8 @@ def ssd_serving_tiers(model: Model, param: PreProcessParam,
     batcher's FIXED bucket); every tier's forward is jit-compiled once
     per (tier, batch) geometry, which the runtime pins by always padding
     the batch axis to ``max_batch``.  ``speed`` values are relative
-    service-time hints for the batcher's flush heuristic, from the
-    banked int8 conv reading — the EWMA refines them online.
+    service-time HINTS for the batcher's flush heuristic, not readings
+    — the EWMA refines them online.
 
     ``specs`` (:class:`~analytics_zoo_tpu.parallel.specs.SpecSet`, e.g.
     ``pipeline_specs("ssd", mesh=mesh)``): every tier's detect program

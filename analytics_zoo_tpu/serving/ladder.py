@@ -7,8 +7,8 @@ CHEAPER variant of the same model — the degradation tiers:
 
 - tier 0: full quality (bf16/fp32 weights, full NMS top-K / beam);
 - tier 1: int8 weights via the existing ``utils.quantize.
-  quantize_params`` path (~4× less HBM traffic, measured 1.3× conv
-  speedup, mAP delta +0.0001 — ``INT8_MAP_PARITY.json``);
+  quantize_params`` path (~4× fewer parameter bytes, mAP delta
+  +0.0001 — ``INT8_MAP_PARITY.json``);
 - tier 2+: int8 plus reduced post-processing work (NMS ``keep_topk``,
   beam width) — bounded, explicit quality cuts.
 

@@ -13,10 +13,9 @@ parameter HBM ~4×.  From that shared storage, two serving modes:
    (``_int8_interceptor`` below) dynamically quantizes conv activations
    per-tensor and runs real ``int8×int8→int32`` convolutions on the
    MXU (``lax.conv_general_dilated`` with ``preferred_element_type=
-   int32``), rescaling once on the way out.  Measured: 1.3× at the
-   conv level (``INT8_CONV_PROBE.json``), mAP delta +0.000145 on a
-   trained model (``INT8_MAP_PARITY.json``); e2e serve gain is
-   link-weather-limited (~1.02–1.10×, ``docs/PERFORMANCE.md``).
+   int32``), rescaling once on the way out.  mAP delta +0.000145 on
+   a trained model (``INT8_MAP_PARITY.json``); its speed against the
+   bf16 path is not measured on the chip (no cell serves it: PERF.md).
 
 Which layers quantize is an abstract-trace census (``QTensor`` hygiene:
 every int8 leaf must be consumed by exactly one conv/matmul), not a

@@ -87,8 +87,9 @@ class TestFusedParity:
     @pytest.mark.parametrize("seed", [0, 7])
     def test_trained_like_conf(self, seed, priors_n):
         """The serving distribution: background bias +7 makes conf
-        sparse exactly like a trained SSD's softmax (the SERVE_PROFILE
-        methodology), a few re-boosted hot priors carry detections."""
+        sparse exactly like a trained SSD's softmax (what the benchmark's
+        `ssd512-vgg16` configuration does), a few re-boosted hot priors
+        carry detections."""
         loc, conf, priors, variances = _inputs(
             seed, priors_n=priors_n, bg_bias=7.0, hot_frac=0.05)
         assert (np.asarray(conf)[..., 1:] > 0.01).mean() < 0.15

@@ -66,8 +66,10 @@ class TestHoistedEquivalence:
         g_fast = jax.grad(loss(fast))(v)
         for a, b in zip(jax.tree_util.tree_leaves(g_legacy),
                         jax.tree_util.tree_leaves(g_fast)):
+            # gradients reach magnitudes of hundreds, where one float32
+            # ulp (3e-5 at 256-512) is already over the atol
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=1e-5)
+                                       rtol=1e-6, atol=1e-5)
 
     @pytest.mark.parametrize("U", [1, 3, 11, 16])
     def test_block_size_is_numerics_inert(self, U):

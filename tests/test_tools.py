@@ -610,8 +610,8 @@ class TestLiveSwapDrill:
 
 class TestObsDrillHelpers:
     """Fast pieces of tools/obs_drill.py (the committed OBS_r01.json is
-    the full-size execution: drill-scale flight recording + replay hash
-    + overhead A/B)."""
+    the full-size execution: drill-scale flight recording + replay
+    hash)."""
 
     def test_traced_scenario_span_conservation_smoke(self):
         from analytics_zoo_tpu.obs import span_conservation
@@ -636,7 +636,6 @@ class TestObsDrillHelpers:
         report = json.load(open(path))
         assert report["verdict"] == "PASS" and report["checks"]["ok"]
         assert report["serve_trace"]["replay_identical"] is True
-        assert report["obs_overhead"]["overhead_le_3pct"] is True
         assert report["serve_trace"]["events_dropped"] == 0
 
 
@@ -677,203 +676,21 @@ class TestCheckArtifacts:
         assert len(ca.check_artifacts(str(tmp_path))) == 1
 
     def test_issue9_artifacts_are_stamped_not_grandfathered(self):
-        """ISSUE 9 satellite: the new BENCH_r08 / MULTICHIP_r06 bankings
-        are covered by the lint as STAMPED artifacts — the LEGACY set
-        stayed closed (adding them there would have silently waived the
-        metadata requirement)."""
+        """ISSUE 9 satellite: the MULTICHIP_r06 banking is covered by
+        the lint as a STAMPED artifact — the LEGACY set stayed closed
+        (adding it there would have silently waived the metadata
+        requirement)."""
         import json
 
         from tools.check_artifacts import LEGACY, PATTERN, REQUIRED_KEYS
 
         root = os.path.join(os.path.dirname(__file__), os.pardir)
-        for name in ("BENCH_r08.json", "MULTICHIP_r06.json"):
-            assert PATTERN.match(name), name
-            assert name not in LEGACY, f"{name} must not be grandfathered"
-            doc = json.load(open(os.path.join(root, name)))
-            meta = doc["run_metadata"]
-            assert all(k in meta for k in REQUIRED_KEYS), name
-
-    def test_issue12_artifacts_are_stamped_not_grandfathered(self):
-        """ISSUE 12 satellite: BENCH_r09 (pattern-matched) and the
-        regenerated SERVE_PROFILE (governed BY NAME via EXTRA_STAMPED —
-        its pre-r7 ancestor escaped the lint only because the filename
-        carries no revision) are STAMPED artifacts; the LEGACY set
-        stayed closed."""
-        import json
-
-        from tools.check_artifacts import (EXTRA_STAMPED, LEGACY, PATTERN,
-                                           REQUIRED_KEYS)
-
-        root = os.path.join(os.path.dirname(__file__), os.pardir)
-        assert PATTERN.match("BENCH_r09.json")
-        assert "SERVE_PROFILE.json" in EXTRA_STAMPED
-        for name in ("BENCH_r09.json", "SERVE_PROFILE.json"):
-            assert name not in LEGACY, f"{name} must not be grandfathered"
-            doc = json.load(open(os.path.join(root, name)))
-            meta = doc["run_metadata"]
-            assert all(k in meta for k in REQUIRED_KEYS), name
-
-    def test_issue13_bench_r10_is_stamped_not_grandfathered(self):
-        """ISSUE 13 satellite: the BENCH_r10 banking is covered by the
-        lint as a STAMPED artifact — the LEGACY set stayed closed."""
-        import json
-
-        from tools.check_artifacts import LEGACY, PATTERN, REQUIRED_KEYS
-
-        root = os.path.join(os.path.dirname(__file__), os.pardir)
-        name = "BENCH_r10.json"
+        name = "MULTICHIP_r06.json"
         assert PATTERN.match(name)
         assert name not in LEGACY, f"{name} must not be grandfathered"
         doc = json.load(open(os.path.join(root, name)))
         meta = doc["run_metadata"]
         assert all(k in meta for k in REQUIRED_KEYS)
-
-    def test_committed_bench_r10_banks_the_train_ab(self):
-        """The r10 artifact's own claims hold: fwd AND train-step
-        sub-phase lines per engine at equal seeded ragged geometry
-        with per-window values, ``engine_fallback`` recorded per pass
-        per line and FALSE everywhere on the banked run
-        (fallback-free — a fallen-back backward cannot bank a
-        scan-vs-scan ratio), and per-pass intensity readouts with the
-        bwd h2h FLOP/byte on every train line."""
-        import json
-
-        path = os.path.join(os.path.dirname(__file__), os.pardir,
-                            "BENCH_r10.json")
-        doc = json.load(open(path))
-        assert doc["round"] == 10 and doc["phase"] == "ds2_persistent"
-        lines = doc["lines"]
-        hiddens = sorted({ln["hidden"] for ln in lines})
-        # 2 engines × 2 sub-phases per hidden size
-        assert len(lines) == 4 * len(hiddens) >= 8
-        for ln in lines:
-            fb = ln["engine_fallback"]
-            assert fb == {"forward": False, "backward": False,
-                          "any": False}, ln["metric"]
-            assert len(ln["windows"]) >= 2
-            assert ln["h2h_intensity_flops_per_byte"] > 0
-            if ln["subphase"] == "train":
-                assert ln["bwd_h2h_intensity_flops_per_byte"] > 0
-        for ln in lines:
-            if "_pallas_" not in ln["metric"]:
-                continue
-            assert ln["vs_baseline"] is not None
-            assert len(ln["ratio_windows"]) == len(ln["windows"])
-            # the residency algebra: persistent intensity = blocked × T'
-            blocked = next(
-                b for b in lines
-                if b["hidden"] == ln["hidden"]
-                and b["subphase"] == ln["subphase"]
-                and "_blocked_" in b["metric"])
-            assert (ln["h2h_intensity_flops_per_byte"]
-                    > blocked["h2h_intensity_flops_per_byte"])
-        for h in hiddens:
-            assert f"pallas_over_blocked_ratio_h{h}_train" \
-                in doc["headline"]
-            assert f"pallas_over_blocked_ratio_h{h}_fwd" \
-                in doc["headline"]
-
-    def test_issue17_bench_r11_is_stamped_not_grandfathered(self):
-        """ISSUE 17 satellite: the BENCH_r11 banking is covered by the
-        lint as a STAMPED artifact — the LEGACY set stayed closed."""
-        import json
-
-        from tools.check_artifacts import LEGACY, PATTERN, REQUIRED_KEYS
-
-        root = os.path.join(os.path.dirname(__file__), os.pardir)
-        name = "BENCH_r11.json"
-        assert PATTERN.match(name)
-        assert name not in LEGACY, f"{name} must not be grandfathered"
-        doc = json.load(open(os.path.join(root, name)))
-        meta = doc["run_metadata"]
-        assert all(k in meta for k in REQUIRED_KEYS)
-
-    def test_committed_bench_r11_banks_the_rec_ab(self):
-        """The r11 artifact's own claims hold: every line carries the
-        SAME seeded Zipfian geometry (vocab/dim/batch/seed and the
-        batch's unique_fraction — the equal-geometry contract), every
-        ratio line keeps per-window values, the sweep's widest line has
-        the table GENUINELY row-sharded, virtual labeling is honest
-        (CPU backend ⇒ virtual), and the headline ratios are present —
-        with dedup beating the densifying one-hot reference."""
-        import json
-
-        path = os.path.join(os.path.dirname(__file__), os.pardir,
-                            "BENCH_r11.json")
-        doc = json.load(open(path))
-        assert doc["round"] == 11 and doc["phase"] == "rec_embedding"
-        lines = doc["lines"]
-        assert len(lines) >= 6
-        geo = {(ln["vocab"], ln["dim"], ln["batch"], ln["seed"],
-                ln["unique_fraction"]) for ln in lines}
-        assert len(geo) == 1, f"geometry drifted across lines: {geo}"
-        assert next(iter(geo))[3] == 0                  # seed
-        for ln in lines:
-            assert len(ln["windows"]) >= 2, ln["metric"]
-            assert ln["virtual"] == (doc["backend"] != "tpu")
-            if ln["vs_baseline"] is not None:
-                assert len(ln["ratio_windows"]) == len(ln["windows"])
-                assert ln["anchor"]
-        widest = max((ln for ln in lines if "sharded_w" in ln["metric"]),
-                     key=lambda ln: ln["width"])
-        if widest["width"] > 1:
-            assert widest["table_row_sharded"] is True
-        sparse = next(ln for ln in lines
-                      if "sparse_over_dense" in ln["metric"])
-        assert sparse["rows_touched"] < sparse["vocab"]
-        head = doc["headline"]
-        for key in ("dedup_over_onehot_ratio", "dedup_over_naive_ratio",
-                    "sparse_over_dense_apply_ratio", "unique_fraction"):
-            assert key in head
-        # the transferable claim: never materializing the (batch, vocab)
-        # one-hot / densified cotangent wins on every backend
-        assert head["dedup_over_onehot_ratio"] > 1.0
-
-    def test_committed_bench_r09_banks_the_fused_ab(self):
-        """The r09 artifact's own claims hold: both readings carry
-        per-window values at equal geometry, exact fused/unfused
-        parity, the runtime accounting conserves every request, and
-        the serving reading names its tiers."""
-        import json
-
-        path = os.path.join(os.path.dirname(__file__), os.pardir,
-                            "BENCH_r09.json")
-        doc = json.load(open(path))
-        ab = doc["detout_ab"]
-        assert ab["parity_max_abs_diff"] <= 1e-5
-        assert len(ab["per_window_ratios"]) >= 2
-        assert len(ab["unfused_img_per_s"]) == len(ab["fused_img_per_s"])
-        assert ab["interstage_hbm_mb"]["fused"] == 0.0
-        serve = doc["serving_tier_ab"]
-        assert serve["requests_accounted"]["unaccounted"] == 0
-        assert len(serve["per_window_ratios"]) >= 2
-        assert any(t.startswith("int8") for t in serve["tiers"])
-
-    def test_regenerated_serve_profile_is_coherent(self):
-        """The ISSUE 12 acceptance line: the regenerated decomposition
-        SUMS — |residual_fraction| <= 0.10 at the program level, and
-        the DetectionOutput stage ladder tiles its total (the pre-r9
-        artifact carried a -423 ms term no stage owned)."""
-        import json
-
-        path = os.path.join(os.path.dirname(__file__), os.pardir,
-                            "SERVE_PROFILE.json")
-        doc = json.load(open(path))
-        assert doc["detout_backend"] == "fused"
-        assert abs(doc["coherence"]["residual_fraction"]) <= 0.10
-        lad = doc["detout_coherence"]
-        # detout_total and the full-kernel rung are two independent
-        # timings of the SAME program minutes apart — their gap is the
-        # 2-core host's window-to-window drift, not structure; the
-        # structural claim (rungs tile the kernel) is the exact-sum
-        # check below
-        assert abs(lad["ladder_residual_fraction"]) <= 0.20
-        ms = doc["ms"]
-        parts = (ms["detout_ladder_decode_and_stream"]
-                 + ms["detout_ladder_select_and_sweep"]
-                 + ms["detout_ladder_global_topk_merge"])
-        assert abs(parts - ms["detout_full_kernel"]) <= max(
-            0.02 * ms["detout_full_kernel"], 0.05)
 
     def test_committed_multichip_r06_banks_sweeps_and_drill(self):
         """The r06 artifact's own claims hold: both model sweeps have a
@@ -895,45 +712,6 @@ class TestCheckArtifacts:
         assert drill["fingerprint_match_bitexact"] is True
         assert drill["loader_coordinates"]["mid_epoch"] is True
         assert drill["resume"]["steps"] == drill["reference"]["steps"]
-
-
-class TestProfileMfuRnnAb:
-    def test_rnn_ab_smoke_writes_h2h_share_artifact(self, tmp_path):
-        """Satellite (ISSUE 6): `tools/profile_mfu.py --rnn-ab` — the
-        blocked-vs-pallas engine probe runs in-process at a tiny
-        geometry and writes the h2h-share artifact (the committed
-        MFU_RNN_AB.json is the DS2-parity-geometry execution)."""
-        import json
-
-        from tools import profile_mfu
-
-        out = str(tmp_path / "MFU_RNN_AB.json")
-        rc = profile_mfu.main(["--rnn-ab", "--rnn-hidden", "16",
-                               "--rnn-batch", "2", "--rnn-frames", "8",
-                               "--iters", "1", "--out", out])
-        assert rc == 0
-        report = json.load(open(out))
-        assert set(report["engines"]) == {"blocked", "pallas"}
-        for eng in report["engines"].values():
-            assert eng["fwd_ms"] > 0 and eng["fwd_bwd_ms"] > 0
-            # ISSUE 13: fallback recorded per engine PER PASS — a
-            # fallen-back backward must not bank a scan-vs-scan reading
-            assert eng["engine_fallback"] == {
-                "fwd": False, "fwd_bwd": False}   # CPU interpret
-        h2h = report["h2h"]
-        # the roofline algebra the ceiling doc reasons in: persistent
-        # intensity = blocked intensity x T (weights read once per
-        # sequence instead of once per step) — for BOTH passes, the r10
-        # transposed backward included
-        assert (h2h["intensity_persistent_flops_per_byte"]
-                == pytest.approx(
-                    h2h["intensity_blocked_flops_per_byte"] * 8))
-        assert (h2h["bwd_intensity_persistent_flops_per_byte"]
-                == pytest.approx(
-                    h2h["bwd_intensity_blocked_flops_per_byte"] * 8))
-        assert h2h["bwd_flops_per_step"] == 2 * h2h["flops_per_step"]
-        assert h2h["v5e_ridge_flops_per_byte"] == 240
-        assert report["run_metadata"]["tool"] == "profile_mfu_rnn_ab"
 
 
 class TestBenchScalingDrill:

@@ -7,8 +7,8 @@ through exactly the program the real pipeline uses: sharding declared
 once via ``pipeline_specs(...)`` (parallel/specs.py), the annotated
 train step placing HOST batches itself, gradient mean compiled to an
 all-reduce.  ``efficiency(n) = throughput(n) / (n · throughput(1))``,
-with per-window values kept per device count (the drift policy of
-``bench.py``'s interleaved phases, applied per mesh size).
+with per-window values kept per device count (a window's drift shows
+per mesh size).
 
 ``--drill`` adds the chaos leg ISSUE 9 banks: on the widest mesh, a
 host preemption (real SIGTERM mid-epoch through the multiprocess
@@ -27,8 +27,8 @@ efficiency trends toward 1/n by construction and every line is labeled
 ``"virtual": true`` (the MULTICHIP_r0* convention).
 
 Each device count runs in a fresh subprocess because XLA fixes the
-device count at backend init.  Every emitted sweep line also appends to
-``bench_artifacts/BENCH_sweeps.jsonl`` like the bench.py phases.
+device count at backend init.  ``--sweep-log PATH`` also appends every
+emitted sweep line to a ``.jsonl`` file.
 
 Usage::
 
@@ -564,11 +564,9 @@ def main() -> int:
                    help="write the full artifact (sweeps + drill + "
                         "run_metadata) to this path, e.g. "
                         "MULTICHIP_r06.json")
-    p.add_argument("--sweep-log",
-                   default=os.path.join(_REPO, "bench_artifacts",
-                                        "BENCH_sweeps.jsonl"),
-                   help="append every sweep line here (like the bench.py "
-                        "phases); '' disables")
+    p.add_argument("--sweep-log", default="",
+                   help="append every sweep line to this .jsonl file; "
+                        "'' (the default) writes none")
     p.add_argument(_CHILD_FLAG, type=int, default=None,
                    dest="child_n", help=argparse.SUPPRESS)
     p.add_argument("--_child-model", default="ssd", dest="child_model",

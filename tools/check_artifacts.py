@@ -1,4 +1,4 @@
-"""Lint committed drill/bench artifacts: parse + run-metadata presence.
+"""Lint committed drill artifacts: parse + run-metadata presence.
 
 Every ``*_rNN*.json`` / ``OBS_*.json`` at the repo root is a *banked
 execution* some ROADMAP claim leans on.  Two failure modes crept in
@@ -8,9 +8,7 @@ commit/backend that produced them.  This lint closes both, and
 ``tests/test_tools.py`` runs it in tier-1 so a stale or hand-edited
 artifact fails the suite:
 
-- every matching artifact (``PATTERN`` plus the by-name
-  ``EXTRA_STAMPED`` set for un-revisioned artifacts like
-  ``SERVE_PROFILE.json``) must PARSE as JSON;
+- every matching artifact (``PATTERN``) must PARSE as JSON;
 - every matching artifact must carry the shared ``run_metadata`` block
   (``analytics_zoo_tpu.obs.run_metadata``: tool, seed, git sha,
   backend, jax version) — EXCEPT the frozen ``LEGACY`` set below,
@@ -38,25 +36,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 from analytics_zoo_tpu.obs.runmeta import REQUIRED_KEYS  # noqa: E402
 
-#: artifacts this lint governs: revisioned drill/bench bankings plus
-#: every obs artifact
+#: artifacts this lint governs: revisioned drill bankings plus every
+#: obs artifact
 PATTERN = re.compile(r"(^OBS_.*\.json$)|(.*_r\d+.*\.json$)")
-
-#: un-revisioned artifacts governed BY NAME.  SERVE_PROFILE.json joined
-#: in r9 when the fused DetectionOutput decomposition regenerated it
-#: stamped (its pre-r7 ancestor escaped the lint only because the name
-#: carries no _rNN revision — not because it deserved grandfathering).
-EXTRA_STAMPED = frozenset({
-    "SERVE_PROFILE.json",
-})
 
 #: frozen pre-PR-7 artifacts (no run_metadata block; the TPU-side ones
 #: cannot be regenerated from this environment).  CLOSED SET — do not
 #: add to it; new artifacts must stamp obs.run_metadata().
 LEGACY = frozenset({
-    "BENCH_r05.json",
-    "BENCH_r07.json",
-    "MFU_CEILING_r4mining.json",
     "MULTICHIP_r02.json",
     "MULTICHIP_r03.json",
     "MULTICHIP_r04.json",
@@ -69,7 +56,7 @@ def check_artifacts(root: str) -> List[str]:
     """Lint ``root``; returns a list of problem strings (empty = clean)."""
     problems: List[str] = []
     names = sorted(n for n in os.listdir(root)
-                   if (PATTERN.match(n) or n in EXTRA_STAMPED)
+                   if PATTERN.match(n)
                    and os.path.isfile(os.path.join(root, n)))
     for name in names:
         path = os.path.join(root, name)
@@ -100,8 +87,7 @@ def main(argv=None) -> int:
         os.path.abspath(__file__))))
     args = ap.parse_args(argv)
     problems = check_artifacts(args.root)
-    n = len([x for x in os.listdir(args.root)
-             if PATTERN.match(x) or x in EXTRA_STAMPED])
+    n = len([x for x in os.listdir(args.root) if PATTERN.match(x)])
     if problems:
         for p in problems:
             print(f"check_artifacts: FAIL {p}")

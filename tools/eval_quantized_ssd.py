@@ -40,9 +40,8 @@ def main() -> int:
                         "(default: the FUSED single-kernel program, "
                         "interpret-mode off-TPU) — quantized-ACCURACY "
                         "numbers then come from the same device program "
-                        "the serving tiers dispatch and the serve-latency "
-                        "bench measures (bench.py ssd_detout), not a "
-                        "parallel decomposition that could drift")
+                        "the serving tiers dispatch, not a parallel "
+                        "decomposition that could drift")
     p.add_argument("--approx", action="store_true",
                    help="also evaluate fp serving with "
                         "DetectionOutputParam(approx_topk=True) — the "

@@ -1,22 +1,18 @@
 """One-command telemetry-spine drill: bank a seeded serve-drill flight
-recording plus the instrumented-vs-bare step overhead as ``OBS_r01.json``.
+recording as ``OBS_r01.json``.
 
-Two halves, both deterministic-or-banked:
-
-1. **Flight recording** — the serve drill's overload/failover scenario
-   (same seeded arrival script, burst window, replica crash + wedge,
-   fp→int8 ladder as ``tools/serve_drill.py``) runs with the
-   ``obs.Observability`` spine armed: every request's life is a rooted
-   span trace (``request`` → ``queue`` → ``dispatch``), replica fences
-   trip the black-box dump, and drill completion dumps the full ring.
-   The artifact pins (a) **span conservation** — every request trace is
-   one rooted tree and the root statuses reconcile EXACTLY with
-   ``ServingRuntime.accounting()``; (b) **byte-identical replay** — the
-   whole scenario runs twice from the seed and the JSONL dump's sha256
-   must match (everything runs on the VirtualClock).
-2. **Overhead A/B** — ``bench.obs_overhead_ab`` (the ``bench.py
-   obs_overhead`` phase core): interleaved instrumented-vs-bare train
-   steps; acceptance is ≤ 3 % median overhead.
+The serve drill's overload/failover scenario (same seeded arrival
+script, burst window, replica crash + wedge, fp→int8 ladder as
+``tools/serve_drill.py``) runs with the ``obs.Observability`` spine
+armed: every request's life is a rooted span trace (``request`` →
+``queue`` → ``dispatch``), replica fences trip the black-box dump, and
+drill completion dumps the full ring.  The artifact pins (a) **span
+conservation** — every request trace is one rooted tree and the root
+statuses reconcile EXACTLY with ``ServingRuntime.accounting()``;
+(b) **byte-identical replay** — the whole scenario runs twice from the
+seed and the JSONL dump's sha256 must match (everything runs on the
+VirtualClock).  What the spine costs a step is a chip reading: PERF.md
+(PR 26).
 
 Usage::
 
@@ -85,7 +81,6 @@ def traced_scenario(seed: int, smoke: bool, dump_path=None,
 
 def obs_drill(seed: int, smoke: bool, flight_path=None) -> dict:
     from analytics_zoo_tpu.obs import render_prometheus, span_conservation
-    from bench import obs_overhead_ab
 
     rt, obs, n_script = traced_scenario(seed, smoke, dump_path=flight_path)
     text = obs.dump("drill_complete")
@@ -109,13 +104,6 @@ def obs_drill(seed: int, smoke: bool, flight_path=None) -> dict:
                    if d["reason"] == "replica_fenced"]
     fenced = [e for e in events if e.get("kind") == "replica_fenced"]
 
-    # the MODEL stays full-size even in smoke: the overhead is an
-    # ~O(µs)/step host cost, only meaningful against a realistically-
-    # sized (~25 ms) step — shrinking the model would measure python
-    # noise against a trivial step, not the spine against a train step
-    # (see obs_overhead_ab's measurement-design note)
-    overhead = obs_overhead_ab(chunks=10 if smoke else 30)
-
     checks = {
         "span_conservation_ok": cons["ok"],
         "roots_reconcile_with_accounting": reconciled,
@@ -124,7 +112,6 @@ def obs_drill(seed: int, smoke: bool, flight_path=None) -> dict:
         "replay_byte_identical_from_seed": replay_identical,
         "fence_tripped_black_box_dump": (bool(fence_dumps)
                                          if flight_path else bool(fenced)),
-        "overhead_le_3pct": overhead["overhead_le_3pct"],
     }
     spans = [e for e in events if e.get("kind") == "span"]
     by_name = {}
@@ -150,7 +137,6 @@ def obs_drill(seed: int, smoke: bool, flight_path=None) -> dict:
         "metrics_snapshot": rt.snapshot()["metrics"],
         "prometheus_sample": render_prometheus(
             obs.registry).splitlines()[:8],
-        "obs_overhead": overhead,
         "checks": {"ok": all(checks.values()), **checks},
     }
 
@@ -185,14 +171,10 @@ def main(argv=None) -> int:
         json.dump(report, f, indent=1)
         f.write("\n")
     st = report["serve_trace"]
-    oh = report["obs_overhead"]
     print(f"obs drill: {report['verdict']} — {st['spans']} spans over "
           f"{st['submitted_total']} requests "
           f"({st['conservation']['roots_by_status']}), replay identical: "
-          f"{st['replay_identical']}, step overhead "
-          f"{oh['overhead_fraction_direct']*100:.2f}% direct "
-          f"({oh['instrumentation_us_per_step']}us/step; e2e ratio "
-          f"{oh['ratio_of_totals']} ~1 within noise); wrote {args.out}")
+          f"{st['replay_identical']}; wrote {args.out}")
     return 0 if report["verdict"] == "PASS" else 1
 
 
