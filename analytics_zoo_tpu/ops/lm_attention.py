@@ -24,14 +24,19 @@ Two shapes of work:
 - **decode** — a batch of rows, one new token each, rows of any sessions:
   the indexer scores every page once against its owner's query (each key
   is read once, whatever the mix of lengths), the scores are laid out by
-  session, the ``topk`` largest are selected and only those latents are
-  gathered for the attention;
+  session, the ``topk`` largest are selected — by the threshold prefill
+  uses, never by a sort: the positions over each row's ``topk``-th
+  largest score, and of those equal to it the earliest that still fit,
+  are packed into bits and counted out into indices (``select_topk``) —
+  and only those latents are gathered for the attention;
 - **prefill** — one session's chunk of T tokens: two loops over the
   session's pages (as many as it has, not as many as the longest could
   have): the first fills the index scores, from which each query's
   selection threshold is found exactly by bisection on the scores' bits;
   the second is attention with an online softmax over the selected keys.
-  The selected SET is the same either way: the ``topk`` largest scores.
+  The selected SET is the same either way: the ``topk`` largest scores,
+  by ONE rule (``ordered_bits``, ``kth_largest``) with ONE order among
+  equal scores, the earlier position first, as ``lax.top_k`` orders them.
 
 Both shapes hand the selected set back beside their result — decode the
 positions (−1 where a row has fewer), prefill a bit-packed row a query —
@@ -112,17 +117,103 @@ def index_scores_paged(q_idx, w_idx, ik_pool, owner):
     return jnp.einsum("pth,ph->pt", jax.nn.relu(s), w_idx[row])
 
 
+#: words a group of ``select_topk``'s search: a slot finds its group among
+#: n / (32 GROUP), then its word among the group's GROUP
+GROUP = 32
+
+
+def nth_set_bit(x, r):
+    """Where the ``r``-th set bit (from 0, lowest first) of each uint32 of
+    ``x`` stands: five steps of bisection on popcounts.  ``r`` int32 under
+    the word's popcount; anything where it is not."""
+    at = jnp.zeros(x.shape, jnp.int32)
+    for width in (16, 8, 4, 2, 1):
+        low = lax.population_count(
+            x & jnp.uint32((1 << width) - 1)).astype(jnp.int32)
+        up = r >= low
+        r = jnp.where(up, r - low, r)
+        x = jnp.where(up, x >> width, x)
+        at = at + jnp.where(up, width, 0)
+    return at
+
+
+def pack_words(mask):
+    """(B, 32 W) bool → (B, W) uint32: bit b of word w is position 32 w + b."""
+    m = mask.reshape(mask.shape[0], -1, 32).astype(jnp.uint32)
+    return jnp.sum(m << jnp.arange(32, dtype=jnp.uint32), -1,
+                   dtype=jnp.uint32)
+
+
+def holder(sizes, rank):
+    """Runs of ``sizes`` (..., M) items stand one after another; which run
+    holds item ``rank`` (...,), and the item's rank inside it: a
+    compare-and-sum over the runs, no search and no gather.  Run M where
+    there are fewer items."""
+    before = jnp.cumsum(sizes, -1) <= rank[..., None]
+    return (jnp.sum(before, -1, dtype=jnp.int32),
+            rank - jnp.sum(jnp.where(before, sizes, 0), -1))
+
+
+def rows_of(table, row):
+    """``table[b, row[b, j]]`` of a uint32 ``table`` (B, G, S), ``row``
+    (B, k) → (B, k, S); zeros where ``row`` is G.  A 0/1 product on the MXU,
+    a byte at a time (exact in bfloat16): gathering 131,072 words took a
+    v5e 1.35 ms (PR 32)."""
+    B, G, S = table.shape
+    one = (row[..., None] == jnp.arange(G)).astype(jnp.bfloat16)
+    shifts = jnp.arange(0, 32, 8, dtype=jnp.uint32)[:, None]
+    byte = ((table[:, :, None, :] >> shifts) & 255).astype(jnp.bfloat16)
+    got = jnp.einsum("bkg,bgc->bkc", one, byte.reshape(B, G, 4 * S),
+                     preferred_element_type=F32).astype(jnp.uint32)
+    return jnp.sum(got.reshape(B, -1, 4, S) << shifts, 2, dtype=jnp.uint32)
+
+
 def select_topk(scores, lengths, k: int):
     """The ``k`` largest of each row's first ``lengths`` scores: (indices
     (B, k), valid (B, k)).  Rows shorter than ``k`` select all they have.
-    ``where(valid, indices, -1)`` is the selected set as decode reports it."""
-    n = scores.shape[1]
-    scores = jnp.where(jnp.arange(n)[None, :] < lengths[:, None], scores, NEG)
+    ``where(valid, indices, -1)`` is the selected set as decode reports it.
+
+    Nothing is sorted, scattered or gathered.  The set is prefill's: every
+    score over the row's ``k``-th largest (``kth_largest`` on the scores'
+    ordered bits) and, of the scores equal to it, as many as still fit, the
+    earlier positions first (as ``top_k`` orders equal scores).  The kept
+    positions are packed into 32-bit words, and slot j of a row finds the
+    j-th of them by counting: its group of ``GROUP`` words, its word in the
+    group, its bit in the word.  So the indices come out ascending by
+    position; slots past a row's count are not ``valid`` and hold 0."""
+    B, n = scores.shape
+    live = jnp.arange(n)[None, :] < lengths[:, None]
     if n <= k:
         idx = jnp.broadcast_to(jnp.arange(n)[None, :], scores.shape)
-        return idx, idx < lengths[:, None]
-    top, idx = lax.top_k(scores, k)
-    return idx, top > NEG / 2
+        return idx, live
+    bits = ordered_bits(jnp.where(live, scores, NEG))
+
+    def count(above):
+        return jnp.sum(above(bits), 1, dtype=jnp.int32)
+
+    tau = kth_largest(count, k, B)[:, None]
+    room = k - count(lambda v: v > tau)
+    pad = ((0, 0), (0, -n % (32 * GROUP)))
+    over = pack_words(jnp.pad(bits > tau, pad))
+    tie = pack_words(jnp.pad((bits == tau) & live, pad))
+    # of a word's ties the first ``fit``: all of them, or those under the
+    # fit-th
+    n_tie = lax.population_count(tie).astype(jnp.int32)
+    fit = room[:, None] - (jnp.cumsum(n_tie, 1) - n_tie)
+    under = jnp.uint32(1) << nth_set_bit(
+        tie, jnp.maximum(fit, 0)).astype(jnp.uint32)
+    tie = jnp.where(fit >= n_tie, tie, tie & (under - 1))
+    groups = (over | tie).reshape(B, -1, GROUP)
+    kept = jnp.sum(lax.population_count(groups).astype(jnp.int32), -1)
+    slot = jnp.broadcast_to(jnp.arange(k), (B, k))
+    g, rank = holder(kept[:, None, :], slot)
+    mine = rows_of(groups, g)                                # (B, k, GROUP)
+    w, rank = holder(lax.population_count(mine).astype(jnp.int32), rank)
+    word = jnp.sum(jnp.where(w[..., None] == jnp.arange(GROUP), mine, 0), -1,
+                   dtype=jnp.uint32)
+    idx = (g * GROUP + w) * 32 + nth_set_bit(word, rank)
+    valid = g < kept.shape[1]
+    return jnp.where(valid, idx, 0), valid
 
 
 def mla_absorbed(q_nope, q_rope, latents, valid, wkv_b, nope: int,

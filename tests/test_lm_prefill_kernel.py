@@ -2,8 +2,10 @@
 against the XLA loop at small lane-aligned widths in interpret mode, and
 compiled for a v5e at the published widths (no chip needed: the TPU's
 compiler is described, not attached).  The repo's other compile-for-a-v5e
-tests live here too (fused DetectionOutput, last): only the one xdist
-worker that is given this file loads the TPU's library."""
+tests live here too (decode's selection, fused DetectionOutput, last): only
+the one xdist worker that is given this file loads the TPU's library."""
+
+import re
 
 import numpy as np
 import pytest
@@ -81,6 +83,22 @@ def test_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, T,
     assert "tpu_custom_call" in compiled.as_text()
     assert pf.declared_vmem_bytes(T, page, entry, rank, 128, 128, 4) \
         < 64 * (1 << 20)
+
+
+def test_selection_compiles_for_a_v5e_with_no_sort_and_no_big_array(one_chip):
+    """``select_topk`` at the LM cell's geometry: the chip's compiler
+    leaves no sort in it and keeps every compare-and-sum inside a fusion
+    (a (64, 2,048, 2,176) array would be 1.1 GB of a chip that the cell
+    fills to 85 %; the largest is the 0/1 product's (64, 2,048, 128))."""
+    B, n, k = 64, 69632, 2048
+    S = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa
+    text = jax.jit(att.select_topk, static_argnums=2).lower(
+        S((B, n), jnp.float32), S((B,), jnp.int32), k).compile().as_text()
+    assert not re.search(r"\b(sort|topk)\(|TopK", text)
+    entry = text[text.index("ENTRY"):]
+    sizes = [np.prod([int(d) for d in dims.split(",")])
+             for dims in re.findall(r"\w+\[([\d,]+)\]", entry)]
+    assert B * n <= max(sizes) <= B * k * 4 * att.GROUP
 
 
 @pytest.mark.parametrize("batch,n_priors,stage,keep_topk", [
