@@ -621,8 +621,6 @@ def _lm_serving(mesh) -> List[AuditProgram]:
     jitted call a batch of rows of any sessions) under the rung's own
     name, and the prefill program of each chunk edge beside it."""
     from analytics_zoo_tpu.models import lm
-    from analytics_zoo_tpu.parallel import pipeline_specs
-    from analytics_zoo_tpu.pipelines.lm import LMModel, lm_serving_tiers
 
     cfg = lm.LMConfig(
         d=32, kinds=(lm.FULL, lm.SLIDING), dense_layers=1,
@@ -630,18 +628,46 @@ def _lm_serving(mesh) -> List[AuditProgram]:
         swa=lm.MLADims(2, 16, 12, 12, 4, 8, 5e4), window=5, idx_heads=4,
         idx_dim=8, topk=4, f_dense=48, f_expert=16, f_shared=16, experts=8,
         held=4, first_held=0, per_tok=2, route_scale=1.0, vocab=40,
-        eps=1e-5, dtype="float32")
+        eps=1e-5, dtype="float32", gate=True, rescale=True, route_bias=True)
+    return _lm_tier_programs("lm", cfg, mesh)
+
+
+def _lm_mla_serving(mesh) -> List[AuditProgram]:
+    """ISSUE 33: the same tier over a model of causal latent attention
+    throughout (no ``layer_types`` in its config: pools only, YaRN,
+    group-limited routing, no gate, no router bias) — its decode step with
+    the paged attention (ops/lm_attention.py ``mla_paged``) and its prefill
+    programs."""
+    from analytics_zoo_tpu.models import lm
+    from analytics_zoo_tpu.ops.lm_attention import RopeScaling
+
+    cfg = lm.LMConfig(
+        d=32, kinds=(lm.CAUSAL, lm.CAUSAL), dense_layers=1,
+        full=lm.MLADims(4, 16, 8, 8, 8, 8, 100.0,
+                        RopeScaling(4.0, 16, 1.0, 0.1, 1.0, 1.0)),
+        swa=None, window=0, idx_heads=0, idx_dim=0, topk=0, f_dense=48,
+        f_expert=16, f_shared=16, experts=8, held=2, first_held=0, per_tok=2,
+        route_scale=2.5, vocab=40, eps=1e-6, dtype="float32", n_group=2,
+        topk_group=1)
+    return _lm_tier_programs("lm-mla", cfg, mesh)
+
+
+def _lm_tier_programs(kind: str, cfg, mesh) -> List[AuditProgram]:
+    from analytics_zoo_tpu.models import lm
+    from analytics_zoo_tpu.parallel import pipeline_specs
+    from analytics_zoo_tpu.pipelines.lm import LMModel, lm_serving_tiers
+
     model = LMModel(cfg, filled(lm.param_shapes(cfg)))
     specs = pipeline_specs("lm", mesh=mesh)
     tiers = lm_serving_tiers(model, cache_tokens=64, max_sessions=4,
                              max_batch=4, page=4, max_len=32)
-    out = _tier_targets("lm", tiers, specs)
+    out = _tier_targets(kind, tiers, specs)
     for edge in (4, 8):
         def build(thunk=tiers[0].device_program_for(edge)) -> BuiltProgram:
             fn, args, static = thunk()
             return BuiltProgram(fn=fn, args=args, static_argnums=static,
                                 specs=specs)
-        out.append(AuditProgram(f"lm/serve:prefill{edge}", build))
+        out.append(AuditProgram(f"{kind}/serve:prefill{edge}", build))
     return out
 
 
@@ -727,4 +753,5 @@ def repo_audit_suite(mesh=None) -> List[AuditProgram]:
     # ISSUE 28: the decoder LM is served, not trained: its session
     # tier's decode and prefill programs are its whole audit surface
     targets += _guarded_tiers("lm", _lm_serving, mesh)
+    targets += _guarded_tiers("lm-mla", _lm_mla_serving, mesh)
     return targets

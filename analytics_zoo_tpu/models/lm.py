@@ -1,13 +1,23 @@
-"""A causal decoder LM of the ``dots3_note`` family, for serving through
-device-resident session caches (pipelines/lm.py): RMS-norm pre-norm
-residual blocks; multi-head latent attention (MLA) in two shapes — full
-layers with a learned sparse indexer that selects the ``index_topk``
-positions a token attends to, sliding layers over a window — each with a
-headwise sigmoid gate on the heads' outputs; a gated dense MLP in the
-leading layers, then sparse experts (sigmoid router of the published
-width, bias-corrected top-k, a shared expert) of which THIS CHIP HOLDS A
-SHARE (parallel/expert.py); embedding and head over a slice of the
-vocabulary.
+"""A causal decoder LM for serving through device-resident session caches
+(pipelines/lm.py): RMS-norm pre-norm residual blocks; multi-head latent
+attention (MLA) in three kinds of layer — FULL layers with a learned
+sparse indexer that selects the ``index_topk`` positions a token attends
+to, SLIDING layers over a window, CAUSAL layers over the whole context
+(every page a row holds, read by a paged decode kernel:
+ops/pallas_lm_decode.py); a gated dense MLP in the leading layers, then
+sparse experts (sigmoid router of the published width, top-k —
+group-limited where the config says so — a shared expert) of which THIS
+CHIP HOLDS A SHARE (parallel/expert.py); embedding and head over a slice
+of the vocabulary.
+
+WHICH model it is comes from the config's keys alone
+(``LMConfig.from_dict``): ``layer_types`` names FULL and SLIDING layers
+(the ``dots3_note`` family: headwise sigmoid gates on the heads' outputs,
+rescaled latents, a bias-corrected router), a config without it is CAUSAL
+throughout (the ``axk1`` family: YaRN rotary scaling, routing groups, no
+gate, no rescale, no router bias).  ``param_shapes`` and ``cache_shapes``
+follow: a model without sliding layers has no ring, one without an
+indexer no index keys.
 
 Functional, not flax: the step functions take the parameters and the
 cache and return the new cache, so one jitted call is a whole batch of
@@ -20,15 +30,18 @@ weights reach both.
 
 Two step programs:
 
-- :func:`decode_step` — B rows of any sessions, one token each;
+- :func:`decode_step` — B rows of any sessions, one token each (its
+  integer arguments in one vector, :func:`pack_rows`: one transfer a
+  step; :func:`decode_rows` is the same step over them apart);
 - :func:`prefill_step` — one session's chunk of T tokens (chunked
   prefill: a chunk of any length up to T, at any position).
 
 Both write the new tokens' cache entries, and both return float32 logits
 over the vocabulary slice at each row's last position, the tokens each held
 expert got, and the step's discrete CHOICES: ``{"selected": a full layer's
-selected positions (ops/lm_attention.py), "routed": (MoE layers, tokens,
-k) expert ids}``.  They stay on the device unless somebody fetches them
+selected positions (ops/lm_attention.py; an empty list without full
+layers), "routed": (MoE layers, tokens, k) expert ids}``.  They stay on
+the device unless somebody fetches them
 (pipelines/lm.py records them for the sessions it is asked to).
 """
 
@@ -36,16 +49,21 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from analytics_zoo_tpu.ops import lm_attention as att
 from analytics_zoo_tpu.parallel.expert import moe_held_experts
 
 F32 = jnp.float32
 FULL, SLIDING = "full_attention", "sliding_attention"
+#: a third kind, for a config that names no ``layer_types``: causal MLA over
+#: the WHOLE context — no indexer, no index keys, no ring; decode reads every
+#: page a row holds (ops/pallas_lm_decode.py)
+CAUSAL = "causal_attention"
 #: a cache entry's width is rounded up to this many elements: the TPU
 #: tiles the minor axis by 128, and for a width of 576 its default layout
 #: puts the PAGE axis minor, so that every gather and scatter of a token's
@@ -67,10 +85,13 @@ class MLADims:
     rope: int
     v: int
     theta: float
+    scaling: Optional[att.RopeScaling] = None    # a config's rope_scaling
 
     @property
     def scale(self) -> float:
-        return 1.0 / math.sqrt(self.nope + self.rope)
+        """The softmax scale; YaRN's ``mscale``, squared, lives here."""
+        m = self.scaling.softmax_mscale if self.scaling else 1.0
+        return m * m / math.sqrt(self.nope + self.rope)
 
     @property
     def entry(self) -> int:
@@ -81,10 +102,10 @@ class MLADims:
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
     d: int
-    kinds: Tuple[str, ...]            # per layer: FULL or SLIDING
+    kinds: Tuple[str, ...]            # per layer: FULL, SLIDING or CAUSAL
     dense_layers: int                 # leading layers with a dense MLP
-    full: MLADims
-    swa: MLADims
+    full: MLADims                     # a FULL or CAUSAL layer's sizes
+    swa: Optional[MLADims]            # a SLIDING layer's (None: there is none)
     window: int
     idx_heads: int
     idx_dim: int
@@ -100,28 +121,51 @@ class LMConfig:
     vocab: int                        # rows of the vocabulary held here
     eps: float
     dtype: str = "bfloat16"
+    # what a config's keys switch on (from_dict): absent, a plain block
+    gate: bool = False                # attention_gate_type: headwise
+    rescale: bool = False             # apply_mla_qkv_lora_rescale
+    route_bias: bool = False          # topk_method: noaux_tc (a router_b)
+    n_group: int = 1                  # routing groups ...
+    topk_group: int = 1               # ... of which a token's experts lie in
 
     @classmethod
     def from_dict(cls, cfg: Dict) -> "LMConfig":
+        """A config's keys are the only source of what the model is.
+        ``layer_types`` (with the ``swa_*`` and ``index_*`` sizes its
+        kinds need) names FULL and SLIDING layers; a config without it is
+        CAUSAL MLA throughout.  ``attention_gate_type: "headwise"`` → the
+        heads' sigmoid gate; ``apply_mla_qkv_lora_rescale`` → sqrt(hidden /
+        rank) on the latents; ``rope_scaling`` → YaRN; ``topk_method:
+        "noaux_tc"`` → the router's bias; ``n_group`` / ``topk_group`` →
+        group-limited routing."""
         n = int(cfg["num_hidden_layers"])
         share = cfg.get("expert_share") or {
             "published_experts": cfg["n_routed_experts"], "index": 0}
         held = int(cfg["n_routed_experts"])
-        mla = lambda p, theta: MLADims(          # noqa: E731
+        kinds = tuple(cfg["layer_types"][:n]) if cfg.get("layer_types") \
+            else (CAUSAL,) * n
+        if cfg.get("attention_gate_type") not in (None, "headwise"):
+            raise ValueError("attention_gate_type: only headwise is known, "
+                             f"got {cfg['attention_gate_type']!r}")
+        mla = lambda p, theta, scaling=None: MLADims(          # noqa: E731
             heads=int(cfg[p + "num_attention_heads"]),
             q_rank=int(cfg[p + "q_lora_rank"]),
             kv_rank=int(cfg[p + "kv_lora_rank"]),
             nope=int(cfg[p + "qk_nope_head_dim"]),
             rope=int(cfg[p + "qk_rope_head_dim"]),
-            v=int(cfg[p + "v_head_dim"]), theta=float(cfg[theta]))
+            v=int(cfg[p + "v_head_dim"]), theta=float(cfg[theta]),
+            scaling=scaling)
+        dtype = cfg.get("compute_dtype", "bfloat16")
         return cls(
-            d=int(cfg["hidden_size"]), kinds=tuple(cfg["layer_types"][:n]),
+            d=int(cfg["hidden_size"]), kinds=kinds,
             dense_layers=int(cfg["first_k_dense_replace"]),
-            full=mla("", "rope_theta"), swa=mla("swa_", "swa_rope_theta"),
-            window=int(cfg["sliding_window_size"]),
-            idx_heads=int(cfg["index_n_heads"]),
-            idx_dim=int(cfg["index_head_dim"]),
-            topk=int(cfg["index_topk"]),
+            full=mla("", "rope_theta",
+                     att.RopeScaling.from_dict(cfg.get("rope_scaling"))),
+            swa=mla("swa_", "swa_rope_theta") if SLIDING in kinds else None,
+            window=int(cfg["sliding_window_size"]) if SLIDING in kinds else 0,
+            idx_heads=int(cfg["index_n_heads"]) if FULL in kinds else 0,
+            idx_dim=int(cfg["index_head_dim"]) if FULL in kinds else 0,
+            topk=int(cfg["index_topk"]) if FULL in kinds else 0,
             f_dense=int(cfg["intermediate_size"]),
             f_expert=int(cfg["moe_intermediate_size"]),
             f_shared=int(cfg["moe_intermediate_size"])
@@ -131,10 +175,12 @@ class LMConfig:
             per_tok=int(cfg["num_experts_per_tok"]),
             route_scale=float(cfg["routed_scaling_factor"]),
             vocab=int(cfg["vocab_size"]), eps=float(cfg["rms_norm_eps"]),
-            dtype={"bf16": "bfloat16"}.get(cfg.get("compute_dtype",
-                                                   "bfloat16"),
-                                           cfg.get("compute_dtype",
-                                                   "bfloat16")))
+            dtype={"bf16": "bfloat16"}.get(dtype, dtype),
+            gate=cfg.get("attention_gate_type") == "headwise",
+            rescale=bool(cfg.get("apply_mla_qkv_lora_rescale", False)),
+            route_bias=cfg.get("topk_method", "noaux_tc") == "noaux_tc",
+            n_group=int(cfg.get("n_group") or 1),
+            topk_group=int(cfg.get("topk_group") or 1))
 
     @property
     def n_full(self) -> int:
@@ -144,8 +190,13 @@ class LMConfig:
     def n_sliding(self) -> int:
         return sum(k == SLIDING for k in self.kinds)
 
+    @property
+    def n_pools(self) -> int:
+        """Layers whose entries live in a paged pool: FULL and CAUSAL."""
+        return len(self.kinds) - self.n_sliding
+
     def mla(self, kind: str) -> MLADims:
-        return self.full if kind == FULL else self.swa
+        return self.swa if kind == SLIDING else self.full
 
 
 # ---------------------------------------------------------------------------
@@ -169,7 +220,9 @@ def param_shapes(cfg: LMConfig) -> Dict:
                 "wkv_a": S(cfg.d, a.kv_rank + a.rope),
                 "kv_norm": S(a.kv_rank),
                 "wkv_b": S(a.kv_rank, a.heads, a.nope + a.v),
-                "wo": S(a.heads, a.v, cfg.d), "w_gate": S(cfg.d, a.heads)}
+                "wo": S(a.heads, a.v, cfg.d)}
+        if cfg.gate:
+            attn["w_gate"] = S(cfg.d, a.heads)
         if kind == FULL:
             attn.update({"idx_wq_b": S(a.q_rank, cfg.idx_heads, cfg.idx_dim),
                          "idx_wk": S(cfg.d, cfg.idx_dim),
@@ -182,9 +235,11 @@ def param_shapes(cfg: LMConfig) -> Dict:
         else:
             layer["moe"] = {
                 "router_w": S(cfg.d, cfg.experts),
-                "router_b": jax.ShapeDtypeStruct((cfg.experts,), F32),
                 "experts": mlp(cfg.f_expert, cfg.held),
                 "shared": mlp(cfg.f_shared)}
+            if cfg.route_bias:
+                layer["moe"]["router_b"] = jax.ShapeDtypeStruct(
+                    (cfg.experts,), F32)
         layers.append(layer)
     return {"layers": layers,
             "ends": {"embed": S(cfg.vocab, cfg.d), "final_norm": S(cfg.d),
@@ -223,9 +278,10 @@ def init_params(cfg: LMConfig, seed: int = 0) -> Dict:
 
 @dataclasses.dataclass(frozen=True)
 class CacheGeometry:
-    """``n_pages`` pages of ``page`` tokens for the full layers (page 0 is
-    nobody's), ``max_pages`` pages a session at most, ``n_slots`` sessions'
-    rings for the sliding layers."""
+    """``n_pages`` pages of ``page`` tokens for the full and causal layers
+    (page 0 is nobody's), ``max_pages`` pages a session at most,
+    ``n_slots`` sessions (each a row of the page tables and, where there
+    are sliding layers, a ring)."""
     n_pages: int
     page: int
     max_pages: int
@@ -237,17 +293,18 @@ class CacheGeometry:
 
 
 def cache_shapes(cfg: LMConfig, geo: CacheGeometry) -> Dict:
-    """One array a layer (a layer's pool is never sliced out of a stack):
-    ``kv`` and ``ik`` for the full layers, ``ring`` for the sliding ones."""
+    """One array a layer (a layer's pool is never sliced out of a stack),
+    for the kinds of layer the model has: ``kv`` for the full and the
+    causal layers, ``ik`` for the full ones (the indexer's keys), ``ring``
+    for the sliding ones."""
     dt = jnp.dtype(cfg.dtype)
-    f, s = cfg.full, cfg.swa
     S = jax.ShapeDtypeStruct
     return {
-        "kv": [S((geo.n_pages, geo.page, f.entry), dt)
-               for _ in range(cfg.n_full)],
+        "kv": [S((geo.n_pages, geo.page, cfg.full.entry), dt)
+               for _ in range(cfg.n_pools)],
         "ik": [S((geo.n_pages, geo.page, cfg.idx_dim), dt)
                for _ in range(cfg.n_full)],
-        "ring": [S((geo.n_slots, cfg.window, s.entry), dt)
+        "ring": [S((geo.n_slots, cfg.window, cfg.swa.entry), dt)
                  for _ in range(cfg.n_sliding)]}
 
 
@@ -280,15 +337,18 @@ def gated_mlp(x, w):
 
 def latents(cfg: LMConfig, a: MLADims, w: Dict, x, pos):
     """(c_q (N, q_rank), c (N, a.entry) the cache entry — latent, rotary
-    key, zeros up to the entry's width — gate (N, H)) of normed inputs ``x`` (N, d) at positions ``pos`` (N,).
-    ``apply_mla_qkv_lora_rescale``: sqrt(hidden/rank) after the norms."""
-    c_q = rms_norm(x @ w["wq_a"], w["q_norm"], cfg.eps) \
-        * jnp.asarray(math.sqrt(cfg.d / a.q_rank), x.dtype)
+    key, zeros up to the entry's width — gate (N, H), ``None`` without one)
+    of normed inputs ``x`` (N, d) at positions ``pos`` (N,).
+    ``cfg.rescale``: sqrt(hidden/rank) after the norms."""
+    c_q = rms_norm(x @ w["wq_a"], w["q_norm"], cfg.eps)
     kv = x @ w["wkv_a"]
-    c_kv = rms_norm(kv[:, :a.kv_rank], w["kv_norm"], cfg.eps) \
-        * jnp.asarray(math.sqrt(cfg.d / a.kv_rank), x.dtype)
-    k_r = att.rope(kv[:, a.kv_rank:], pos, a.theta)
-    gate = jax.nn.sigmoid((x @ w["w_gate"]).astype(F32))
+    c_kv = rms_norm(kv[:, :a.kv_rank], w["kv_norm"], cfg.eps)
+    if cfg.rescale:
+        c_q = c_q * jnp.asarray(math.sqrt(cfg.d / a.q_rank), x.dtype)
+        c_kv = c_kv * jnp.asarray(math.sqrt(cfg.d / a.kv_rank), x.dtype)
+    k_r = att.rope(kv[:, a.kv_rank:], pos, a.theta, a.scaling)
+    gate = jax.nn.sigmoid((x @ w["w_gate"]).astype(F32)) if cfg.gate \
+        else None
     pad = jnp.zeros((x.shape[0], a.entry - a.kv_rank - a.rope), x.dtype)
     return c_q, jnp.concatenate([c_kv, k_r, pad], -1), gate
 
@@ -296,7 +356,8 @@ def latents(cfg: LMConfig, a: MLADims, w: Dict, x, pos):
 def queries(a: MLADims, w: Dict, c_q, pos):
     """(q_nope (N, H, nope), q_rope (N, H, rope) rotated)."""
     q = jnp.einsum("nr,rhe->nhe", c_q, w["wq_b"])
-    return q[..., :a.nope], att.rope(q[..., a.nope:], pos, a.theta)
+    return q[..., :a.nope], att.rope(q[..., a.nope:], pos, a.theta,
+                                     a.scaling)
 
 
 def indexer(cfg: LMConfig, w: Dict, x, c_q, pos):
@@ -313,8 +374,10 @@ def indexer(cfg: LMConfig, w: Dict, x, c_q, pos):
 
 
 def finish_attention(w: Dict, o, gate):
-    """Headwise gate, then the output projection: ``o`` (N, H, v)."""
-    o = o * gate[..., None].astype(o.dtype)
+    """Headwise gate (if the model has one), then the output projection:
+    ``o`` (N, H, v)."""
+    if gate is not None:
+        o = o * gate[..., None].astype(o.dtype)
     return jnp.einsum("nhv,hvd->nd", o, w["wo"])
 
 
@@ -326,8 +389,9 @@ def feed_forward(cfg: LMConfig, layer: Dict, x):
         with jax.named_scope("lm/dense_mlp"):
             return gated_mlp(x, layer["mlp"]), jnp.zeros((cfg.held,),
                                                          jnp.int32), None
-    y, chosen, counts = moe_held_experts(x, layer["moe"], cfg.first_held,
-                                         cfg.per_tok, cfg.route_scale)
+    y, chosen, counts = moe_held_experts(
+        x, layer["moe"], cfg.first_held, cfg.per_tok, cfg.route_scale,
+        n_group=cfg.n_group, topk_group=cfg.topk_group)
     return y, counts, chosen
 
 
@@ -347,7 +411,31 @@ def head(cfg: LMConfig, ends: Dict, h):
 # decode: B rows of any sessions, one token each
 # ---------------------------------------------------------------------------
 
+def pack_rows(tokens, slots, pos, tables, owner) -> np.ndarray:
+    """A decode step's five integer arguments (:func:`decode_rows`) as ONE
+    int32 vector on the host: the tier then makes one transfer a step where
+    five took 1.4 ms of a 25 ms cycle (PERF.md, PR 33)."""
+    return np.concatenate([np.asarray(a, np.int32).ravel() for a in
+                           (tokens, slots, pos, tables, owner)])
+
+
+def unpack_rows(geo: CacheGeometry, rows):
+    """:func:`pack_rows` undone inside the step: B follows from the
+    vector's length and the geometry."""
+    B = (rows.shape[0] - geo.n_pages) // (3 + geo.max_pages)
+    tokens, slots, pos = (rows[i * B:(i + 1) * B] for i in range(3))
+    tables = rows[3 * B:3 * B + B * geo.max_pages].reshape(B, geo.max_pages)
+    return tokens, slots, pos, tables, rows[rows.shape[0] - geo.n_pages:]
+
+
 def decode_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
+                cache: Dict, rows):
+    """The step as the tier calls it: :func:`decode_rows` over the packed
+    arguments of :func:`pack_rows`."""
+    return decode_rows(cfg, geo, params, cache, *unpack_rows(geo, rows))
+
+
+def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                 cache: Dict, tokens, slots, pos, tables, owner):
     """One token for each of B rows.  ``tokens`` (B,) ids; ``slots`` (B,)
     the rows' ring slots (−1: a padding row); ``pos`` (B,) the positions
@@ -366,7 +454,7 @@ def decode_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     h = params["ends"]["embed"][tokens]
     kv, ik, ring = (list(cache[k]) for k in ("kv", "ik", "ring"))
     counts, selected, routed = [], [], []
-    i_full = i_slide = 0
+    i_pool = i_full = i_slide = 0
     for layer, kind in zip(params["layers"], cfg.kinds):
         a, w = cfg.mla(kind), layer["attn"]
         x = rms_norm(h, layer["attn_norm"], cfg.eps)
@@ -375,7 +463,7 @@ def decode_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
         if kind == FULL:
             with jax.named_scope("lm/indexer"):
                 q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
-                kv[i_full] = kv[i_full].at[page, off].set(c)
+                kv[i_pool] = kv[i_pool].at[page, off].set(c)
                 ik[i_full] = ik[i_full].at[page, off].set(k_idx)
                 by_page = att.index_scores_paged(q_idx, w_idx, ik[i_full],
                                                  owner)
@@ -385,13 +473,21 @@ def decode_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                 selected.append(jnp.where(valid, idx, -1))
                 phys = tables[jnp.arange(B)[:, None], idx // geo.page] \
                     * geo.page + idx % geo.page
-                chosen = kv[i_full].reshape(
+                chosen = kv[i_pool].reshape(
                     geo.n_pages * geo.page, -1)[phys]
             with jax.named_scope("lm/mla_full"):
                 o = att.mla_absorbed(q_nope, q_rope, chosen, valid,
                                      w["wkv_b"], a.nope, a.rope, a.scale)
                 h = h + finish_attention(w, o, gate)
-            i_full += 1
+            i_pool, i_full = i_pool + 1, i_full + 1
+        elif kind == CAUSAL:
+            kv[i_pool] = kv[i_pool].at[page, off].set(c)
+            with jax.named_scope("lm/mla_paged"):
+                o = att.mla_paged(q_nope, q_rope, kv[i_pool], tables,
+                                  lengths, w["wkv_b"], a.nope, a.rope,
+                                  a.scale)
+                h = h + finish_attention(w, o, gate)
+            i_pool += 1
         else:
             with jax.named_scope("lm/mla_window"):
                 at = jnp.where(live, pos % cfg.window, cfg.window)
@@ -437,7 +533,7 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     kv, ik, ring = (list(cache[k]) for k in ("kv", "ik", "ring"))
     W = cfg.window
     counts, selected, routed = [], [], []
-    i_full = i_slide = 0
+    i_pool = i_full = i_slide = 0
     for layer, kind in zip(params["layers"], cfg.kinds):
         a, w = cfg.mla(kind), layer["attn"]
         x = rms_norm(h, layer["attn_norm"], cfg.eps)
@@ -446,17 +542,26 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
         if kind == FULL:
             with jax.named_scope("lm/indexer"):
                 q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
-                kv[i_full] = kv[i_full].at[page, off].set(c)
+                kv[i_pool] = kv[i_pool].at[page, off].set(c)
                 ik[i_full] = ik[i_full].at[page, off].set(k_idx)
             with jax.named_scope("lm/mla_full"):
                 o, sets = att.prefill_full_attention(
-                    q_nope, q_rope, q_idx, w_idx, kv[i_full], ik[i_full],
+                    q_nope, q_rope, q_idx, w_idx, kv[i_pool], ik[i_full],
                     table, start, n_valid, w["wkv_b"], a.nope, a.rope,
                     a.scale, cfg.topk, pages_per_step,
                     flash=PREFILL_HEADS_PER_STEP)
                 selected.append(sets)
                 h = h + finish_attention(w, o, gate)
-            i_full += 1
+            i_pool, i_full = i_pool + 1, i_full + 1
+        elif kind == CAUSAL:
+            kv[i_pool] = kv[i_pool].at[page, off].set(c)
+            with jax.named_scope("lm/mla_paged"):
+                o = att.prefill_causal_attention(
+                    q_nope, q_rope, kv[i_pool], table, start, n_valid,
+                    w["wkv_b"], a.nope, a.rope, a.scale, pages_per_step,
+                    flash=PREFILL_HEADS_PER_STEP)
+                h = h + finish_attention(w, o, gate)
+            i_pool += 1
         else:
             with jax.named_scope("lm/mla_window"):
                 prev_pos = start - (W - 1) + jnp.arange(W - 1)

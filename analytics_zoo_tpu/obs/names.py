@@ -64,6 +64,15 @@ CATALOG: Dict[str, str] = {
         "gauge · share of the paged pool's pages that sessions hold",
     "lm/sessions_live":
         "gauge · sessions that hold a slot (and pages) on the replica",
+    "lm/paged_pages":
+        "gauge · pages that hold at least one token of the last decode "
+        "step's live rows, one causal layer: what the paged decode "
+        "attention (ops/pallas_lm_decode.py) had to read",
+    "lm/paged_grid_steps":
+        "gauge · grid steps the paged decode attention was launched with "
+        "in the last decode step, one causal layer (the flat list of "
+        "(row, page) items is as long as the pool; the steps past the "
+        "last item do nothing)",
     "lm/expert_tokens/stat=*":
         "histogram · tokens a held expert of an expert layer got in one "
         "decode step: stat=mean over the held experts of the step's "

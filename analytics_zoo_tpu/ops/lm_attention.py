@@ -1,12 +1,16 @@
 """Attention over device-resident session caches for the decoder LM
 (models/lm.py): multi-head latent attention (MLA) in the absorbed form
-over a PAGED pool of latents, the learned sparse indexer with its
-top-k selection, and windowed MLA over per-session rings.  XLA only.
+over a PAGED pool of latents — over the positions a learned sparse
+indexer selects (full layers) or over the whole context (causal layers)
+— and windowed MLA over per-session rings; rotary embedding, plain or
+YaRN-scaled (``RopeScaling``).  XLA, but for two Pallas programs it hands
+lane-aligned widths to: prefill's attention (ops/pallas_lm_prefill.py)
+and a causal layer's paged decode (ops/pallas_lm_decode.py).
 
 Cache layout (one replica's, all sessions'):
 
-- ``kv``  a full layer: (n_pages, page, entry) — per token the normed kv
-  latent, the rotated shared key, and zeros up to ``entry`` (a multiple
+- ``kv``  a full or causal layer: (n_pages, page, entry) — per token the
+  normed kv latent, the rotated shared key, and zeros up to ``entry`` (a multiple
   of the TPU's 128 lanes, so a token's row is the minor axis);
 - ``ik``  a full layer: (n_pages, page, idx_dim) — the indexer's key;
 - ``ring`` a sliding layer: (n_slots, window, swa entry) — the last
@@ -19,7 +23,10 @@ table, the pages' owners and the positions come from the host with every
 call (pipelines/lm.py owns the allocation); the arrays here hold data
 only.
 
-Two shapes of work:
+Two shapes of work (a causal layer's are the plain ones: decode is
+``mla_paged`` over every page a row holds, prefill
+``prefill_causal_attention`` with the causal mask alone as the bias; what
+follows is a full layer's):
 
 - **decode** — a batch of rows, one new token each, rows of any sessions:
   the indexer scores every page once against its owner's query (each key
@@ -48,13 +55,16 @@ the flip does to the output says nothing about either side's arithmetic
 
 from __future__ import annotations
 
+import dataclasses
 import math
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from analytics_zoo_tpu.ops import pallas_lm_prefill
+from analytics_zoo_tpu.ops import pallas_lm_decode, pallas_lm_prefill
 
 F32 = jnp.float32
 NEG = -1e30
@@ -65,14 +75,74 @@ NEG = -1e30
 SELECT_BLOCK = 4096
 
 
-def rope(x, pos, theta: float):
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN (a config's ``rope_scaling`` of type ``yarn``): rotary pairs
+    that turn often inside the ``original`` context keep their frequency,
+    those that turn less than once are slowed by ``factor``, a linear ramp
+    between the pairs that make ``beta_fast`` and ``beta_slow`` turns."""
+    factor: float
+    original: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @classmethod
+    def from_dict(cls, d: Optional[Dict]) -> Optional["RopeScaling"]:
+        if not d:
+            return None
+        if d.get("type", d.get("rope_type")) != "yarn":
+            raise ValueError(f"rope_scaling: only yarn is known, got {d}")
+        return cls(float(d["factor"]),
+                   int(d["original_max_position_embeddings"]),
+                   float(d.get("beta_fast", 32)), float(d.get("beta_slow", 1)),
+                   float(d.get("mscale", 1)),
+                   float(d.get("mscale_all_dim", 0)))
+
+    def inv_freq(self, r: int, theta: float) -> np.ndarray:
+        """The ``r / 2`` pairs' frequencies, float32."""
+        def pair_at(turns):       # the pair that makes ``turns`` rotations
+            return r * math.log(self.original / (turns * 2 * math.pi)) \
+                / (2 * math.log(theta))
+        lo = max(math.floor(pair_at(self.beta_fast)), 0)
+        hi = min(math.ceil(pair_at(self.beta_slow)), r // 2 - 1)
+        f = theta ** (-np.arange(0, r, 2, dtype=np.float64) / r)
+        ramp = np.clip((np.arange(r // 2) - lo)
+                       / (hi - lo if hi > lo else 0.001), 0, 1)
+        return (f / self.factor * ramp + f * (1 - ramp)).astype(np.float32)
+
+    @staticmethod
+    def _mscale(factor: float, m: float) -> float:
+        return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    @property
+    def amplitude(self) -> float:
+        """What cos and sin are multiplied by."""
+        return self._mscale(self.factor, self.mscale) \
+            / self._mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_mscale(self) -> float:
+        """The attention scale is multiplied by its square."""
+        return self._mscale(self.factor, self.mscale_all_dim)
+
+
+def rope(x, pos, theta: float, scaling: Optional[RopeScaling] = None):
     """Rotary embedding of the last axis in interleaved pairs; ``pos``
-    has ``x``'s leading axes up to where it stops (``x`` (..., [h,] r))."""
+    has ``x``'s leading axes up to where it stops (``x`` (..., [h,] r)).
+    ``scaling``: YaRN's frequencies and amplitude in place of the plain
+    ones."""
     r = x.shape[-1]
-    freq = theta ** (-jnp.arange(0, r, 2, dtype=F32) / r)
+    if scaling is None:
+        freq = theta ** (-jnp.arange(0, r, 2, dtype=F32) / r)
+    else:
+        freq = jnp.asarray(scaling.inv_freq(r, theta))
     ang = pos.astype(F32).reshape(pos.shape + (1,) * (x.ndim - pos.ndim)) \
         * freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling.amplitude != 1.0:
+        cos, sin = cos * scaling.amplitude, sin * scaling.amplitude
     xf = x.astype(F32)
     x0, x1 = xf[..., 0::2], xf[..., 1::2]
     return jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos],
@@ -233,6 +303,32 @@ def mla_absorbed(q_nope, q_rope, latents, valid, wkv_b, nope: int,
     return jnp.einsum("bhr,rhv->bhv", o, wkv_b[..., nope:])
 
 
+def mla_paged(q_nope, q_rope, kv_pool, tables, lengths, wkv_b, nope: int,
+              r: int, scale: float):
+    """MLA of B rows, each over ALL the entries of its own pages (a causal
+    layer's decode), in the absorbed form.  ``q_nope`` (B, H, nope),
+    ``q_rope`` (B, H, r), ``kv_pool`` (n_pages, page, entry), ``tables``
+    (B, max_pages), ``lengths`` (B,) the entries a row attends to (0: a
+    padding row) → (B, H, v).  At widths the Pallas kernel takes
+    (ops/pallas_lm_decode.py) the pages are read where they lie; at others
+    (a toy's) every row's pages are gathered for ``mla_absorbed``."""
+    rank = wkv_b.shape[0]
+    B, H, _ = q_nope.shape
+    _, page, entry = kv_pool.shape
+    if not pallas_lm_decode.supported(rank, entry, page):
+        mine = kv_pool[tables].reshape(B, -1, entry)
+        valid = jnp.arange(mine.shape[1])[None, :] < lengths[:, None]
+        return mla_absorbed(q_nope, q_rope, mine, valid, wkv_b, nope, r,
+                            scale)
+    q_abs = jnp.einsum("bhn,rhn->bhr", q_nope, wkv_b[..., :nope])
+    q = jnp.concatenate(
+        [q_abs, q_rope.astype(q_abs.dtype),
+         jnp.zeros((B, H, entry - rank - r), q_abs.dtype)], -1)
+    o = pallas_lm_decode.paged_mla_decode(q, kv_pool, tables, lengths,
+                                          rank=rank, scale=scale)
+    return jnp.einsum("bhr,rhv->bhv", o, wkv_b[..., nope:])
+
+
 # ---------------------------------------------------------------------------
 # prefill: one session's chunk of T tokens
 # ---------------------------------------------------------------------------
@@ -253,9 +349,8 @@ def prefill_full_attention(q_nope, q_rope, q_idx, w_idx, kv_pool, ik_pool,
     where query t attends to position s).  ``flash``: how many heads a step
     of the Pallas form of pass 2 takes (ops/pallas_lm_prefill.py; 0, or
     widths it does not take: the XLA loop)."""
-    T, H, _ = q_nope.shape
+    T = q_nope.shape[0]
     page = kv_pool.shape[1]
-    rank = wkv_b.shape[0]
     # the selection works on blocks of ``blk`` columns of the scores: whole
     # pages, whole bytes of the selected sets, whole steps of the XLA
     # attention loop, and no fewer than ``topk`` (a session's first block
@@ -326,6 +421,43 @@ def prefill_full_attention(q_nope, q_rope, q_idx, w_idx, kv_pool, ik_pool,
          jnp.full((T, max_len), NEG, kv_pool.dtype),
          jnp.zeros((T, max_len // 8), jnp.uint8)))
 
+    return attend_biased(q_nope, q_rope, kv_pool, bias, table, n_pages, wkv_b,
+                         nope, r, scale, pages_per_step, flash), sets
+
+
+def prefill_causal_attention(q_nope, q_rope, kv_pool, table, start, n_valid,
+                             wkv_b, nope: int, r: int, scale: float,
+                             pages_per_step: int = 2, flash: int = 0):
+    """MLA of a chunk against ALL of its session's entries up to each
+    query's own (a causal layer: no indexer, no selected sets), the
+    chunk's own tokens (already written to the pool) included.  Arguments
+    as :func:`prefill_full_attention`'s → (T, H, v)."""
+    T = q_nope.shape[0]
+    page = kv_pool.shape[1]
+    span = pages_per_step * page
+    max_len = -(-table.shape[0] * page // span) * span
+    end = start + n_valid
+    key_pos = jnp.arange(max_len)[None, :]
+    seen = (key_pos <= (start + jnp.arange(T))[:, None]) & (key_pos < end)
+    bias = jnp.where(seen, 0.0, NEG).astype(kv_pool.dtype)
+    return attend_biased(q_nope, q_rope, kv_pool, bias, table,
+                         (end + page - 1) // page, wkv_b, nope, r, scale,
+                         pages_per_step, flash)
+
+
+def attend_biased(q_nope, q_rope, kv_pool, bias, table, n_pages, wkv_b,
+                  nope: int, r: int, scale: float, pages_per_step: int,
+                  flash: int):
+    """Attention of a chunk's queries over the first ``n_pages`` pages of
+    its session, online softmax; which keys a query attends to comes as
+    ``bias`` (T, whole steps of ``pages_per_step`` pages): 0 or ``NEG``.
+    ``flash``: how many heads a step of the Pallas form takes
+    (ops/pallas_lm_prefill.py; 0, or widths it does not take: the XLA
+    loop) → (T, H, v)."""
+    T, H, _ = q_nope.shape
+    page = kv_pool.shape[1]
+    rank = wkv_b.shape[0]
+    max_len = bias.shape[1]
     entry = kv_pool.shape[2]
     if flash and pallas_lm_prefill.supported(nope, wkv_b.shape[2] - nope,
                                              rank, entry, r, H, flash):
@@ -338,7 +470,7 @@ def prefill_full_attention(q_nope, q_rope, q_idx, w_idx, kv_pool, ik_pool,
             q.transpose(1, 0, 2), wkv_b, kv_pool, bias, table, n_pages,
             nope=nope, scale=scale, steps=max_len // page,
             heads_per_step=flash)
-        return o.transpose(1, 0, 2), sets
+        return o.transpose(1, 0, 2)
 
     # pass 2 in XLA: attention over the selected keys, online softmax, the
     # absorbed form; ``pages_per_step`` pages an iteration, because every
@@ -376,7 +508,7 @@ def prefill_full_attention(q_nope, q_rope, q_idx, w_idx, kv_pool, ik_pool,
         0, (n_pages + pages_per_step - 1) // pages_per_step, attend,
         (m0, jnp.zeros((T, H), F32), jnp.zeros((T, H, rank), F32)))
     o = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(kv_pool.dtype)
-    return jnp.einsum("thr,rhv->thv", o, wkv_b[..., nope:]), sets
+    return jnp.einsum("thr,rhv->thv", o, wkv_b[..., nope:])
 
 
 def prefill_window_attention(q, c_new, prev, prev_pos, start, n_valid,

@@ -1,0 +1,177 @@
+"""Decode attention of the decoder LM's CAUSAL layers (models/lm.py) as
+ONE Pallas program a layer and step: multi-head latent attention in the
+absorbed form of B rows of any sessions, each over ALL of its own pages,
+read straight out of the paged pool through the rows' page tables —
+online softmax, nothing gathered, nothing of the scores in HBM.
+
+A step that gathered its rows' contexts first (``pool[tables]``, the XLA
+form: ops/lm_attention.py ``mla_paged``'s fallback) would copy every
+row's padded ``max_len`` entries a layer: 64 rows x 45 k x 1,280 B = 3.7 GB.
+Here a page is DMA'd once and scored by all heads against the one
+``entry``-wide row it holds a token: a head's absorbed query
+``[q_nope W_kvb^K ; q_rope ; 0]`` against ``[c_kv ; k_r ; 0]``.
+
+**The grid is a flat list of (row, page) work items**, not (B, max_pages):
+rows are ragged (2 to 88 pages at the benchmark's mix), and a rectangular
+grid would be 5,632 steps for some 1,400 pages that hold anything.  A
+page has one owner, so the pool's ``n_pages − 1`` pages bound the list:
+``work_items`` lays the live rows' pages one row after another (a row's
+pages consecutive: its running maximum, sum and accumulator stay in VMEM
+scratch from its first page to its last) and the steps past the last item
+repeat it and do nothing (the same blocks: no DMA).  ``grid_steps`` is
+what the tier's gauge ``lm/paged_grid_steps`` reports beside the pages
+that held something.
+
+Per item the MXU sees two products with the 64 heads as the short side —
+``(H, entry) x (page, entry)ᵀ`` and ``(H, page) x (page, rank)`` — so a
+loaded tile of the page is used by 64 rows only.  On a v5e the kernel
+takes 1.04–1.13 µs a page where the page's DMA is 0.80 (PERF.md §5, PR 33):
+the short side and a grid step's own cost are reckoned to be the rest, not
+read apart.
+
+Off the TPU the kernel runs in interpret mode (the tests' way).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from analytics_zoo_tpu.ops import vmem
+from analytics_zoo_tpu.utils import engine
+
+F32 = jnp.float32
+NEG = -1e30
+LANES = 128
+
+
+def supported(rank: int, entry: int, page: int) -> bool:
+    """Whether the kernel takes these widths: the latent it cuts out of a
+    page ends on a lane tile, and a page is whole sublane tiles."""
+    return rank % LANES == 0 and entry % LANES == 0 and page % 16 == 0
+
+
+def grid_steps(rows: int, max_pages: int, n_pages: int) -> int:
+    """Steps the kernel is launched with for ``rows`` rows of up to
+    ``max_pages`` pages over a pool of ``n_pages`` (page 0 nobody's)."""
+    return max(1, min(rows * max_pages, n_pages - 1))
+
+
+def work_items(lengths, tables, page: int, n_items: int):
+    """The flat list: item w is page ``idx[w]`` (in its row's order) of
+    row ``row[w]``, pool page ``phys[w]``; rows one after another, each
+    row's ``ceil(length / page)`` pages in order.  Items past the last
+    repeat its row and page with ``idx`` −1.  ``lengths`` (B,) tokens a
+    row holds (0: a padding row), ``tables`` (B, max_pages)."""
+    B, max_pages = tables.shape
+    pages = (lengths + page - 1) // page
+    ends = jnp.cumsum(pages)
+    w = jnp.minimum(jnp.arange(n_items), jnp.maximum(ends[-1] - 1, 0))
+    row = jnp.minimum(jnp.sum(ends[None, :] <= w[:, None], 1), B - 1)
+    idx = w - (ends - pages)[row]
+    phys = tables[row, jnp.clip(idx, 0, max_pages - 1)]
+    real = jnp.arange(n_items) < ends[-1]
+    return (row.astype(jnp.int32), phys.astype(jnp.int32),
+            jnp.where(real, idx, -1).astype(jnp.int32))
+
+
+def _kernel(row_ref, page_ref, idx_ref, len_ref, q_ref, kv_ref, o_ref,
+            m_sc, l_sc, acc_sc, *, page: int, rank: int, scale: float):
+    w = pl.program_id(0)
+    j = idx_ref[w]
+    n = len_ref[row_ref[w]]
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, NEG, F32)
+        l_sc[...] = jnp.zeros(l_sc.shape, F32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
+
+    @pl.when(j >= 0)
+    def _():
+        kv = kv_ref[0]                                    # (page, entry)
+        s = lax.dot_general(q_ref[0], kv, (((1,), (1,)), ((), ())),
+                            preferred_element_type=F32) * scale
+        at = j * page + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(at < n, s, NEG)                     # (H, page)
+        m_prev, l_prev = m_sc[...], l_sc[...]             # (H, LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row's first page holds its first token, so from the first
+        # step on the maximum is a real score and a masked key's
+        # exp(NEG − m) is 0
+        p = jnp.exp(s - m_new[:, :1])
+        l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        m_sc[...] = m_new
+        acc_sc[...] = acc_sc[...] * alpha[:, :1] + jnp.dot(
+            p.astype(kv.dtype), kv[:, :rank], preferred_element_type=F32)
+
+    @pl.when((j >= 0) & ((j + 1) * page >= n))            # its last page
+    def _():
+        o_ref[0] = (acc_sc[...] / l_sc[...][:, :1]).astype(o_ref.dtype)
+
+
+def declared_vmem_bytes(H: int, page: int, entry: int, rank: int,
+                        dtype) -> int:
+    """The kernel's blocks (double-buffered) and scratch as Mosaic lays
+    them out, plus a step's scores and probabilities."""
+    blocks = (vmem.padded_bytes((H, entry), dtype)
+              + vmem.padded_bytes((page, entry), dtype)
+              + vmem.padded_bytes((H, rank), dtype))
+    scratch = (2 * vmem.padded_bytes((H, LANES), F32)
+               + vmem.padded_bytes((H, rank), F32))
+    return 2 * blocks + scratch + 3 * vmem.padded_bytes((H, page), F32)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def paged_mla_decode(q, kv_pool, tables, lengths, *, rank: int, scale: float,
+                     interpret=None):
+    """Absorbed MLA of B rows over their own pages.
+
+    ``q`` (B, H, entry): a head's absorbed query — ``q_nope W_kvb^K``
+    (``rank`` wide), the rotated rotary part, zeros up to the entry's
+    width; ``kv_pool`` (n_pages, page, entry): latent, rotated shared key,
+    zeros; ``tables`` (B, max_pages) the rows' page tables; ``lengths``
+    (B,) the tokens each row attends to (the one being decoded, already
+    written, included; 0: a padding row) → (B, H, rank) in the pool's
+    dtype, the softmax-weighted latents (zeros for a padding row)."""
+    B, H, entry = q.shape
+    n_pages, page, _ = kv_pool.shape
+    if interpret is None:
+        interpret = not engine.on_tpu()
+    if kv_pool.shape[2] != entry or not supported(rank, entry, page):
+        raise ValueError(f"paged_mla_decode: q {q.shape}, pool "
+                         f"{kv_pool.shape}, rank {rank} do not fit")
+    lengths = lengths.astype(jnp.int32)
+    n_items = grid_steps(B, tables.shape[1], n_pages)
+    row, phys, idx = work_items(lengths, tables, page, n_items)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(n_items,),
+        in_specs=[
+            pl.BlockSpec((1, H, entry), lambda w, r, p, i, n: (r[w], 0, 0)),
+            pl.BlockSpec((1, page, entry),
+                         lambda w, r, p, i, n: (p[w], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, H, rank),
+                               lambda w, r, p, i, n: (r[w], 0, 0)),
+        scratch_shapes=[pltpu.VMEM((H, LANES), F32),
+                        pltpu.VMEM((H, LANES), F32),
+                        pltpu.VMEM((H, rank), F32)])
+    out = pl.pallas_call(
+        functools.partial(_kernel, page=page, rank=rank, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), kv_pool.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem.limit_bytes(declared_vmem_bytes(
+                H, page, entry, rank, kv_pool.dtype))),
+        name="lm_decode_mla_paged",
+        interpret=interpret,
+    )(row, phys, idx, lengths, q, kv_pool)
+    # a padding row's block is never visited: whatever stood there
+    return jnp.where((lengths > 0)[:, None, None], out, 0)

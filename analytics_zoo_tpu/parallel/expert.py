@@ -171,15 +171,28 @@ def moe_apply_expert_parallel(
 # ---------------------------------------------------------------------------
 
 def route_topk_sigmoid(x: jax.Array, router_w: jax.Array,
-                       router_b: jax.Array, top_k: int,
-                       scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
-    """``noaux_tc`` routing in one group: scores ``sigmoid(x W_r)`` over
-    the router's whole width, the ``top_k`` largest of score + bias chosen
-    (ties to the lower expert id), weights the chosen SCORES over their
-    sum, times ``scale``.  → (chosen (N, k) int32, weights (N, k) f32)."""
+                       router_b: Optional[jax.Array], top_k: int,
+                       scale: float = 1.0, n_group: int = 1,
+                       topk_group: int = 1) -> Tuple[jax.Array, jax.Array]:
+    """``noaux_tc`` routing: scores ``sigmoid(x W_r)`` over the router's
+    whole width, the ``top_k`` largest of score + bias chosen (ties to the
+    lower expert id; ``router_b`` ``None``: no bias correction), weights
+    the chosen SCORES over their sum, times ``scale``.  With ``n_group``
+    groups (of consecutive expert ids) the choice is group-limited: a
+    group's score is the sum of its two largest score + bias, only the
+    ``topk_group`` best groups (ties to the lower group) stay eligible.
+    → (chosen (N, k) int32, weights (N, k) f32)."""
     s = jax.nn.sigmoid(jnp.einsum("nd,de->ne", x, router_w,
                                   preferred_element_type=jnp.float32))
-    _, chosen = jax.lax.top_k(s + router_b.astype(jnp.float32), top_k)
+    biased = s if router_b is None else s + router_b.astype(jnp.float32)
+    if n_group > 1:
+        groups = biased.reshape(s.shape[0], n_group, -1)
+        best_two, _ = jax.lax.top_k(groups, 2)
+        _, kept = jax.lax.top_k(jnp.sum(best_two, -1), topk_group)
+        eligible = jnp.any(kept[:, :, None] == jnp.arange(n_group), 1)
+        biased = jnp.where(eligible[:, :, None], groups,
+                           -jnp.inf).reshape(s.shape)
+    _, chosen = jax.lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(s, chosen, 1)
     return chosen, scale * picked / jnp.sum(picked, 1, keepdims=True)
 
@@ -262,15 +275,18 @@ def _held_grouped(x, experts, local, weights, counts):
 
 
 def moe_held_experts(x: jax.Array, params: Any, first_held: Any,
-                     top_k: int, scale: float = 1.0, shared: bool = True):
+                     top_k: int, scale: float = 1.0, shared: bool = True,
+                     n_group: int = 1, topk_group: int = 1):
     """One share of the layer on one chip, without its exchange: route
     over the router's whole width, the held experts' part, plus the shared
-    expert.  ``params``: ``router_w`` (d, E), ``router_b`` (E,),
-    ``experts`` (held, stacked), ``shared`` (a gated MLP).
+    expert.  ``params``: ``router_w`` (d, E), ``router_b`` (E,) if the
+    router corrects by a bias, ``experts`` (held, stacked), ``shared`` (a
+    gated MLP); ``n_group`` / ``topk_group``: :func:`route_topk_sigmoid`'s.
     → (y (N, d), chosen (N, k), tokens per held expert (H,))."""
     with jax.named_scope("lm/route"):
         chosen, weights = route_topk_sigmoid(
-            x, params["router_w"], params["router_b"], top_k, scale)
+            x, params["router_w"], params.get("router_b"), top_k, scale,
+            n_group, topk_group)
     with jax.named_scope("lm/experts"):
         y, counts = held_experts_apply(x, params["experts"], chosen,
                                        weights, first_held)
@@ -283,7 +299,8 @@ def moe_held_experts(x: jax.Array, params: Any, first_held: Any,
 
 def moe_held_experts_parallel(x: jax.Array, params: Any, mesh: Mesh,
                               top_k: int, scale: float = 1.0,
-                              axis_name: str = EXPERT_AXIS) -> jax.Array:
+                              axis_name: str = EXPERT_AXIS, n_group: int = 1,
+                              topk_group: int = 1) -> jax.Array:
     """Every share of the layer on an ``expert`` mesh axis, with the
     exchange: ``params["experts"]`` holds ALL experts stacked and sharded
     over the axis (device i holds experts ``i·H .. (i+1)·H``), ``x``
@@ -298,17 +315,20 @@ def moe_held_experts_parallel(x: jax.Array, params: Any, mesh: Mesh,
         raise ValueError(f"{n_experts} experts / {x.shape[0]} tokens do not "
                          f"divide over {n} devices of {axis_name!r}")
     held = n_experts // n
-    spec = {"router_w": P(), "router_b": P(),
+    spec = {"router_w": P(),
             "experts": jax.tree_util.tree_map(lambda _: P(axis_name),
                                               params["experts"]),
             "shared": jax.tree_util.tree_map(lambda _: P(),
                                              params["shared"])}
+    if "router_b" in params:
+        spec["router_b"] = P()
 
     def local(p, x_l):
         x_all = jax.lax.all_gather(x_l, axis_name, axis=0, tiled=True)
         first = jax.lax.axis_index(axis_name) * held
         part, _, _ = moe_held_experts(x_all, p, first, top_k, scale,
-                                      shared=False)
+                                      shared=False, n_group=n_group,
+                                      topk_group=topk_group)
         y = jax.lax.psum_scatter(part, axis_name, scatter_dimension=0,
                                  tiled=True)
         sh = p["shared"]

@@ -374,7 +374,10 @@ def _lm_specs(mesh: Mesh) -> SpecSet:
     (expert) dim — what ``expert.moe_held_experts_parallel`` expects —
     and everything else (attention, router, shared expert, the ends) is
     replicated; on any other mesh all of it is replicated (one chip
-    serves its own share and its own sessions)."""
+    serves its own share and its own sessions).  The rules go by the
+    parameters' names, which every decoder configuration shares (a model
+    without a router bias or headwise gates has fewer leaves, no other
+    names)."""
     from analytics_zoo_tpu.parallel import tensor as tensor_lib
     from analytics_zoo_tpu.parallel.expert import EXPERT_AXIS
 

@@ -17,8 +17,10 @@ chunk's last token.  ``lm_serving_tiers`` is the model's ``tier_factory``::
 
 **Who owns what.**  One replica's tier instance owns one
 :class:`SessionCache`: a paged pool for the full-attention layers' latents
-and index keys and a ring a session for the sliding layers
-(ops/lm_attention.py), the device arrays of both, and the host's books —
+and index keys and for the causal layers' latents, and a ring a session
+for the sliding layers (ops/lm_attention.py) — of each only what the
+model's kinds of layer need: a model of causal layers alone has pools and
+nothing else — the device arrays, and the host's books —
 which pages a session holds, how long it is, which pages are free.  A
 chunk is admitted before it runs (``az/lm/cache_admit``): its session gets
 a slot on first sight and as many pages as its new length needs; a chunk
@@ -46,7 +48,9 @@ each token was routed to — in ``tier.choices[session_id]``, a list of
 ``(position of the chunk's first token, tokens, {"selected": [a full
 layer: (tokens, topk) positions, −1 where there are fewer, from a decode
 step; uint8 (tokens, ceil(end / 8)) bit-packed rows from a prefill call],
-"routed": (MoE layers, tokens, k)})`` in the order the chunks ran.  A
+"routed": (MoE layers, tokens, k)})`` in the order the chunks ran
+(``selected`` is empty for a model without full layers: nothing is
+selected, ``routed`` is all there is to record).  A
 comparison with another implementation needs them (past ``index_topk``
 tokens a rounding flips members of the sets: benchmarks/reference/lm.py).
 Nothing is fetched from the device for a session nobody asked about.
@@ -55,7 +59,7 @@ Nothing is fetched from the device for a session nobody asked about.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +68,7 @@ import numpy as np
 from analytics_zoo_tpu.models import lm
 from analytics_zoo_tpu.obs.registry import MetricRegistry
 from analytics_zoo_tpu.obs.span import stage
+from analytics_zoo_tpu.ops import pallas_lm_decode
 
 
 #: the most tokens one prefill call takes; a longer chunk runs as
@@ -180,7 +185,9 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
     edge) int32 token ids, "n_tokens": (max_batch,) how many of each row
     are real, "session": (max_batch,) int64 ids (−1: a padding row),
     "final": (max_batch,) int8}``.  Returns one float32 (vocab,) row of
-    logits a row of the batch.  ``cache_tokens``: the paged pool's size;
+    logits a row of the batch (a decode batch's as ONE (max_batch, vocab)
+    array: the runtime hands its rows out without copying them again).
+    ``cache_tokens``: the paged pool's size;
     ``max_len``: the longest a session may grow (default: the pool).
     ``registry``: where the cache's gauges and the experts' load go."""
     from analytics_zoo_tpu.serving.ladder import ServingTier
@@ -221,6 +228,18 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
             registry.histogram("lm/expert_tokens/stat=max").observe(
                 float(moe.max()))
 
+    paged = lm.CAUSAL in cfg.kinds
+
+    def note_paged(lengths: np.ndarray) -> None:
+        """What a causal layer's decode walked this step: the pages that
+        hold a token of a live row, and the steps the kernel's grid was
+        launched with (ops/pallas_lm_decode.py), one layer."""
+        registry.gauge("lm/paged_pages").set(
+            int((-(-lengths // geo.page)).sum()))
+        registry.gauge("lm/paged_grid_steps").set(
+            pallas_lm_decode.grid_steps(len(lengths), geo.max_pages,
+                                        geo.n_pages))
+
     def note_cache() -> None:
         registry.gauge("lm/cache_tokens").set(books.tokens)
         registry.gauge("lm/cache_fill").set(books.fill)
@@ -240,11 +259,15 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
         row_of_slot[slots[live]] = np.nonzero(live)[0]
         owner = row_of_slot[books.page_slot]      # slot −1 → last entry
         with stage("az/serve/h2d"):
-            args = [jnp.asarray(a) for a in
-                    (ids[:, 0].astype(np.int32), slots, pos, tables, owner)]
+            rows = jnp.asarray(lm.pack_rows(ids[:, 0], slots, pos, tables,
+                                            owner))
         with stage("az/serve/dispatch"):
             state["cache"], logits, counts, chosen = lm.decode_jit(
-                cfg, geo, model.params, cache(), *args)
+                cfg, geo, model.params, cache(), rows)
+            # the answers' copies start when the step ends, not when the
+            # host comes to ask for them
+            logits.copy_to_host_async()
+            counts.copy_to_host_async()
         with stage("az/serve/result_wait"):
             logits, counts = np.asarray(logits), np.asarray(counts)
         mine = [i for i in np.nonzero(live)[0]
@@ -255,7 +278,11 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
                 keep_choices(int(sessions[i]), int(pos[i]), 1, chosen,
                              slice(i, i + 1))
         note_experts(counts)
-        return list(logits)
+        if paged:
+            note_paged(np.where(live, pos + 1, 0))
+        # the (B, vocab) array itself: a list of its rows is stacked again,
+        # 5 MB copied, when the runtime hands the answers out
+        return logits
 
     def run_prefill(ids, lens, sessions):
         """One call a live row (one, dry, when there is none: warm-up)."""
@@ -296,7 +323,7 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
             answers[i] = o
         return answers
 
-    def forward(batch: Dict) -> List[np.ndarray]:
+    def forward(batch: Dict) -> Sequence[np.ndarray]:
         ids = np.asarray(batch["input"])
         sessions = np.asarray(batch["session"])
         edge = ids.shape[1]
@@ -328,10 +355,9 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
             if edge == 1:
                 B = max_batch
                 return (lm.decode_jit,
-                        (cfg, geo, params, shapes, S((B,), i32),
-                         S((B,), i32), S((B,), i32),
-                         S((B, geo.max_pages), i32),
-                         S((geo.n_pages,), i32)), (0, 1))
+                        (cfg, geo, params, shapes,
+                         S((B * (3 + geo.max_pages) + geo.n_pages,), i32)),
+                        (0, 1))
             return (lm.prefill_jit, (cfg, geo, params, shapes,
                               S((min(edge, PREFILL_BLOCK),), i32), S((), i32),
                               S((), i32), S((), i32),

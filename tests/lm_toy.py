@@ -1,6 +1,8 @@
 """The toy size the LM tests run at on the CPU: every width small, 8
 experts of which 4 are held (two shares), 2 full + 3 sliding layers in
-the published pattern's order, window 5, top-k 4."""
+the published pattern's order, window 5, top-k 4 — with the keys of the
+published config that switch on its headwise gates, its latents' rescale
+and its router's bias (models/lm.py::LMConfig.from_dict)."""
 
 import numpy as np
 
@@ -9,6 +11,8 @@ TOY = {
     "layer_types": ["full_attention", "full_attention", "sliding_attention",
                     "sliding_attention", "sliding_attention"],
     "first_k_dense_replace": 1,
+    "attention_gate_type": "headwise", "apply_mla_qkv_lora_rescale": True,
+    "topk_method": "noaux_tc", "rope_scaling": None,
     "num_attention_heads": 4, "q_lora_rank": 16, "kv_lora_rank": 8,
     "qk_nope_head_dim": 8, "qk_rope_head_dim": 4, "v_head_dim": 8,
     "rope_theta": 80000000,

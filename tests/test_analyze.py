@@ -553,6 +553,10 @@ class TestRepoClean:
             assert f"{pipe}/eval" in names, names
         assert {"lm/serve:bf16", "lm/serve:prefill4",
                 "lm/serve:prefill8"} <= names
+        # ISSUE 33: the same tier over a model of causal latent attention
+        # throughout (the paged decode attention, pools only)
+        assert {"lm-mla/serve:bf16", "lm-mla/serve:prefill4",
+                "lm-mla/serve:prefill8"} <= names
         assert {"ssd/serve:fp", "ssd/serve:int8"} <= names
         # ISSUE 13: the persistent-RNN TRAIN program (pallas engine,
         # transposed persistent backward) is audited alongside the

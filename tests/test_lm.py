@@ -375,6 +375,43 @@ def test_answers_reach_the_caller_without_retained_requests(weights):
         np.testing.assert_allclose(row, want[pos], atol=TOL, rtol=0)
 
 
+@pytest.mark.parametrize("B,max_pages,n_pages", [(1, 1, 2), (4, 12, 25),
+                                                 (64, 88, 1751)])
+def test_packed_rows_unpack_to_the_step_s_arguments(B, max_pages, n_pages):
+    """The tier's one transfer a decode step: ``pack_rows`` on the host,
+    ``unpack_rows`` inside the jitted step, B read off the length."""
+    rng = np.random.default_rng(B)
+    geo = lm.CacheGeometry(n_pages=n_pages, page=4, max_pages=max_pages,
+                           n_slots=B)
+    args = (rng.integers(0, 99, B), rng.integers(-1, B, B).astype(np.int64),
+            rng.integers(0, 40, B), rng.integers(0, n_pages, (B, max_pages)),
+            rng.integers(-1, B, n_pages))
+    rows = lm.pack_rows(*args)
+    assert rows.dtype == np.int32 and rows.ndim == 1
+    got = jax.jit(lambda r: lm.unpack_rows(geo, r))(jnp.asarray(rows))
+    for mine, want in zip(got, args):
+        np.testing.assert_array_equal(np.asarray(mine), want)
+
+
+def test_a_decode_batch_is_handed_out_without_a_second_copy(served):
+    """The tier returns the (max_batch, vocab) array itself, so the rows
+    the callers get are views of ONE array (a list of rows was stacked
+    again, the whole batch copied, in the runtime's handout)."""
+    rt, _ = served
+    sids = [rt.open_session("lm") for _ in range(3)]
+    for step in (3, 1):                     # a prefill chunk, then a decode
+        reqs = [rt.submit_chunk(sid, {"input": tokens(40 + i, step)},
+                                length=step) for i, sid in enumerate(sids)]
+        rt.pump(force=True)
+        assert all(r.state == "done" for r in reqs)
+    rows = [np.asarray(r.result) for r in reqs]
+    assert rows[0].base is not None
+    assert all(r.base is rows[0].base for r in rows)
+    assert rows[0].base.shape == (4, TOY["vocab_size"])
+    for sid in sids:
+        rt.close_session(sid)
+
+
 # -- the steps' discrete choices ----------------------------------------------
 
 def packed_rows(log, length):

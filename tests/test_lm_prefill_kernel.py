@@ -2,7 +2,8 @@
 against the XLA loop at small lane-aligned widths in interpret mode, and
 compiled for a v5e at the published widths (no chip needed: the TPU's
 compiler is described, not attached).  The repo's other compile-for-a-v5e
-tests live here too (decode's selection, fused DetectionOutput, last): only
+tests live here too (decode's selection, the causal layers' paged decode
+kernel and their prefill at 64 heads, fused DetectionOutput, last): only
 the one xdist worker that is given this file loads the TPU's library."""
 
 import re
@@ -51,6 +52,34 @@ def test_widths_the_kernel_does_not_take_run_the_xla_loop():
     assert not pf.supported(16, 16, 32, 128, 8, 4, 4)        # the toy's
     assert not pf.supported(128, 128, 512, 640, 64, 128, 3)  # heads % step
     assert pf.supported(128, 128, 512, 640, 64, 128, 4)      # published
+    assert pf.supported(128, 128, 512, 640, 64, 64, 4)       # A.X-K1's heads
+
+
+@pytest.mark.parametrize("start,n_valid", [(19, 13), (0, 16), (40, 16),
+                                           (0, 0)])
+def test_causal_prefill_kernel_equals_the_xla_loop(start, n_valid):
+    """A causal layer's chunk: the causal mask alone as the kernel's bias,
+    against the XLA loop and against plain softmax attention."""
+    (q_nope, q_rope, _, _, kv, _, table, s, n, wkv_b), kw = chunk(
+        start=start, n_valid=n_valid)
+    kw.pop("topk")
+    want = att.prefill_causal_attention(q_nope, q_rope, kv, table, s, n,
+                                        wkv_b, **kw, flash=0)
+    got = att.prefill_causal_attention(q_nope, q_rope, kv, table, s, n,
+                                       wkv_b, **kw, flash=2)
+    real = slice(0, n_valid)
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real],
+                               rtol=2e-5, atol=2e-5)
+    if n_valid:
+        mine = kv[table].reshape(-1, ENTRY)
+        valid = (jnp.arange(mine.shape[0])[None, :]
+                 <= start + jnp.arange(16)[:, None])
+        plain = att.mla_absorbed(
+            q_nope, q_rope, jnp.broadcast_to(mine, (16,) + mine.shape),
+            valid, wkv_b, NOPE, ROPE, kw["scale"])
+        np.testing.assert_allclose(np.asarray(want)[real],
+                                   np.asarray(plain)[real], rtol=2e-5,
+                                   atol=2e-5)
 
 
 @pytest.fixture(scope="module")
@@ -83,6 +112,43 @@ def test_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, T,
     assert "tpu_custom_call" in compiled.as_text()
     assert pf.declared_vmem_bytes(T, page, entry, rank, 128, 128, 4) \
         < 64 * (1 << 20)
+
+
+def test_paged_decode_kernel_compiles_for_a_v5e_at_the_published_widths(
+        one_chip, monkeypatch):
+    """ops/pallas_lm_decode.py at the A.X-K1 cell's geometry: 64 rows x 64
+    heads, a pool of 1,751 pages of 512 x 640, tables of 88 pages; Mosaic
+    takes the flat (row, page) grid inside the VMEM the kernel asks for."""
+    from analytics_zoo_tpu.ops import pallas_lm_decode as pd
+
+    monkeypatch.setattr(pd.engine, "on_tpu", lambda: True)
+    S = lambda s, d=jnp.bfloat16: jax.ShapeDtypeStruct(      # noqa: E731
+        s, d, sharding=one_chip)
+    fn = lambda q, pool, tables, n: pd.paged_mla_decode(     # noqa: E731
+        q, pool, tables, n, rank=512, scale=0.13)
+    compiled = jax.jit(fn).lower(
+        S((64, 64, 640)), S((1751, 512, 640)), S((64, 88), jnp.int32),
+        S((64,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert pd.grid_steps(64, 88, 1751) == 1750
+    assert pd.declared_vmem_bytes(64, 512, 640, 512, jnp.bfloat16) \
+        < 8 * (1 << 20)
+
+
+def test_causal_prefill_compiles_for_a_v5e_at_64_heads(one_chip,
+                                                       monkeypatch):
+    """A causal layer's chunk of 2,048 tokens at A.X-K1's widths through
+    the prefill kernel, the causal mask as its bias."""
+    monkeypatch.setattr(pf.engine, "on_tpu", lambda: True)
+    S = lambda s, d=jnp.bfloat16: jax.ShapeDtypeStruct(      # noqa: E731
+        s, d, sharding=one_chip)
+    fn = lambda qn, qr, kv, tab, s, n, w: att.prefill_causal_attention(  # noqa
+        qn, qr, kv, tab, s, n, w, 128, 64, 0.13, pages_per_step=1, flash=4)
+    compiled = jax.jit(fn).lower(
+        S((2048, 64, 128)), S((2048, 64, 64)), S((1751, 512, 640)),
+        S((88,), jnp.int32), S((), jnp.int32), S((), jnp.int32),
+        S((512, 64, 256))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_selection_compiles_for_a_v5e_with_no_sort_and_no_big_array(one_chip):

@@ -120,10 +120,9 @@ def test_decode_step_holds_no_sort_under_the_selection():
     B = 4
     S = jax.ShapeDtypeStruct
     text = lm.decode_jit.lower(
-        cfg, geo, weights, lm.cache_shapes(cfg, geo), S((B,), jnp.int32),
-        S((B,), jnp.int32), S((B,), jnp.int32),
-        S((B, geo.max_pages), jnp.int32),
-        S((geo.n_pages,), jnp.int32)).compile().as_text()
+        cfg, geo, weights, lm.cache_shapes(cfg, geo),
+        S((B * (3 + geo.max_pages) + geo.n_pages,), jnp.int32)
+    ).compile().as_text()
     assert geo.max_len > cfg.topk        # the long path
     by_scope = hlo_scopes.scope_map(text)
     assert by_scope["lm/select"] and by_scope["lm/experts"]
