@@ -37,9 +37,18 @@ Design (one producer ring per worker, order-preserving):
   byte-identical for ANY worker count — including ``num_workers=0``
   (the in-process serial reference path), pinned by
   ``tests/test_parallel_loader.py``.
+- **The pool outlives an epoch**: workers are forked once and run on
+  from epoch to epoch — after an epoch's end marker a worker reseeds for
+  the next epoch exactly as a fresh fork of that epoch would, opens the
+  source again and goes on putting groups.  The ring's back-pressure is
+  the only throttle, so the next epoch's first groups are decoded while
+  this epoch's last ones drain.  The pool stops when an epoch is closed
+  early or raises, on :meth:`ParallelLoader.close`, when the loader is
+  collected and at interpreter exit.
 - **Worker death** flows into the PR-1 resilience classification: a crashed
   worker is respawned (deterministic seeding lets it recompute from its
-  next owed group) at most ``max_respawns`` times per epoch, after
+  next owed group of the epoch in flight, and it too runs on into later
+  epochs) at most ``max_respawns`` times per epoch, after
   which :class:`~analytics_zoo_tpu.resilience.errors.PrefetchWorkerDied`
   (retryable) escalates to the supervisor.
 
@@ -52,6 +61,7 @@ transfer of batch ``t+1`` — one packed uint8 transfer on the
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import logging
 import multiprocessing as mp
@@ -61,8 +71,10 @@ import random
 import shutil
 import struct
 import tempfile
+import threading
 import time
 import warnings
+import weakref
 from multiprocessing.sharedctypes import RawArray
 from typing import Any, Iterator, List, Optional, Sequence, Tuple
 
@@ -79,8 +91,10 @@ logger = logging.getLogger("analytics_zoo_tpu")
 
 _DEFAULT_SLOT_BYTES = 32 << 20
 _POLL_S = 0.2
-# a worker's counters: indices into ``_Ring.counters``
-_CHAIN_S, _PUT_S, _WALK_S, _GROUPS, _ALIVE_S = range(5)
+# a worker's counters of the epoch it is in: indices into
+# ``_Ring.counters``, and the layout of an end marker's copy of them
+_CHAIN_S, _PUT_S, _WALK_S, _GROUPS, _T0, _T1, _EPOCH = range(7)
+_COUNTERS = struct.Struct("<7d")
 
 
 # ---------------------------------------------------------------------------
@@ -305,13 +319,15 @@ class _Ring:
         self.free = ctx.Semaphore(slots)
         self.items = ctx.Semaphore(0)
         self.seq = 0            # producer- and consumer-side slot cursor
-        # what the producer spent, cumulative, for the parent to record
-        # when the pool closes (a forked worker can open no obs.stage):
-        # seconds in the per-sample chain, in put_group, reading groups
-        # that are another worker's; groups shipped; seconds alive.
-        # Single writer, so no lock.
-        self.counters = RawArray("d", 5)
-        self.born_t = obs_span.now()
+        # what the producer has spent in the epoch it is in, for the
+        # parent to record (a forked worker can open no obs.stage):
+        # seconds in the per-sample chain, in put_group and blocked on
+        # full slots, reading groups that are another worker's; groups
+        # shipped; ``time.monotonic()`` at the epoch's start and at its
+        # end marker; the epoch.  An end marker carries a copy, so the
+        # parent reads these only of an epoch that never ended (the pool
+        # stopped, the worker died).  Single writer, so no lock.
+        self.counters = RawArray("d", _COUNTERS.size // 8)
         self.spilled = 0        # consumer side: groups read from a file
 
     def close(self) -> None:
@@ -322,8 +338,18 @@ class _Ring:
             pass
 
     # -- producer side (worker process) -----------------------------------
-    def _write_slot(self, kind: int, idx: int, meta: bytes,
-                    lens: Sequence[int], payload: Sequence) -> None:
+    def reserve(self, stop_event) -> bool:
+        """Wait for a writable slot; False when cancelled via
+        ``stop_event``.  This is where a worker that is ahead of the
+        parent stands still: the pool's only throttle."""
+        while not self.free.acquire(timeout=_POLL_S):
+            if stop_event.is_set():
+                return False
+        return True
+
+    def publish(self, kind: int, idx: int, meta: bytes,
+                lens: Sequence[int], payload: Sequence) -> None:
+        """Write one message into the reserved slot and make it visible."""
         base = (self.seq % self.slots) * self.slot_bytes
         buf = self.shm.buf
         _HDR.pack_into(buf, base, kind, idx, len(meta), len(lens))
@@ -337,6 +363,7 @@ class _Ring:
             buf[off:off + len(m)] = m
             off += len(m)
         self.seq += 1
+        self.items.release()          # publish — ONLY after a full write
 
     def put(self, kind: int, idx: int, meta: bytes, lens: Sequence[int],
             payload: Sequence, stop_event) -> bool:
@@ -346,11 +373,9 @@ class _Ring:
             raise ValueError(
                 f"message needs {need} bytes > slot_bytes={self.slot_bytes}"
                 " (spill should have caught this)")
-        while not self.free.acquire(timeout=_POLL_S):
-            if stop_event.is_set():
-                return False
-        self._write_slot(kind, idx, meta, lens, payload)
-        self.items.release()          # publish — ONLY after a full write
+        if not self.reserve(stop_event):
+            return False
+        self.publish(kind, idx, meta, lens, payload)
         return True
 
     def put_group(self, group_idx: int, samples: List[Any],
@@ -380,8 +405,10 @@ class _Ring:
         # spill file carries meta AND payload: a group whose IN-BAND
         # pickle alone exceeds the slot (e.g. raw JPEG bytes objects)
         # must degrade the same way as one with big ndarray buffers
+        # named by the slot cursor: a kept worker ships group_idx again
+        # every epoch, and may do so before the parent has read the last
         path = os.path.join(self.spill_dir,
-                            f"spill-{os.getpid()}-{group_idx}.bin")
+                            f"spill-{os.getpid()}-{self.seq}.bin")
         with open(path, "wb") as f:
             f.write(meta)
             for m in raw:
@@ -396,7 +423,8 @@ class _Ring:
     def get(self, timeout: float):
         """One published message or None on timeout: (kind, idx, obj)
         where obj is the unpickled group for GRP/SPILL, the pickled
-        payload bytes for ERR, and None for END."""
+        payload bytes for ERR, and the worker's packed counters of the
+        epoch for END."""
         if not self.items.acquire(timeout=timeout):
             return None
         base = (self.seq % self.slots) * self.slot_bytes
@@ -434,9 +462,7 @@ class _Ring:
                 off2 += n
             return _KIND_SPILL, idx, pickle.loads(view[:meta_len],
                                                   buffers=bufs)
-        if kind == _KIND_ERR:
-            return kind, idx, meta
-        return kind, idx, None
+        return kind, idx, meta          # ERR, END
 
 
 # ---------------------------------------------------------------------------
@@ -464,13 +490,23 @@ def _worker_main(worker_id: int, num_workers: int, epoch: int,
     it opens no ``obs.stage`` either: what it spends goes into
     ``ring.counters``).
 
-    Iterates the full raw stream (cheap), transforms only the groups
-    owned by this shard, and ships them through the ring.  All
-    randomness is pinned: worker-level RNGs from ``(base_seed, epoch,
-    shard)``, per-sample RNGs folded in from the global stream index."""
-    clock, counters = time.perf_counter, ring.counters
-    t_born = clock()
-    try:
+    Epoch after epoch, from ``epoch`` on, until ``stop_event``: iterates
+    the full raw stream (cheap), transforms only the groups owned by
+    this shard (of the first epoch those from ``start_group`` on — a
+    respawn's), ships them through the ring and ends the epoch with an
+    end marker that carries its counters.  All randomness is pinned
+    anew every epoch, as a fresh fork of that epoch would pin it:
+    worker-level RNGs from ``(base_seed, epoch, shard)``, per-sample
+    RNGs folded in from the global stream index.  The worker's own copy
+    of the source's per-epoch state advances by walking the stream."""
+    clock, counters = time.monotonic, ring.counters
+    warned = [False]
+
+    def one_epoch(epoch: int, start_group: int) -> bool:
+        """False when cancelled."""
+        counters[:] = [0.0] * len(counters)
+        counters[_EPOCH] = epoch
+        counters[_T0] = clock()
         # per-worker base PRNG: worker-local decisions (none on the hot
         # path today, but the contract is part of the API)
         random.seed(stable_seed("worker", base_seed, epoch, worker_id))
@@ -484,8 +520,6 @@ def _worker_main(worker_id: int, num_workers: int, epoch: int,
         g = 0
         idx = 0
         mine = (g % num_workers == worker_id) and g >= start_group
-
-        warned = [False]
 
         def flush() -> bool:
             if mine:
@@ -505,7 +539,7 @@ def _worker_main(worker_id: int, num_workers: int, epoch: int,
         t_group = clock()
         for sample in it:
             if stop_event.is_set():
-                return
+                return False
             if mine:
                 t = clock()
                 seed_sample(chain, base_seed, epoch, idx)
@@ -518,21 +552,29 @@ def _worker_main(worker_id: int, num_workers: int, epoch: int,
                 if not mine:
                     counters[_WALK_S] += clock() - t_group
                 if not flush():
-                    return
+                    return False
                 group = []
                 g += 1
                 mine = ((g % num_workers == worker_id)
                         and g >= start_group)
                 t_group = clock()
-                counters[_ALIVE_S] = t_group - t_born
         if idx % group_size:
             if not mine:
                 counters[_WALK_S] += clock() - t_group
             if not flush():
-                return
+                return False
             g += 1
-        counters[_ALIVE_S] = clock() - t_born
-        ring.put(_KIND_END, g, b"", (), (), stop_event)
+        t = clock()
+        if not ring.reserve(stop_event):
+            return False
+        counters[_T1] = clock()
+        counters[_PUT_S] += counters[_T1] - t
+        ring.publish(_KIND_END, g, _COUNTERS.pack(*counters), (), ())
+        return True
+
+    try:
+        while one_epoch(epoch, start_group):
+            epoch, start_group = epoch + 1, 0
     except BaseException as e:  # noqa: BLE001 - shipped to the parent
         import traceback
 
@@ -545,6 +587,70 @@ def _worker_main(worker_id: int, num_workers: int, epoch: int,
             ring.put(_KIND_ERR, 0, payload, (), (), stop_event)
         except Exception:
             pass
+
+
+# ---------------------------------------------------------------------------
+# The pool
+# ---------------------------------------------------------------------------
+
+
+def _record_worker(w: int, counters: Sequence[float], spills: int) -> None:
+    """One ``az/input/worker`` record from what worker ``w`` counted in
+    one epoch (nothing of a worker that never began one)."""
+    chain_s, put_s, walk_s, groups, t0, t1, epoch = counters
+    if t0:
+        obs_span.record_stage(
+            "az/input/worker", t0, t1, worker=w, epoch=int(epoch),
+            chain_s=chain_s, put_s=put_s, walk_s=walk_s,
+            groups=int(groups), spills=spills)
+
+
+class _Pool:
+    """What outlives an epoch: the forked workers, a ring each, the event
+    that cancels them and the directory their oversize groups spill to.
+    ``epoch`` is the one whose messages are next in the rings.  The
+    collection of ``owner`` (the loader) and the interpreter's exit stop
+    it too."""
+
+    def __init__(self, ctx, epoch: int, owner: Any):
+        self.ctx = ctx
+        self.epoch = epoch
+        self.stop_event = ctx.Event()
+        self.spill_dir = tempfile.mkdtemp(prefix="azt-loader-")
+        self.rings: List[_Ring] = []
+        self.procs: List[mp.Process] = []
+        self.respawns_left = 0
+        self.stopped = False
+        self._lock = threading.Lock()
+        self._finalizer = weakref.finalize(owner, self.stop)
+
+    def retire(self, w: int) -> None:
+        """Record what worker ``w`` (gone) counted in the epoch it never
+        ended — which therefore lasts until now — and unlink its ring."""
+        ring = self.rings[w]
+        counters = list(ring.counters)
+        counters[_T1] = obs_span.now()
+        _record_worker(w, counters, ring.spilled)
+        ring.close()
+
+    def stop(self) -> None:
+        """Cancel and reap the workers, record each one's unfinished
+        epoch, unlink the rings, remove the spill directory.  Idempotent,
+        and complete when it returns, whichever thread came first."""
+        with self._lock:
+            if self.stopped:
+                return
+            self.stopped = True
+            self.stop_event.set()
+            for proc in self.procs:
+                proc.join(timeout=1.0)
+                if proc.is_alive():
+                    proc.terminate()
+                    proc.join(timeout=5.0)
+            for w in range(len(self.rings)):
+                self.retire(w)
+            shutil.rmtree(self.spill_dir, ignore_errors=True)
+            self._finalizer.detach()
 
 
 # ---------------------------------------------------------------------------
@@ -561,8 +667,20 @@ class ParallelLoader:
     to forked worker processes with shared-memory rings.
 
     One live iterator at a time: each ``iter()`` call starts a new
-    epoch (advancing the shuffle state exactly like serial epochs do)
-    and owns the worker pool until exhausted or ``.close()``d.
+    epoch (advancing the shuffle state exactly like serial epochs do);
+    a second ``iter()`` while an epoch's iterator is open (neither
+    exhausted nor closed) raises.
+
+    The worker pool outlives an epoch: it is forked by the first epoch
+    that needs one and serves every later epoch from the same processes
+    and rings, the workers decoding the next epoch's first groups while
+    this epoch's last ones drain.  An epoch that is closed early or
+    raises stops the pool, and the next ``iter()`` forks a new one.
+    While it idles between epochs a pool holds its rings
+    (``num_workers x slots x slot_bytes`` of shared memory) and its
+    processes; :meth:`close` releases them (the loader stays usable:
+    the next epoch forks again), and so do the loader's collection and
+    the interpreter's exit.
 
     Note on shared RNGs: the vision/augment transforms draw from the
     process-global ``random`` (pre-existing design) and numpy consumers
@@ -631,7 +749,8 @@ class ParallelLoader:
         #: coordinate of a bad batch (with base_seed + batch index, the
         #: determinism contract pins the batch; see replay_batches)
         self.last_epoch: Optional[int] = None
-        self._procs: List[mp.Process] = []
+        self._pool: Optional[_Pool] = None
+        self._epoch_open = False
         if num_workers > 0 and not hasattr(os, "fork"):  # pragma: no cover
             warnings.warn("platform lacks fork(); ParallelLoader falls "
                           "back to the serial path")
@@ -642,19 +761,27 @@ class ParallelLoader:
         return len(self.dataset)
 
     def worker_pids(self) -> List[int]:
-        """Live worker PIDs of the current epoch (chaos drills)."""
-        return [p.pid for p in self._procs if p.is_alive()]
+        """Live worker PIDs of the pool (chaos drills)."""
+        pool = self._pool
+        return [p.pid for p in pool.procs if p.is_alive()] if pool else []
+
+    def close(self) -> None:
+        """Stop the worker pool: no child, no shared-memory segment and
+        no spill directory is left.  Idempotent; the loader stays usable
+        (the next ``iter()`` forks a new pool)."""
+        pool, self._pool = self._pool, None
+        if pool is not None:
+            pool.stop()
 
     def __iter__(self) -> Iterator[Any]:
-        if any(p.is_alive() for p in self._procs):
-            # enforce the one-live-iterator contract: a second pool
-            # would fork from the previous epoch's UN-advanced source
-            # state (silent stream corruption) and clobber the first
-            # pool's cleanup tracking
+        if self._epoch_open:
+            # enforce the one-live-iterator contract: both iterators
+            # would read the same rings, and the second would take the
+            # first's groups for its own epoch's
             raise RuntimeError(
-                "previous epoch's worker pool is still live — exhaust "
-                "or close() the prior iterator before starting a new "
-                "epoch (ParallelLoader supports one live iterator)")
+                "the previous epoch's iterator is still open — exhaust "
+                "or close() it before starting a new epoch "
+                "(ParallelLoader supports one live iterator)")
         epoch = self._epoch
         self._epoch += 1
         self.last_epoch = epoch
@@ -686,13 +813,13 @@ class ParallelLoader:
         return it
 
     # -- parallel path ----------------------------------------------------
-    def _spawn(self, ctx, worker_id: int, epoch: int, start_group: int,
-               stop_event, spill_dir: str) -> Tuple[_Ring, mp.Process]:
-        ring = _Ring(ctx, self.slots, self.slot_bytes, spill_dir)
-        proc = ctx.Process(
+    def _spawn(self, pool: "_Pool", worker_id: int, epoch: int,
+               start_group: int) -> Tuple[_Ring, mp.Process]:
+        ring = _Ring(pool.ctx, self.slots, self.slot_bytes, pool.spill_dir)
+        proc = pool.ctx.Process(
             target=_worker_main,
             args=(worker_id, self.num_workers, epoch, start_group, ring,
-                  stop_event, self.dataset._source_fn, self.leading,
+                  pool.stop_event, self.dataset._source_fn, self.leading,
                   self._stream_keys, self.chain, self.group_size,
                   self.base_seed),
             daemon=True)
@@ -710,91 +837,87 @@ class ParallelLoader:
             proc.start()
         return ring, proc
 
-    @staticmethod
-    def _record_worker(w: int, ring: _Ring) -> None:
-        """One ``az/input/worker`` record from what worker ``w`` counted
-        over its life."""
-        chain_s, put_s, walk_s, groups, alive_s = ring.counters
-        obs_span.record_stage(
-            "az/input/worker", ring.born_t, ring.born_t + alive_s,
-            worker=w, chain_s=chain_s, put_s=put_s, walk_s=walk_s,
-            groups=int(groups), spills=ring.spilled)
+    def _fork_pool(self, epoch: int) -> "_Pool":
+        """A new pool whose workers begin at ``epoch``.  Forked children
+        inherit the parent's source state verbatim, so the parent never
+        consumes the source itself: it advances its copy once an epoch,
+        at the epoch's end."""
+        pool = _Pool(mp.get_context("fork"), epoch, self)
+        try:
+            for w in range(self.num_workers):
+                ring, proc = self._spawn(pool, w, epoch, 0)
+                pool.rings.append(ring)
+                pool.procs.append(proc)
+        except BaseException:
+            pool.stop()
+            raise
+        return pool
 
     def _merged_samples(self, epoch: int) -> Iterator[Any]:
-        ctx = mp.get_context("fork")
-        stop_event = ctx.Event()
+        self._epoch_open = True
         W = self.num_workers
-        spill_dir = tempfile.mkdtemp(prefix="azt-loader-")
-        # forked children inherit the parent's source state verbatim, so
-        # the parent must NOT consume the source itself this epoch; it
-        # advances its copy once in the finally below, which keeps
-        # serial epochs and parallel epochs interchangeable.
-        rings: List[_Ring] = []
-        procs: List[mp.Process] = []
-        respawns_left = self.max_respawns
-        self._procs = procs
-        # open until the first group has arrived, so inside the
-        # consumer's first next() of the epoch — never across a yield
-        starting = obs_span.stage("az/input/pool_start",
-                                  workers=W).__enter__()
+        if self._pool is not None and self._pool.epoch != epoch:
+            self.close()        # not the epoch its rings hold: start anew
+        pool = self._pool
+        # both stages are open until the first group has arrived, so
+        # inside the consumer's first next() of the epoch — never across
+        # a yield
+        opening = contextlib.ExitStack()
+        opening.enter_context(obs_span.stage("az/input/epoch_start",
+                                             kept=pool is not None))
+        ended = False
         try:
-            for w in range(W):
-                ring, proc = self._spawn(ctx, w, epoch, 0, stop_event,
-                                         spill_dir)
-                rings.append(ring)
-                procs.append(proc)
+            if pool is None:
+                opening.enter_context(obs_span.stage("az/input/pool_start",
+                                                     workers=W))
+                pool = self._pool = self._fork_pool(epoch)
+            pool.respawns_left = self.max_respawns
             g = 0
-            total_groups: Optional[int] = None
-            while total_groups is None or g < total_groups:
-                w = g % W
-                kind, payload = self._next_message(
-                    ctx, w, g, epoch, rings, procs, stop_event, spill_dir,
-                    respawns_left)
-                if kind == "respawned":
-                    respawns_left -= 1
-                    continue
-                if kind == "end":
-                    total_groups = payload
-                    continue   # re-check the loop condition (g == total)
-                if starting is not None:
-                    starting.__exit__(None, None, None)
-                    starting = None
-                for sample in payload:
+            while True:
+                samples = self._next_message(pool, g % W, g, epoch)
+                if samples is None:
+                    break
+                opening.close()
+                for sample in samples:
                     yield sample
                 g += 1
+            # every worker's end marker of this epoch: what follows in
+            # its ring is the next epoch's
+            for w in range(W):
+                if w != g % W:
+                    self._next_message(pool, w, g, epoch)
+            pool.epoch = epoch + 1
+            ended = True
         finally:
-            if starting is not None:        # no sample ever arrived
-                starting.__exit__(None, None, None)
-            # pool cleanup FIRST (a failing source advance must never
-            # leave workers spinning on live rings)...
-            stop_event.set()
-            for proc in procs:
-                proc.join(timeout=1.0)
-                if proc.is_alive():
-                    proc.terminate()
-                    proc.join(timeout=5.0)
-            for w, ring in enumerate(rings):
-                self._record_worker(w, ring)
-                ring.close()
-            shutil.rmtree(spill_dir, ignore_errors=True)
-            self._procs = []
+            opening.close()                 # no sample ever arrived
+            self._epoch_open = False
+            # closed early or raised: the pool stops FIRST (a failing
+            # source advance must never leave workers spinning on live
+            # rings)...
+            if not ended:
+                self.close()
             # ...then advance the parent's copy of the source state by
             # one epoch, so serial and parallel epochs stay
-            # interchangeable.  Workers (and respawns) always fork from
-            # the UN-advanced state — respawns happen only inside the
-            # loop, never after this point.
-            _advance_source_epochs(self.dataset._source_fn, 1)
+            # interchangeable.  Respawns fork from the UN-advanced state
+            # of the epoch in flight — they happen only inside the loop,
+            # never after this point.
+            try:
+                _advance_source_epochs(self.dataset._source_fn, 1)
+            except BaseException:
+                self.close()    # its workers have run on from that state
+                raise
 
-    def _next_message(self, ctx, w: int, g: int, epoch: int,
-                      rings: List[_Ring], procs: List[mp.Process],
-                      stop_event, spill_dir: str, respawns_left: int):
+    def _next_message(self, pool: "_Pool", w: int, g: int, epoch: int):
         """Wait for worker ``w``'s next ring message, handling death.
 
-        Returns ("grp", samples) / ("end", total) / ("respawned", None).
-        A dead worker with an empty ring is respawned from the group it
-        still owes — deterministic seeding makes the respawn recompute
-        the identical stream — until the respawn budget is exhausted,
-        then PrefetchWorkerDied (retryable) escalates."""
+        Returns group ``g``'s samples, or None for the worker's end
+        marker of the epoch (at ``g`` groups: its counters are recorded).
+        A dead worker with an empty ring is respawned from the group it still owes of the
+        epoch in flight — deterministic seeding makes the respawn
+        recompute the identical stream — until the epoch's respawn
+        budget is exhausted, then PrefetchWorkerDied (retryable)
+        escalates."""
+        rings, procs = pool.rings, pool.procs
         while True:
             msg = rings[w].get(timeout=_POLL_S)
             if msg is None:
@@ -804,26 +927,22 @@ class ParallelLoader:
                 # declaring the ring empty
                 msg = rings[w].get(timeout=0.0)
                 if msg is None:
-                    if respawns_left <= 0:
+                    if pool.respawns_left <= 0:
                         raise PrefetchWorkerDied(
                             f"input worker {w} (pid {procs[w].pid}) died "
                             f"at group {g} with the respawn budget "
                             f"exhausted (max_respawns="
                             f"{self.max_respawns}) — input pipeline is "
                             "gone; restart the attempt")
+                    pool.respawns_left -= 1
                     logger.warning(
                         "input worker %d died (exitcode %s); respawning "
-                        "from group %d (%d respawns left)", w,
-                        procs[w].exitcode, g, respawns_left - 1)
-                    self._record_worker(w, rings[w])
-                    rings[w].close()
-                    ring, proc = self._spawn(ctx, w, epoch, g, stop_event,
-                                             spill_dir)
-                    rings[w] = ring
-                    procs[w] = proc
-                    self._procs = procs
+                        "from group %d of epoch %d (%d respawns left)", w,
+                        procs[w].exitcode, g, epoch, pool.respawns_left)
+                    pool.retire(w)
+                    rings[w], procs[w] = self._spawn(pool, w, epoch, g)
                     self.respawns += 1
-                    return "respawned", None
+                    continue
             kind, idx, obj = msg
             if kind == _KIND_ERR:
                 try:
@@ -847,16 +966,15 @@ class ParallelLoader:
                 self.spills += 1
                 rings[w].spilled += 1
                 kind = _KIND_GRP
-            if kind == _KIND_END:
-                if idx > g:  # pragma: no cover - protocol bug
-                    raise PrefetchWorkerDied(
-                        f"worker {w} ended at group {idx} while group "
-                        f"{g} was still owed")
-                return "end", idx
             if idx != g:  # pragma: no cover - protocol bug
                 raise PrefetchWorkerDied(
-                    f"worker {w} sent group {idx}, expected {g}")
-            return "grp", obj
+                    f"worker {w} sent group {idx} (kind {kind}), "
+                    f"expected {g}")
+            if kind == _KIND_END:
+                _record_worker(w, _COUNTERS.unpack(obj), rings[w].spilled)
+                rings[w].spilled = 0
+                return None
+            return obj
 
 
 # ---------------------------------------------------------------------------

@@ -72,9 +72,12 @@ def device_prefetch(batches: Iterable[Any], mesh, size: int = 2,
     only thread ever executing the source generator (a consumer-side
     ``close()`` on a generator suspended inside another thread's
     ``next()`` raises).  Use it when the source owns real resources —
-    e.g. a multiprocess ``ParallelLoader`` epoch whose worker processes
-    must not outlive the stream.  Leave it False when the caller reuses
-    the source across several prefetch streams (``bench_overlap``).
+    e.g. a multiprocess ``ParallelLoader`` epoch: closing one that was
+    cancelled midway stops the loader's worker pool, closing one that
+    ran to its end does nothing (the pool is kept for the next epoch;
+    ``ParallelLoader.close()`` ends it).  Leave it False when the caller
+    reuses the source across several prefetch streams
+    (``bench_overlap``).
     """
     if size < 1:
         # a non-positive maxsize would make the Queue UNBOUNDED and the
@@ -131,14 +134,14 @@ def device_prefetch(batches: Iterable[Any], mesh, size: int = 2,
         cancelled.set()
         if close_source:
             # Wait for the worker to actually finish: its cleanup
-            # (closing a multiprocess loader epoch = reaping worker
-            # processes + advancing the source state) must COMPLETE
-            # before control returns to the consumer — an immediately
-            # restarted epoch would otherwise fork new workers from the
-            # not-yet-advanced source state (replaying the old shuffle
-            # order) while two pools briefly coexist.  Bounded: the
-            # worker observes ``cancelled`` within one batch
-            # production.  Without close_source there is nothing to
+            # (closing a cancelled multiprocess loader epoch = reaping
+            # the pool's worker processes + advancing the source state)
+            # must COMPLETE before control returns to the consumer — an
+            # immediately restarted epoch would otherwise fork new
+            # workers from the not-yet-advanced source state (replaying
+            # the old shuffle order) while two pools briefly coexist.
+            # Bounded: the worker observes ``cancelled`` within one
+            # batch production.  Without close_source there is nothing to
             # reap, and blocking here would stall the very paths (e.g.
             # StallWatchdog recovery around a hung source) that close
             # early.  The timeout bounds stall-recovery latency when
@@ -162,8 +165,9 @@ class PrefetchDataSet:
     > 0`` additionally fans the host decode/augment work out to that
     many processes (``data.parallel.ParallelLoader``) before the
     overlapped H2D stage — the full host-input pipeline in one wrapper.
-    Early consumer exit closes the host iterator too, so worker
-    processes never outlive the epoch."""
+    Early consumer exit closes the host iterator too, which stops the
+    loader's worker pool; after an epoch that ran to its end the pool is
+    kept for the next one, until :meth:`close`."""
 
     def __init__(self, dataset, mesh, size: int = 2, num_workers: int = 0,
                  base_seed: int = 0, **loader_kw):
@@ -184,6 +188,12 @@ class PrefetchDataSet:
 
     def __len__(self):
         return len(self.dataset)
+
+    def close(self) -> None:
+        """Close the wrapped data set, if it has a ``close`` (a
+        ``ParallelLoader``'s stops its worker pool)."""
+        if hasattr(self.dataset, "close"):
+            self.dataset.close()
 
 
 def overlap_window(items, dispatch, consume, max_inflight: int = 4) -> None:

@@ -215,8 +215,8 @@ CATALOG: Dict[str, str] = {
 #: profiler's trace and in the process's stage ring (``obs.stages()``),
 #: not in a registry, so they have no kind.  ``az/input/worker`` is the
 #: one record that no ``stage`` writes: a forked worker must never touch
-#: JAX, so it counts seconds into shared memory and the parent records
-#: them when the epoch's pool closes.
+#: JAX, so it counts seconds into shared memory, sends them with the
+#: epoch's end marker, and the parent records them as it reads that.
 STAGES: Dict[str, str] = {
     "az/input/next":
         "prefetch thread · the loader's next(): one host batch",
@@ -229,14 +229,25 @@ STAGES: Dict[str, str] = {
         "main thread · the get from the prefetch queue up to the item's "
         "arrival (blocked = the train loop is starved)",
     "az/input/pool_start":
-        "prefetch thread · ParallelLoader epoch start: forking the "
-        "workers until the first sample is ready to yield (inside the "
-        "epoch's first az/input/next)",
+        "prefetch thread · ParallelLoader forking a worker pool, until "
+        "its first group is ready to yield: once a POOL (kept from epoch "
+        "to epoch), inside az/input/epoch_start of the epoch that had to "
+        "fork it",
+    "az/input/epoch_start":
+        "prefetch thread · ParallelLoader's first next() of an epoch, "
+        "until the epoch's first group is handed over (inside that "
+        "az/input/next); attrs: kept (True when the pool that serves the "
+        "epoch was alive before it, False when the epoch had to fork one)",
     "az/input/worker":
-        "record, one a worker an epoch · the worker's lifetime; attrs: "
-        "chain_s (decode + augment of its own samples), put_s (copy "
-        "into the ring + blocked on full slots), walk_s (reading "
-        "samples that are another worker's), groups shipped, spills",
+        "record, one a worker an epoch, written when the parent reads "
+        "the worker's end marker of that epoch (and once for the epoch "
+        "a worker was in when its pool stopped or it died) · from the "
+        "start of the worker's walk of the epoch to its end marker; "
+        "attrs: worker, epoch, chain_s (decode + augment of its own "
+        "samples), put_s (copy into the ring + blocked on full slots: "
+        "where a kept worker that is ahead stands still), walk_s "
+        "(reading samples that are another worker's), groups shipped, "
+        "spills",
     "az/train/prepare":
         "main thread · from the batch's arrival to the step's call: its "
         "size, the choice of step program, and place_batch where neither "

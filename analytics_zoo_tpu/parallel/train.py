@@ -1066,6 +1066,8 @@ class Optimizer:
                 wd.stop()
             if ph is not None:
                 ph.uninstall()
+            if hasattr(self.dataset, "close"):
+                self.dataset.close()    # a kept loader pool ends here
         # write trained variables back into the model wrapper (local-
         # replica read: safe on a mesh spanning processes)
         host_state = mesh_lib.host_local_state(state)

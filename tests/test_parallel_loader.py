@@ -1,11 +1,21 @@
 """Multiprocess input pipeline (data.parallel): determinism pinned
-byte-identical to the serial path, worker-crash -> respawn ->
-PrefetchWorkerDied escalation, ring spill fallback, and the tier-1
-smoke over the real SSD chain (2 workers, tiny synthetic set)."""
+byte-identical to the serial path, the worker pool kept from epoch to
+epoch (and gone on close(), collection and exit), worker-crash ->
+respawn -> PrefetchWorkerDied escalation, ring spill fallback, and the
+tier-1 smoke over the real SSD chain (2 workers, tiny synthetic set).
 
+A wait on a pool that this file adds is bounded by the test itself
+(``bounded``, ``gone``): a hung pool fails its test and does not eat the
+suite's clock."""
+
+import gc
+import json
 import os
 import random
 import signal
+import subprocess
+import sys
+import threading
 import time
 
 import numpy as np
@@ -62,14 +72,70 @@ def _assert_batches_equal(a, b):
             assert repr(x) == repr(y)
 
 
-def test_byte_identical_across_worker_counts_and_epochs():
+def bounded(fn, seconds=60.0):
+    """``fn()`` on a thread of its own, given up after ``seconds``."""
+    out = []
+
+    def run():
+        try:
+            out.append((fn(), None))
+        except BaseException as e:  # noqa: BLE001 - raised again below
+            out.append((None, e))
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert not t.is_alive(), f"the loader hung for {seconds} s"
+    value, error = out[0]
+    if error is not None:
+        raise error
+    return value
+
+
+def gone(pid, seconds=5.0):
+    """True once no process ``pid`` is left (a zombie counts as gone
+    only when reaped: the loader joins what it forked)."""
+    deadline = time.monotonic() + seconds
+    while time.monotonic() < deadline:
+        try:
+            os.kill(pid, 0)
+        except ProcessLookupError:
+            return True
+        time.sleep(0.05)
+    return False
+
+
+def holdings(loader):
+    """What a live pool holds outside the process: (worker pids, paths of
+    its rings' shared-memory segments, its spill directory)."""
+    pool = loader._pool
+    return (loader.worker_pids(),
+            [os.path.join("/dev/shm", r.shm.name.lstrip("/"))
+             for r in pool.rings], pool.spill_dir)
+
+
+def assert_released(pids, segments, spill_dir):
+    assert all(gone(pid) for pid in pids), pids
+    assert not [p for p in segments if os.path.exists(p)]
+    assert not os.path.exists(spill_dir)
+
+
+@pytest.mark.parametrize("workers", [1, 2, 3])
+def test_byte_identical_across_worker_counts_and_epochs(workers):
+    """Three epochs of a reshuffling source from ONE pool: the kept
+    workers reseed and re-open the source as a fresh fork would."""
     serial = ParallelLoader(_rng_ds(), 0, base_seed=9)
-    ref = [list(serial), list(serial)]       # two epochs
-    assert repr(ref[0]) != repr(ref[1])      # epochs genuinely differ
-    for w in (1, 2):
-        loader = ParallelLoader(_rng_ds(), w, base_seed=9)
-        got = [list(loader), list(loader)]
-        assert repr(got) == repr(ref), f"num_workers={w}"
+    ref = [list(serial) for _ in range(3)]
+    assert len({repr(e) for e in ref}) == 3  # epochs genuinely differ
+    loader = ParallelLoader(_rng_ds(), workers, base_seed=9)
+    got, pids = [], []
+    for _ in range(3):
+        got.append(bounded(lambda: list(loader)))
+        pids.append(sorted(loader.worker_pids()))
+    assert repr(got) == repr(ref)
+    # the same processes served all three
+    assert len(pids[0]) == workers and pids[0] == pids[1] == pids[2]
+    loader.close()
 
 
 def test_ndarray_payloads_through_ring():
@@ -78,17 +144,58 @@ def test_ndarray_payloads_through_ring():
     _assert_batches_equal(ref, got)
 
 
-def test_worker_crash_respawns_and_stream_is_unchanged():
-    ref = list(ParallelLoader(_array_ds(sleep=0.01), 0))
-    loader = ParallelLoader(_array_ds(sleep=0.01), 2, max_respawns=2)
+def _shuffled_ds(sleep=0.01):
+    """24 rows reshuffled every epoch, so a respawn that forked from
+    another epoch's source state would show."""
+    ds = DataSet.from_arrays(shuffle=True, seed=3,
+                             x=np.arange(96, dtype=np.float32).reshape(24, 4))
+
+    def fn(s):
+        time.sleep(sleep)
+        return {"x": s["x"] * 2}
+
+    return ds.transform(FnTransformer(fn)).batch(4)
+
+
+@pytest.mark.parametrize("kill_epoch", [0, 1])
+def test_worker_crash_respawns_and_stream_is_unchanged(kill_epoch):
+    """A worker lost in the pool's first epoch, or in the SECOND epoch of
+    a kept pool, is re-forked from the group it owes of the epoch in
+    flight; the respawned worker too runs on into the next epoch."""
+    serial = ParallelLoader(_shuffled_ds(), 0)
+    ref = [list(serial) for _ in range(kill_epoch + 2)]
+    loader = ParallelLoader(_shuffled_ds(), 2, max_respawns=2)
+    got = [bounded(lambda: list(loader)) for _ in range(kill_epoch)]
     it = iter(loader)
-    got = [next(it)]
+    epoch = [next(it)]
     pids = loader.worker_pids()
-    assert pids
+    assert len(pids) == 2
     os.kill(pids[0], signal.SIGKILL)         # chaos: lose one worker
-    got.extend(it)
+    epoch.extend(bounded(lambda: list(it)))
+    got.append(epoch)
     assert loader.respawns >= 1
-    _assert_batches_equal(ref, got)
+    after = loader.worker_pids()
+    assert len(after) == 2 and pids[0] not in after and pids[1] in after
+    got.append(bounded(lambda: list(loader)))
+    assert sorted(loader.worker_pids()) == sorted(after)
+    for want, have in zip(ref, got):
+        _assert_batches_equal(want, have)
+    loader.close()
+
+
+def test_the_respawn_budget_is_an_epochs():
+    """``max_respawns`` a epoch, in a kept pool too: one loss in each of
+    two epochs passes with a budget of one."""
+    ref = list(ParallelLoader(_array_ds(sleep=0.01), 0))
+    loader = ParallelLoader(_array_ds(sleep=0.01), 2, max_respawns=1)
+    for _ in range(2):
+        it = iter(loader)
+        got = [next(it)]
+        os.kill(loader.worker_pids()[0], signal.SIGKILL)
+        got.extend(bounded(lambda: list(it)))
+        _assert_batches_equal(ref, got)
+    assert loader.respawns == 2
+    loader.close()
 
 
 def test_crash_escalates_to_prefetch_worker_died():
@@ -130,15 +237,114 @@ def test_oversize_group_spills_and_stays_correct():
     _assert_batches_equal(list(ParallelLoader(ds, 0)), got)
 
 
-def test_early_close_shuts_down_workers():
-    loader = ParallelLoader(_array_ds(sleep=0.01), 2)
+def test_early_close_stops_the_pool_and_the_next_epoch_forks_anew():
+    serial = ParallelLoader(_shuffled_ds(0), 0)
+    ref = [list(serial) for _ in range(3)]
+    loader = ParallelLoader(_shuffled_ds(0), 2)
+    it = iter(loader)
+    _assert_batches_equal(ref[0][:1], [next(it)])
+    held = holdings(loader)
+    it.close()                               # after one batch
+    assert not loader.worker_pids() and loader._pool is None
+    assert_released(*held)
+    # from a new pool, still the serial stream: the closed epoch counted
+    got = bounded(lambda: list(loader))
+    assert not set(loader.worker_pids()) & set(held[0])
+    _assert_batches_equal(ref[1], got)
+    _assert_batches_equal(ref[2], bounded(lambda: list(loader)))
+    loader.close()
+
+
+def test_an_epoch_that_raises_stops_the_pool():
+    def bad(s):
+        if float(s["x"][0]) > 100:
+            raise ValueError("poison sample")
+        return s
+
+    ds = (DataSet.from_arrays(x=np.arange(256, dtype=np.float32).reshape(32, 8))
+          .transform(FnTransformer(bad)).batch(8))
+    loader = ParallelLoader(ds, 2)
     it = iter(loader)
     next(it)
-    it.close()
-    deadline = time.time() + 5
-    while loader.worker_pids() and time.time() < deadline:
-        time.sleep(0.05)
-    assert not loader.worker_pids()
+    held = holdings(loader)
+    with pytest.raises(ValueError, match="poison sample"):
+        bounded(lambda: list(it))
+    assert loader._pool is None
+    assert_released(*held)
+
+
+def test_a_second_iter_on_an_open_epoch_raises():
+    loader = ParallelLoader(_array_ds(), 2)
+    it = iter(loader)
+    next(it)
+    with pytest.raises(RuntimeError, match="still open"):
+        iter(loader)
+    rest = bounded(lambda: list(it))         # exhausted: no longer open
+    assert len(rest) == 5
+    again = iter(loader)                     # the kept pool's next epoch
+    next(again)
+    again.close()                            # closed: no longer open
+    assert len(bounded(lambda: list(loader))) == 6
+    loader.close()
+
+
+def _collected(loader):
+    del loader
+    gc.collect()
+
+
+@pytest.mark.parametrize("end", [
+    lambda loader: loader.close(),
+    lambda loader: (loader.close(), loader.close()),    # idempotent
+    _collected,
+], ids=["close", "close_twice", "collected"])
+def test_a_kept_pool_is_released(end):
+    """After two whole epochs the pool idles, alive; ``close()`` or the
+    loader's collection leaves no child, no shared-memory segment and no
+    spill directory."""
+    loader = ParallelLoader(_array_ds(), 2)
+    for _ in range(2):
+        assert len(bounded(lambda: list(loader))) == 6
+    held = holdings(loader)
+    assert len(held[0]) == 2 and all(map(os.path.exists, held[1]))
+    assert os.path.isdir(held[2])
+    bounded(lambda: end(loader))
+    del loader
+    assert_released(*held)
+
+
+_EXITS_WITHOUT_CLOSING = """
+import json, os, sys
+import numpy as np
+from analytics_zoo_tpu.data import DataSet, FnTransformer, ParallelLoader
+
+ds = (DataSet.from_arrays(x=np.arange(96, dtype=np.float32).reshape(24, 4))
+      .transform(FnTransformer(lambda s: {"x": s["x"] * 2})).batch(4))
+loader = ParallelLoader(ds, 2)
+assert len(list(loader)) == len(list(loader)) == 6
+pool = loader._pool
+print(json.dumps({"pids": loader.worker_pids(),
+                  "segments": [r.shm.name for r in pool.rings],
+                  "spill_dir": pool.spill_dir}))
+"""
+
+
+def test_a_process_that_exits_without_closing_leaves_nothing():
+    """The benchmark's feed exposes no ``close``: the interpreter's exit
+    ends the pool, and ``resource_tracker`` finds nothing to warn of."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", _EXITS_WITHOUT_CLOSING], cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
+    held = json.loads(done.stdout.strip().splitlines()[-1])
+    assert len(held["pids"]) == 2
+    assert_released(held["pids"],
+                    [os.path.join("/dev/shm", n.lstrip("/"))
+                     for n in held["segments"]], held["spill_dir"])
+    assert "resource_tracker" not in done.stderr, done.stderr
+    assert "leaked" not in done.stderr, done.stderr
 
 
 def test_split_stages_classification():

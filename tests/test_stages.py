@@ -166,13 +166,21 @@ class TestLoaderWorkerCounters:
         return ParallelLoader(ds.transform(FnTransformer(fn)).batch(4),
                               workers)
 
-    def test_workers_report_groups_chain_seconds_and_lifetimes(self):
+    def test_a_pool_once_an_epoch_start_and_a_worker_record_an_epoch(self):
+        """Three epochs from one pool: ``az/input/pool_start`` once,
+        ``az/input/epoch_start`` once an epoch with ``kept`` false then
+        true, ``az/input/worker`` once a worker and epoch, written as
+        the parent reads that worker's end marker."""
+        loader = self.loader()
         t0 = time.monotonic()
-        batches = list(self.loader())
+        batches = [list(loader) for _ in range(3)]
         records = obs.stages(since=t0)
         workers = [r for r in records if r.name == "az/input/worker"]
-        assert sorted(r.attrs["worker"] for r in workers) == [0, 1]
-        assert sum(r.attrs["groups"] for r in workers) == len(batches) == 6
+        assert (sorted((r.attrs["epoch"], r.attrs["worker"]) for r in workers)
+                == [(e, w) for e in range(3) for w in range(2)])
+        for e in range(3):
+            assert (sum(r.attrs["groups"] for r in workers
+                        if r.attrs["epoch"] == e) == len(batches[e]) == 6)
         for r in workers:
             a = r.attrs
             # 12 samples of its own at 2 ms each
@@ -182,12 +190,61 @@ class TestLoaderWorkerCounters:
         starts = [r for r in records if r.name == "az/input/pool_start"]
         assert len(starts) == 1 and starts[0].attrs == {"workers": 2}
         assert starts[0].t0 <= min(r.t0 for r in workers)
+        epochs = [r for r in records if r.name == "az/input/epoch_start"]
+        assert [r.attrs for r in epochs] == [{"kept": False}, {"kept": True},
+                                            {"kept": True}]
+        # the fork is inside the epoch's start that needed it
+        assert epochs[0].t0 <= starts[0].t0 < starts[0].t1 <= epochs[0].t1
+        assert epochs[0].thread == starts[0].thread
 
-    def test_pool_start_closes_inside_the_first_next(self):
+    def test_a_stopped_pool_records_each_workers_unfinished_epoch(self):
+        """A window that closes no pool has the records of the epochs
+        that ended in it; the pool's stop adds one a worker for the epoch
+        it was in, up to the stop."""
+        from analytics_zoo_tpu.data import parallel
+
+        loader = self.loader()
+        assert len(list(loader)) == 6
+        # a kept worker runs ahead until its ring is full: epoch 1's three
+        # groups and end marker take the four slots, so it stands in
+        # epoch 2 with a group decoded that it cannot put
+        deadline = time.monotonic() + 10
+        while (any(r.counters[parallel._CHAIN_S] < 4 * 0.002
+                   or r.counters[parallel._EPOCH] < 2
+                   for r in loader._pool.rings)
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        t0 = time.monotonic()
+        loader.close()
+        workers = [r for r in obs.stages(since=t0 - 60)
+                   if r.name == "az/input/worker"][-2:]
+        assert sorted(r.attrs["worker"] for r in workers) == [0, 1]
+        for r in workers:
+            assert (r.attrs["epoch"], r.attrs["groups"]) == (2, 0)
+            assert r.attrs["chain_s"] >= 4 * 0.002 and r.t1 >= t0 > r.t0
+
+    def test_an_epoch_closed_early_forks_the_next_ones_pool(self):
+        loader = self.loader()
+        t0 = time.monotonic()
+        it = iter(loader)
+        next(it)
+        it.close()
+        assert len(list(loader)) == 6 and len(list(loader)) == 6
+        got = [(r.name, r.attrs.get("kept")) for r in obs.stages(since=t0)
+               if r.name in ("az/input/pool_start", "az/input/epoch_start")]
+        assert got == [("az/input/pool_start", None),
+                       ("az/input/epoch_start", False),
+                       ("az/input/pool_start", None),
+                       ("az/input/epoch_start", False),
+                       ("az/input/epoch_start", True)]
+        loader.close()
+
+    def test_pool_and_epoch_start_close_inside_the_first_next(self):
         it = iter(self.loader())
         t0 = time.monotonic()
         next(it)
-        assert since(t0)["az/input/pool_start"] == 1
+        got = since(t0)
+        assert got["az/input/pool_start"] == got["az/input/epoch_start"] == 1
         it.close()
 
     def test_spilled_groups_are_counted_on_their_worker(self):
