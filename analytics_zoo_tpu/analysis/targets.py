@@ -652,6 +652,24 @@ def _lm_mla_serving(mesh) -> List[AuditProgram]:
     return _lm_tier_programs("lm-mla", cfg, mesh)
 
 
+def _lm_gqa_serving(mesh) -> List[AuditProgram]:
+    """ISSUE 35: the same tier over a model of grouped-query attention
+    (``hybrid_layer_pattern`` in its config: global layers in pools, read
+    by the paged attention ``gqa_paged``, window layers with sinks in
+    rings, entries of two widths; partial rotary, scaled values, a router
+    bias, no shared expert) — its decode step and its prefill programs."""
+    from analytics_zoo_tpu.models import lm
+
+    cfg = lm.LMConfig(
+        d=32, kinds=(lm.CAUSAL, lm.SLIDING, lm.SLIDING), dense_layers=1,
+        full=lm.GQADims(4, 2, 12, 8, 4, 1e3, 0.707, False),
+        swa=lm.GQADims(4, 4, 12, 8, 4, 10.0, 0.707, True), window=5,
+        idx_heads=0, idx_dim=0, topk=0, f_dense=48, f_expert=16, f_shared=0,
+        experts=8, held=2, first_held=0, per_tok=2, route_scale=1.0,
+        vocab=40, eps=1e-5, dtype="float32", route_bias=True)
+    return _lm_tier_programs("lm-gqa", cfg, mesh)
+
+
 def _lm_tier_programs(kind: str, cfg, mesh) -> List[AuditProgram]:
     from analytics_zoo_tpu.models import lm
     from analytics_zoo_tpu.parallel import pipeline_specs
@@ -754,4 +772,5 @@ def repo_audit_suite(mesh=None) -> List[AuditProgram]:
     # tier's decode and prefill programs are its whole audit surface
     targets += _guarded_tiers("lm", _lm_serving, mesh)
     targets += _guarded_tiers("lm-mla", _lm_mla_serving, mesh)
+    targets += _guarded_tiers("lm-gqa", _lm_gqa_serving, mesh)
     return targets

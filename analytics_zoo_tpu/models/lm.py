@@ -1,23 +1,36 @@
 """A causal decoder LM for serving through device-resident session caches
-(pipelines/lm.py): RMS-norm pre-norm residual blocks; multi-head latent
-attention (MLA) in three kinds of layer — FULL layers with a learned
-sparse indexer that selects the ``index_topk`` positions a token attends
-to, SLIDING layers over a window, CAUSAL layers over the whole context
-(every page a row holds, read by a paged decode kernel:
-ops/pallas_lm_decode.py); a gated dense MLP in the leading layers, then
-sparse experts (sigmoid router of the published width, top-k —
-group-limited where the config says so — a shared expert) of which THIS
-CHIP HOLDS A SHARE (parallel/expert.py); embedding and head over a slice
-of the vocabulary.
+(pipelines/lm.py): RMS-norm pre-norm residual blocks; attention in one of
+two FORMS — multi-head latent attention (MLA: one latent + rotary key a
+token in the cache, shared by all heads) or grouped-query attention (GQA:
+real keys and values a KV head in the cache) — and three kinds of layer by
+REACH: FULL layers (MLA only) with a learned sparse indexer that selects
+the ``index_topk`` positions a token attends to, SLIDING layers over a
+window (a ring a session), CAUSAL layers over the whole context (every
+page a row holds, read by a paged decode kernel: ops/pallas_lm_decode.py);
+a gated dense MLP in the leading layers, then sparse experts (sigmoid
+router of the published width, top-k — group-limited where the config says
+so — a shared expert where the config has one) of which THIS CHIP HOLDS A
+SHARE (parallel/expert.py); embedding and head over a slice of the
+vocabulary.
 
 WHICH model it is comes from the config's keys alone
-(``LMConfig.from_dict``): ``layer_types`` names FULL and SLIDING layers
-(the ``dots3_note`` family: headwise sigmoid gates on the heads' outputs,
-rescaled latents, a bias-corrected router), a config without it is CAUSAL
-throughout (the ``axk1`` family: YaRN rotary scaling, routing groups, no
-gate, no rescale, no router bias).  ``param_shapes`` and ``cache_shapes``
-follow: a model without sliding layers has no ring, one without an
-indexer no index keys.
+(``LMConfig.from_dict``).  The FORM: a config with ``kv_lora_rank`` is
+latent (``MLADims``), one with ``num_key_value_heads`` and none is
+grouped-query (``GQADims``: heads and KV heads, key and value widths,
+partial rotary, value scale and a learned softmax sink a kind of layer).
+The KINDS: ``layer_types`` names FULL and SLIDING layers (the
+``dots3_note`` family: headwise sigmoid gates on the heads' outputs,
+rescaled latents, a bias-corrected router); else ``hybrid_layer_pattern``
+names CAUSAL (0) and SLIDING (1) layers (the ``mimo_v2`` family:
+grouped-query, KV heads and rotary base a kind, sinks in the window
+layers, no shared expert); a config with neither is CAUSAL throughout
+(the ``axk1`` family: YaRN rotary scaling, routing groups, no gate, no
+rescale, no router bias).  The leading dense layers are
+``first_k_dense_replace`` or the leading zeros of a ``moe_layer_freq``
+list.  ``param_shapes`` and ``cache_shapes`` follow: a model without
+sliding layers has no ring, one without an indexer no index keys, one
+without a shared expert no ``shared`` leaf; a grouped-query model's pools
+and rings are as wide as their kind's KV heads.
 
 Functional, not flax: the step functions take the parameters and the
 cache and return the new cache, so one jitted call is a whole batch of
@@ -49,7 +62,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -100,12 +113,40 @@ class MLADims:
 
 
 @dataclasses.dataclass(frozen=True)
+class GQADims:
+    """A layer of grouped-query attention: ``heads`` query heads, each
+    reading KV head ``a // (heads / kv_heads)``; keys ``k`` and values
+    ``v`` wide; rotary on a head's first ``rotary`` dims in pairs at a
+    distance (ops/lm_attention.py ``rope_half``); the values times
+    ``value_scale``; ``sink``: one learned scalar a head joins the
+    softmax as one more column."""
+    heads: int
+    kv_heads: int
+    k: int
+    v: int
+    rotary: int
+    theta: float
+    value_scale: float = 1.0
+    sink: bool = False
+
+    @property
+    def scale(self) -> float:
+        return 1.0 / math.sqrt(self.k)
+
+    @property
+    def entry(self) -> int:
+        """Width of a token's cache entry: every KV head's key and value
+        (ops/lm_attention.py ``gqa_entry``), nothing else."""
+        return self.kv_heads * (self.k + self.v)
+
+
+@dataclasses.dataclass(frozen=True)
 class LMConfig:
     d: int
     kinds: Tuple[str, ...]            # per layer: FULL, SLIDING or CAUSAL
     dense_layers: int                 # leading layers with a dense MLP
-    full: MLADims                     # a FULL or CAUSAL layer's sizes
-    swa: Optional[MLADims]            # a SLIDING layer's (None: there is none)
+    full: Union[MLADims, GQADims]     # a FULL or CAUSAL layer's sizes
+    swa: Union[MLADims, GQADims, None]  # a SLIDING layer's (None: none)
     window: int
     idx_heads: int
     idx_dim: int
@@ -142,26 +183,69 @@ class LMConfig:
         share = cfg.get("expert_share") or {
             "published_experts": cfg["n_routed_experts"], "index": 0}
         held = int(cfg["n_routed_experts"])
-        kinds = tuple(cfg["layer_types"][:n]) if cfg.get("layer_types") \
-            else (CAUSAL,) * n
+        if cfg.get("layer_types"):
+            kinds = tuple(cfg["layer_types"][:n])
+        elif cfg.get("hybrid_layer_pattern"):
+            kinds = tuple(SLIDING if g else CAUSAL
+                          for g in cfg["hybrid_layer_pattern"][:n])
+        else:
+            kinds = (CAUSAL,) * n
         if cfg.get("attention_gate_type") not in (None, "headwise"):
             raise ValueError("attention_gate_type: only headwise is known, "
                              f"got {cfg['attention_gate_type']!r}")
-        mla = lambda p, theta, scaling=None: MLADims(          # noqa: E731
-            heads=int(cfg[p + "num_attention_heads"]),
-            q_rank=int(cfg[p + "q_lora_rank"]),
-            kv_rank=int(cfg[p + "kv_lora_rank"]),
-            nope=int(cfg[p + "qk_nope_head_dim"]),
-            rope=int(cfg[p + "qk_rope_head_dim"]),
-            v=int(cfg[p + "v_head_dim"]), theta=float(cfg[theta]),
-            scaling=scaling)
+        if "kv_lora_rank" in cfg:
+            dims = lambda p, theta, scaling=None: MLADims(     # noqa: E731
+                heads=int(cfg[p + "num_attention_heads"]),
+                q_rank=int(cfg[p + "q_lora_rank"]),
+                kv_rank=int(cfg[p + "kv_lora_rank"]),
+                nope=int(cfg[p + "qk_nope_head_dim"]),
+                rope=int(cfg[p + "qk_rope_head_dim"]),
+                v=int(cfg[p + "v_head_dim"]), theta=float(cfg[theta]),
+                scaling=scaling)
+            full = dims("", "rope_theta",
+                        att.RopeScaling.from_dict(cfg.get("rope_scaling")))
+        elif "num_key_value_heads" in cfg:
+            scaling = cfg.get("rope_scaling") or {}
+            if scaling.get("type", scaling.get("rope_type",
+                                               "default")) != "default":
+                raise ValueError("rope_scaling: a grouped-query model's is "
+                                 f"not scaled here, got {scaling}")
+            if FULL in kinds or cfg.get("add_full_attention_sink_bias"):
+                raise ValueError("grouped-query attention: no indexer and "
+                                 "no sink in a global layer are known")
+
+            def dims(p, theta):
+                k = int(cfg[p + "head_dim"])
+                return GQADims(
+                    heads=int(cfg[p + "num_attention_heads"]),
+                    kv_heads=int(cfg[p + "num_key_value_heads"]), k=k,
+                    v=int(cfg[p + "v_head_dim"]),
+                    rotary=int(float(cfg.get("partial_rotary_factor", 1.0))
+                               * k),
+                    theta=float(cfg[theta]),
+                    value_scale=float(cfg.get("attention_value_scale")
+                                      or 1.0),
+                    sink=bool(cfg.get(
+                        f"add_{p or 'full_'}attention_sink_bias")))
+            full = dims("", "rope_theta")
+        else:
+            raise ValueError("neither kv_lora_rank (latent attention) nor "
+                             "num_key_value_heads (grouped-query) in the "
+                             "config")
+        if "first_k_dense_replace" in cfg:
+            dense_layers = int(cfg["first_k_dense_replace"])
+        else:
+            freq = [int(f) for f in cfg["moe_layer_freq"]][:n]
+            dense_layers = freq.index(1) if 1 in freq else n
+            if any(f != 1 for f in freq[dense_layers:]):
+                raise ValueError("moe_layer_freq: only dense layers first, "
+                                 f"then expert layers, got {freq}")
+        route_scale = cfg.get("routed_scaling_factor")
         dtype = cfg.get("compute_dtype", "bfloat16")
         return cls(
             d=int(cfg["hidden_size"]), kinds=kinds,
-            dense_layers=int(cfg["first_k_dense_replace"]),
-            full=mla("", "rope_theta",
-                     att.RopeScaling.from_dict(cfg.get("rope_scaling"))),
-            swa=mla("swa_", "swa_rope_theta") if SLIDING in kinds else None,
+            dense_layers=dense_layers, full=full,
+            swa=dims("swa_", "swa_rope_theta") if SLIDING in kinds else None,
             window=int(cfg["sliding_window_size"]) if SLIDING in kinds else 0,
             idx_heads=int(cfg["index_n_heads"]) if FULL in kinds else 0,
             idx_dim=int(cfg["index_head_dim"]) if FULL in kinds else 0,
@@ -169,12 +253,14 @@ class LMConfig:
             f_dense=int(cfg["intermediate_size"]),
             f_expert=int(cfg["moe_intermediate_size"]),
             f_shared=int(cfg["moe_intermediate_size"])
-            * int(cfg["n_shared_experts"]),
+            * int(cfg["n_shared_experts"] or 0),
             experts=int(share["published_experts"]), held=held,
             first_held=int(share["index"]) * held,
             per_tok=int(cfg["num_experts_per_tok"]),
-            route_scale=float(cfg["routed_scaling_factor"]),
-            vocab=int(cfg["vocab_size"]), eps=float(cfg["rms_norm_eps"]),
+            route_scale=1.0 if route_scale is None else float(route_scale),
+            vocab=int(cfg["vocab_size"]),
+            eps=float(cfg["rms_norm_eps"] if "rms_norm_eps" in cfg
+                      else cfg["layernorm_epsilon"]),
             dtype={"bf16": "bfloat16"}.get(dtype, dtype),
             gate=cfg.get("attention_gate_type") == "headwise",
             rescale=bool(cfg.get("apply_mla_qkv_lora_rescale", False)),
@@ -195,7 +281,7 @@ class LMConfig:
         """Layers whose entries live in a paged pool: FULL and CAUSAL."""
         return len(self.kinds) - self.n_sliding
 
-    def mla(self, kind: str) -> MLADims:
+    def dims(self, kind: str) -> Union[MLADims, GQADims]:
         return self.swa if kind == SLIDING else self.full
 
 
@@ -214,13 +300,21 @@ def param_shapes(cfg: LMConfig) -> Dict:
 
     layers = []
     for i, kind in enumerate(cfg.kinds):
-        a = cfg.mla(kind)
-        attn = {"wq_a": S(cfg.d, a.q_rank), "q_norm": S(a.q_rank),
-                "wq_b": S(a.q_rank, a.heads, a.nope + a.rope),
-                "wkv_a": S(cfg.d, a.kv_rank + a.rope),
-                "kv_norm": S(a.kv_rank),
-                "wkv_b": S(a.kv_rank, a.heads, a.nope + a.v),
-                "wo": S(a.heads, a.v, cfg.d)}
+        a = cfg.dims(kind)
+        if isinstance(a, GQADims):
+            attn = {"wq": S(cfg.d, a.heads, a.k),
+                    "wk": S(cfg.d, a.kv_heads, a.k),
+                    "wv": S(cfg.d, a.kv_heads, a.v),
+                    "wo": S(a.heads, a.v, cfg.d)}
+            if a.sink:
+                attn["sink"] = jax.ShapeDtypeStruct((a.heads,), F32)
+        else:
+            attn = {"wq_a": S(cfg.d, a.q_rank), "q_norm": S(a.q_rank),
+                    "wq_b": S(a.q_rank, a.heads, a.nope + a.rope),
+                    "wkv_a": S(cfg.d, a.kv_rank + a.rope),
+                    "kv_norm": S(a.kv_rank),
+                    "wkv_b": S(a.kv_rank, a.heads, a.nope + a.v),
+                    "wo": S(a.heads, a.v, cfg.d)}
         if cfg.gate:
             attn["w_gate"] = S(cfg.d, a.heads)
         if kind == FULL:
@@ -235,8 +329,9 @@ def param_shapes(cfg: LMConfig) -> Dict:
         else:
             layer["moe"] = {
                 "router_w": S(cfg.d, cfg.experts),
-                "experts": mlp(cfg.f_expert, cfg.held),
-                "shared": mlp(cfg.f_shared)}
+                "experts": mlp(cfg.f_expert, cfg.held)}
+            if cfg.f_shared:
+                layer["moe"]["shared"] = mlp(cfg.f_shared)
             if cfg.route_bias:
                 layer["moe"]["router_b"] = jax.ShapeDtypeStruct(
                     (cfg.experts,), F32)
@@ -248,7 +343,8 @@ def param_shapes(cfg: LMConfig) -> Dict:
 
 def init_params(cfg: LMConfig, seed: int = 0) -> Dict:
     """Random parameters: normal of variance 1/fan_in (the first of a
-    matrix's contracted axes sets it), norm weights one, router bias 0."""
+    matrix's contracted axes sets it), norm weights one, router bias and
+    sinks 0."""
     shapes = param_shapes(cfg)
     leaves, tree = jax.tree_util.tree_flatten_with_path(shapes)
     keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
@@ -257,11 +353,11 @@ def init_params(cfg: LMConfig, seed: int = 0) -> Dict:
         name = jax.tree_util.keystr(path)
         if "norm" in name and "_b" not in name:
             out.append(jnp.ones(s.shape, s.dtype))
-        elif name.endswith("router_b']") or "norm_b" in name:
+        elif name.endswith(("router_b']", "sink']")) or "norm_b" in name:
             out.append(jnp.zeros(s.shape, s.dtype))
         else:
             fan = s.shape[-2] if len(s.shape) > 1 else s.shape[0]
-            if "wq_b" in name or "wkv_b" in name:
+            if name.endswith(("wq_b']", "wkv_b']", "wq']", "wk']", "wv']")):
                 fan = s.shape[0]
             elif name.endswith("wo']"):
                 fan = s.shape[0] * s.shape[1]
@@ -360,6 +456,21 @@ def queries(a: MLADims, w: Dict, c_q, pos):
                                      a.scaling)
 
 
+def gqa_project(a: GQADims, w: Dict, x, pos):
+    """A grouped-query layer's (q_plain (N, H, k − rotary), q_rot (N, H,
+    rotary) rotated, c (N, a.entry) the cache entry: the rotated keys and
+    the scaled values, ops/lm_attention.py ``gqa_entry``) of normed inputs
+    ``x`` (N, d) at positions ``pos`` (N,)."""
+    q = jnp.einsum("nd,dhe->nhe", x, w["wq"])
+    k = jnp.einsum("nd,dge->nge", x, w["wk"])
+    v = (jnp.einsum("nd,dge->nge", x, w["wv"], preferred_element_type=F32)
+         * a.value_scale).astype(x.dtype)
+    r = a.rotary
+    return (q[..., r:], att.rope_half(q[..., :r], pos, a.theta),
+            att.gqa_entry(k[..., r:], att.rope_half(k[..., :r], pos, a.theta),
+                          v))
+
+
 def indexer(cfg: LMConfig, w: Dict, x, c_q, pos):
     """(q_idx (N, Hi, Di), k_idx (N, Di), w_idx (N, Hi) float32)."""
     a = cfg.full
@@ -391,7 +502,8 @@ def feed_forward(cfg: LMConfig, layer: Dict, x):
                                                          jnp.int32), None
     y, chosen, counts = moe_held_experts(
         x, layer["moe"], cfg.first_held, cfg.per_tok, cfg.route_scale,
-        n_group=cfg.n_group, topk_group=cfg.topk_group)
+        shared=cfg.f_shared > 0, n_group=cfg.n_group,
+        topk_group=cfg.topk_group)
     return y, counts, chosen
 
 
@@ -456,10 +568,15 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     counts, selected, routed = [], [], []
     i_pool = i_full = i_slide = 0
     for layer, kind in zip(params["layers"], cfg.kinds):
-        a, w = cfg.mla(kind), layer["attn"]
+        a, w = cfg.dims(kind), layer["attn"]
         x = rms_norm(h, layer["attn_norm"], cfg.eps)
-        c_q, c, gate = latents(cfg, a, w, x, pos)
-        q_nope, q_rope = queries(a, w, c_q, pos)
+        gqa = isinstance(a, GQADims)
+        if gqa:
+            q_plain, q_rot, c = gqa_project(a, w, x, pos)
+            gate = None
+        else:
+            c_q, c, gate = latents(cfg, a, w, x, pos)
+            q_nope, q_rope = queries(a, w, c_q, pos)
         if kind == FULL:
             with jax.named_scope("lm/indexer"):
                 q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
@@ -482,23 +599,32 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
             i_pool, i_full = i_pool + 1, i_full + 1
         elif kind == CAUSAL:
             kv[i_pool] = kv[i_pool].at[page, off].set(c)
-            with jax.named_scope("lm/mla_paged"):
-                o = att.mla_paged(q_nope, q_rope, kv[i_pool], tables,
-                                  lengths, w["wkv_b"], a.nope, a.rope,
-                                  a.scale)
+            with jax.named_scope("lm/gqa_paged" if gqa else "lm/mla_paged"):
+                if gqa:
+                    o = att.gqa_paged(q_plain, q_rot, kv[i_pool], tables,
+                                      lengths, a.kv_heads, a.v, a.scale)
+                else:
+                    o = att.mla_paged(q_nope, q_rope, kv[i_pool], tables,
+                                      lengths, w["wkv_b"], a.nope, a.rope,
+                                      a.scale)
                 h = h + finish_attention(w, o, gate)
             i_pool += 1
         else:
-            with jax.named_scope("lm/mla_window"):
+            with jax.named_scope("lm/gqa_window" if gqa else "lm/mla_window"):
                 at = jnp.where(live, pos % cfg.window, cfg.window)
                 ring[i_slide] = ring[i_slide].at[slot, at].set(
                     c, mode="drop")
-                mine = ring[i_slide][slot]                 # (B, W, rank+r)
+                mine = ring[i_slide][slot]                 # (B, W, entry)
                 age = (pos[:, None] - jnp.arange(cfg.window)[None, :]) \
                     % cfg.window
                 valid = (age <= pos[:, None]) & live[:, None]
-                o = att.mla_absorbed(q_nope, q_rope, mine, valid,
-                                     w["wkv_b"], a.nope, a.rope, a.scale)
+                if gqa:
+                    o = att.gqa_gathered(q_plain, q_rot, mine, valid,
+                                         w.get("sink"), a.kv_heads, a.v,
+                                         a.scale)
+                else:
+                    o = att.mla_absorbed(q_nope, q_rope, mine, valid,
+                                         w["wkv_b"], a.nope, a.rope, a.scale)
                 h = h + finish_attention(w, o, gate)
             i_slide += 1
         x = rms_norm(h, layer["mlp_norm"], cfg.eps)
@@ -535,10 +661,15 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     counts, selected, routed = [], [], []
     i_pool = i_full = i_slide = 0
     for layer, kind in zip(params["layers"], cfg.kinds):
-        a, w = cfg.mla(kind), layer["attn"]
+        a, w = cfg.dims(kind), layer["attn"]
         x = rms_norm(h, layer["attn_norm"], cfg.eps)
-        c_q, c, gate = latents(cfg, a, w, x, pos)
-        q_nope, q_rope = queries(a, w, c_q, pos)
+        gqa = isinstance(a, GQADims)
+        if gqa:
+            q_plain, q_rot, c = gqa_project(a, w, x, pos)
+            gate = None
+        else:
+            c_q, c, gate = latents(cfg, a, w, x, pos)
+            q_nope, q_rope = queries(a, w, c_q, pos)
         if kind == FULL:
             with jax.named_scope("lm/indexer"):
                 q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
@@ -555,21 +686,32 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
             i_pool, i_full = i_pool + 1, i_full + 1
         elif kind == CAUSAL:
             kv[i_pool] = kv[i_pool].at[page, off].set(c)
-            with jax.named_scope("lm/mla_paged"):
-                o = att.prefill_causal_attention(
-                    q_nope, q_rope, kv[i_pool], table, start, n_valid,
-                    w["wkv_b"], a.nope, a.rope, a.scale, pages_per_step,
-                    flash=PREFILL_HEADS_PER_STEP)
+            with jax.named_scope("lm/gqa_paged" if gqa else "lm/mla_paged"):
+                if gqa:
+                    o = att.prefill_gqa_causal(
+                        q_plain, q_rot, kv[i_pool], table, start, n_valid,
+                        a.kv_heads, a.v, a.scale, pages_per_step)
+                else:
+                    o = att.prefill_causal_attention(
+                        q_nope, q_rope, kv[i_pool], table, start, n_valid,
+                        w["wkv_b"], a.nope, a.rope, a.scale, pages_per_step,
+                        flash=PREFILL_HEADS_PER_STEP)
                 h = h + finish_attention(w, o, gate)
             i_pool += 1
         else:
-            with jax.named_scope("lm/mla_window"):
+            with jax.named_scope("lm/gqa_window" if gqa else "lm/mla_window"):
                 prev_pos = start - (W - 1) + jnp.arange(W - 1)
                 prev = ring[i_slide][slot, prev_pos % W]
-                o = att.prefill_window_attention(
-                    jnp.concatenate([q_nope, q_rope], -1), c, prev,
-                    prev_pos, start, n_valid, w["wkv_b"], a.nope, a.rope,
-                    a.scale, W, min(q_block, T))
+                if gqa:
+                    o = att.prefill_gqa_window(
+                        q_plain, q_rot, c, prev, prev_pos, start, n_valid,
+                        w.get("sink"), a.kv_heads, a.v, a.scale, W,
+                        min(q_block, T))
+                else:
+                    o = att.prefill_window_attention(
+                        jnp.concatenate([q_nope, q_rope], -1), c, prev,
+                        prev_pos, start, n_valid, w["wkv_b"], a.nope, a.rope,
+                        a.scale, W, min(q_block, T))
                 h = h + finish_attention(w, o, gate)
                 # the chunk's last W real tokens go into the ring; the
                 # others (overwritten within the chunk, or padding) are

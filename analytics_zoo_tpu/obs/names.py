@@ -67,12 +67,17 @@ CATALOG: Dict[str, str] = {
     "lm/paged_pages":
         "gauge · pages that hold at least one token of the last decode "
         "step's live rows, one causal layer: what the paged decode "
-        "attention (ops/pallas_lm_decode.py) had to read",
+        "attention (ops/pallas_lm_decode.py, the latent or the "
+        "grouped-query kernel) had to read",
     "lm/paged_grid_steps":
         "gauge · grid steps the paged decode attention was launched with "
         "in the last decode step, one causal layer (the flat list of "
         "(row, page) items is as long as the pool; the steps past the "
         "last item do nothing)",
+    "lm/ring_tokens":
+        "gauge · entries the last decode step's live rows hold in a "
+        "sliding layer's ring, one layer (sum of min(length, window)): "
+        "the windows' work beside lm/paged_pages, the paged layers'",
     "lm/expert_tokens/stat=*":
         "histogram · tokens a held expert of an expert layer got in one "
         "decode step: stat=mean over the held experts of the step's "

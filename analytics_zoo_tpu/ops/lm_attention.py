@@ -5,7 +5,11 @@ indexer selects (full layers) or over the whole context (causal layers)
 — and windowed MLA over per-session rings; rotary embedding, plain or
 YaRN-scaled (``RopeScaling``).  XLA, but for two Pallas programs it hands
 lane-aligned widths to: prefill's attention (ops/pallas_lm_prefill.py)
-and a causal layer's paged decode (ops/pallas_lm_decode.py).
+and a causal layer's paged decode (ops/pallas_lm_decode.py).  At the end
+of the file the same two reaches — the whole context out of a pool, a
+window out of a ring — for GROUPED-QUERY attention, whose cache holds real
+keys and values a KV head (``gqa_entry``), with partial rotary in pairs at
+a distance (``rope_half``) and a learned sink column (``softmax_sink``).
 
 Cache layout (one replica's, all sessions'):
 
@@ -15,6 +19,10 @@ Cache layout (one replica's, all sessions'):
 - ``ik``  a full layer: (n_pages, page, idx_dim) — the indexer's key;
 - ``ring`` a sliding layer: (n_slots, window, swa entry) — the last
   ``window`` tokens of a session at ``position % window``.
+
+A grouped-query layer's entry, in a pool or a ring, is its KV heads' keys
+and values and nothing else (``gqa_entry``), so a model's pools and rings
+can be of two widths.
 
 A session's tokens live in the pages its row of the page table names
 (position ``p`` at page ``table[p // page]``, offset ``p % page``); page
@@ -152,6 +160,23 @@ def rope(x, pos, theta: float, scaling: Optional[RopeScaling] = None):
 def rope_head(x, pos, theta: float, r: int):
     """Rotary on the first ``r`` dims of the last axis."""
     return jnp.concatenate([rope(x[..., :r], pos, theta), x[..., r:]], -1)
+
+
+def rope_half(x, pos, theta: float):
+    """Rotary embedding of the whole last axis (r wide) in pairs AT A
+    DISTANCE, ``(x[j], x[j + r/2])`` for ``j < r/2`` — the rotate-half
+    layout of the grouped-query family's checkpoints, where :func:`rope`
+    pairs neighbours; ``pos`` as :func:`rope`'s.  A model of partial
+    rotary hands in the dims that turn and keeps the others as they are."""
+    r = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, r, 2, dtype=F32) / r)
+    ang = pos.astype(F32).reshape(pos.shape + (1,) * (x.ndim - pos.ndim)) \
+        * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(F32)
+    x0, x1 = xf[..., :r // 2], xf[..., r // 2:]
+    return jnp.concatenate([x0 * cos - x1 * sin, x1 * cos + x0 * sin],
+                           -1).astype(x.dtype)
 
 
 def ordered_bits(x):
@@ -544,4 +569,184 @@ def prefill_window_attention(q, c_new, prev, prev_pos, start, n_valid,
         s = jnp.where(ok[None], s, NEG)
         p = jax.nn.softmax(s, -1).astype(v.dtype)
         out.append(jnp.einsum("hts,she->the", p, v[ks]))
+    return jnp.concatenate(out, 0)
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention: real keys and values a KV head in the cache
+# ---------------------------------------------------------------------------
+
+def gqa_entry(k_plain, k_rot, v):
+    """A token's cache entry of a grouped-query layer: every KV head's
+    unrotated key dims, then every KV head's rotated dims, then every KV
+    head's values — ``k_plain`` (N, G, dk − r), ``k_rot`` (N, G, r)
+    rotated, ``v`` (N, G, dv) → (N, G (dk + dv)).  At the published widths
+    (G 4, 128 + 64 + 128) each part ends on a 128-lane tile, which a KV
+    head's own 192-wide key would not: the paged decode kernel
+    (ops/pallas_lm_decode.py) cuts keys and values out of a page on tile
+    edges, and the entry stays as wide as what it holds."""
+    return jnp.concatenate([t.reshape(t.shape[0], -1)
+                            for t in (k_plain, k_rot, v)], -1)
+
+
+def gqa_split(entries, G: int, plain: int, r: int, dv: int):
+    """:func:`gqa_entry` undone: ``entries`` (..., entry) → (k_plain
+    (..., G, plain), k_rot (..., G, r), v (..., G, dv))."""
+    lead = entries.shape[:-1]
+    a, b = G * plain, G * (plain + r)
+    return (entries[..., :a].reshape(lead + (G, plain)),
+            entries[..., a:b].reshape(lead + (G, r)),
+            entries[..., b:].reshape(lead + (G, dv)))
+
+
+def softmax_sink(s, sink):
+    """Softmax over the last axis of float32 scores ``s`` (..., G, hp, S)
+    with one more column a head, the learned ``sink`` (G hp,), which takes
+    its share of the sum and is then dropped; ``sink`` None: the plain
+    softmax."""
+    if sink is None:
+        return jax.nn.softmax(s, -1)
+    col = sink.astype(F32).reshape(s.shape[-3:-1] + (1,))
+    col = jnp.broadcast_to(col, s.shape[:-1] + (1,))
+    return jax.nn.softmax(jnp.concatenate([s, col], -1), -1)[..., :-1]
+
+
+def gqa_gathered(q_plain, q_rot, entries, valid, sink, G: int, dv: int,
+                 scale: float):
+    """Grouped-query attention over gathered cache entries: a sliding
+    layer's decode over its rows' rings, and the paged decode's form at
+    widths the kernel does not take.  ``q_plain`` (B, H, dk − r),
+    ``q_rot`` (B, H, r) rotated — head a reads KV head ``a // (H / G)``,
+    and its score is the sum of its unrotated and its rotated dims'
+    products; ``entries`` (B, S, entry); ``valid`` (B, S); ``sink`` (H,)
+    or None → (B, H, dv)."""
+    B, H, plain = q_plain.shape
+    kp, kr, v = gqa_split(entries, G, plain, q_rot.shape[-1], dv)
+    s = (jnp.einsum("bgae,bsge->bgas", q_plain.reshape(B, G, H // G, -1), kp,
+                    preferred_element_type=F32)
+         + jnp.einsum("bgae,bsge->bgas", q_rot.reshape(B, G, H // G, -1), kr,
+                      preferred_element_type=F32)) * scale
+    s = jnp.where(valid[:, None, None, :], s, NEG)
+    p = softmax_sink(s, sink).astype(v.dtype)
+    return jnp.einsum("bgas,bsge->bgae", p, v).reshape(B, H, dv)
+
+
+def gqa_block_queries(q_plain, q_rot, G: int):
+    """The paged kernel's queries: (B, H, G (dk − r) + G r), a head's
+    dims standing against the keys of ITS KV head in an entry's ``[plain |
+    rotary]`` columns and zeros against the others'."""
+    B, H, _ = q_plain.shape
+    own = jnp.eye(G, dtype=q_plain.dtype)
+
+    def spread(q):
+        return jnp.einsum("bgae,gk->bgake", q.reshape(B, G, H // G, -1),
+                          own).reshape(B, H, -1)
+    return jnp.concatenate([spread(q_plain), spread(q_rot)], -1)
+
+
+def gqa_paged(q_plain, q_rot, kv_pool, tables, lengths, G: int, dv: int,
+              scale: float):
+    """Grouped-query attention of B rows, each over ALL the entries of its
+    own pages (a causal layer's decode): arguments as :func:`mla_paged`'s
+    and :func:`gqa_gathered`'s → (B, H, dv).  At widths the Pallas kernel
+    takes the pages are read where they lie; at others (a toy's) every
+    row's pages are gathered."""
+    B, H, plain = q_plain.shape
+    _, page, entry = kv_pool.shape
+    if not pallas_lm_decode.gqa_supported(G, plain + q_rot.shape[-1], dv, H,
+                                          page):
+        mine = kv_pool[tables].reshape(B, -1, entry)
+        valid = jnp.arange(mine.shape[1])[None, :] < lengths[:, None]
+        return gqa_gathered(q_plain, q_rot, mine, valid, None, G, dv, scale)
+    return pallas_lm_decode.paged_gqa_decode(
+        gqa_block_queries(q_plain, q_rot, G), kv_pool, tables, lengths,
+        kv_heads=G, v=dv, scale=scale)
+
+
+def prefill_gqa_causal(q_plain, q_rot, kv_pool, table, start, n_valid,
+                       G: int, dv: int, scale: float,
+                       pages_per_step: int = 1):
+    """Grouped-query attention of a chunk against ALL of its session's
+    entries up to each query's own, the chunk's own tokens (already
+    written to the pool) included: a loop over the session's pages — as
+    many as it has — with an online softmax, so that of the scores only a
+    step's (T, H, ``pages_per_step`` pages) exist.  ``q_plain`` (T, H,
+    dk − r), ``q_rot`` (T, H, r); the rest as
+    :func:`prefill_causal_attention`'s → (T, H, dv)."""
+    T, H, plain = q_plain.shape
+    hp, r = H // G, q_rot.shape[-1]
+    page = kv_pool.shape[1]
+    span = pages_per_step * page
+    end = start + n_valid
+    n_pages = (end + page - 1) // page
+    # KV head first, a KV head's queries (token, head) as ONE long axis,
+    # a head's unrotated and rotated dims side by side again: a step is ONE
+    # product of (T hp, dk) x (dk, span) a KV head, and the (T, H, span)
+    # float32 scores — a step's traffic is theirs — are written once
+    q = jnp.concatenate([q_plain, q_rot], -1).reshape(T, G, hp, -1) \
+        .transpose(1, 0, 2, 3).reshape(G, T * hp, -1)
+    q_pos = jnp.repeat(start + jnp.arange(T), hp)             # (T hp,)
+
+    def attend(i, carry):
+        m, l, acc = carry
+        pages = [jnp.where(i * pages_per_step + b < n_pages,
+                           table[jnp.minimum(i * pages_per_step + b,
+                                             table.shape[0] - 1)], 0)
+                 for b in range(pages_per_step)]
+        kv = jnp.concatenate([kv_pool[p] for p in pages], 0)  # (span, entry)
+        kp, kr, v = gqa_split(kv, G, plain, r, dv)
+        key_pos = i * span + jnp.arange(span)
+        ok = (key_pos[None, :] <= q_pos[:, None]) & (key_pos[None, :] < end)
+        s = jnp.einsum("gne,sge->gns", q, jnp.concatenate([kp, kr], -1),
+                       preferred_element_type=F32) * scale
+        s = jnp.where(ok[None], s, NEG)
+        m_new = jnp.maximum(m, jnp.max(s, -1))
+        p = jnp.where(ok[None], jnp.exp(s - m_new[..., None]), 0.0)
+        fix = jnp.exp(m - m_new)
+        l = l * fix + jnp.sum(p, -1)
+        acc = acc * fix[..., None] + jnp.einsum(
+            "gns,sge->gne", p.astype(kv.dtype), v,
+            preferred_element_type=F32)
+        return m_new, l, acc
+
+    _, l, acc = lax.fori_loop(
+        0, (n_pages + pages_per_step - 1) // pages_per_step, attend,
+        (jnp.full((G, T * hp), NEG, F32), jnp.zeros((G, T * hp), F32),
+         jnp.zeros((G, T * hp, dv), F32)))
+    o = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(kv_pool.dtype)
+    return o.reshape(G, T, hp, dv).transpose(1, 0, 2, 3).reshape(T, H, dv)
+
+
+def prefill_gqa_window(q_plain, q_rot, c_new, prev, prev_pos, start, n_valid,
+                       sink, G: int, dv: int, scale: float, window: int,
+                       q_block: int):
+    """Windowed grouped-query attention of a chunk, with the sink column
+    where the layer has one: keys and values are the session's last
+    ``window − 1`` entries before the chunk (``prev`` (window − 1, entry)
+    in position order, at positions ``prev_pos``, negative where there is
+    none) and the chunk's own (``c_new`` (T, entry)); ``q_block`` queries
+    at a time against the ``q_block + window − 1`` entries they can see
+    → (T, H, dv)."""
+    T, H, plain = q_plain.shape
+    entries = jnp.concatenate([prev, c_new], 0)            # (W-1+T, entry)
+    pos = jnp.concatenate([prev_pos, start + jnp.arange(T)])
+    ok_key = jnp.concatenate([prev_pos >= 0, jnp.arange(T) < n_valid])
+    kp, kr, v = gqa_split(entries, G, plain, q_rot.shape[-1], dv)
+    qp, qr = (q.reshape(T, G, H // G, -1) for q in (q_plain, q_rot))
+    out = []
+    for lo in range(0, T, q_block):
+        hi = min(T, lo + q_block)
+        # queries lo..hi see entries (lo .. hi + window - 1)
+        ks = slice(lo, hi + window - 1)
+        q_pos = start + jnp.arange(lo, hi)
+        ok = (ok_key[ks][None, :] & (pos[ks][None, :] <= q_pos[:, None])
+              & (pos[ks][None, :] > q_pos[:, None] - window))
+        s = (jnp.einsum("tgae,sge->tgas", qp[lo:hi], kp[ks],
+                        preferred_element_type=F32)
+             + jnp.einsum("tgae,sge->tgas", qr[lo:hi], kr[ks],
+                          preferred_element_type=F32)) * scale
+        s = jnp.where(ok[:, None, None, :], s, NEG)
+        p = softmax_sink(s, sink).astype(v.dtype)
+        out.append(jnp.einsum("tgas,sge->tgae", p, v[ks]).reshape(
+            hi - lo, H, dv))
     return jnp.concatenate(out, 0)

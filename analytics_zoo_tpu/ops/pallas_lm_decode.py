@@ -1,8 +1,12 @@
 """Decode attention of the decoder LM's CAUSAL layers (models/lm.py) as
 ONE Pallas program a layer and step: multi-head latent attention in the
-absorbed form of B rows of any sessions, each over ALL of its own pages,
-read straight out of the paged pool through the rows' page tables —
-online softmax, nothing gathered, nothing of the scores in HBM.
+absorbed form (``paged_mla_decode``) or grouped-query attention
+(``paged_gqa_decode``, at the end of the file) of B rows of any sessions,
+each over ALL of its own pages, read straight out of the paged pool
+through the rows' page tables — online softmax, nothing gathered, nothing
+of the scores in HBM.  What follows is the latent kernel's story; the
+grouped-query one shares the grid, the work items and the online softmax
+(``_accumulate``).
 
 A step that gathered its rows' contexts first (``pool[tables]``, the XLA
 form: ops/lm_attention.py ``mla_paged``'s fallback) would copy every
@@ -80,52 +84,139 @@ def work_items(lengths, tables, page: int, n_items: int):
             jnp.where(real, idx, -1).astype(jnp.int32))
 
 
-def _kernel(row_ref, page_ref, idx_ref, len_ref, q_ref, kv_ref, o_ref,
-            m_sc, l_sc, acc_sc, *, page: int, rank: int, scale: float):
-    w = pl.program_id(0)
-    j = idx_ref[w]
-    n = len_ref[row_ref[w]]
+def _accumulate(s, values, j, n, m_sc, l_sc, acc_sc, *, page: int):
+    """One page of a row's online softmax: ``s`` (H, page) float32 scores
+    of the page's tokens (row positions ``j * page ..``, of which the row
+    holds ``n``), ``values`` (page, ·) what the probabilities weigh."""
+    at = j * page + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(at < n, s, NEG)                         # (H, page)
+    m_prev, l_prev = m_sc[...], l_sc[...]                 # (H, LANES)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # a row's first page holds its first token, so from the first
+    # step on the maximum is a real score and a masked key's
+    # exp(NEG − m) is 0
+    p = jnp.exp(s - m_new[:, :1])
+    l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    m_sc[...] = m_new
+    acc_sc[...] = acc_sc[...] * alpha[:, :1] + jnp.dot(
+        p.astype(values.dtype), values, preferred_element_type=F32)
 
+
+def _start(j, m_sc, l_sc, acc_sc):
     @pl.when(j == 0)
     def _():
         m_sc[...] = jnp.full(m_sc.shape, NEG, F32)
         l_sc[...] = jnp.zeros(l_sc.shape, F32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, F32)
 
+
+def _kernel(row_ref, page_ref, idx_ref, len_ref, q_ref, kv_ref, o_ref,
+            m_sc, l_sc, acc_sc, *, page: int, rank: int, scale: float):
+    w = pl.program_id(0)
+    j = idx_ref[w]
+    n = len_ref[row_ref[w]]
+    _start(j, m_sc, l_sc, acc_sc)
+
     @pl.when(j >= 0)
     def _():
         kv = kv_ref[0]                                    # (page, entry)
         s = lax.dot_general(q_ref[0], kv, (((1,), (1,)), ((), ())),
                             preferred_element_type=F32) * scale
-        at = j * page + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(at < n, s, NEG)                     # (H, page)
-        m_prev, l_prev = m_sc[...], l_sc[...]             # (H, LANES)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # a row's first page holds its first token, so from the first
-        # step on the maximum is a real score and a masked key's
-        # exp(NEG − m) is 0
-        p = jnp.exp(s - m_new[:, :1])
-        l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        m_sc[...] = m_new
-        acc_sc[...] = acc_sc[...] * alpha[:, :1] + jnp.dot(
-            p.astype(kv.dtype), kv[:, :rank], preferred_element_type=F32)
+        _accumulate(s, kv[:, :rank], j, n, m_sc, l_sc, acc_sc, page=page)
 
     @pl.when((j >= 0) & ((j + 1) * page >= n))            # its last page
     def _():
         o_ref[0] = (acc_sc[...] / l_sc[...][:, :1]).astype(o_ref.dtype)
 
 
+def _gqa_kernel(row_ref, page_ref, idx_ref, len_ref, q_ref, kv_ref, o_ref,
+                m_sc, l_sc, acc_sc, *, page: int, keys: int, groups: int,
+                scale: float):
+    w = pl.program_id(0)
+    j = idx_ref[w]
+    n = len_ref[row_ref[w]]
+    _start(j, m_sc, l_sc, acc_sc)
+
+    @pl.when(j >= 0)
+    def _():
+        kv = kv_ref[0]                                    # (page, entry)
+        # every head against every KV head's keys in ONE product: a head's
+        # query has zeros in the other KV heads' columns
+        s = lax.dot_general(q_ref[0], kv[:, :keys], (((1,), (1,)), ((), ())),
+                            preferred_element_type=F32) * scale
+        _accumulate(s, kv[:, keys:], j, n, m_sc, l_sc, acc_sc, page=page)
+
+    @pl.when((j >= 0) & ((j + 1) * page >= n))            # its last page
+    def _():
+        # of a head's sums over all KV heads' values, its own KV head's
+        H, v = o_ref.shape[1], o_ref.shape[2]
+        hp = H // groups
+        for g in range(groups):
+            rows = slice(g * hp, (g + 1) * hp)
+            o_ref[0, rows, :] = (
+                acc_sc[rows, g * v:(g + 1) * v] / l_sc[rows, :1]
+            ).astype(o_ref.dtype)
+
+
+def _vmem_bytes(H: int, page: int, q: int, entry: int, out: int, acc: int,
+                dtype) -> int:
+    """A paged kernel's blocks (double-buffered) and scratch as Mosaic lays
+    them out, plus a step's scores and probabilities: queries ``q`` wide,
+    a page of ``entry``, an output of ``out`` and an accumulator of
+    ``acc`` a head."""
+    blocks = (vmem.padded_bytes((H, q), dtype)
+              + vmem.padded_bytes((page, entry), dtype)
+              + vmem.padded_bytes((H, out), dtype))
+    scratch = (2 * vmem.padded_bytes((H, LANES), F32)
+               + vmem.padded_bytes((H, acc), F32))
+    return 2 * blocks + scratch + 3 * vmem.padded_bytes((H, page), F32)
+
+
 def declared_vmem_bytes(H: int, page: int, entry: int, rank: int,
                         dtype) -> int:
-    """The kernel's blocks (double-buffered) and scratch as Mosaic lays
-    them out, plus a step's scores and probabilities."""
-    blocks = (vmem.padded_bytes((H, entry), dtype)
-              + vmem.padded_bytes((page, entry), dtype)
-              + vmem.padded_bytes((H, rank), dtype))
-    scratch = (2 * vmem.padded_bytes((H, LANES), F32)
-               + vmem.padded_bytes((H, rank), F32))
-    return 2 * blocks + scratch + 3 * vmem.padded_bytes((H, page), F32)
+    """What the latent kernel asks for."""
+    return _vmem_bytes(H, page, entry, entry, rank, rank, dtype)
+
+
+def _paged_call(kernel, name: str, q, kv_pool, tables, lengths, out: int,
+                acc: int, interpret):
+    """One paged kernel over the flat (row, page) list: ``q`` (B, H, ·) a
+    row's block, a page of the pool an item, the row's (H, ``out``) block
+    written at its last page; scratch: running maximum, sum and an (H,
+    ``acc``) accumulator.  → (B, H, out), zeros for a padding row (its
+    block is never visited: whatever stood there)."""
+    B, H, width = q.shape
+    n_pages, page, entry = kv_pool.shape
+    if interpret is None:
+        interpret = not engine.on_tpu()
+    lengths = lengths.astype(jnp.int32)
+    n_items = grid_steps(B, tables.shape[1], n_pages)
+    row, phys, idx = work_items(lengths, tables, page, n_items)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(n_items,),
+        in_specs=[
+            pl.BlockSpec((1, H, width), lambda w, r, p, i, n: (r[w], 0, 0)),
+            pl.BlockSpec((1, page, entry),
+                         lambda w, r, p, i, n: (p[w], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, H, out),
+                               lambda w, r, p, i, n: (r[w], 0, 0)),
+        scratch_shapes=[pltpu.VMEM((H, LANES), F32),
+                        pltpu.VMEM((H, LANES), F32),
+                        pltpu.VMEM((H, acc), F32)])
+    res = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, H, out), kv_pool.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem.limit_bytes(_vmem_bytes(
+                H, page, width, entry, out, acc, kv_pool.dtype))),
+        name=name,
+        interpret=interpret,
+    )(row, phys, idx, lengths, q, kv_pool)
+    return jnp.where((lengths > 0)[:, None, None], res, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
@@ -140,38 +231,67 @@ def paged_mla_decode(q, kv_pool, tables, lengths, *, rank: int, scale: float,
     (B,) the tokens each row attends to (the one being decoded, already
     written, included; 0: a padding row) → (B, H, rank) in the pool's
     dtype, the softmax-weighted latents (zeros for a padding row)."""
-    B, H, entry = q.shape
-    n_pages, page, _ = kv_pool.shape
-    if interpret is None:
-        interpret = not engine.on_tpu()
+    entry, page = q.shape[2], kv_pool.shape[1]
     if kv_pool.shape[2] != entry or not supported(rank, entry, page):
         raise ValueError(f"paged_mla_decode: q {q.shape}, pool "
                          f"{kv_pool.shape}, rank {rank} do not fit")
-    lengths = lengths.astype(jnp.int32)
-    n_items = grid_steps(B, tables.shape[1], n_pages)
-    row, phys, idx = work_items(lengths, tables, page, n_items)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=(n_items,),
-        in_specs=[
-            pl.BlockSpec((1, H, entry), lambda w, r, p, i, n: (r[w], 0, 0)),
-            pl.BlockSpec((1, page, entry),
-                         lambda w, r, p, i, n: (p[w], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, rank),
-                               lambda w, r, p, i, n: (r[w], 0, 0)),
-        scratch_shapes=[pltpu.VMEM((H, LANES), F32),
-                        pltpu.VMEM((H, LANES), F32),
-                        pltpu.VMEM((H, rank), F32)])
-    out = pl.pallas_call(
+    return _paged_call(
         functools.partial(_kernel, page=page, rank=rank, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((B, H, rank), kv_pool.dtype),
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=vmem.limit_bytes(declared_vmem_bytes(
-                H, page, entry, rank, kv_pool.dtype))),
-        name="lm_decode_mla_paged",
-        interpret=interpret,
-    )(row, phys, idx, lengths, q, kv_pool)
-    # a padding row's block is never visited: whatever stood there
-    return jnp.where((lengths > 0)[:, None, None], out, 0)
+        "lm_decode_mla_paged", q, kv_pool, tables, lengths, rank, rank,
+        interpret)
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention: real keys and values a KV head in the pool
+# ---------------------------------------------------------------------------
+
+def gqa_supported(kv_heads: int, k: int, v: int, heads: int,
+                  page: int) -> bool:
+    """Whether :func:`paged_gqa_decode` takes these widths: the entry's
+    keys ``[plain | rotary]`` of all KV heads end on a lane tile, a KV
+    head's values are whole lane tiles, a KV head's query heads whole
+    sublane tiles, a page whole sublane tiles."""
+    return (kv_heads * k % LANES == 0 and v % LANES == 0
+            and heads % kv_heads == 0 and heads // kv_heads % 8 == 0
+            and page % 16 == 0)
+
+
+def gqa_declared_vmem_bytes(H: int, page: int, keys: int, values: int,
+                            v: int, dtype) -> int:
+    """What the grouped-query kernel asks for."""
+    return _vmem_bytes(H, page, keys, keys + values, v, values, dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("kv_heads", "v", "scale",
+                                             "interpret"))
+def paged_gqa_decode(q, kv_pool, tables, lengths, *, kv_heads: int, v: int,
+                     scale: float, interpret=None):
+    """Grouped-query attention of B rows over their own pages.
+
+    ``kv_pool`` (n_pages, page, entry): a token's entry is ``[keys |
+    values]``, the keys ``keys = entry − kv_heads · v`` wide — every KV
+    head's unrotated dims, then every KV head's rotated dims
+    (ops/lm_attention.py ``gqa_entry``: each part ends on a lane tile where
+    a head's own 192 do not) — the values a KV head after another.  ``q``
+    (B, H, keys): a head's query laid out against the keys of ITS KV head
+    (head a reads KV head ``a // (H / kv_heads)``), zeros elsewhere
+    (``gqa_block_queries``), so one product scores a page for all heads;
+    ``tables``, ``lengths`` as :func:`paged_mla_decode`'s → (B, H, v) in
+    the pool's dtype (zeros for a padding row).  A page is DMA'd once and
+    serves all H heads: 64 x 768 x 512 and 64 x 512 x 512 multiply-adds a
+    page of 1.3 MB — three quarters of them against another KV head's
+    columns (zeros, or sums nobody reads), all of them under the page's
+    DMA."""
+    H, keys = q.shape[1:]
+    _, page, entry = kv_pool.shape
+    values = kv_heads * v
+    if keys + values != entry or keys % kv_heads \
+            or not gqa_supported(kv_heads, keys // kv_heads, v, H, page):
+        raise ValueError(f"paged_gqa_decode: q {q.shape}, pool "
+                         f"{kv_pool.shape}, {kv_heads} KV heads of {v} "
+                         f"values do not fit")
+    return _paged_call(
+        functools.partial(_gqa_kernel, page=page, keys=keys, groups=kv_heads,
+                          scale=scale),
+        "lm_decode_gqa_paged", q, kv_pool, tables, lengths, v, values,
+        interpret)

@@ -376,8 +376,10 @@ def _lm_specs(mesh: Mesh) -> SpecSet:
     replicated; on any other mesh all of it is replicated (one chip
     serves its own share and its own sessions).  The rules go by the
     parameters' names, which every decoder configuration shares (a model
-    without a router bias or headwise gates has fewer leaves, no other
-    names)."""
+    without a router bias, headwise gates or a shared expert has fewer
+    leaves; a grouped-query model's attention has ``wq``, ``wk``, ``wv``
+    and ``sink`` for the latent one's leaves — replicated like them:
+    attention is data-parallel over the sessions)."""
     from analytics_zoo_tpu.parallel import tensor as tensor_lib
     from analytics_zoo_tpu.parallel.expert import EXPERT_AXIS
 

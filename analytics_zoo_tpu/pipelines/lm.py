@@ -20,7 +20,8 @@ chunk's last token.  ``lm_serving_tiers`` is the model's ``tier_factory``::
 and index keys and for the causal layers' latents, and a ring a session
 for the sliding layers (ops/lm_attention.py) — of each only what the
 model's kinds of layer need: a model of causal layers alone has pools and
-nothing else — the device arrays, and the host's books —
+nothing else; a grouped-query model's pools and rings hold keys and values
+at their kind's width — the device arrays, and the host's books —
 which pages a session holds, how long it is, which pages are free.  A
 chunk is admitted before it runs (``az/lm/cache_admit``): its session gets
 a slot on first sight and as many pages as its new length needs; a chunk
@@ -232,8 +233,9 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
 
     def note_paged(lengths: np.ndarray) -> None:
         """What a causal layer's decode walked this step: the pages that
-        hold a token of a live row, and the steps the kernel's grid was
-        launched with (ops/pallas_lm_decode.py), one layer."""
+        hold a token of a live row, and the steps the kernel's grid (the
+        latent or the grouped-query one: ops/pallas_lm_decode.py) was
+        launched with, one layer."""
         registry.gauge("lm/paged_pages").set(
             int((-(-lengths // geo.page)).sum()))
         registry.gauge("lm/paged_grid_steps").set(
@@ -280,6 +282,11 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
         note_experts(counts)
         if paged:
             note_paged(np.where(live, pos + 1, 0))
+        if cfg.n_sliding:
+            # the windows' work beside the paged layers': the entries the
+            # live rows hold in a sliding layer's ring
+            registry.gauge("lm/ring_tokens").set(
+                int(np.minimum(pos[live] + 1, cfg.window).sum()))
         # the (B, vocab) array itself: a list of its rows is stacked again,
         # 5 MB copied, when the runtime hands the answers out
         return logits

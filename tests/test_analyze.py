@@ -557,6 +557,10 @@ class TestRepoClean:
         # throughout (the paged decode attention, pools only)
         assert {"lm-mla/serve:bf16", "lm-mla/serve:prefill4",
                 "lm-mla/serve:prefill8"} <= names
+        # ISSUE 35: and over a model of grouped-query attention (pools
+        # and rings of two widths, sinks, no shared expert)
+        assert {"lm-gqa/serve:bf16", "lm-gqa/serve:prefill4",
+                "lm-gqa/serve:prefill8"} <= names
         assert {"ssd/serve:fp", "ssd/serve:int8"} <= names
         # ISSUE 13: the persistent-RNN TRAIN program (pallas engine,
         # transposed persistent backward) is audited alongside the

@@ -504,6 +504,42 @@ def test_config_reads_the_published_keys():
     assert shapes["layers"][1]["moe"]["router_w"].shape == (32, 8)
 
 
+@pytest.mark.parametrize("name,geo,leaves,count,tree,cache", [
+    ("dots3-note-prev-ep8", (2401, 512, 136, 64), 98, 4087154176,
+     "09edca6ccd04cd81", "c24b4c820c95c402"),
+    ("ax-k1-ep16", (1751, 512, 88, 64), 79, 3491257344,
+     "b3901c3932d8e722", "8286b4e17aca8eb3"),
+])
+def test_the_benchmark_s_latent_models_are_what_they_were(name, geo, leaves,
+                                                          count, tree, cache):
+    """``LMConfig.from_dict`` of the two configuration files the benchmark
+    had before the grouped-query form joined the module: every leaf's
+    name, shape and dtype of the parameter tree and of the cache at the
+    cell's geometry, as a digest taken at PR 34."""
+    import hashlib
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           name + ".json")) as f:
+        cfg = lm.LMConfig.from_dict(json.load(f))
+    assert isinstance(cfg.full, lm.MLADims) \
+        and isinstance(cfg.swa, (lm.MLADims, type(None)))
+
+    def flat(shapes):
+        return [(jax.tree_util.keystr(p), v.shape, str(v.dtype)) for p, v
+                in jax.tree_util.tree_flatten_with_path(shapes)[0]]
+
+    def digest(rows):
+        return hashlib.sha256(repr(rows).encode()).hexdigest()[:16]
+
+    params = flat(lm.param_shapes(cfg))
+    assert len(params) == leaves
+    assert sum(int(np.prod(s)) for _, s, _ in params) == count
+    assert digest(params) == tree
+    assert digest(flat(lm.cache_shapes(cfg, lm.CacheGeometry(*geo)))) == cache
+
+
 def test_kept_rows_alone_equal_the_whole_forward(weights):
     """With ``keep`` a layer computes only the rows the kept ones can
     see; their logits are the whole forward's, one session or several in

@@ -135,6 +135,28 @@ def test_paged_decode_kernel_compiles_for_a_v5e_at_the_published_widths(
         < 8 * (1 << 20)
 
 
+def test_paged_gqa_decode_kernel_compiles_for_a_v5e_at_the_published_widths(
+        one_chip, monkeypatch):
+    """``paged_gqa_decode`` at the MiMo cell's geometry: 64 rows x 64 heads
+    on 4 KV heads, a pool of 2,101 pages of 512 x 1,280 (keys 768: every
+    KV head's 128 unrotated dims, then every KV head's 64 rotated ones;
+    values 512), tables of 136 pages."""
+    from analytics_zoo_tpu.ops import pallas_lm_decode as pd
+
+    monkeypatch.setattr(pd.engine, "on_tpu", lambda: True)
+    S = lambda s, d=jnp.bfloat16: jax.ShapeDtypeStruct(      # noqa: E731
+        s, d, sharding=one_chip)
+    fn = lambda qp, qr, pool, tables, n: att.gqa_paged(      # noqa: E731
+        qp, qr, pool, tables, n, 4, 128, 192 ** -0.5)
+    compiled = jax.jit(fn).lower(
+        S((64, 64, 128)), S((64, 64, 64)), S((2101, 512, 1280)),
+        S((64, 136), jnp.int32), S((64,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert pd.grid_steps(64, 136, 2101) == 2100
+    assert pd.gqa_declared_vmem_bytes(64, 512, 768, 512, 128, jnp.bfloat16) \
+        < 8 * (1 << 20)
+
+
 def test_causal_prefill_compiles_for_a_v5e_at_64_heads(one_chip,
                                                        monkeypatch):
     """A causal layer's chunk of 2,048 tokens at A.X-K1's widths through
