@@ -53,9 +53,15 @@ CATALOG: Dict[str, str] = {
     "serve/queue_depth":
         "histogram · admission-queue depth sampled at each dispatch",
     "serve/staging_alloc":
-        "counter · batches (warm() included) whose staging buffer was "
-        "allocated or replaced instead of reused: stays at the number "
-        "of geometries when every model's rows keep their shape",
+        "counter · batches (warm() included) whose pair of staging "
+        "buffers was allocated or replaced instead of reused: stays at "
+        "the number of geometries when every model's rows keep their "
+        "shape",
+    "serve/assembled_ahead":
+        "counter · batches assembled while another batch's program ran "
+        "(ServingRuntime._assemble_ahead: a tier that returns a device "
+        "array; never registered by a runtime whose tiers answer on the "
+        "host)",
     # -- decoder-LM session tier (pipelines/lm.py, ISSUE 28) ----------------
     "lm/cache_tokens":
         "gauge · tokens the replica's live sessions hold in the paged "
@@ -269,12 +275,17 @@ STAGES: Dict[str, str] = {
         "main thread · ServingRuntime.pump(), whole",
     "az/serve/collate":
         "main thread · DeadlineBatcher._collate: the batch's payloads "
-        "copied, padded, into the staging buffer kept for the geometry; "
-        "attrs: reused (False when the buffer was allocated or replaced "
-        "for this batch)",
+        "copied, padded, into one of the two staging buffers kept for "
+        "the geometry; attrs: reused (False when the pair was allocated "
+        "or replaced for this batch), ahead (True when the batch was "
+        "assembled while another's program ran, inside that batch's "
+        "az/serve/forward; counter serve/assembled_ahead counts those)",
     "az/serve/forward":
         "main thread · ReplicaPool.dispatch of one batch (replica "
-        "choice, watchdog, the tier's forward, failover)",
+        "choice, watchdog, the tier's forward, failover); for a tier "
+        "that returns a device array also the NEXT batch's "
+        "az/serve/collate and the start of its transfer, between the "
+        "tier's return and the fetch",
     "az/serve/h2d":
         "main thread · a tier's jnp.asarray of the host batch (SSD: the "
         "pictures; LM: the token ids, positions and page tables): the "
@@ -284,8 +295,10 @@ STAGES: Dict[str, str] = {
         "detect_normalized; LM: the decode or prefill step; "
         "asynchronous dispatch)",
     "az/serve/result_wait":
-        "main thread · a tier's np.asarray of the answer: waits for the "
-        "program and copies the answer (detections, logits) to the host",
+        "main thread · np.asarray of the answer: waits for the program "
+        "and copies the answer (detections, logits) to the host. The LM "
+        "and DS2 tiers' own, inside their forward; for a tier that "
+        "returns a device array (SSD) Replica.forward's fetch of it",
     "az/lm/step":
         "main thread · the LM tier's forward of one batch, whole; attrs: "
         "rows (live rows), edge, phase (prefill or decode)",

@@ -889,18 +889,26 @@ def ssd_serving_tiers(model: Model, param: PreProcessParam,
     low = copy.copy(int8)
     low.post = dataclasses.replace(int8.post, keep_topk=degraded_topk)
 
-    def fwd(pred: SSDPredictor) -> Callable[[Dict], np.ndarray]:
-        def forward(batch: Dict) -> np.ndarray:
-            # three stages of one batch: the host's side of the transfer
-            # (staging and enqueue), the program's asynchronous
-            # dispatch (detect_normalized's own jnp.asarray of a device
-            # array is free), and the wait for the answer
+    def place(batch: Dict) -> Dict:
+        """``ServingTier.place``: the transfer ``forward`` would start."""
+        return {"input": jnp.asarray(batch["input"])}
+
+    def fwd(pred: SSDPredictor) -> Callable[[Dict], jnp.ndarray]:
+        def forward(batch: Dict) -> jnp.ndarray:
+            # two stages of one batch: the host's side of the transfer
+            # (staging and enqueue; free where the runtime placed the
+            # batch ahead, as detect_normalized's own jnp.asarray of a
+            # device array is), and the program's asynchronous dispatch
+            # with the start of the answer's copy.  The answer goes back
+            # as the device array: the replica fetches it
+            # (az/serve/result_wait), and the runtime assembles the next
+            # batch in between
             with stage("az/serve/h2d"):
                 x = jnp.asarray(batch["input"])
             with stage("az/serve/dispatch"):
                 out = pred.detect_normalized(x)
-            with stage("az/serve/result_wait"):
-                return np.asarray(out)
+                out.copy_to_host_async()
+            return out
         return forward
 
     def audit(pred: SSDPredictor) -> Callable[[], tuple]:
@@ -921,16 +929,19 @@ def ssd_serving_tiers(model: Model, param: PreProcessParam,
                     (4,))
         return device_program
 
+    # the transfer ahead is offered off a mesh only: with specs= the
+    # annotated program places its batch over the data axis itself
+    ahead = place if specs is None else None
     return [
         ServingTier("fp", fwd(full), speed=1.0,
                     quality_note="full precision, full NMS top-K",
-                    device_program=audit(full)),
+                    device_program=audit(full), place=ahead),
         ServingTier("int8", fwd(int8), speed=0.77,
                     quality_note="int8 weights, fp math (mAP delta "
                                  "+0.0001, INT8_MAP_PARITY.json)",
-                    device_program=audit(int8)),
+                    device_program=audit(int8), place=ahead),
         ServingTier(f"int8_topk{degraded_topk}", fwd(low), speed=0.7,
                     quality_note=f"int8 + keep_topk={degraded_topk} "
                                  "(fewer kept detections per image)",
-                    device_program=audit(low)),
+                    device_program=audit(low), place=ahead),
     ]

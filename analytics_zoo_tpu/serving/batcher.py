@@ -85,25 +85,36 @@ class AssembledBatch:
     keys the replica's per-model forward table; ``affinity`` (set for
     session batches) pins the dispatch to one replica.
 
-    **Who owns the bytes.**  ``batch[pad_key]`` is the batcher's staging
-    buffer of this ``(model, edge)``, filled in place: it is valid until
-    the NEXT batch of that geometry is assembled, and no longer.  A
-    forward must have finished reading it when it returns, and must copy
+    **Who owns the bytes.**  ``batch[pad_key]`` is one of the PAIR of
+    staging buffers the batcher keeps for this ``(model, edge)``, filled
+    in place and in turn: it is valid until the next batch BUT ONE of
+    that geometry is assembled, and no longer.  A forward must copy
     whatever it keeps (a row, a view, a zero-copy device alias).  The
-    runtime keeps its side: ``pump`` assembles the next batch only after
-    ``_dispatch`` has handed the answers out, and the hand-out copies an
-    answer that shares memory with the buffer before a request retains
-    it.  The shipped tiers keep theirs by ONE fence, the fetch of the
-    answer (``np.asarray(out)``): on the chip the runtime may read the
-    host buffer until the transfer ``jnp.asarray`` started completes,
-    and on a CPU backend ``jnp.asarray`` of an aligned array may alias
-    it with no copy at all — either way the program that consumed the
-    input has run when its answer is on the host.  The SSD tier relies
-    on that fetch (``az/serve/result_wait``), not on the transfer's
-    completion, which it never waits for by itself.  The small vectors
-    (``length_key``, ``session``, ``final``) are new for every batch.
-    ``staging_reused`` is False when the buffer was allocated or
-    replaced for this batch (``serve/staging_alloc`` counts those)."""
+    runtime keeps its side: it assembles at most ONE batch ahead — the
+    next batch while this one's program runs, into the other buffer —
+    and the batch after that only once this one's answers are handed
+    out, and the hand-out copies an answer that shares memory with the
+    buffer before a request retains it.  Who fences what: on the chip
+    the device may read the host buffer until the transfer
+    ``jnp.asarray`` started completes, and on a CPU backend
+    ``jnp.asarray`` of an aligned array may alias it with no copy at all
+    — either way the program that consumed the input has run when its
+    answer is on the host, and that ONE fence, the fetch of the answer
+    (``az/serve/result_wait``), is the only one: the tiers that fetch
+    before they return make it themselves, and for a tier that hands
+    back a device array (the SSD tiers) :meth:`Replica.forward
+    <analytics_zoo_tpu.serving.replica.Replica.forward>` makes it before
+    the answer leaves the replica.  Nobody waits for the transfer by
+    itself.  The small vectors (``length_key``, ``session``, ``final``)
+    are new for every batch.  ``staging_reused`` is False when the pair
+    was allocated or replaced for this batch (``serve/staging_alloc``
+    counts those: one a geometry).
+
+    ``placed`` rides BESIDE the host batch, never in its place: ``(the
+    tier that placed, {leaf: device array})`` once the runtime started
+    the batch's transfer ahead (``ServingTier.place``).  The first
+    forward of the batch on a replica that serves that very tier takes
+    it; a failover's second forward re-sends the host buffer."""
 
     requests: List[Request]
     batch: Dict[str, Any]
@@ -114,10 +125,23 @@ class AssembledBatch:
     model: str = DEFAULT_MODEL
     affinity: Optional[int] = None
     staging_reused: bool = True
+    placed: Optional[Tuple[Any, Dict[str, Any]]] = None
 
     @property
     def earliest_deadline(self) -> float:
         return min(r.deadline_t for r in self.requests)
+
+
+@dataclasses.dataclass
+class _StagingPair:
+    """A geometry's two staging buffers, ONE allocation of shape
+    ``(2, cap) + row shape``: ``dirty[k]`` rows of ``pair[k]`` were left
+    non-zero by the last batch assembled in it, and ``pair[turn]`` is the
+    one the next batch fills."""
+
+    pair: np.ndarray
+    dirty: List[int] = dataclasses.field(default_factory=lambda: [0, 0])
+    turn: int = 0
 
 
 class DeadlineBatcher:
@@ -173,13 +197,12 @@ class DeadlineBatcher:
         #: feeds these from the SLO burn rates each decision window
         self._weights: Dict[str, float] = {}
         self._weighted = False
-        #: per (model, edge): (staging buffer of shape (cap,) + row
-        #: shape, rows the last batch assembled in it left non-zero).
+        #: per (model, edge): the geometry's pair of staging buffers.
         #: Tier is no part of the key (every tier of a model takes the
         #: same input), and a batch of another row shape or dtype
-        #: REPLACES its geometry's buffer, so what is held is bounded by
+        #: REPLACES its geometry's pair, so what is held is bounded by
         #: the geometries, never by traffic.
-        self._staging: Dict[Tuple[str, Any], Tuple[np.ndarray, int]] = {}
+        self._staging: Dict[Tuple[str, Any], _StagingPair] = {}
 
     def _plan(self, model: str) -> ModelPlan:
         try:
@@ -247,7 +270,7 @@ class DeadlineBatcher:
                 stats[key] = (cur[0] + 1, min(cur[1], r.deadline_t))
         return stats
 
-    def next_batch(self, tier, force: bool = False
+    def next_batch(self, tier, force: bool = False, ahead: bool = False
                    ) -> Optional[AssembledBatch]:
         """Assemble the most urgent flush-ready batch, or ``None`` when
         every bucket can still afford to wait.  ``tier`` is the current
@@ -255,7 +278,8 @@ class DeadlineBatcher:
         multiplexed mode (each model rides its own ladder).
         ``force=True`` (drain) flushes the most urgent non-empty bucket
         regardless of slack.  Expired requests are shed first — never
-        dispatched."""
+        dispatched.  ``ahead`` only tags the ``az/serve/collate`` stage:
+        the runtime assembles this batch while another's program runs."""
         self.queue.expire()
         stats = self._group_stats()
         if not stats:
@@ -302,16 +326,21 @@ class DeadlineBatcher:
             batch = self._collate(taken, edge, m_tier, model=model,
                                   affinity=affinity)
             collate.attrs["reused"] = batch.staging_reused
+            collate.attrs["ahead"] = ahead
         return batch
 
     def _collate(self, reqs: List[Request], edge: Any, tier: int,
                  model: str = DEFAULT_MODEL,
                  affinity: Optional[int] = None) -> AssembledBatch:
         """Pad rows to the bucket edge and the batch axis to the model's
-        batch size — both geometries already compiled — in the staging
-        buffer this batcher keeps for ``(model, edge)``: a new array of
-        a batch's size costs its page faults again for every batch, the
-        kept one only the copy.  The bytes are those ``np.stack`` of the
+        batch size — both geometries already compiled — in one of the two
+        staging buffers this batcher keeps for ``(model, edge)``, the one
+        the batch before last used: a new array of a batch's size costs
+        its page faults again for every batch, a kept one only the copy,
+        and the batch before this one may still be read out of the other
+        (a transfer, a program under way).  The two are allocated
+        together the first time the geometry is seen.  The bytes are
+        those ``np.stack`` of the
         padded rows gave (its promoted dtype, its ``ValueError`` for
         rows of different shapes); see :class:`AssembledBatch` for how
         long they stay valid."""
@@ -335,11 +364,14 @@ class DeadlineBatcher:
                        for r, arr in zip(reqs, arrs)]
         shape = (cap,) + row_shape
         dtype = np.result_type(*dict.fromkeys(a.dtype for a in arrs))
-        buf, dirty = self._staging.get((model, edge), (None, 0))
-        reused = buf is not None and buf.shape == shape \
-            and buf.dtype == dtype
-        if not reused:      # zeroed, so no row of it is dirty
-            buf, dirty = np.zeros(shape, dtype), 0
+        kept = self._staging.get((model, edge))
+        reused = kept is not None and kept.pair.shape[1:] == shape \
+            and kept.pair.dtype == dtype
+        if not reused:      # zeroed, so no row of either is dirty
+            kept = self._staging[(model, edge)] = _StagingPair(
+                np.zeros((2,) + shape, dtype))
+        turn = kept.turn
+        buf, dirty = kept.pair[turn], kept.dirty[turn]
         zero = np.zeros((), dtype)      # '' for strings, where 0 reads '0'
         if edge is FIXED:
             for i, arr in enumerate(arrs):
@@ -352,7 +384,8 @@ class DeadlineBatcher:
         pad = cap - n_valid
         if dirty > n_valid:
             buf[n_valid:dirty] = zero
-        self._staging[(model, edge)] = (buf, n_valid)
+        kept.dirty[turn] = n_valid
+        kept.turn = 1 - turn
         batch: Dict[str, Any] = {plan.pad_key: buf}
         if edge is not FIXED and plan.length_key:
             batch[plan.length_key] = np.asarray(lengths + [0] * pad,

@@ -42,6 +42,23 @@ class ServingTier:
     relative speed the batcher's service-time model may consult
     (1.0 = tier-0 time; int8 < 1).
 
+    ``forward(batch) -> answer``: rows on the host (a numpy array, a
+    list), or a DEVICE array whose program may still be running.  The
+    replica fetches the second kind itself
+    (:meth:`~analytics_zoo_tpu.serving.replica.Replica.forward`), and
+    while its program runs the runtime assembles the next due batch — so
+    a tier that launches and returns gets the overlap, and one that
+    fetches before it returns runs as it always did.  Either way the
+    batch's input must have been read when the answer is on the host
+    (:class:`~analytics_zoo_tpu.serving.batcher.AssembledBatch`).
+
+    ``place`` (optional, for a tier that returns device arrays):
+    ``batch dict -> {leaf: device array}``, the start of the transfer of
+    the leaves the forward would send itself.  The runtime calls it on
+    the batch it assembled ahead, and the batch's forward is then called
+    with a copy of the dict that holds those leaves in their device form
+    (never with the host batch changed: a failover re-sends that).
+
     ``device_program`` (optional): a zero-arg thunk returning ``(fn,
     example_args, static_argnums)`` for the tier's underlying jitted
     device program — what ``az_analyze --program`` traces, so the
@@ -69,6 +86,7 @@ class ServingTier:
     device_program: Optional[Callable[[], tuple]] = None
     evict_session: Optional[Callable[[int], None]] = None
     pads_session_rows: bool = False
+    place: Optional[Callable[[Dict[str, Any]], Dict[str, Any]]] = None
 
 
 @dataclasses.dataclass

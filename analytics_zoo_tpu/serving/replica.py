@@ -64,6 +64,9 @@ import logging
 from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
                     Tuple)
 
+import numpy as np
+
+from analytics_zoo_tpu.obs.span import stage
 from analytics_zoo_tpu.resilience.errors import (ReplicaWedged, StallError,
                                                  is_retryable)
 from analytics_zoo_tpu.resilience.watchdog import StallWatchdog
@@ -75,6 +78,15 @@ logger = logging.getLogger("analytics_zoo_tpu")
 #: a (model, edge, tier) compiled-geometry key — what pre-warm plans
 #: enumerate and ``warm_keys`` tracks
 GeometryKey = Tuple[str, Any, int]
+
+
+def _on_host(out: Any) -> bool:
+    """Whether a tier's answer is on the host already: a numpy array or
+    scalar, or anything that is no array at all (a list of strings).
+    What else offers ``__array__`` — a jax array the tier handed back
+    without waiting for it — is still on its way."""
+    return isinstance(out, (np.ndarray, np.generic)) \
+        or not hasattr(out, "__array__")
 
 
 class Replica:
@@ -214,21 +226,47 @@ class Replica:
         not a stall) and nothing caught (a compile error is the
         caller's to see).  Returns the wall seconds, compile included."""
         t0 = self.clock.now()
-        self._fn_for(batch)(batch.batch)
+        out = self._fn_for(batch)(batch.batch)
+        if not _on_host(out):
+            # the program has run, and read its input, only when its
+            # answer is here: the next warm batch may then be assembled
+            np.asarray(out)
         self._ran.add((batch.model, batch.edge, batch.tier))
         return self.clock.now() - t0
 
+    def _inputs(self, batch: AssembledBatch) -> Dict[str, Any]:
+        """What the tier is called with: the host batch, with the leaves
+        the runtime placed ahead in their device form if THIS replica
+        serves the tier that placed them.  Taken once: a failover's
+        second forward re-sends the host buffer."""
+        placed, batch.placed = batch.placed, None
+        if placed is not None \
+                and self.tier_objs[batch.model][batch.tier] is placed[0]:
+            return {**batch.batch, **placed[1]}
+        return batch.batch
+
     def forward(self, batch: AssembledBatch,
-                fault: Optional[Callable[["Replica"], None]] = None) -> Any:
+                fault: Optional[Callable[["Replica"], None]] = None,
+                meanwhile: Optional[Callable[["Replica"], None]] = None
+                ) -> Any:
         """Run one batch under stall supervision.  ``fault`` (chaos) runs
         just before the model fn — it may raise (crash) or advance the
-        virtual clock (slow forward).  Raises :class:`ReplicaWedged` on
+        virtual clock (slow forward).  A tier may hand back its answer
+        before it is on the host (a device array whose program is still
+        running): ``meanwhile(self)`` then runs — the runtime's one
+        batch of look-ahead, which must not raise — and the answer is
+        fetched here, under ``az/serve/result_wait`` and the same
+        supervision as the call, so what leaves the replica is always a
+        host answer.  An answer that is on the host already skips both.
+        Raises :class:`ReplicaWedged` on
         a retryable crash or deadline overrun; the POOL owns
         fencing/failover.  A PROGRAM error is not a replica fault and
         propagates as itself: anything the failure classification calls fatal,
-        and anything the model fn raises the first time a geometry runs
-        here (that call compiles it) — fencing and failing over would
-        only repeat the same compile error on the next replica."""
+        and anything the model fn or the fetch of its answer raises the
+        first time a geometry runs here (that call compiles it, and an
+        asynchronous program's first run ends at the fetch) — fencing and
+        failing over would only repeat the same error on the next
+        replica."""
         self.watchdog.beat()
         self.dispatches += 1
         self.inflight += 1
@@ -243,7 +281,12 @@ class Replica:
             self._maybe_cold_compile(batch)
             fn = self._fn_for(batch)
             compiling = key not in self._ran
-            out = fn(batch.batch)
+            out = fn(self._inputs(batch))
+            if not _on_host(out):
+                if meanwhile is not None:
+                    meanwhile(self)
+                with stage("az/serve/result_wait"):
+                    out = np.asarray(out)
             compiling = False
             self._ran.add(key)
             if self.service_hook is not None:
@@ -796,12 +839,16 @@ class ReplicaPool:
 
     def dispatch(self, batch: AssembledBatch,
                  fault_for: Optional[Callable[[Replica], Optional[
-                     Callable[[Replica], None]]]] = None) -> Any:
+                     Callable[[Replica], None]]]] = None,
+                 meanwhile: Optional[Callable[[Replica], None]] = None
+                 ) -> Any:
         """Run ``batch`` on a healthy replica; on :class:`ReplicaWedged`
         fence the replica and re-dispatch EXACTLY once.  Returns the
         forward outputs; raises :class:`ReplicaWedged` when the retry is
         spent or no healthy replica remains (the runtime fails the
         batch's requests — retryable from the client's side).
+        ``meanwhile`` goes to every :meth:`Replica.forward` of the batch,
+        the failover's too, so it is the caller's to make idempotent.
 
         A batch with ``affinity`` set (a streaming-session batch) MUST
         run on that replica — its RNN carry lives there, so failover to
@@ -819,7 +866,7 @@ class ReplicaPool:
                     f"session state lost")
             fault = fault_for(replica) if fault_for is not None else None
             try:
-                return self.dispatch_on(replica, batch, fault)
+                return self.dispatch_on(replica, batch, fault, meanwhile)
             except ReplicaWedged as err:
                 self._fence(replica, err)
                 raise
@@ -828,7 +875,7 @@ class ReplicaPool:
             raise ReplicaWedged("no healthy replica available")
         try:
             fault = fault_for(replica) if fault_for is not None else None
-            return self.dispatch_on(replica, batch, fault)
+            return self.dispatch_on(replica, batch, fault, meanwhile)
         except ReplicaWedged as err:
             self._fence(replica, err)
             if batch.redispatched:
@@ -845,16 +892,18 @@ class ReplicaPool:
                          "requests": [r.rid for r in batch.requests]})
             fault = fault_for(backup) if fault_for is not None else None
             try:
-                return self.dispatch_on(backup, batch, fault)
+                return self.dispatch_on(backup, batch, fault, meanwhile)
             except ReplicaWedged as err2:
                 self._fence(backup, err2)
                 raise
 
     def dispatch_on(self, replica: Replica, batch: AssembledBatch,
-                    fault: Optional[Callable[[Replica], None]]) -> Any:
+                    fault: Optional[Callable[[Replica], None]],
+                    meanwhile: Optional[Callable[[Replica], None]] = None
+                    ) -> Any:
         for req in batch.requests:
             req.attempts += 1
-        return replica.forward(batch, fault=fault)
+        return replica.forward(batch, fault=fault, meanwhile=meanwhile)
 
     def snapshot(self) -> Dict[str, Any]:
         out = {

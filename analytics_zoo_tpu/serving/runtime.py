@@ -15,10 +15,17 @@ Glues the pieces into one synchronous, clock-driven scheduler:
 
 Single-threaded on purpose: every scheduling decision happens inside
 :meth:`ServingRuntime.pump`, reading time ONLY through the injected
-clock.  Against a real accelerator the same loop runs on a
-:class:`~analytics_zoo_tpu.serving.clock.MonotonicClock` with jax's
-async dispatch providing the device overlap (the
-``SSDPredictor._detect_device`` contract); under a
+clock.  ``pump()`` assembles, dispatches and answers every due batch and
+returns only when each is answered.  Against a real accelerator the same
+loop runs on a :class:`~analytics_zoo_tpu.serving.clock.MonotonicClock`
+with jax's async dispatch providing the device overlap: a tier may hand
+back its answer as a device array whose program is still running (the
+SSD tiers do), and between that return and the fetch of the answer the
+runtime assembles the NEXT due batch and starts its transfer
+(:meth:`ServingRuntime._assemble_ahead` — one batch of look-ahead, on
+this thread, inside the batch's ``az/serve/forward``; the pump's loop
+dispatches that batch next).  A tier whose answer is on the host when it
+returns sees none of this.  Under a
 :class:`~analytics_zoo_tpu.serving.clock.VirtualClock` plus a
 ``service_time`` model the whole overload/failover story replays
 deterministically — that is what ``tests/test_serving.py`` and
@@ -378,6 +385,13 @@ class ServingRuntime:
         #: open/in-flight session count per replica rid — the
         #: open_session placement input and the shrink-protection set
         self._session_load: Dict[int, int] = {}
+        #: the batch assembled while another's program ran, which the
+        #: pump's loop dispatches next (_assemble_ahead), or what its
+        #: assembly raised, which the loop raises there; ``_force`` is
+        #: the running pump's, for that assembly
+        self._held: Optional[AssembledBatch] = None
+        self._held_error: Optional[BaseException] = None
+        self._force = False
 
         self.queue = AdmissionQueue(queue_capacity, self.clock,
                                     on_shed=self._on_shed,
@@ -848,6 +862,7 @@ class ServingRuntime:
         advancing the clock."""
         with stage("az/serve/pump"):
             self._swap_tick()
+            self._force = force
             dispatched = 0
             while True:
                 if self.parallel and not force \
@@ -858,8 +873,10 @@ class ServingRuntime:
                     # next_batch
                     self.queue.expire()
                     break
-                batch = self.batcher.next_batch(self._tier_arg(),
-                                                force=force)
+                batch = self._take_held()
+                if batch is None:
+                    batch = self.batcher.next_batch(self._tier_arg(),
+                                                    force=force)
                 if batch is None:
                     # no batch is flush-ready; expiry may still have
                     # shed — that counts toward the current decision
@@ -868,6 +885,57 @@ class ServingRuntime:
                 self._dispatch(batch)
                 dispatched += 1
             return dispatched
+
+    def _take_held(self) -> Optional[AssembledBatch]:
+        """The batch assembled ahead, if there is one — or the error its
+        assembly met, raised here, where ``next_batch`` would have."""
+        err, self._held_error = self._held_error, None
+        if err is not None:
+            raise err
+        batch, self._held = self._held, None
+        return batch
+
+    def _assemble_ahead(self, replica: Replica) -> None:
+        """The one batch of look-ahead: :meth:`Replica.forward` calls
+        this between a tier's return of an answer that is not on the
+        host yet and the fetch of it, so it runs while the batch's
+        program does.  Assembles the next due batch exactly as the
+        pump's loop would — into the geometry's OTHER staging buffer,
+        at the ladder's rung of now — holds it for the loop to dispatch
+        next, and starts its transfer if the tier offers ``place``.
+        Holds at most one batch, so a failover's second forward of the
+        same batch finds it held and assembles no third.  Never raises
+        into the forward it runs under: what the assembly raises the
+        loop raises when it comes for the batch, and a transfer that
+        fails is left to the batch's own forward."""
+        if self._held is not None or self._held_error is not None:
+            return
+        try:
+            batch = self.batcher.next_batch(self._tier_arg(),
+                                            force=self._force, ahead=True)
+        except Exception as err:
+            self._held_error = err
+            return
+        if batch is None:
+            return
+        self._held = batch
+        self.metrics.registry.counter("serve/assembled_ahead").inc()
+        tier = replica.tier_objs[batch.model][batch.tier]
+        if tier.place is None:
+            return
+        try:
+            batch.placed = (tier, tier.place(batch.batch))
+        except Exception:
+            logger.warning("serving: transfer ahead of a batch of model "
+                           "%r failed; its forward sends it again",
+                           batch.model, exc_info=True)
+
+    def _depth(self) -> int:
+        """Requests that wait for a dispatch: the queue's, and those of
+        the batch assembled ahead (out of the queue, not yet served) —
+        the load the ladder and the metrics saw before the look-ahead."""
+        held = len(self._held.requests) if self._held is not None else 0
+        return self.queue.depth + held
 
     def next_event_t(self) -> Optional[float]:
         """Parallel mode: the next virtual instant the pool changes
@@ -1296,8 +1364,9 @@ class ServingRuntime:
 
     def _answer_rows(self, batch: AssembledBatch, out: Any) -> np.ndarray:
         """The tier's answer as rows to hand out.  The batch's input is
-        the batcher's staging buffer, which the next batch of the
-        geometry overwrites (:class:`AssembledBatch`): an answer that
+        one of the batcher's staging buffers, which the next batch but
+        one of the geometry overwrites (:class:`AssembledBatch`): an
+        answer that
         shares memory with it — a tier that returns its input, or a
         view of it — is copied before a request retains a row of it."""
         rows = np.asarray(out)
@@ -1315,7 +1384,7 @@ class ServingRuntime:
         self._dispatch_idx += 1
         self.metrics.on_batch(batch.n_valid,
                               self.batcher.model_batch(batch.model),
-                              self.queue.depth)
+                              self._depth())
         self._note_fill(batch)
         model_label = batch.model if self._multi else None
         t0 = self.clock.now()
@@ -1342,7 +1411,8 @@ class ServingRuntime:
                     batch=self._dispatch_idx)
         try:
             with stage("az/serve/forward"):
-                out = self.pool.dispatch(batch, fault_for=self._fault_for)
+                out = self.pool.dispatch(batch, fault_for=self._fault_for,
+                                         meanwhile=self._assemble_ahead)
         except ReplicaWedged as err:
             with stage("az/serve/handout"):
                 now = self.clock.now()
@@ -1676,6 +1746,9 @@ class ServingRuntime:
 
     def _after_dispatch(self, batch: AssembledBatch, t0: float,
                         failed: bool) -> None:
+        """``t0`` to now is THIS batch's service, launch to answer, for
+        the batcher's EWMA; where the next batch was assembled ahead it
+        contains that batch's collate, which ran under this program."""
         dt = self.clock.now() - t0
         if not failed and self.batcher.service_time is None:
             # the EWMA is only ever read when no explicit service model
@@ -1689,8 +1762,9 @@ class ServingRuntime:
             self._decide_window()
 
     def _decide_window(self) -> None:
+        depth = self._depth()
         detail = {"shed_in_window": self._window_shed,
-                  "queue_depth": self.queue.depth}
+                  "queue_depth": depth}
         if self.slo is not None:
             # SLO-driven path: window verdicts come from multi-window
             # burn rates over registry snapshots, not the raw flag —
@@ -1730,12 +1804,12 @@ class ServingRuntime:
                     depth_high = ladder.policy.depth_high * self.max_batch
                     overloaded = (
                         self._window_shed_by.get(name, 0) > 0
-                        or self.queue.depth > depth_high)
+                        or depth > depth_high)
                     ladder.observe_window(overloaded, detail=dict(detail))
             else:
                 depth_high = self.ladder.policy.depth_high * self.max_batch
                 overloaded = (self._window_shed > 0
-                              or self.queue.depth > depth_high)
+                              or depth > depth_high)
                 self.ladder.observe_window(overloaded, detail=detail)
         self._window_shed = 0
         self._window_shed_by = {}

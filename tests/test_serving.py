@@ -193,6 +193,10 @@ STAGING_SCRIPTS = {
     "fixed_short_after_full": (
         {}, [(FIXED, [(5, 3)] * 4, {}), (FIXED, [(5, 3)], {}),
              (FIXED, [(5, 3)] * 3, {}), (FIXED, [(5, 3)] * 2, {})]),
+    "fixed_both_of_the_pair_shrink": (
+        {}, [(FIXED, [(5, 3)] * 4, {}), (FIXED, [(5, 3)] * 4, {}),
+             (FIXED, [(5, 3)], {}), (FIXED, [(5, 3)] * 2, {}),
+             (FIXED, [(5, 3)] * 3, {}), (FIXED, [(5, 3)], {})]),
     "bucketed_lengths_shrink": (
         {"bucket_edges": [8]},
         [(8, [(8, 2), (7, 2), (8, 2)], {"lengths": [8, 7, 8]}),
@@ -212,9 +216,11 @@ STAGING_SCRIPTS = {
 
 
 class TestStagingBuffer:
-    """ISSUE 27: ``_collate`` fills the staging buffer the batcher keeps
-    for the geometry.  The bytes are those ``np.stack`` gave; the buffer
-    is one a geometry; nobody who kept an answer sees the next batch."""
+    """ISSUE 27, ISSUE 36: ``_collate`` fills, in turn, the two staging
+    buffers the batcher keeps for the geometry.  The bytes are those
+    ``np.stack`` gave; the pair is one allocation a geometry; a batch's
+    bytes stand until the next batch but one; nobody who kept an answer
+    sees a later batch."""
 
     CAP = 4
 
@@ -231,6 +237,7 @@ class TestStagingBuffer:
         plan_kw, batches = STAGING_SCRIPTS[script]
         b, plan = self._batcher(**plan_kw)
         rng = np.random.RandomState(27)
+        seen = []
         for k, (edge, shapes, kw) in enumerate(batches):
             reqs = _rows(rng, shapes, **kw)
             got = b._collate(reqs, edge, 0)
@@ -242,6 +249,14 @@ class TestStagingBuffer:
                     script, k, key)
             assert got.n_valid == len(reqs)
             assert got.staging_reused == (k > 0)
+            # filled in turn: the batch before this one stands as it
+            # was, the one before that gave its buffer
+            if seen:
+                assert not np.shares_memory(got.batch["input"], seen[-1][0])
+                assert seen[-1][0].tobytes() == seen[-1][1], (script, k)
+            if len(seen) > 1:
+                assert np.shares_memory(got.batch["input"], seen[-2][0])
+            seen.append((got.batch["input"], want["input"].tobytes()))
 
     @pytest.mark.parametrize("dtypes,promoted", [
         ((np.float32, np.float64, np.float32), np.float64),
@@ -276,23 +291,26 @@ class TestStagingBuffer:
         b, plan = self._batcher(bucket_edges=None if edge is FIXED else [8])
         rng = np.random.RandomState(5)
         full = _rows(rng, [shapes[0]] * self.CAP)
-        first = b._collate(full, edge, 0).batch["input"].copy()
+        b._collate(full, edge, 0)
+        kept = b._staging[("default", edge)]
+        before = kept.pair.copy()
         reqs = _rows(rng, shapes)
         with pytest.raises(ValueError, match="same shape") as theirs:
             _reference_batch(reqs, edge, self.CAP, plan)
         with pytest.raises(ValueError, match="same shape") as ours:
             b._collate(reqs, edge, 0)
         assert str(ours.value) == str(theirs.value)
-        # refused before a byte was written: the buffer's zero-padding
-        # bookkeeping still holds for the next batch
-        np.testing.assert_array_equal(
-            b._staging[("default", edge)][0], first)
+        # refused before a byte was written or the turn passed: both
+        # buffers' zero-padding bookkeeping still holds for the next batch
+        assert b._staging[("default", edge)] is kept
+        np.testing.assert_array_equal(kept.pair, before)
+        assert (kept.dirty, kept.turn) == ([self.CAP, 0], 1)
         ok = _rows(rng, [shapes[0]])
         assert (b._collate(ok, edge, 0).batch["input"].tobytes()
                 == _reference_batch(ok, edge, self.CAP, plan)["input"]
                 .tobytes())
 
-    def test_one_buffer_a_geometry_replaced_when_the_rows_change(self):
+    def test_one_pair_a_geometry_replaced_when_the_rows_change(self):
         from analytics_zoo_tpu.serving.batcher import ModelPlan
 
         clock = VirtualClock()
@@ -302,27 +320,39 @@ class TestStagingBuffer:
                             plans=plans)
         geometries = [("a", 4), ("a", 8), ("b", FIXED)]
         rng = np.random.RandomState(11)
-        kept = {}
+        pairs, last = {}, {}
         for round_ in range(3):
             for tier in (0, 1):                 # tier is no part of the key
                 for model, edge in geometries:
                     shape = (3, 2) if model == "a" else (5,)
-                    got = b._collate(_rows(rng, [shape] * 2), edge, tier,
-                                     model=model).batch["input"]
-                    first = kept.setdefault((model, edge), got)
-                    assert got is first and np.shares_memory(got, first)
+                    batch = b._collate(_rows(rng, [shape] * 2), edge, tier,
+                                       model=model)
+                    got = batch.batch["input"]
+                    pair = pairs.setdefault((model, edge),
+                                            b._staging[(model, edge)].pair)
+                    # the two were allocated together, once
+                    assert b._staging[(model, edge)].pair is pair
+                    assert batch.staging_reused == ((model, edge) in last)
+                    assert got.base is pair and len(pair) == 2
+                    if (model, edge) in last:
+                        assert not np.shares_memory(got, last[(model, edge)])
+                    last[(model, edge)] = got
                     assert len(b._staging) <= len(geometries)
         assert sorted(b._staging, key=str) == sorted(geometries, key=str)
-        assert kept[("b", FIXED)].shape == (2, 5)
-        # rows of another shape: the buffer of that geometry alone goes
+        assert pairs[("b", FIXED)].shape == (2, 2, 5)
+        # rows of another shape: the pair of that geometry alone goes
         wider = b._collate(_rows(rng, [(6,)]), FIXED, 0, model="b")
         assert wider.staging_reused is False
         assert wider.batch["input"].shape == (2, 6)
-        assert not np.shares_memory(wider.batch["input"], kept[("b", FIXED)])
-        assert b._staging[("a", 4)][0] is kept[("a", 4)]
+        assert not np.shares_memory(wider.batch["input"],
+                                    pairs[("b", FIXED)])
+        assert b._staging[("a", 4)].pair is pairs[("a", 4)]
         assert len(b._staging) == len(geometries)
-        assert b._collate(_rows(rng, [(6,)]), FIXED, 1,
-                          model="b").batch["input"] is wider.batch["input"]
+        again = b._collate(_rows(rng, [(6,)]), FIXED, 1, model="b")
+        assert again.staging_reused is True
+        assert again.batch["input"].base is wider.batch["input"].base
+        assert not np.shares_memory(again.batch["input"],
+                                    wider.batch["input"])
 
     @pytest.mark.parametrize("parallel", [False, True],
                              ids=["serial", "parallel"])
@@ -339,25 +369,26 @@ class TestStagingBuffer:
                             default_deadline_s=10.0, parallel_replicas=parallel,
                             service_time=lambda e, n, t: 0.01)
         rng = np.random.RandomState(2)
-        pictures = [rng.rand(3, 2).astype(np.float32) for _ in range(4)]
-        first = [rt.submit({"input": p}) for p in pictures[:2]]
-        rt.pump()
-        clock.advance(0.05)
-        rt.pump()
-        assert all(r.state == "done" for r in first)
-        before = [np.array(r.result) for r in first]
-        second = [rt.submit({"input": p}) for p in pictures[2:]]
-        clock.advance(0.05)
-        rt.drain()
-        assert all(r.state == "done" for r in second)
-        staging = rt.batcher._staging[("default", FIXED)][0]
-        for req, was, pic in zip(first, before, pictures):
-            np.testing.assert_array_equal(req.result, was)
-            np.testing.assert_array_equal(
-                req.result, answer({"input": pic[None]})[0])
-            assert not np.shares_memory(req.result, staging)
-        # the second batch did overwrite the rows the first one used
-        np.testing.assert_array_equal(staging[0], pictures[2])
+        pictures = [rng.rand(3, 2).astype(np.float32) for _ in range(6)]
+        rounds, before = [], []
+        for k in range(3):      # the third batch fills the first's buffer
+            rounds.append([rt.submit({"input": p})
+                           for p in pictures[2 * k:2 * k + 2]])
+            rt.pump()
+            clock.advance(0.05)
+            rt.drain()
+            assert all(r.state == "done" for r in rounds[k])
+            before.append([np.array(r.result) for r in rounds[k]])
+        staging = rt.batcher._staging[("default", FIXED)].pair
+        for k, reqs in enumerate(rounds):
+            for req, was, pic in zip(reqs, before[k], pictures[2 * k:]):
+                np.testing.assert_array_equal(req.result, was)
+                np.testing.assert_array_equal(
+                    req.result, answer({"input": pic[None]})[0])
+                assert not np.shares_memory(req.result, staging)
+        # the third batch did overwrite the rows the first one used
+        np.testing.assert_array_equal(staging[0, 0], pictures[4])
+        np.testing.assert_array_equal(staging[1, 0], pictures[2])
 
     def test_collate_stage_says_reused_and_the_registry_counts_allocs(self):
         import time
@@ -373,8 +404,9 @@ class TestStagingBuffer:
             assert rt.pump() == 1
         collates = [r for r in obs.stages(since=t0)
                     if r.name == "az/serve/collate"]
-        assert [r.attrs for r in collates] == [{"reused": False},
-                                               {"reused": True}]
+        assert [r.attrs for r in collates] == [
+            {"reused": False, "ahead": False},
+            {"reused": True, "ahead": False}]
         reg = rt.metrics.registry
         assert reg.counter("serve/staging_alloc").value == 1
         # warm() allocates off the books of no one: a warmed runtime's
@@ -392,7 +424,7 @@ class TestStagingBuffer:
 
     def test_streaming_session_through_kept_rows_matches_offline(self):
         """Chunks of shrinking lengths from two sessions share the rows
-        of one kept buffer over four batches: what ``StreamingDS2``
+        of one kept pair of buffers over four batches: what ``StreamingDS2``
         buffers across chunks must be its own (the tier hands it a view
         of the staging row), and a row's tail the zeros of THIS chunk."""
         import jax.numpy as jnp
@@ -433,7 +465,7 @@ class TestStagingBuffer:
                     final=(k == 3)))
             clock.advance(0.1)
             assert rt.pump() == 1
-            buffers.add(id(rt.batcher._staging[("ds2-stream", EDGE)][0]))
+            buffers.add(id(rt.batcher._staging[("ds2-stream", EDGE)].pair))
         assert len(buffers) == 1
         assert rt.accounting()["by_state"] == {"done": 8}
         for s, c in cuts.items():
@@ -443,6 +475,318 @@ class TestStagingBuffer:
             pieces.append(direct.flush())
             assert "".join(str(r.result) for r in reqs[s]) \
                 == "".join(pieces), s
+
+
+class _Later:
+    """A tier's answer that is not on the host yet, as a device array
+    whose program is still running: the rows are computed from the input
+    only when ``__array__`` fetches them (so an input overwritten in
+    between shows in the answer), and the fetch is stamped in ``log``."""
+
+    def __init__(self, rows, log, tag):
+        self.rows, self.log, self.tag = rows, log, tag
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("fetch", self.tag))
+        return self.rows()
+
+
+class _Placed(np.ndarray):
+    """What a test tier's ``place`` makes of a leaf: the same bytes,
+    recognisable."""
+
+
+class TestLookAhead:
+    """ISSUE 36: a tier may hand back an answer that is not on the host
+    yet; the replica fetches it, and in between the runtime assembles the
+    next due batch (and starts its transfer).  One batch ahead, one
+    thread, ``pump()`` answers all it dispatched."""
+
+    CAP = 4
+
+    def _runtime(self, log, *, later=True, fail=None, n_tiers=1,
+                 n_replicas=1, place=False, warm=False, **kw):
+        """A runtime over tiers that log ``("forward", first row's value,
+        kind of input)`` and answer with a :class:`_Later` (or, with
+        ``later=False``, with host rows); ``fail`` maps the n-th fetch
+        (after ``warm()``, if ``warm``) to the exception it raises; the
+        batcher's ``_collate`` logs ``("collate", first row's value)``
+        and checks the pair's contract."""
+        clock = VirtualClock()
+        fetches = [-1000 if warm else 0]    # nothing fails in warm()
+
+        def forward(batch):
+            x = batch["input"]
+            tag = float(np.asarray(x)[0].ravel()[0])
+            log.append(("forward", tag, type(x).__name__))
+            if not later:
+                return _fwd(batch)
+
+            def rows():
+                fetches[0] += 1
+                err = (fail or {}).get(fetches[0])
+                if err is not None:
+                    raise err
+                return _fwd({"input": np.asarray(x)})
+
+            return _Later(rows, log, tag)
+
+        def ahead(batch):
+            log.append(("place", float(batch["input"][0].ravel()[0])))
+            return {"input": batch["input"].view(_Placed)}
+
+        tiers = [ServingTier(f"t{i}", forward, speed,
+                             place=ahead if place else None)
+                 for i, speed in enumerate([1.0, 0.6, 0.45][:n_tiers])]
+        kw.setdefault("queue_capacity", 32)
+        kw.setdefault("service_time", lambda e, n, t: 0.05)
+        rt = ServingRuntime(tiers, n_replicas=n_replicas, clock=clock,
+                            max_batch=self.CAP, default_deadline_s=10.0,
+                            wedge_timeout_s=1.0, restart_s=30.0, **kw)
+        collate = rt.batcher._collate
+        owners = {}
+
+        def logged(reqs, *a, **k):
+            batch = collate(reqs, *a, **k)
+            buf = batch.batch["input"]
+            log.append(("collate", float(buf[0].ravel()[0])))
+            # a buffer is written again only once the batch that used it
+            # last was handed out
+            at = buf.__array_interface__["data"][0]
+            assert all(r.finished for r in owners.get(at, ()))
+            owners[at] = list(reqs)
+            return batch
+
+        rt.batcher._collate = logged
+        if warm:        # a geometry's first run is the program's own
+            rt.warm({"input": np.zeros((1, 2), np.float32)})
+            del log[:]
+            owners.clear()
+            fetches[0] = 0
+        return rt, clock
+
+    def _submit(self, rt, n_batches, first=1.0):
+        """``n_batches`` full batches; every row of batch k holds
+        ``first + k`` and one more in its own first cell, so a request's
+        answer names its batch AND its row."""
+        reqs = []
+        for k in range(n_batches):
+            for i in range(self.CAP):
+                x = np.full((1, 2), first + k, np.float32)
+                x[0, 1] = i
+                reqs.append(rt.submit({"input": x}))
+        return reqs
+
+    def _check_answers(self, reqs, first=1.0):
+        for j, r in enumerate(reqs):
+            assert r.state == "done", (j, r.state, r.error)
+            assert float(r.result) == first + j // self.CAP + j % self.CAP
+
+    def test_the_next_batch_is_assembled_between_the_call_and_the_fetch(
+            self):
+        import time
+
+        from analytics_zoo_tpu import obs
+
+        log = []
+        rt, _ = self._runtime(log)
+        reqs = self._submit(rt, 3)
+        t0 = time.monotonic()
+        assert rt.pump() == 3
+        assert log == [
+            ("collate", 1.0), ("forward", 1.0, "ndarray"),
+            ("collate", 2.0), ("fetch", 1.0),
+            ("forward", 2.0, "ndarray"), ("collate", 3.0), ("fetch", 2.0),
+            ("forward", 3.0, "ndarray"), ("fetch", 3.0)]
+        self._check_answers(reqs)
+        done = [r.completed_t for r in reqs]
+        assert max(done[:4]) <= min(done[4:8]) <= max(done[4:8]) \
+            <= min(done[8:])
+        records = [r for r in obs.stages(since=t0)
+                   if r.name.startswith("az/serve/")]
+        assert [r.attrs for r in records
+                if r.name == "az/serve/collate"] == [
+            {"reused": False, "ahead": False},
+            {"reused": True, "ahead": True},
+            {"reused": True, "ahead": True}]
+        # the replica's fetch is the batch's result_wait, inside its
+        # forward; the collate ahead lies inside the forward before it
+        by = {n: [r for r in records if r.name == "az/serve/" + n]
+              for n in ("forward", "collate", "result_wait", "handout")}
+        assert [len(v) for v in by.values()] == [3, 3, 3, 3]
+        for k, (fwd, wait) in enumerate(zip(by["forward"],
+                                            by["result_wait"])):
+            assert fwd.t0 <= wait.t0 <= wait.t1 <= fwd.t1
+            if k < 2:
+                ahead = by["collate"][k + 1]
+                assert fwd.t0 <= ahead.t0 <= ahead.t1 <= wait.t0
+        reg = rt.metrics.registry
+        assert reg.counter("serve/assembled_ahead").value == 2
+        assert reg.counter("serve/staging_alloc").value == 1
+        assert rt.accounting()["by_state"] == {"done": 12}
+        assert rt._held is None
+
+    def test_a_tier_whose_answer_is_on_the_host_runs_as_it_did(self):
+        import time
+
+        from analytics_zoo_tpu import obs
+
+        log = []
+        rt, _ = self._runtime(log, later=False, place=True)
+        reqs = self._submit(rt, 2)
+        t0 = time.monotonic()
+        assert rt.pump() == 2
+        assert log == [("collate", 1.0), ("forward", 1.0, "ndarray"),
+                       ("collate", 2.0), ("forward", 2.0, "ndarray")]
+        self._check_answers(reqs)
+        names = [r.name for r in sorted(obs.stages(since=t0),
+                                        key=lambda r: r.t0)
+                 if r.name.startswith("az/serve/")]
+        assert names == ["az/serve/pump"] + [
+            "az/serve/collate", "az/serve/forward", "az/serve/handout"] * 2
+        assert [r.attrs for r in obs.stages(since=t0)
+                if r.name == "az/serve/collate"] == [
+            {"reused": False, "ahead": False},
+            {"reused": True, "ahead": False}]
+        assert "serve/assembled_ahead" not in \
+            rt.metrics.registry.snapshot()["counters"]
+        assert rt.metrics.registry.counter("serve/staging_alloc").value == 1
+
+    def test_the_batch_ahead_is_placed_beside_its_host_buffer(self):
+        log = []
+        rt, _ = self._runtime(log, place=True)
+        seen = []
+        dispatch = rt._dispatch
+
+        def spy(batch):
+            seen.append((type(batch.batch["input"]).__name__,
+                         batch.placed is not None))
+            dispatch(batch)
+            assert batch.placed is None         # taken, once
+
+        rt._dispatch = spy
+        reqs = self._submit(rt, 2)
+        assert rt.pump() == 2
+        # the first batch of a pump goes up inside its own forward; the
+        # second was placed while the first ran, and its forward got the
+        # placed leaf while the batch kept the host buffer
+        assert log == [
+            ("collate", 1.0), ("forward", 1.0, "ndarray"),
+            ("collate", 2.0), ("place", 2.0), ("fetch", 1.0),
+            ("forward", 2.0, "_Placed"), ("fetch", 2.0)]
+        assert seen == [("ndarray", False), ("ndarray", True)]
+        self._check_answers(reqs)
+
+    def test_warm_fetches_an_answer_that_is_not_on_the_host(self):
+        log = []
+        rt, _ = self._runtime(log, n_tiers=2)
+        rt.warm({"input": np.full((1, 2), 7.0, np.float32)})
+        assert log == [("collate", 7.0), ("forward", 7.0, "ndarray"),
+                       ("fetch", 7.0)] * 2
+        assert rt.metrics.registry.counter("serve/staging_alloc").value == 1
+
+    def test_an_error_at_the_fetch_fails_over_once_and_the_held_batch_follows(
+            self):
+        from analytics_zoo_tpu.resilience.errors import InjectedFault
+
+        log = []
+        rt, _ = self._runtime(
+            log, n_replicas=2, place=True, warm=True,
+            fail={1: InjectedFault("device lost at the fetch")})
+        reqs = self._submit(rt, 2)
+        assert rt.pump() == 2
+        # the second forward of the first batch assembles no third, and
+        # gets the host buffer, whose bytes the batch ahead left alone
+        assert log == [
+            ("collate", 1.0), ("forward", 1.0, "ndarray"),
+            ("collate", 2.0), ("place", 2.0), ("fetch", 1.0),
+            ("forward", 1.0, "ndarray"), ("fetch", 1.0),
+            ("forward", 2.0, "_Placed"), ("fetch", 2.0)]
+        self._check_answers(reqs)
+        assert [r.attempts for r in reqs] == [2] * 4 + [1] * 4
+        kinds = [e["kind"] for e in rt.pool.events]
+        assert kinds == ["replica_fenced", "failover"]
+        assert [r.state for r in rt.pool.replicas] == ["fenced", "healthy"]
+        assert rt.metrics.redispatches == 1
+        assert rt.metrics.registry.counter(
+            "serve/assembled_ahead").value == 1
+
+    def test_with_no_replica_left_both_batches_end_in_a_terminal_state(self):
+        from analytics_zoo_tpu.resilience.errors import InjectedFault
+
+        log = []
+        rt, _ = self._runtime(
+            log, warm=True,
+            fail={1: InjectedFault("device lost at the fetch")})
+        reqs = self._submit(rt, 2)
+        rt.drain()
+        assert log == [("collate", 1.0), ("forward", 1.0, "ndarray"),
+                       ("collate", 2.0), ("fetch", 1.0)]
+        assert [r.state for r in reqs] == ["failed"] * 8
+        assert all(isinstance(r.error, ReplicaWedged) for r in reqs)
+        assert rt.accounting()["unaccounted"] == 0 and rt._held is None
+
+    @pytest.mark.parametrize("warm,error", [
+        (True, ValueError("a program error")),
+        # retryable, but at a geometry's first run: the program's own
+        (False, RuntimeError("first run of the program")),
+    ], ids=["fatal", "first_run"])
+    def test_an_exception_out_of_a_forward_leaves_the_held_batch_to_the_next_pump(
+            self, warm, error):
+        from analytics_zoo_tpu.resilience.errors import InjectedFault
+
+        log = []
+        if not warm:
+            error = InjectedFault(str(error))
+        rt, _ = self._runtime(log, warm=warm, fail={1: error})
+        reqs = self._submit(rt, 2)
+        with pytest.raises(type(error), match=str(error)):
+            rt.pump()
+        assert [r.state for r in rt.pool.replicas] == ["healthy"]
+        assert [r.state for r in reqs[4:]] == ["pending"] * 4
+        assert rt._held is not None and len(rt.queue) == 0
+        rt.drain()
+        self._check_answers(reqs[4:], first=2.0)
+        assert rt._held is None
+
+    def test_what_the_assembly_ahead_raises_the_pump_raises_after_the_answer(
+            self):
+        log = []
+        rt, _ = self._runtime(log)
+        reqs = self._submit(rt, 1)
+        odd = [rt.submit({"input": np.ones(shape, np.float32)})
+               for shape in [(1, 2)] * 3 + [(1, 3)]]
+        with pytest.raises(ValueError, match="same shape"):
+            rt.pump()
+        # as without the look-ahead: the batch in flight is answered
+        # first, and the next pump goes on
+        self._check_answers(reqs)
+        assert all(r.state == "pending" for r in odd)
+        assert rt.pump() == 0 and rt._held is None
+
+    def test_a_held_batch_rides_the_rung_of_its_assembly_and_counts_as_depth(
+            self):
+        log = []
+        rt, _ = self._runtime(
+            log, n_tiers=3, decision_every=1,
+            ladder_policy=LadderPolicy(down_after=1, up_after=99,
+                                       depth_high=1))
+        # 9 requests: while the first batch runs the second is held and
+        # ONE request is queued.  1 is no overload (depth_high is one
+        # batch), 1 + 4 held is: the ladder sees the load it saw before
+        reqs = self._submit(rt, 2) + [rt.submit(
+            {"input": np.full((1, 2), 3.0, np.float32)})]
+        assert rt.pump() == 2
+        down = rt.ladder.snapshot()["transitions"]
+        assert [(e["kind"], e["window"], e["queue_depth"])
+                for e in down] == [("tier_down", 1, 5)]
+        # the step the first batch's completion decided applies from the
+        # batch AFTER the one that was already assembled
+        assert [r.tier for r in reqs[:8]] == [0] * 8
+        assert rt.ladder.tier == 1
+        rt.drain()
+        assert reqs[8].tier == 1
+        assert rt.accounting()["by_state"] == {"done": 9}
 
 
 class TestEdfShedding:
