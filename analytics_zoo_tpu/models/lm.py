@@ -563,20 +563,24 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     page = jnp.where(live, tables[jnp.arange(B), pos // geo.page], 0)
     off = pos % geo.page
     lengths = jnp.where(live, pos + 1, 0)
-    h = params["ends"]["embed"][tokens]
+    with jax.named_scope("lm/embed"):
+        h = params["ends"]["embed"][tokens]
     kv, ik, ring = (list(cache[k]) for k in ("kv", "ik", "ring"))
     counts, selected, routed = [], [], []
     i_pool = i_full = i_slide = 0
     for layer, kind in zip(params["layers"], cfg.kinds):
         a, w = cfg.dims(kind), layer["attn"]
-        x = rms_norm(h, layer["attn_norm"], cfg.eps)
         gqa = isinstance(a, GQADims)
-        if gqa:
-            q_plain, q_rot, c = gqa_project(a, w, x, pos)
-            gate = None
-        else:
-            c_q, c, gate = latents(cfg, a, w, x, pos)
-            q_nope, q_rope = queries(a, w, c_q, pos)
+        # a sibling of the attention scopes below, never around one: the
+        # readers take the first lm/ name of an op_name (obs/names.py)
+        with jax.named_scope("lm/proj"):
+            x = rms_norm(h, layer["attn_norm"], cfg.eps)
+            if gqa:
+                q_plain, q_rot, c = gqa_project(a, w, x, pos)
+                gate = None
+            else:
+                c_q, c, gate = latents(cfg, a, w, x, pos)
+                q_nope, q_rope = queries(a, w, c_q, pos)
         if kind == FULL:
             with jax.named_scope("lm/indexer"):
                 q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
@@ -598,7 +602,8 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                 h = h + finish_attention(w, o, gate)
             i_pool, i_full = i_pool + 1, i_full + 1
         elif kind == CAUSAL:
-            kv[i_pool] = kv[i_pool].at[page, off].set(c)
+            with jax.named_scope("lm/cache_write"):
+                kv[i_pool] = kv[i_pool].at[page, off].set(c)
             with jax.named_scope("lm/gqa_paged" if gqa else "lm/mla_paged"):
                 if gqa:
                     o = att.gqa_paged(q_plain, q_rot, kv[i_pool], tables,
@@ -655,21 +660,25 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     real = jnp.arange(T) < n_valid
     page = jnp.where(real, table[pos // geo.page], 0)
     off = pos % geo.page
-    h = params["ends"]["embed"][tokens]
+    with jax.named_scope("lm/embed"):
+        h = params["ends"]["embed"][tokens]
     kv, ik, ring = (list(cache[k]) for k in ("kv", "ik", "ring"))
     W = cfg.window
     counts, selected, routed = [], [], []
     i_pool = i_full = i_slide = 0
     for layer, kind in zip(params["layers"], cfg.kinds):
         a, w = cfg.dims(kind), layer["attn"]
-        x = rms_norm(h, layer["attn_norm"], cfg.eps)
         gqa = isinstance(a, GQADims)
-        if gqa:
-            q_plain, q_rot, c = gqa_project(a, w, x, pos)
-            gate = None
-        else:
-            c_q, c, gate = latents(cfg, a, w, x, pos)
-            q_nope, q_rope = queries(a, w, c_q, pos)
+        # a sibling of the attention scopes below, never around one: the
+        # readers take the first lm/ name of an op_name (obs/names.py)
+        with jax.named_scope("lm/proj"):
+            x = rms_norm(h, layer["attn_norm"], cfg.eps)
+            if gqa:
+                q_plain, q_rot, c = gqa_project(a, w, x, pos)
+                gate = None
+            else:
+                c_q, c, gate = latents(cfg, a, w, x, pos)
+                q_nope, q_rope = queries(a, w, c_q, pos)
         if kind == FULL:
             with jax.named_scope("lm/indexer"):
                 q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
@@ -685,7 +694,8 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                 h = h + finish_attention(w, o, gate)
             i_pool, i_full = i_pool + 1, i_full + 1
         elif kind == CAUSAL:
-            kv[i_pool] = kv[i_pool].at[page, off].set(c)
+            with jax.named_scope("lm/cache_write"):
+                kv[i_pool] = kv[i_pool].at[page, off].set(c)
             with jax.named_scope("lm/gqa_paged" if gqa else "lm/mla_paged"):
                 if gqa:
                     o = att.prefill_gqa_causal(

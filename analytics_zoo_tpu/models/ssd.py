@@ -213,19 +213,30 @@ class SSDVgg(nn.Module):
     def __call__(self, x, train: bool = False):
         cfg = self.config
         priors_per_cell = num_priors_per_cell(cfg)
-        conv4_3, fc7 = VGGBase(name="vgg")(x)
-        extra = ExtraLayers(resolution=self.resolution, name="extra")(fc7)
-        sources = [NormalizeScale(channels=512, scale=20.0,
-                                  name="conv4_3_norm")(conv4_3), fc7] + extra
-        locs, confs = [], []
-        for i, (src, k) in enumerate(zip(sources, priors_per_cell)):
-            loc = nn.Conv(k * 4, (3, 3), padding=((1, 1), (1, 1)),
-                          name=f"loc_{i}")(src)
-            conf = nn.Conv(k * self.num_classes, (3, 3),
-                           padding=((1, 1), (1, 1)), name=f"conf_{i}")(src)
-            locs.append(loc.reshape(loc.shape[0], -1, 4))
-            confs.append(conf.reshape(conf.shape[0], -1, self.num_classes))
-        return jnp.concatenate(locs, axis=1), jnp.concatenate(confs, axis=1)
+        # three named sections of the compiled program, forward and
+        # backward (obs/names.py::SCOPES); the flax layer names follow
+        # the scope in an instruction's op_name
+        with jax.named_scope("ssd/base"):
+            conv4_3, fc7 = VGGBase(name="vgg")(x)
+        with jax.named_scope("ssd/extras"):
+            extra = ExtraLayers(resolution=self.resolution,
+                                name="extra")(fc7)
+        with jax.named_scope("ssd/heads"):
+            sources = [NormalizeScale(channels=512, scale=20.0,
+                                      name="conv4_3_norm")(conv4_3),
+                       fc7] + extra
+            locs, confs = [], []
+            for i, (src, k) in enumerate(zip(sources, priors_per_cell)):
+                loc = nn.Conv(k * 4, (3, 3), padding=((1, 1), (1, 1)),
+                              name=f"loc_{i}")(src)
+                conf = nn.Conv(k * self.num_classes, (3, 3),
+                               padding=((1, 1), (1, 1)),
+                               name=f"conf_{i}")(src)
+                locs.append(loc.reshape(loc.shape[0], -1, 4))
+                confs.append(conf.reshape(conf.shape[0], -1,
+                                          self.num_classes))
+            return (jnp.concatenate(locs, axis=1),
+                    jnp.concatenate(confs, axis=1))
 
 
 class SSDDetector(nn.Module):

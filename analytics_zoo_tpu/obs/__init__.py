@@ -31,7 +31,15 @@ instrumentation are the pattern sources):
   item-1 autoscaler hook;
 - :mod:`names` — :data:`CATALOG`: every registry metric name declared
   once (the ``registered-metric-names`` az-analyze rule pins usage
-  against it); :data:`STAGES`: every stage name, likewise.
+  against it); :data:`STAGES`: every stage name, likewise;
+  :data:`SCOPES`: every ``jax.named_scope`` of a hot-path device
+  program, likewise;
+- :mod:`device_scopes` — :func:`register_program` /
+  :func:`program_scopes` / :func:`dump_program_scopes` /
+  :func:`registered`: the program hands over which instructions of its
+  compiled step and serve programs stand under which named scope, so a
+  device trace (which names an operation by its HLO line alone) can be
+  read by scope.
 
 Everything but the stages runs on the injected clock
 (``utils.clock``), so drills on a ``VirtualClock`` produce
@@ -50,7 +58,11 @@ from analytics_zoo_tpu.obs.exporters import (SummaryBridge,
 from analytics_zoo_tpu.obs.recorder import DEFAULT_CAPACITY, FlightRecorder
 from analytics_zoo_tpu.obs.registry import (Counter, Gauge, MetricRegistry,
                                             ReservoirHistogram)
-from analytics_zoo_tpu.obs.names import CATALOG, STAGES
+from analytics_zoo_tpu.obs.device_scopes import (dump_program_scopes,
+                                                 program_scopes,
+                                                 register_program,
+                                                 registered)
+from analytics_zoo_tpu.obs.names import CATALOG, SCOPES, STAGES
 from analytics_zoo_tpu.obs.runmeta import run_metadata
 from analytics_zoo_tpu.obs.slo import (SLO, SloDecision, SloEvaluator,
                                        deadline_miss_slo,
@@ -117,6 +129,7 @@ __all__ = [
     "Observability",
     "ReservoirHistogram",
     "SEGMENTS",
+    "SCOPES",
     "SLO",
     "SloDecision",
     "SloEvaluator",
@@ -129,12 +142,16 @@ __all__ = [
     "attribution_rows",
     "deadline_miss_slo",
     "default_serving_slos",
+    "dump_program_scopes",
     "model_deadline_miss_slo",
     "model_shed_rate_slo",
     "model_slos",
     "dump_flight_jsonl",
     "format_critical_path",
     "p99_latency_slo",
+    "program_scopes",
+    "register_program",
+    "registered",
     "render_prometheus",
     "run_metadata",
     "shed_rate_slo",

@@ -310,6 +310,95 @@ STAGES: Dict[str, str] = {
         "finishing each request, accounting, _after_dispatch",
 }
 
+#: Every ``jax.named_scope`` of a hot-path device program, declared once
+#: with the code it brackets.  A scope is metadata of the compiled
+#: program (the ``op_name`` of its instructions): it changes no
+#: instruction and costs nothing at run time.  The device trace names an
+#: operation by its HLO line alone, so ``obs.device_scopes`` maps the
+#: instructions of a registered program to these names from its compiled
+#: text.  A scope is a SIBLING of the ones beside it, never around one:
+#: readers take the first ``lm/`` name of an ``op_name``.
+SCOPES: Dict[str, str] = {
+    "train/augment":
+        "make_train_step · the device_transform fused into the step (the "
+        "device-side augmentation)",
+    "train/update":
+        "make_train_step · everything after the gradient: loss-scale and "
+        "clip, lr_for_step, the optimizer's update, apply_updates, the "
+        "health word, the masked selects of a skipped step",
+    "ssd/base":
+        "SSDVgg · VGGBase: conv1_1 to fc7 (the flax layer names follow "
+        "the scope in an op_name: ssd/base/vgg/conv1_2)",
+    "ssd/extras":
+        "SSDVgg · ExtraLayers: conv6_1 onwards",
+    "ssd/heads":
+        "SSDVgg · conv4_3_norm, the loc_i / conf_i convolutions, their "
+        "reshapes and the two concatenations",
+    "ssd/loss_match":
+        "multibox_loss · match_priors: the IoU matrix, each prior's best "
+        "ground truth, the bipartite scatter",
+    "ssd/loss_loc":
+        "multibox_loss · smooth-L1 of the encoded deltas on the positives",
+    "ssd/loss_conf":
+        "multibox_loss · the cross-entropy: log_softmax, the gather of "
+        "the matched class's log-probability, the masked sum",
+    "ssd/loss_mine":
+        "multibox_loss · hard-negative mining: the candidates' sort (or "
+        "top_k) and the scatter of the keep mask",
+    "ssd/normalize":
+        "SSDPredictor._detect / _detect_yuv · the staging arithmetic "
+        "before the forward: uint8 to float less the pixel means, the "
+        "yuv420 reconstruction",
+    "ssd/softmax":
+        "SSDPredictor._forward_tail · jax.nn.softmax of the conf logits",
+    "ssd/detout":
+        "SSDPredictor._forward_tail · detection_output whole: the Pallas "
+        "program (or the XLA path) and whatever XLA puts round it",
+    "ssd/rescale":
+        "SSDPredictor._forward_tail · scale_detections: the boxes to the "
+        "pictures' sizes",
+    "lm/embed":
+        "decode_rows / prefill_step · the embedding look-up",
+    "lm/proj":
+        "decode_rows / prefill_step · the top of each layer: rms_norm of "
+        "the stream, then gqa_project, or latents and queries",
+    "lm/cache_write":
+        "decode_rows / prefill_step · a CAUSAL layer's write of the new "
+        "entries into the paged pool (a full layer's stands under "
+        "lm/indexer, a window layer's under its window scope)",
+    "lm/indexer":
+        "decode_rows / prefill_step · a full layer's index projections, "
+        "its cache writes and the paged index scores",
+    "lm/select":
+        "decode_rows · a full layer's top-k of the index scores and the "
+        "gather of the selected entries",
+    "lm/mla_full":
+        "decode_rows / prefill_step · a full layer's latent attention "
+        "over the selected entries, with its output projection",
+    "lm/mla_paged":
+        "decode_rows / prefill_step · a causal latent layer's attention "
+        "over the paged pool, with its output projection",
+    "lm/gqa_paged":
+        "decode_rows / prefill_step · a global grouped-query layer's "
+        "attention over the paged pool, with its output projection",
+    "lm/mla_window":
+        "decode_rows / prefill_step · a sliding latent layer: ring write, "
+        "gather, attention, output projection",
+    "lm/gqa_window":
+        "decode_rows / prefill_step · a window grouped-query layer: ring "
+        "write, gather, attention with the sink, output projection",
+    "lm/dense_mlp":
+        "feed_forward · a dense layer's gated MLP",
+    "lm/route":
+        "moe_held_experts · the router: scores, groups, top-k",
+    "lm/experts":
+        "moe_held_experts · the held experts' products",
+    "lm/shared_mlp":
+        "moe_held_experts · the shared expert's gated MLP",
+    "lm/head":
+        "head · the final norm and the vocabulary projection",
+}
+
 
 def lookup(name: str) -> bool:
     """Whether a concrete registry name is covered by the catalog —

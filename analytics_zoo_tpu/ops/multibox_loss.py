@@ -104,48 +104,60 @@ def multibox_loss(loc_pred: jax.Array, conf_logits: jax.Array,
     """
 
     def per_image(loc_p, conf_l, boxes, labels, mask):
-        matched, positive, best_iou = match_priors(priors, boxes, mask,
-                                                   param.overlap_threshold)
-        pos_f = positive.astype(jnp.float32)
-        num_pos = jnp.sum(pos_f)
+        # four named sections of the compiled step, forward and backward
+        # (obs/names.py::SCOPES)
+        with jax.named_scope("ssd/loss_match"):
+            matched, positive, best_iou = match_priors(
+                priors, boxes, mask, param.overlap_threshold)
+            pos_f = positive.astype(jnp.float32)
+            num_pos = jnp.sum(pos_f)
 
         # --- localization: smooth-L1 on encoded deltas, positives only
-        matched_boxes = boxes[matched]                        # (P,4)
-        loc_target = encode_bbox(priors, variances, matched_boxes)
-        loc_loss = jnp.sum(
-            jnp.sum(smooth_l1(loc_p - loc_target), axis=-1) * pos_f)
+        with jax.named_scope("ssd/loss_loc"):
+            matched_boxes = boxes[matched]                    # (P,4)
+            loc_target = encode_bbox(priors, variances, matched_boxes)
+            loc_loss = jnp.sum(
+                jnp.sum(smooth_l1(loc_p - loc_target), axis=-1) * pos_f)
 
         # --- confidence: CE with matched label for positives, bg for rest
-        matched_label = jnp.where(positive, labels[matched].astype(jnp.int32),
-                                  param.background_id)
-        logp = jax.nn.log_softmax(conf_l, axis=-1)            # (P,C)
-        ce = -jnp.take_along_axis(logp, matched_label[:, None], axis=1)[:, 0]
+        with jax.named_scope("ssd/loss_conf"):
+            matched_label = jnp.where(
+                positive, labels[matched].astype(jnp.int32),
+                param.background_id)
+            logp = jax.nn.log_softmax(conf_l, axis=-1)        # (P,C)
+            ce = -jnp.take_along_axis(logp, matched_label[:, None],
+                                      axis=1)[:, 0]
 
         # --- hard-negative mining (reference ``mineHardExamples:334``):
         # candidates = non-positive priors whose best gt overlap is below
         # negOverlap (near-matches are neither positive nor negative)
-        neg_cand = (~positive) & (best_iou < param.neg_overlap)
-        neg_loss = jnp.where(neg_cand, -logp[:, param.background_id], -jnp.inf)
-        num_neg = jnp.minimum(param.neg_pos_ratio * num_pos,
-                              jnp.sum(neg_cand.astype(jnp.float32)))
-        # count-exact top-num_neg selection with ONE sort + a scatter
-        # (the former double-argsort rank trick paid a second full sort
-        # for the same mask; a value-threshold variant would be cheaper
-        # still but over-selects whole tie groups — e.g. the uniform
-        # logits of a fresh model — so the count contract would break)
-        if param.mining == "topk":
-            k = min(param.mining_topk, neg_loss.shape[0])
-            _, cand_idx = jax.lax.top_k(neg_loss, k)          # desc (k,)
-            num_neg = jnp.minimum(num_neg, float(k))
-        elif param.mining == "sort":
-            cand_idx = jnp.argsort(-neg_loss)                 # desc (P,)
-        else:
-            raise ValueError(f"unknown mining mode {param.mining!r}")
-        take = jnp.arange(cand_idx.shape[0]) < num_neg
-        neg_selected = (jnp.zeros(neg_loss.shape[0], bool)
-                        .at[cand_idx].set(take)) & neg_cand
+        with jax.named_scope("ssd/loss_mine"):
+            neg_cand = (~positive) & (best_iou < param.neg_overlap)
+            neg_loss = jnp.where(neg_cand, -logp[:, param.background_id],
+                                 -jnp.inf)
+            num_neg = jnp.minimum(param.neg_pos_ratio * num_pos,
+                                  jnp.sum(neg_cand.astype(jnp.float32)))
+            # count-exact top-num_neg selection with ONE sort + a scatter
+            # (the former double-argsort rank trick paid a second full
+            # sort for the same mask; a value-threshold variant would be
+            # cheaper still but over-selects whole tie groups — e.g. the
+            # uniform logits of a fresh model — so the count contract
+            # would break)
+            if param.mining == "topk":
+                k = min(param.mining_topk, neg_loss.shape[0])
+                _, cand_idx = jax.lax.top_k(neg_loss, k)      # desc (k,)
+                num_neg = jnp.minimum(num_neg, float(k))
+            elif param.mining == "sort":
+                cand_idx = jnp.argsort(-neg_loss)             # desc (P,)
+            else:
+                raise ValueError(f"unknown mining mode {param.mining!r}")
+            take = jnp.arange(cand_idx.shape[0]) < num_neg
+            neg_selected = (jnp.zeros(neg_loss.shape[0], bool)
+                            .at[cand_idx].set(take)) & neg_cand
 
-        conf_loss = jnp.sum(ce * (pos_f + neg_selected.astype(jnp.float32)))
+        with jax.named_scope("ssd/loss_conf"):
+            conf_loss = jnp.sum(
+                ce * (pos_f + neg_selected.astype(jnp.float32)))
         return param.loc_weight * loc_loss, conf_loss, num_pos
 
     loc_l, conf_l, n_pos = jax.vmap(per_image)(

@@ -29,6 +29,7 @@ import optax
 from flax import struct
 
 from analytics_zoo_tpu.core.module import Model, accepted_kwargs
+from analytics_zoo_tpu.obs import device_scopes
 from analytics_zoo_tpu.obs.span import stage
 from analytics_zoo_tpu.parallel import mesh as mesh_lib
 from analytics_zoo_tpu.parallel.optim import (
@@ -278,58 +279,68 @@ def make_train_step(
             # passed here simply inlines during tracing.  stop_gradient
             # marks the batch constant w.r.t. params so autodiff/remat
             # never recomputes the transform in the backward pass.
-            batch = jax.lax.stop_gradient(device_transform(batch))
+            with jax.named_scope("train/augment"):
+                batch = jax.lax.stop_gradient(device_transform(batch))
         rng, new_rng = jax.random.split(jax.random.fold_in(state.rng, state.step))
         grads, new_model_state, loss = _grads(
             state.params, state.model_state, batch, rng)
-        if loss_scale != 1.0:
-            grads = jax.tree_util.tree_map(lambda g: g / loss_scale, grads)
-        gnorm = optax.global_norm(grads) if grad_clip_norm else None
-        if grad_clip_norm:
-            scale = jnp.minimum(1.0, grad_clip_norm / (gnorm + 1e-6))
-            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-        lr = optim.lr_for_step(state.step, lr_scale)
-        opt_state = _set_lr(state.opt_state, lr)
-        updates, new_opt_state = optim.tx.update(grads, opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        # everything after the gradient stands under ONE named section of
+        # the compiled step (obs/names.py::SCOPES), in two pieces: the
+        # model's and the criterion's own scopes end where _grads returns
+        with jax.named_scope("train/update"):
+            if loss_scale != 1.0:
+                grads = jax.tree_util.tree_map(lambda g: g / loss_scale,
+                                               grads)
+            gnorm = optax.global_norm(grads) if grad_clip_norm else None
+            if grad_clip_norm:
+                scale = jnp.minimum(1.0, grad_clip_norm / (gnorm + 1e-6))
+                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+            lr = optim.lr_for_step(state.step, lr_scale)
+            opt_state = _set_lr(state.opt_state, lr)
+            updates, new_opt_state = optim.tx.update(grads, opt_state,
+                                                     state.params)
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss, "lr": lr}
         if metric_fn is not None:
             metrics.update(metric_fn(batch))
         # merge: mutable apply only returns the batch_stats collection; any
         # other collection in model_state must survive untouched
         merged_model_state = {**state.model_state, **new_model_state}
-        health = None
-        if health_check or skip_unhealthy:
-            from analytics_zoo_tpu.resilience import anomaly
 
-            health = anomaly.tree_health_word(
-                loss, grads, new_params,
-                anomaly.health_sections(state.params),
-                spike_loss_above=skip_loss_above)
-            metrics["health"] = health
         def masked(keep, new, old):
             """Elementwise select: the update applies only where ``keep``."""
             return jax.tree_util.tree_map(
                 lambda n, o: jnp.where(keep, n, o), new, old)
 
-        if skip_unhealthy:
-            # anomaly-sentinel guard: ANY non-finite loss/grad/param (or
-            # a loss spike past skip_loss_above) discards the entire
-            # update — params, optimizer slots and batch stats keep their
-            # pre-step values, so a poison batch can never seed NaNs into
-            # the training state
-            keep = health == 0
-            new_params = masked(keep, new_params, state.params)
-            new_opt_state = masked(keep, new_opt_state, opt_state)
-            merged_model_state = masked(keep, merged_model_state,
-                                        state.model_state)
-        elif skip_loss_above is not None:
-            # reference guard (MultiBoxLoss.scala:546): a loss spike skips
-            # the ENTIRE update — params and optimizer state (momentum/Adam
-            # moments, counts) stay untouched, not just zeroed grads
-            keep = loss <= skip_loss_above
-            new_params = masked(keep, new_params, state.params)
-            new_opt_state = masked(keep, new_opt_state, opt_state)
+        with jax.named_scope("train/update"):
+            health = None
+            if health_check or skip_unhealthy:
+                from analytics_zoo_tpu.resilience import anomaly
+
+                health = anomaly.tree_health_word(
+                    loss, grads, new_params,
+                    anomaly.health_sections(state.params),
+                    spike_loss_above=skip_loss_above)
+                metrics["health"] = health
+            if skip_unhealthy:
+                # anomaly-sentinel guard: ANY non-finite loss/grad/param
+                # (or a loss spike past skip_loss_above) discards the
+                # entire update — params, optimizer slots and batch stats
+                # keep their pre-step values, so a poison batch can never
+                # seed NaNs into the training state
+                keep = health == 0
+                new_params = masked(keep, new_params, state.params)
+                new_opt_state = masked(keep, new_opt_state, opt_state)
+                merged_model_state = masked(keep, merged_model_state,
+                                            state.model_state)
+            elif skip_loss_above is not None:
+                # reference guard (MultiBoxLoss.scala:546): a loss spike
+                # skips the ENTIRE update — params and optimizer state
+                # (momentum/Adam moments, counts) stay untouched, not
+                # just zeroed grads
+                keep = loss <= skip_loss_above
+                new_params = masked(keep, new_params, state.params)
+                new_opt_state = masked(keep, new_opt_state, opt_state)
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
@@ -752,8 +763,12 @@ class Optimizer:
         spike = self.skip_loss_above
         if anomaly_on and self.anomaly_policy.spike_loss_above is not None:
             spike = self.anomaly_policy.spike_loss_above
+        # step program's registered name -> the first batch it was
+        # dispatched with, as shapes (obs/device_scopes.py)
+        batch_shapes: Dict[str, Any] = {}
+
         def build_step(annotate_batches=True):
-            return make_train_step(
+            step = make_train_step(
                 self.model.module, self.criterion, self.optim,
                 specs=self.specs, state=state,
                 annotate_batches=annotate_batches,
@@ -767,6 +782,20 @@ class Optimizer:
                 skip_unhealthy=anomaly_on and self.anomaly_policy.skip,
                 metric_fn=self.metric_fn,
             )
+            # how a traced run finds which instructions of this program
+            # stand under which named scope: the step and its arguments
+            # as shapes (the state is donated every step: nothing live is
+            # held), compiled again only when someone asks for the map
+            name = "train/step" if annotate_batches else "train/step_scalar"
+            state_shapes = device_scopes.abstract(state)
+
+            def program():
+                if name not in batch_shapes:
+                    raise LookupError(f"{name} dispatched no step")
+                return step, (state_shapes, batch_shapes[name], 1.0)
+
+            device_scopes.register_program(name, program)
+            return step
 
         train_step = build_step()
         # built lazily the first time a batch carries a 0-d leaf: the
@@ -905,12 +934,13 @@ class Optimizer:
                         # scalars, preserving the shard_batch contract).
                         with stage("az/train/prepare"):
                             n = _batch_size(batch)
-                            step_fn = train_step
+                            step_fn, step_name = train_step, "train/step"
                             if batch_annotated and _has_scalar_leaf(batch):
                                 if scalar_step[0] is None:
                                     scalar_step[0] = build_step(
                                         annotate_batches=False)
                                 step_fn = scalar_step[0]
+                                step_name = "train/step_scalar"
                                 dev_batch = (
                                     batch if self.prefetch
                                     else self.specs.place_batch(batch))
@@ -918,6 +948,10 @@ class Optimizer:
                                 dev_batch = (
                                     batch if (self.prefetch or jit_places)
                                     else self.specs.place_batch(batch))
+                            if step_name not in batch_shapes:
+                                # once a step program, at its first step
+                                batch_shapes[step_name] = \
+                                    device_scopes.abstract(dev_batch)
                         # device_transform is fused INSIDE train_step
                         step_span = None
                         if tracer is not None:

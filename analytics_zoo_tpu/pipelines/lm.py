@@ -350,9 +350,11 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
         books.evict(int(sid))
         note_cache()
 
-    def device_program(edge: int):
-        """``az_analyze --program`` hook: the jitted step of one edge with
-        shape-only arguments."""
+    def device_program(edge: int, rows: Optional[int] = None):
+        """``az_analyze --program`` hook, and what a runtime registers a
+        geometry (``ServingTier.device_program_for``): the jitted step of
+        one edge with shape-only arguments.  The tier's batch is its own
+        (``max_batch``), whatever ``rows`` says."""
         def thunk():
             S = jax.ShapeDtypeStruct
             i32 = jnp.int32
@@ -376,9 +378,8 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
         quality_note="paged latent cache on the device; decode is one "
                      "jitted call a batch, prefill one a chunk",
         device_program=device_program(1), evict_session=evict,
-        pads_session_rows=True)
+        pads_session_rows=True, device_program_for=device_program)
     # what the driver, the tests and the audit reach for
     tier.books, tier.registry = books, registry
-    tier.device_program_for = device_program
     tier.record_choices, tier.choices = record_choices, choices
     return [tier]

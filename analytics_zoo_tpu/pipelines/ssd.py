@@ -439,7 +439,8 @@ class SSDPredictor:
             if inputs.dtype == jnp.uint8:
                 # uint8 staging path: normalize ON DEVICE (host sends 4×
                 # fewer bytes; MatToFloats semantics, in-graph)
-                inputs = inputs.astype(jnp.float32) - means
+                with jax.named_scope("ssd/normalize"):
+                    inputs = inputs.astype(jnp.float32) - means
             return tail(variables, inputs, h, w, post)
 
         return self._serving_jit(detect, static_argnums=(4,),
@@ -454,10 +455,16 @@ class SSDPredictor:
         priors, variances = self._priors, self._variances
 
         def tail(variables, inputs, h, w, post):
+            # the model's own sections (ssd/base, ssd/extras, ssd/heads)
+            # and three more of the compiled program
+            # (obs/names.py::SCOPES)
             loc, conf = eval_step(variables, inputs)
-            probs = jax.nn.softmax(conf, axis=-1)
-            dets = detection_output(loc, probs, priors, variances, post)
-            return scale_detections(dets, h, w)
+            with jax.named_scope("ssd/softmax"):
+                probs = jax.nn.softmax(conf, axis=-1)
+            with jax.named_scope("ssd/detout"):
+                dets = detection_output(loc, probs, priors, variances, post)
+            with jax.named_scope("ssd/rescale"):
+                return scale_detections(dets, h, w)
 
         return tail
 
@@ -474,8 +481,9 @@ class SSDPredictor:
         tail = self._forward_tail
 
         def detect(variables, y, uv, h, w, post):
-            return tail(variables, yuv420_to_bgr_device(y, uv) - means,
-                        h, w, post)
+            with jax.named_scope("ssd/normalize"):
+                bgr = yuv420_to_bgr_device(y, uv) - means
+            return tail(variables, bgr, h, w, post)
 
         return self._serving_jit(detect, static_argnums=(5,),
                                  n_batch_args=4)
@@ -911,13 +919,17 @@ def ssd_serving_tiers(model: Model, param: PreProcessParam,
             return out
         return forward
 
-    def audit(pred: SSDPredictor) -> Callable[[], tuple]:
+    def audit(pred: SSDPredictor, rows: Optional[int] = None
+              ) -> Callable[[], tuple]:
         """``az_analyze --program`` hook: the tier's actual jitted
         detect program + shape-only example args (ShapeDtypeStructs —
-        the audit traces, it never dispatches)."""
+        the audit traces, it never dispatches).  ``rows``: the batch of
+        a runtime's geometry (``ServingTier.device_program_for``: the
+        program a trace of that runtime ran); the audit's own is the
+        smallest the data axis divides."""
         def device_program():
-            B = (pred.specs.data_axis_size if pred.specs is not None
-                 else 1)
+            B = rows or (pred.specs.data_axis_size
+                         if pred.specs is not None else 1)
             res = pred.param.resolution
             variables = (pred._variables if pred._variables is not None
                          else pred.model.variables)
@@ -932,16 +944,21 @@ def ssd_serving_tiers(model: Model, param: PreProcessParam,
     # the transfer ahead is offered off a mesh only: with specs= the
     # annotated program places its batch over the data axis itself
     ahead = place if specs is None else None
+
+    def tier(name: str, pred: SSDPredictor, speed: float,
+             note: str) -> ServingTier:
+        # the FIXED bucket has one edge: a geometry is its rows
+        return ServingTier(
+            name, fwd(pred), speed=speed, quality_note=note,
+            device_program=audit(pred), place=ahead,
+            device_program_for=lambda edge, rows: audit(pred, rows))
+
     return [
-        ServingTier("fp", fwd(full), speed=1.0,
-                    quality_note="full precision, full NMS top-K",
-                    device_program=audit(full), place=ahead),
-        ServingTier("int8", fwd(int8), speed=0.77,
-                    quality_note="int8 weights, fp math (mAP delta "
-                                 "+0.0001, INT8_MAP_PARITY.json)",
-                    device_program=audit(int8), place=ahead),
-        ServingTier(f"int8_topk{degraded_topk}", fwd(low), speed=0.7,
-                    quality_note=f"int8 + keep_topk={degraded_topk} "
-                                 "(fewer kept detections per image)",
-                    device_program=audit(low), place=ahead),
+        tier("fp", full, 1.0, "full precision, full NMS top-K"),
+        tier("int8", int8, 0.77,
+             "int8 weights, fp math (mAP delta +0.0001, "
+             "INT8_MAP_PARITY.json)"),
+        tier(f"int8_topk{degraded_topk}", low, 0.7,
+             f"int8 + keep_topk={degraded_topk} (fewer kept detections "
+             "per image)"),
     ]

@@ -66,6 +66,14 @@ class ServingTier:
     ``forward`` callable itself is a host closure with decode loops and
     cannot be traced).
 
+    ``device_program_for`` (optional): ``(edge, rows) -> thunk`` of the
+    same contract, for ONE geometry of a runtime's plan: the program the
+    tier dispatches for a batch of ``rows`` rows padded to ``edge`` —
+    same function, shapes, dtypes and static arguments — which is what a
+    map of a device trace's instructions has to be made from
+    (``obs/device_scopes.py``; ``device_program`` alone builds the
+    audit's smallest batch).  ``rows=None``: the tier's own batch.
+
     ``evict_session`` (streaming session tiers, ISSUE 14): drop one
     session's carry state from this tier instance's store — the
     runtime calls it on the pinned replica when a session dies without
@@ -84,6 +92,8 @@ class ServingTier:
     speed: float = 1.0
     quality_note: str = ""
     device_program: Optional[Callable[[], tuple]] = None
+    device_program_for: Optional[Callable[[Any, Optional[int]],
+                                          Callable[[], tuple]]] = None
     evict_session: Optional[Callable[[int], None]] = None
     pads_session_rows: bool = False
     place: Optional[Callable[[Dict[str, Any]], Dict[str, Any]]] = None

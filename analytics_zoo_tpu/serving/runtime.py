@@ -73,6 +73,7 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
 
 import numpy as np
 
+from analytics_zoo_tpu.obs import device_scopes
 from analytics_zoo_tpu.obs.span import stage
 from analytics_zoo_tpu.resilience.errors import (ReplicaWedged,
                                                  ServerOverloaded)
@@ -440,6 +441,7 @@ class ServingRuntime:
         #: single-model alias — the PR-5 API surface
         self.ladder = (self.ladders[DEFAULT_MODEL]
                        if not self._multi else None)
+        self._register_programs()
 
     # -- construction helpers ------------------------------------------------
     def _geometry_plan(self) -> List[Tuple[str, Any, int]]:
@@ -452,6 +454,33 @@ class ServingRuntime:
                 for tier in range(len(cfg.tiers)):
                     keys.append((name, edge, tier))
         return keys
+
+    def _register_programs(self) -> None:
+        """Note, for every geometry of the plan whose tier exposes its
+        device program, how to compile it again from shapes
+        (``obs.device_scopes``: ``serve/<model>/<tier>/<edge>``) — what a
+        traced run maps the device's operations to named scopes with.  A
+        dict store a geometry: nothing is traced or compiled here, and
+        the runtime is held weakly."""
+        for name, edge, tier in self._geometry_plan():
+            template = self.models[name].tiers[tier]
+            if template.device_program is None:
+                continue
+            device_scopes.register_program(
+                f"serve/{name}/{template.name}/{edge}",
+                device_scopes.weak_thunk(
+                    self, lambda rt, key=(name, edge, tier):
+                    rt._geometry_program(*key)))
+
+    def _geometry_program(self, model: str, edge: Any, tier: int) -> tuple:
+        """``(jitted, args, static_argnums)`` of one geometry of the
+        plan as the first replica dispatches it: the batcher's rows for
+        the model, padded to ``edge``."""
+        t = self.pool.replicas[0].tier_objs[model][tier]
+        if t.device_program_for is None:
+            return t.device_program()
+        return t.device_program_for(
+            edge, self.batcher.model_batch(model))()
 
     def _make_replica(self, rid: int) -> Replica:
         """Build one replica (also the pool's growth factory): the

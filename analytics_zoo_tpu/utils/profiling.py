@@ -7,9 +7,11 @@ throughput accumulators in ``Validator.test``.  TPU equivalents:
 - :func:`trace` — context manager around ``jax.profiler`` emitting a
   TensorBoard-viewable trace (op-level timing replaces module-level);
 - :class:`StepTimer` — host-side per-step wall-clock accumulator with the
-  Validator-style "[N] in T seconds. Throughput is …" summary;
-- ``jax.named_scope`` re-exported as :func:`named_scope` so model code can
-  label regions that show up in traces (the ``getTimes`` analogue).
+  Validator-style "[N] in T seconds. Throughput is …" summary.
+
+The ``getTimes`` analogue — device time by named section of a program — is
+``jax.named_scope`` in the model code with ``obs/device_scopes.py``'s map
+(docs/OBSERVABILITY.md, "Device scopes").
 """
 
 from __future__ import annotations
@@ -22,8 +24,6 @@ from typing import Dict, List, Optional
 import jax
 
 logger = logging.getLogger("analytics_zoo_tpu")
-
-named_scope = jax.named_scope
 
 
 @contextlib.contextmanager

@@ -524,10 +524,10 @@ class TestMetricCatalog:
         return names
 
     def test_docs_names_table_matches_the_catalog_exactly(self):
-        from analytics_zoo_tpu.obs.names import CATALOG, STAGES
+        from analytics_zoo_tpu.obs.names import CATALOG, SCOPES, STAGES
 
         doc = self._doc_names()
-        cat = set(CATALOG) | set(STAGES)
+        cat = set(CATALOG) | set(STAGES) | set(SCOPES)
         assert doc - cat == set(), \
             f"documented but undeclared: {sorted(doc - cat)}"
         assert cat - doc == set(), \
