@@ -555,7 +555,17 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     ``owner`` (n_pages,) which row owns each page (−1: none).
     → (cache, logits (B, vocab) float32, expert tokens (n_layers, held),
     choices: ``selected`` (B, topk) positions a full layer, −1 where a row
-    has fewer)."""
+    has fewer).
+
+    A full layer: the indexer scores every page once, ``select_topk``
+    names the row's ``topk`` positions (ascending), their addresses in the
+    pool come from the row's page table by a compare-and-sum
+    (``selected_addresses``: no lookup a position at a time), and ONE
+    gather a layer — the only one left between the selection and the
+    softmax — fetches the entries ``mla_selected`` attends to, reading the
+    copy once at lane-aligned widths (a Pallas program a layer) and as
+    ``mla_absorbed`` does at any other.  A slot past a row's count reads
+    the row's first entry, which the mask drops."""
     B = tokens.shape[0]
     live = slots >= 0
     slot = jnp.maximum(slots, 0)
@@ -592,12 +602,11 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
             with jax.named_scope("lm/select"):
                 idx, valid = att.select_topk(scores, lengths, cfg.topk)
                 selected.append(jnp.where(valid, idx, -1))
-                phys = tables[jnp.arange(B)[:, None], idx // geo.page] \
-                    * geo.page + idx % geo.page
+                phys = att.selected_addresses(tables, idx, geo.page)
                 chosen = kv[i_pool].reshape(
                     geo.n_pages * geo.page, -1)[phys]
             with jax.named_scope("lm/mla_full"):
-                o = att.mla_absorbed(q_nope, q_rope, chosen, valid,
+                o = att.mla_selected(q_nope, q_rope, chosen, valid,
                                      w["wkv_b"], a.nope, a.rope, a.scale)
                 h = h + finish_attention(w, o, gate)
             i_pool, i_full = i_pool + 1, i_full + 1

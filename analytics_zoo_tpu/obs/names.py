@@ -80,6 +80,12 @@ CATALOG: Dict[str, str] = {
         "in the last decode step, one causal layer (the flat list of "
         "(row, page) items is as long as the pool; the steps past the "
         "last item do nothing)",
+    "lm/selected_one_pass":
+        "gauge · full layers of the decode step program whose attention "
+        "over the selected entries is ONE Pallas program that reads a "
+        "row's gathered copy once (ops/pallas_lm_decode.py "
+        "selected_mla_decode); 0 where the widths leave it to XLA's "
+        "mla_absorbed, and in a model without full layers",
     "lm/ring_tokens":
         "gauge · entries the last decode step's live rows hold in a "
         "sliding layer's ring, one layer (sum of min(length, window)): "

@@ -3,9 +3,10 @@
 over a PAGED pool of latents — over the positions a learned sparse
 indexer selects (full layers) or over the whole context (causal layers)
 — and windowed MLA over per-session rings; rotary embedding, plain or
-YaRN-scaled (``RopeScaling``).  XLA, but for two Pallas programs it hands
-lane-aligned widths to: prefill's attention (ops/pallas_lm_prefill.py)
-and a causal layer's paged decode (ops/pallas_lm_decode.py).  At the end
+YaRN-scaled (``RopeScaling``).  XLA, but for the Pallas programs it hands
+lane-aligned widths to: prefill's attention (ops/pallas_lm_prefill.py),
+a causal layer's paged decode and a full layer's attention over its
+gathered entries (ops/pallas_lm_decode.py).  At the end
 of the file the same two reaches — the whole context out of a pool, a
 window out of a ring — for GROUPED-QUERY attention, whose cache holds real
 keys and values a KV head (``gqa_entry``), with partial rotary in pairs at
@@ -43,7 +44,11 @@ follows is a full layer's):
   uses, never by a sort: the positions over each row's ``topk``-th
   largest score, and of those equal to it the earliest that still fit,
   are packed into bits and counted out into indices (``select_topk``) —
-  and only those latents are gathered for the attention;
+  and only those latents are gathered for the attention, from addresses
+  that the row's page table gives by a compare-and-sum
+  (``selected_addresses``): an XLA gather costs a v5e 10–15 ns an INDEX
+  whatever it fetches, so the entries' own gather is the one that is left,
+  and its copy is read once (``mla_selected``);
 - **prefill** — one session's chunk of T tokens: two loops over the
   session's pages (as many as it has, not as many as the longest could
   have): the first fills the index scores, from which each query's
@@ -263,6 +268,23 @@ def rows_of(table, row):
     return jnp.sum(got.reshape(B, -1, 4, S) << shifts, 2, dtype=jnp.uint32)
 
 
+def selected_addresses(tables, idx, page: int):
+    """Where the positions ``idx`` (B, k) of B rows lie in a pool laid flat,
+    ``tables[b, idx // page] * page + idx % page`` with ``tables`` (B,
+    max_pages) the rows' page tables: each position's word of its row's
+    table by a compare-and-sum over the row's pages, exact in int32, as
+    :func:`holder` finds a run — no gather.  Looked up a position at a time
+    the 131,072 words of a step's full layer took a v5e 1.34 ms, a gather's
+    10 ns an index whatever it fetches (PERF.md §6, PR 38).  The positions
+    stand on the minor axis, so the sum runs over whole vectors and never
+    across lanes."""
+    at = (idx // page)[:, None, :]                     # (B, 1, k)
+    mine = at == jnp.arange(tables.shape[1])[None, :, None]
+    word = jnp.sum(jnp.where(mine, tables[:, :, None], 0), 1,
+                   dtype=jnp.int32)
+    return word * page + idx % page
+
+
 def select_topk(scores, lengths, k: int):
     """The ``k`` largest of each row's first ``lengths`` scores: (indices
     (B, k), valid (B, k)).  Rows shorter than ``k`` select all they have.
@@ -328,6 +350,36 @@ def mla_absorbed(q_nope, q_rope, latents, valid, wkv_b, nope: int,
     return jnp.einsum("bhr,rhv->bhv", o, wkv_b[..., nope:])
 
 
+def absorbed_queries(q_nope, q_rope, wkv_b, nope: int, entry: int):
+    """A head's query as the decode kernels take it (ops/pallas_lm_decode.py):
+    ``[q_nope W_kvb^K ; q_rope ; 0]`` against an entry's ``[c_kv ; k_r ;
+    0]`` → (B, H, entry)."""
+    B, H, _ = q_nope.shape
+    q_abs = jnp.einsum("bhn,rhn->bhr", q_nope, wkv_b[..., :nope])
+    pad = entry - q_abs.shape[-1] - q_rope.shape[-1]
+    return jnp.concatenate([q_abs, q_rope.astype(q_abs.dtype),
+                            jnp.zeros((B, H, pad), q_abs.dtype)], -1)
+
+
+def mla_selected(q_nope, q_rope, latents, valid, wkv_b, nope: int, r: int,
+                 scale: float):
+    """:func:`mla_absorbed` over the entries a full layer's rows selected
+    (arguments and result as its own).  At widths the Pallas kernel takes
+    (ops/pallas_lm_decode.py ``selected_mla_decode``) a row's gathered
+    entries are read ONCE, scores and probabilities never leave VMEM; at
+    others (a toy's) this IS ``mla_absorbed``, which reads the copy for
+    the scores, for their rotary part and for the values."""
+    rank = wkv_b.shape[0]
+    _, k, entry = latents.shape
+    if not pallas_lm_decode.supported(rank, entry, k):
+        return mla_absorbed(q_nope, q_rope, latents, valid, wkv_b, nope, r,
+                            scale)
+    o = pallas_lm_decode.selected_mla_decode(
+        absorbed_queries(q_nope, q_rope, wkv_b, nope, entry), latents, valid,
+        rank=rank, scale=scale)
+    return jnp.einsum("bhr,rhv->bhv", o, wkv_b[..., nope:])
+
+
 def mla_paged(q_nope, q_rope, kv_pool, tables, lengths, wkv_b, nope: int,
               r: int, scale: float):
     """MLA of B rows, each over ALL the entries of its own pages (a causal
@@ -338,19 +390,16 @@ def mla_paged(q_nope, q_rope, kv_pool, tables, lengths, wkv_b, nope: int,
     (ops/pallas_lm_decode.py) the pages are read where they lie; at others
     (a toy's) every row's pages are gathered for ``mla_absorbed``."""
     rank = wkv_b.shape[0]
-    B, H, _ = q_nope.shape
+    B = q_nope.shape[0]
     _, page, entry = kv_pool.shape
     if not pallas_lm_decode.supported(rank, entry, page):
         mine = kv_pool[tables].reshape(B, -1, entry)
         valid = jnp.arange(mine.shape[1])[None, :] < lengths[:, None]
         return mla_absorbed(q_nope, q_rope, mine, valid, wkv_b, nope, r,
                             scale)
-    q_abs = jnp.einsum("bhn,rhn->bhr", q_nope, wkv_b[..., :nope])
-    q = jnp.concatenate(
-        [q_abs, q_rope.astype(q_abs.dtype),
-         jnp.zeros((B, H, entry - rank - r), q_abs.dtype)], -1)
-    o = pallas_lm_decode.paged_mla_decode(q, kv_pool, tables, lengths,
-                                          rank=rank, scale=scale)
+    o = pallas_lm_decode.paged_mla_decode(
+        absorbed_queries(q_nope, q_rope, wkv_b, nope, entry), kv_pool,
+        tables, lengths, rank=rank, scale=scale)
     return jnp.einsum("bhr,rhv->bhv", o, wkv_b[..., nope:])
 
 

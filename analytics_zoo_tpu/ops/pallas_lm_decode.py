@@ -1,12 +1,15 @@
-"""Decode attention of the decoder LM's CAUSAL layers (models/lm.py) as
-ONE Pallas program a layer and step: multi-head latent attention in the
+"""Decode attention of the decoder LM (models/lm.py) as ONE Pallas program
+a layer and step.  A CAUSAL layer: multi-head latent attention in the
 absorbed form (``paged_mla_decode``) or grouped-query attention
 (``paged_gqa_decode``, at the end of the file) of B rows of any sessions,
 each over ALL of its own pages, read straight out of the paged pool
 through the rows' page tables — online softmax, nothing gathered, nothing
-of the scores in HBM.  What follows is the latent kernel's story; the
-grouped-query one shares the grid, the work items and the online softmax
-(``_accumulate``).
+of the scores in HBM.  A FULL layer, whose rows attend to the ``topk``
+entries they selected: ``selected_mla_decode`` over the gathered copy of
+those entries, read once (after the paged kernels, with why it does not
+read the pool itself).  What follows is the paged latent kernel's story;
+the grouped-query one shares the grid, the work items and the online
+softmax (``_accumulate``).
 
 A step that gathered its rows' contexts first (``pool[tables]``, the XLA
 form: ops/lm_attention.py ``mla_paged``'s fallback) would copy every
@@ -239,6 +242,83 @@ def paged_mla_decode(q, kv_pool, tables, lengths, *, rank: int, scale: float,
         functools.partial(_kernel, page=page, rank=rank, scale=scale),
         "lm_decode_mla_paged", q, kv_pool, tables, lengths, rank, rank,
         interpret)
+
+
+# ---------------------------------------------------------------------------
+# a full layer: the entries a row selected, gathered, read once
+# ---------------------------------------------------------------------------
+#
+# The selected entries are NOT read where they lie (PERF.md §6, PR 38): the
+# pool's HBM layout is ``tiled<(8,128)(2,1)>`` — two positions share every
+# 32-bit word, eight a tile — so Mosaic refuses a copy of fewer than 8
+# aligned entries, and a copy of 8 issues in 27–29 ns on a v5e however many
+# are in flight, where the XLA gather takes 15.7 ns an entry.  (From 64
+# entries a copy the same loop runs at 715 GB/s: whole pages are another
+# matter.)  So XLA gathers, once a layer, and this program keeps the copy
+# from being read twice more: ``mla_absorbed`` read it for the scores, for
+# their rotary part and for the values (alone: 1.0 ms a layer where one pass
+# takes 0.45; in the dots3 step the scope ``lm/mla_full`` went 3.03 → 1.38 ms).
+
+def _selected_kernel(q_ref, kv_ref, ok_ref, o_ref, *, rank: int,
+                     scale: float):
+    kv = kv_ref[0]                                        # (k, entry)
+    s = lax.dot_general(q_ref[0], kv, (((1,), (1,)), ((), ())),
+                        preferred_element_type=F32) * scale
+    ok = jnp.broadcast_to(ok_ref[0], s.shape) != 0        # (H, k)
+    s = jnp.where(ok, s, NEG)
+    # a row with no entry at all (a padding row) would weigh every slot
+    # alike: its probabilities are dropped, its output zeros
+    p = jnp.where(ok, jnp.exp(s - jnp.max(s, axis=1, keepdims=True)), 0.0)
+    l = jnp.sum(p, axis=1, keepdims=True)
+    o = jnp.dot(p.astype(kv.dtype), kv[:, :rank], preferred_element_type=F32)
+    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def selected_declared_vmem_bytes(H: int, k: int, entry: int, rank: int,
+                                 dtype) -> int:
+    """What :func:`selected_mla_decode` asks for: a row's queries, its
+    ``k`` entries, their mask and its output (double-buffered), the (H, k)
+    float32 scores and their probabilities."""
+    return (_vmem_bytes(H, k, entry, entry, rank, rank, dtype)
+            + 2 * vmem.padded_bytes((1, k), jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def selected_mla_decode(q, entries, valid, *, rank: int, scale: float,
+                        interpret=None):
+    """Absorbed MLA of B rows, each over its own ``k`` gathered entries.
+
+    ``q`` (B, H, entry): a head's absorbed query as
+    :func:`paged_mla_decode` takes it; ``entries`` (B, k, entry): latent,
+    rotated shared key, zeros; ``valid`` (B, k): which slots hold an entry
+    the row attends to (the others hold anything finite) → (B, H, rank) in
+    the entries' dtype, the softmax-weighted latents — float32 scores and
+    sums, probabilities in the entries' dtype, as the paged kernels have
+    them; zeros for a row with no valid slot.  A grid step is a row: its
+    entries come into VMEM once (2.6 MB at 2,048 x 640 in bfloat16) while
+    the row before is scored."""
+    B, H, entry = q.shape
+    k = entries.shape[1]
+    if entries.shape != (B, k, entry) or not supported(rank, entry, k):
+        raise ValueError(f"selected_mla_decode: q {q.shape}, entries "
+                         f"{entries.shape}, rank {rank} do not fit")
+    if interpret is None:
+        interpret = not engine.on_tpu()
+    return pl.pallas_call(
+        functools.partial(_selected_kernel, rank=rank, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), entries.dtype),
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, entry), lambda b: (b, 0, 0)),
+                  pl.BlockSpec((1, k, entry), lambda b: (b, 0, 0)),
+                  pl.BlockSpec((1, 1, k), lambda b: (b, 0, 0))],
+        out_specs=pl.BlockSpec((1, H, rank), lambda b: (b, 0, 0)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem.limit_bytes(selected_declared_vmem_bytes(
+                H, k, entry, rank, entries.dtype))),
+        name="lm_decode_mla_selected",
+        interpret=interpret,
+    )(q, entries, valid.astype(jnp.int32)[:, None, :])
 
 
 # ---------------------------------------------------------------------------

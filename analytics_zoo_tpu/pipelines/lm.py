@@ -230,6 +230,16 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
                 float(moe.max()))
 
     paged = lm.CAUSAL in cfg.kinds
+    # the full layers whose attention over the selected entries is the
+    # Pallas program that reads their copy once (``lm_attention.
+    # mla_selected`` decides on the same widths); 0: ``mla_absorbed`` runs
+    one_pass = 0
+    if cfg.n_full:
+        full = cfg.dims(lm.FULL)
+        if pallas_lm_decode.supported(full.kv_rank, full.entry,
+                                      min(cfg.topk, geo.max_len)):
+            one_pass = cfg.n_full
+    registry.gauge("lm/selected_one_pass").set(one_pass)
 
     def note_paged(lengths: np.ndarray) -> None:
         """What a causal layer's decode walked this step: the pages that

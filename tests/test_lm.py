@@ -52,9 +52,9 @@ def weights():
     return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), w)
 
 
-def serve(weights, max_batch=4, retain_requests=True, **tier_args):
+def serve(weights, max_batch=4, retain_requests=True, toy=TOY, **tier_args):
     """(runtime, the replica's tier) over the toy model."""
-    model = make_lm_model(TOY, params=weights)
+    model = make_lm_model(toy, params=weights)
     args = dict(cache_tokens=96, max_sessions=4, max_batch=max_batch,
                 page=4, max_len=48)
     args.update(tier_args)
@@ -115,6 +115,25 @@ def test_prefill_then_decode_equals_full_forward(served, weights, name,
     for pos, row in got.items():
         np.testing.assert_allclose(row, want[pos], atol=TOL, rtol=0,
                                    err_msg=f"{name}: position {pos}")
+
+
+def test_lane_aligned_full_layers_decode_through_the_one_pass_kernel():
+    """A toy whose latent is a whole lane tile and whose ``index_topk`` is
+    whole sublane tiles: its full layers' decode attention is
+    ``selected_mla_decode`` (interpreted here), the tier's gauge says so,
+    and prefill then decode still equal the full forward."""
+    toy = dict(TOY, kv_lora_rank=128, index_topk=16)
+    w = {"layers": [ref.layer_weights(SEED, toy, i) for i in range(5)],
+         "ends": ref.end_weights(SEED, toy)}
+    w = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), w)
+    rt, tier = serve(w, toy=toy)
+    toks = tokens(38, 23)
+    _, got = run_session(rt, toks, (8, 8, 4, 1, 1, 1))
+    want = np.asarray(ref.forward(toy, SEED, toks, weights=w,
+                                  blocks=BLOCKS)["logits"])
+    for pos, row in got.items():
+        np.testing.assert_allclose(row, want[pos], atol=TOL, rtol=0)
+    assert tier.registry.snapshot()["gauges"]["lm/selected_one_pass"] == 2
 
 
 def test_long_chunk_runs_as_blocks(weights, monkeypatch):
@@ -295,6 +314,7 @@ def test_final_chunk_retires_the_session(served):
     assert r.state == "done" and len(tier.books.free_pages) == before
     gauges = tier.registry.snapshot()["gauges"]
     assert gauges["lm/sessions_live"] == len(tier.books.slot_of)
+    assert gauges["lm/selected_one_pass"] == 0     # a toy's widths: XLA
 
 
 def test_admission_refuses_what_does_not_fit(weights):

@@ -138,6 +138,7 @@ def test_sessions_share_a_decode_batch_and_the_tier_counts_pages(weights):
     assert gauges["lm/paged_pages"] == 3 + 1            # 9 and 3 tokens
     assert gauges["lm/paged_grid_steps"] == pallas_lm_decode.grid_steps(
         4, 12, 25)
+    assert gauges["lm/selected_one_pass"] == 0           # no full layer
     for sid, n in zip(sids, (9, 3)):
         recorded = tier.choices[sid]
         assert [(s, k) for s, k, _ in recorded] == [(0, n - 1), (n - 1, 1)]

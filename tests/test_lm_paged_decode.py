@@ -1,7 +1,9 @@
 """The paged decode attention of the causal layers (ops/pallas_lm_decode.py)
 in interpret mode against the XLA form — every row's pages gathered, then
 ``mla_absorbed`` — on seeded pools, tables and queries; the flat list of
-(row, page) work items against a loop in plain Python."""
+(row, page) work items against a loop in plain Python.  Then the full
+layers' kernel, which reads a row's gathered selection once, against
+``mla_absorbed`` over the same copy."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -101,3 +103,84 @@ def test_work_items_are_the_rows_pages_in_order(lengths):
     # the steps past the last item repeat its blocks and do nothing
     last = want[-1][:2] if want else got[0][:2]
     assert all(g[:2] == last and g[2] == -1 for g in got[len(want):])
+
+
+# -- a full layer: the selected entries, gathered, read once (PR 38) --------
+
+K = 32                                  # slots a row: whole sublane tiles
+
+
+def selected_case(lengths, kind, seed=0, shuffle=True):
+    """A pool and tables as :func:`case`'s, and each row's selection as
+    decode makes it (``select_topk`` on seeded scores of ``kind``), its
+    addresses and the gathered entries."""
+    pool, tables, n, q_nope, q_rope, wkv_b = case(lengths, seed, shuffle)
+    rng = np.random.RandomState(seed + 1)
+    scores = rng.normal(size=(len(lengths), PAGE * MAX_PAGES))
+    if kind == "tied":                  # ties straddle the threshold
+        scores = np.round(scores)
+    if kind == "one_run":               # the first K positions win
+        scores = np.broadcast_to(-np.arange(PAGE * MAX_PAGES, dtype=float),
+                                 scores.shape)
+    idx, valid = att.select_topk(jnp.asarray(scores, jnp.float32), n, K)
+    phys = att.selected_addresses(tables, idx, PAGE)
+    chosen = pool.reshape(N_PAGES * PAGE, ENTRY)[phys]
+    return chosen, valid, idx, q_nope, q_rope, wkv_b
+
+
+SELECTED = {
+    "ragged": [5, 96, 33, 17],
+    "one_token_row": [1, 40, 1, 7],
+    "k_minus_one_k_k_plus_one": [K - 1, K, K + 1, 96],
+    "padding_rows": [0, 20, 0, 64, 0],
+    "only_padding": [0, 0, 0],
+    "one_row_all_its_pages": [96],
+}
+
+
+@pytest.mark.parametrize("kind", ["distinct", "tied", "one_run"])
+@pytest.mark.parametrize("name", sorted(SELECTED))
+def test_selected_kernel_equals_absorbed_over_the_gathered_entries(name,
+                                                                   kind):
+    lengths = SELECTED[name]
+    chosen, valid, idx, q_nope, q_rope, wkv_b = selected_case(
+        lengths, kind, len(name))
+    assert pd.supported(RANK, ENTRY, K)
+    got = np.asarray(att.mla_selected(q_nope, q_rope, chosen, valid, wkv_b,
+                                      NOPE, ROPE, 0.25))
+    want = np.asarray(att.mla_absorbed(q_nope, q_rope, chosen, valid, wkv_b,
+                                       NOPE, ROPE, 0.25))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=0)
+    assert not got[~live].any()                   # a padding row: zeros
+    if kind == "one_run":               # runs that cross a page boundary
+        for b, n in enumerate(lengths):
+            assert np.asarray(idx)[b, :min(n, K)].tolist() == \
+                list(range(min(n, K)))
+
+
+def test_selected_kernel_takes_any_mask_and_refuses_other_widths():
+    """The mask need not be a prefix, and what an invalid slot holds does
+    not matter while it is finite; widths off the lane tiles raise (and
+    ``mla_selected`` takes ``mla_absorbed`` there)."""
+    chosen, valid, _, q_nope, q_rope, wkv_b = selected_case(
+        [96, 40, 33], "distinct", 3)
+    rng = np.random.RandomState(4)
+    valid = jnp.asarray(rng.rand(3, K) < 0.5)
+    chosen = jnp.where(valid[..., None], chosen, 1e4)
+    got = att.mla_selected(q_nope, q_rope, chosen, valid, wkv_b, NOPE, ROPE,
+                           0.25)
+    want = att.mla_absorbed(q_nope, q_rope, chosen, valid, wkv_b, NOPE, ROPE,
+                            0.25)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=0)
+    q = att.absorbed_queries(q_nope, q_rope, wkv_b, NOPE, ENTRY)
+    with pytest.raises(ValueError):
+        pd.selected_mla_decode(q, chosen[:, :K - 1], valid[:, :K - 1],
+                               rank=RANK, scale=0.25)
+    narrow = att.mla_selected(q_nope, q_rope, chosen[:, :K - 1],
+                              valid[:, :K - 1], wkv_b, NOPE, ROPE, 0.25)
+    np.testing.assert_array_equal(
+        np.asarray(narrow), np.asarray(att.mla_absorbed(
+            q_nope, q_rope, chosen[:, :K - 1], valid[:, :K - 1], wkv_b,
+            NOPE, ROPE, 0.25)))

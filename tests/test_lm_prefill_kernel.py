@@ -2,9 +2,10 @@
 against the XLA loop at small lane-aligned widths in interpret mode, and
 compiled for a v5e at the published widths (no chip needed: the TPU's
 compiler is described, not attached).  The repo's other compile-for-a-v5e
-tests live here too (decode's selection, the causal layers' paged decode
-kernel and their prefill at 64 heads, fused DetectionOutput, last): only
-the one xdist worker that is given this file loads the TPU's library."""
+tests live here too (decode's selection and its addresses, the causal
+layers' paged decode kernel and their prefill at 64 heads, fused
+DetectionOutput, last): only the one xdist worker that is given this file
+loads the TPU's library."""
 
 import re
 
@@ -157,6 +158,30 @@ def test_paged_gqa_decode_kernel_compiles_for_a_v5e_at_the_published_widths(
         < 8 * (1 << 20)
 
 
+def test_selected_decode_kernel_compiles_for_a_v5e_at_the_published_widths(
+        one_chip, monkeypatch):
+    """``selected_mla_decode`` at the dots3 cell's geometry: 64 rows x 128
+    heads over 2,048 gathered entries of 640 — two buffers of 2.6 MB, the
+    (128, 2,048) float32 scores and their probabilities: Mosaic takes it
+    inside the VMEM the kernel asks for."""
+    from analytics_zoo_tpu.ops import pallas_lm_decode as pd
+    from analytics_zoo_tpu.ops import vmem
+
+    monkeypatch.setattr(pd.engine, "on_tpu", lambda: True)
+    S = lambda s, d=jnp.bfloat16: jax.ShapeDtypeStruct(      # noqa: E731
+        s, d, sharding=one_chip)
+    fn = lambda qn, qr, c, ok, w: att.mla_selected(          # noqa: E731
+        qn, qr, c, ok, w, 128, 64, 0.07)
+    compiled = jax.jit(fn).lower(
+        S((64, 128, 128)), S((64, 128, 64)), S((64, 2048, 640)),
+        S((64, 2048), jnp.bool_), S((512, 128, 256))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    declared = pd.selected_declared_vmem_bytes(128, 2048, 640, 512,
+                                               jnp.bfloat16)
+    assert 8 * (1 << 20) < declared < 10 * (1 << 20)
+    assert vmem.fits(declared)
+
+
 def test_causal_prefill_compiles_for_a_v5e_at_64_heads(one_chip,
                                                        monkeypatch):
     """A causal layer's chunk of 2,048 tokens at A.X-K1's widths through
@@ -187,6 +212,24 @@ def test_selection_compiles_for_a_v5e_with_no_sort_and_no_big_array(one_chip):
     sizes = [np.prod([int(d) for d in dims.split(",")])
              for dims in re.findall(r"\w+\[([\d,]+)\]", entry)]
     assert B * n <= max(sizes) <= B * k * 4 * att.GROUP
+
+
+def test_selected_addresses_compile_for_a_v5e_with_no_gather(one_chip):
+    """``selected_addresses`` at the LM cell's geometry (64 rows x 2,048
+    slots into tables of 136 pages): the chip's compiler leaves no gather
+    in it — the parent's lookup of a word a position took 1.34 ms a layer —
+    and the compare-and-sum stays inside a fusion (a (64, 136, 2,048)
+    array would be 71 MB; nothing is larger than the (64, 2,048) result)."""
+    B, k, max_pages = 64, 2048, 136
+    S = lambda s: jax.ShapeDtypeStruct(s, jnp.int32,         # noqa: E731
+                                       sharding=one_chip)
+    text = jax.jit(att.selected_addresses, static_argnums=2).lower(
+        S((B, max_pages)), S((B, k)), 512).compile().as_text()
+    assert not re.search(r"\bgather\(|dynamic-slice\(", text)
+    entry = text[text.index("ENTRY"):]
+    sizes = [np.prod([int(d) for d in dims.split(",")])
+             for dims in re.findall(r"\w+\[([\d,]+)\]", entry)]
+    assert max(sizes) == B * k
 
 
 @pytest.mark.parametrize("batch,n_priors,stage,keep_topk", [
