@@ -670,6 +670,28 @@ def _lm_gqa_serving(mesh) -> List[AuditProgram]:
     return _lm_tier_programs("lm-gqa", cfg, mesh)
 
 
+def _lm_ssm_serving(mesh) -> List[AuditProgram]:
+    """ISSUE 39: the same tier over a model whose every block is a
+    state-space mixer AND grouped-query attention off one norm (5 query
+    heads a KV head, rotary on every dim, muP multipliers, no experts):
+    its decode step with the recurrent and the convolution states among
+    the cache's leaves (a slot a session) and its prefill programs with
+    the chunked scan."""
+    from analytics_zoo_tpu.models import lm
+
+    cfg = lm.LMConfig(
+        d=32, kinds=(lm.CAUSAL, lm.CAUSAL), dense_layers=2,
+        full=lm.GQADims(10, 2, 8, 8, 8, 1e3), swa=None, window=0,
+        idx_heads=0, idx_dim=0, topk=0, f_dense=48, f_expert=0, f_shared=0,
+        experts=0, held=0, first_held=0, per_tok=0, route_scale=1.0,
+        vocab=40, eps=1e-5, dtype="float32",
+        ssm=lm.SSMDims(4, 8, 16, 2, 4, 8),
+        mup=lm.Multipliers(embed=1.7, key=0.3, attn_out=0.6, ssm_in=0.8,
+                           ssm=(0.7, 0.5, 0.6, 1.3, 0.9), ssm_out=0.4,
+                           mlp_gate=0.75, mlp_down=0.35, head=0.25))
+    return _lm_tier_programs("lm-ssm", cfg, mesh)
+
+
 def _lm_tier_programs(kind: str, cfg, mesh) -> List[AuditProgram]:
     from analytics_zoo_tpu.models import lm
     from analytics_zoo_tpu.parallel import pipeline_specs
@@ -773,4 +795,5 @@ def repo_audit_suite(mesh=None) -> List[AuditProgram]:
     targets += _guarded_tiers("lm", _lm_serving, mesh)
     targets += _guarded_tiers("lm-mla", _lm_mla_serving, mesh)
     targets += _guarded_tiers("lm-gqa", _lm_gqa_serving, mesh)
+    targets += _guarded_tiers("lm-ssm", _lm_ssm_serving, mesh)
     return targets

@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from analytics_zoo_tpu.ops import lm_attention as att
+from analytics_zoo_tpu.ops import pallas_ssm_decode, ssm
 from analytics_zoo_tpu.parallel.expert import moe_held_experts
 
 F32 = jnp.float32
@@ -141,6 +142,72 @@ class GQADims:
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMDims:
+    """A layer's state-space mixer (ops/ssm.py): ``heads`` heads of
+    ``head`` channels in ``groups`` groups that share ``B`` and ``C``, a
+    state of ``state`` a channel, a causal convolution ``conv`` wide in
+    front, prefill in blocks of ``chunk`` tokens."""
+    heads: int
+    head: int
+    state: int
+    groups: int
+    conv: int
+    chunk: int
+
+    @property
+    def inner(self) -> int:
+        """Channels of the gate ``z`` and of ``x``."""
+        return self.heads * self.head
+
+    @property
+    def blocks(self) -> Tuple[int, ...]:
+        """The in-projection's column blocks ``[z | x | B | C | dt]``."""
+        shared = self.groups * self.state
+        return (self.inner, self.inner, shared, shared, self.heads)
+
+    @property
+    def conv_width(self) -> int:
+        """What the convolution runs over: ``x``, ``B``, ``C``."""
+        return sum(self.blocks[1:4])
+
+    @property
+    def proj(self) -> int:
+        return sum(self.blocks)
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """The muP multipliers a config's keys give (``falcon_h1``); all 1
+    for a config that has none, and a factor of 1 is never multiplied
+    in."""
+    embed: float = 1.0            # embedding_multiplier
+    attn_in: float = 1.0          # attention_in_multiplier
+    key: float = 1.0              # key_multiplier
+    attn_out: float = 1.0         # attention_out_multiplier
+    ssm_in: float = 1.0           # ssm_in_multiplier
+    ssm: Tuple[float, ...] = (1.0,) * 5   # ssm_multipliers: z, x, B, C, dt
+    ssm_out: float = 1.0          # ssm_out_multiplier
+    mlp_gate: float = 1.0         # mlp_multipliers[0]
+    mlp_down: float = 1.0         # mlp_multipliers[1]
+    head: float = 1.0             # lm_head_multiplier
+
+    @classmethod
+    def from_dict(cls, cfg: Dict) -> "Multipliers":
+        gate, down = cfg.get("mlp_multipliers") or (1.0, 1.0)
+        one = lambda key: float(cfg.get(key) or 1.0)          # noqa: E731
+        return cls(
+            embed=one("embedding_multiplier"),
+            attn_in=one("attention_in_multiplier"),
+            key=one("key_multiplier"),
+            attn_out=one("attention_out_multiplier"),
+            ssm_in=one("ssm_in_multiplier"),
+            ssm=tuple(float(m) for m in cfg.get("ssm_multipliers")
+                      or (1.0,) * 5),
+            ssm_out=one("ssm_out_multiplier"), mlp_gate=float(gate),
+            mlp_down=float(down), head=one("lm_head_multiplier"))
+
+
+@dataclasses.dataclass(frozen=True)
 class LMConfig:
     d: int
     kinds: Tuple[str, ...]            # per layer: FULL, SLIDING or CAUSAL
@@ -168,6 +235,8 @@ class LMConfig:
     route_bias: bool = False          # topk_method: noaux_tc (a router_b)
     n_group: int = 1                  # routing groups ...
     topk_group: int = 1               # ... of which a token's experts lie in
+    ssm: Optional[SSMDims] = None     # mamba_*: a mixer beside EVERY attention
+    mup: Multipliers = Multipliers()
 
     @classmethod
     def from_dict(cls, cfg: Dict) -> "LMConfig":
@@ -178,11 +247,15 @@ class LMConfig:
         heads' sigmoid gate; ``apply_mla_qkv_lora_rescale`` → sqrt(hidden /
         rank) on the latents; ``rope_scaling`` → YaRN; ``topk_method:
         "noaux_tc"`` → the router's bias; ``n_group`` / ``topk_group`` →
-        group-limited routing."""
+        group-limited routing.  A config without ``n_routed_experts`` has
+        no experts: every layer's MLP is dense.  ``mamba_d_state`` → a
+        state-space mixer beside every layer's attention (``SSMDims``; its
+        inner width is ``mamba_d_ssm``, or ``mamba_expand`` x hidden where
+        that is null); the ``*_multiplier(s)`` keys → ``Multipliers``."""
         n = int(cfg["num_hidden_layers"])
-        share = cfg.get("expert_share") or {
-            "published_experts": cfg["n_routed_experts"], "index": 0}
-        held = int(cfg["n_routed_experts"])
+        held = int(cfg.get("n_routed_experts") or 0)
+        share = cfg.get("expert_share") or {"published_experts": held,
+                                            "index": 0}
         if cfg.get("layer_types"):
             kinds = tuple(cfg["layer_types"][:n])
         elif cfg.get("hybrid_layer_pattern"):
@@ -219,7 +292,7 @@ class LMConfig:
                 return GQADims(
                     heads=int(cfg[p + "num_attention_heads"]),
                     kv_heads=int(cfg[p + "num_key_value_heads"]), k=k,
-                    v=int(cfg[p + "v_head_dim"]),
+                    v=int(cfg.get(p + "v_head_dim") or k),
                     rotary=int(float(cfg.get("partial_rotary_factor", 1.0))
                                * k),
                     theta=float(cfg[theta]),
@@ -232,7 +305,9 @@ class LMConfig:
             raise ValueError("neither kv_lora_rank (latent attention) nor "
                              "num_key_value_heads (grouped-query) in the "
                              "config")
-        if "first_k_dense_replace" in cfg:
+        if not held:
+            dense_layers = n
+        elif "first_k_dense_replace" in cfg:
             dense_layers = int(cfg["first_k_dense_replace"])
         else:
             freq = [int(f) for f in cfg["moe_layer_freq"]][:n]
@@ -242,6 +317,23 @@ class LMConfig:
                                  f"then expert layers, got {freq}")
         route_scale = cfg.get("routed_scaling_factor")
         dtype = cfg.get("compute_dtype", "bfloat16")
+        mixer = None
+        if cfg.get("mamba_d_state"):
+            if cfg.get("attn_layer_indices") is not None \
+                    or not cfg.get("mamba_rms_norm", True) \
+                    or cfg.get("mamba_norm_before_gate"):
+                raise ValueError("state-space mixer: only one beside every "
+                                 "attention, with the gated norm after the "
+                                 "gate, is known")
+            inner = int(cfg.get("mamba_d_ssm")
+                        or cfg["mamba_expand"] * cfg["hidden_size"])
+            mixer = SSMDims(
+                heads=int(cfg["mamba_n_heads"]),
+                head=inner // int(cfg["mamba_n_heads"]),
+                state=int(cfg["mamba_d_state"]),
+                groups=int(cfg["mamba_n_groups"]),
+                conv=int(cfg["mamba_d_conv"]),
+                chunk=int(cfg["mamba_chunk_size"]))
         return cls(
             d=int(cfg["hidden_size"]), kinds=kinds,
             dense_layers=dense_layers, full=full,
@@ -251,12 +343,12 @@ class LMConfig:
             idx_dim=int(cfg["index_head_dim"]) if FULL in kinds else 0,
             topk=int(cfg["index_topk"]) if FULL in kinds else 0,
             f_dense=int(cfg["intermediate_size"]),
-            f_expert=int(cfg["moe_intermediate_size"]),
-            f_shared=int(cfg["moe_intermediate_size"])
-            * int(cfg["n_shared_experts"] or 0),
+            f_expert=int(cfg.get("moe_intermediate_size") or 0),
+            f_shared=int(cfg.get("moe_intermediate_size") or 0)
+            * int(cfg.get("n_shared_experts") or 0),
             experts=int(share["published_experts"]), held=held,
             first_held=int(share["index"]) * held,
-            per_tok=int(cfg["num_experts_per_tok"]),
+            per_tok=int(cfg.get("num_experts_per_tok") or 0),
             route_scale=1.0 if route_scale is None else float(route_scale),
             vocab=int(cfg["vocab_size"]),
             eps=float(cfg["rms_norm_eps"] if "rms_norm_eps" in cfg
@@ -266,7 +358,8 @@ class LMConfig:
             rescale=bool(cfg.get("apply_mla_qkv_lora_rescale", False)),
             route_bias=cfg.get("topk_method", "noaux_tc") == "noaux_tc",
             n_group=int(cfg.get("n_group") or 1),
-            topk_group=int(cfg.get("topk_group") or 1))
+            topk_group=int(cfg.get("topk_group") or 1),
+            ssm=mixer, mup=Multipliers.from_dict(cfg))
 
     @property
     def n_full(self) -> int:
@@ -324,6 +417,14 @@ def param_shapes(cfg: LMConfig) -> Dict:
                          "idx_k_norm_b": S(cfg.idx_dim),
                          "idx_w": S(cfg.d, cfg.idx_heads)})
         layer = {"attn_norm": S(cfg.d), "mlp_norm": S(cfg.d), "attn": attn}
+        if cfg.ssm:
+            m = cfg.ssm
+            f32 = lambda *s: jax.ShapeDtypeStruct(s, F32)     # noqa: E731
+            layer["ssm"] = {
+                "in_proj": S(cfg.d, m.proj), "conv_w": S(m.conv_width, m.conv),
+                "conv_b": S(m.conv_width), "dt_bias": f32(m.heads),
+                "A_log": f32(m.heads), "D": f32(m.heads),
+                "norm": S(m.inner), "out_proj": S(m.inner, cfg.d)}
         if i < cfg.dense_layers:
             layer["mlp"] = mlp(cfg.f_dense)
         else:
@@ -377,7 +478,8 @@ class CacheGeometry:
     """``n_pages`` pages of ``page`` tokens for the full and causal layers
     (page 0 is nobody's), ``max_pages`` pages a session at most,
     ``n_slots`` sessions (each a row of the page tables and, where there
-    are sliding layers, a ring)."""
+    are sliding layers, a ring; where there is a state-space mixer, a
+    recurrent state and a convolution's state a layer)."""
     n_pages: int
     page: int
     max_pages: int
@@ -392,16 +494,27 @@ def cache_shapes(cfg: LMConfig, geo: CacheGeometry) -> Dict:
     """One array a layer (a layer's pool is never sliced out of a stack),
     for the kinds of layer the model has: ``kv`` for the full and the
     causal layers, ``ik`` for the full ones (the indexer's keys), ``ring``
-    for the sliding ones."""
+    for the sliding ones; for a model with a state-space mixer, a layer's
+    recurrent states ``ssm`` (float32, a slot a session) and the last
+    ``conv − 1`` inputs of its convolution ``conv``."""
     dt = jnp.dtype(cfg.dtype)
     S = jax.ShapeDtypeStruct
+    m, mixers = cfg.ssm, len(cfg.kinds) if cfg.ssm else 0
     return {
         "kv": [S((geo.n_pages, geo.page, cfg.full.entry), dt)
                for _ in range(cfg.n_pools)],
         "ik": [S((geo.n_pages, geo.page, cfg.idx_dim), dt)
                for _ in range(cfg.n_full)],
         "ring": [S((geo.n_slots, cfg.window, cfg.swa.entry), dt)
-                 for _ in range(cfg.n_sliding)]}
+                 for _ in range(cfg.n_sliding)],
+        "ssm": [S((geo.n_slots, m.heads, m.head, m.state), F32)
+                for _ in range(mixers)],
+        "conv": [S((geo.n_slots, m.conv - 1, m.conv_width), dt)
+                 for _ in range(mixers)]}
+
+
+#: the cache's leaves in the order the step programs unpack them
+CACHE_KEYS = ("kv", "ik", "ring", "ssm", "conv")
 
 
 def new_cache(cfg: LMConfig, geo: CacheGeometry) -> Dict:
@@ -427,8 +540,30 @@ def layer_norm(x, w, b, eps):
             + b.astype(F32)).astype(x.dtype)
 
 
-def gated_mlp(x, w):
-    return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
+def times(x, by: float):
+    """``by · x`` through float32, rounded once; ``x`` itself at 1.  The
+    ``== 1.0`` forks here and in ``gated_mlp``, ``gqa_project`` and ``head``
+    are for one thing: a configuration without multipliers lowers to
+    the program it did before there were any (the three decoder cells'
+    step programs are compared as text with their parent's)."""
+    return x if by == 1.0 else (x.astype(F32) * by).astype(x.dtype)
+
+
+def scaled(spec: str, x, w, by):
+    """``by · einsum(x, w)``: accumulated and scaled in float32 (``by`` a
+    number or a vector over the last axis), rounded once."""
+    return (jnp.einsum(spec, x, w, preferred_element_type=F32)
+            * by).astype(x.dtype)
+
+
+def gated_mlp(x, w, gate: float = 1.0, down: float = 1.0):
+    """``down · (SiLU(gate · x W_gate) ⊙ x W_up) W_down``; without
+    multipliers the plain products."""
+    if gate == 1.0 and down == 1.0:
+        return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
+    return scaled("nf,fd->nd", jax.nn.silu(
+        scaled("nd,df->nf", x, w["w_gate"], gate)) * (x @ w["w_up"]),
+        w["w_down"], down)
 
 
 def latents(cfg: LMConfig, a: MLADims, w: Dict, x, pos):
@@ -456,13 +591,16 @@ def queries(a: MLADims, w: Dict, c_q, pos):
                                      a.scaling)
 
 
-def gqa_project(a: GQADims, w: Dict, x, pos):
+def gqa_project(a: GQADims, w: Dict, x, pos, mup: Multipliers = Multipliers()):
     """A grouped-query layer's (q_plain (N, H, k − rotary), q_rot (N, H,
     rotary) rotated, c (N, a.entry) the cache entry: the rotated keys and
     the scaled values, ops/lm_attention.py ``gqa_entry``) of normed inputs
-    ``x`` (N, d) at positions ``pos`` (N,)."""
+    ``x`` (N, d) at positions ``pos`` (N,); with multipliers, of
+    ``attn_in · x``, the keys times ``key``."""
+    x = times(x, mup.attn_in)
     q = jnp.einsum("nd,dhe->nhe", x, w["wq"])
-    k = jnp.einsum("nd,dge->nge", x, w["wk"])
+    k = jnp.einsum("nd,dge->nge", x, w["wk"]) if mup.key == 1.0 \
+        else scaled("nd,dge->nge", x, w["wk"], mup.key)
     v = (jnp.einsum("nd,dge->nge", x, w["wv"], preferred_element_type=F32)
          * a.value_scale).astype(x.dtype)
     r = a.rotary
@@ -484,12 +622,113 @@ def indexer(cfg: LMConfig, w: Dict, x, c_q, pos):
     return q, k, wt
 
 
-def finish_attention(w: Dict, o, gate):
-    """Headwise gate (if the model has one), then the output projection:
-    ``o`` (N, H, v)."""
+def finish_attention(w: Dict, o, gate, out: float = 1.0):
+    """Headwise gate (if the model has one), then the output projection
+    (times ``out``, a model's ``attention_out_multiplier``): ``o`` (N, H,
+    v)."""
     if gate is not None:
         o = o * gate[..., None].astype(o.dtype)
+    if out != 1.0:
+        return scaled("nhv,hvd->nd", o, w["wo"], out)
     return jnp.einsum("nhv,hvd->nd", o, w["wo"])
+
+
+def embed(cfg: LMConfig, ends: Dict, tokens):
+    with jax.named_scope("lm/embed"):
+        return times(ends["embed"][tokens], cfg.mup.embed)
+
+
+# -- the state-space mixer (ops/ssm.py), off the same normed input as the
+# -- attention: four sibling scopes a layer
+
+def ssm_project(cfg: LMConfig, w: Dict, x):
+    """(z (N, inner) the gate, u (N, conv_width) what the convolution
+    runs over — both in the stream's dtype — dt (N, heads) float32):
+    ``((ssm_in · x) W_in) ⊙ μ``, μ the five ``ssm_multipliers`` over their
+    column blocks, accumulated and scaled in float32."""
+    m = cfg.ssm
+    with jax.named_scope("lm/ssm_proj"):
+        by = cfg.mup.ssm_in * np.concatenate([
+            np.full(n, f, np.float32) for n, f in zip(m.blocks, cfg.mup.ssm)])
+        p = jnp.einsum("nd,dp->np", x, w["in_proj"],
+                       preferred_element_type=F32) * by
+        return (p[:, :m.inner].astype(x.dtype),
+                p[:, m.inner:m.inner + m.conv_width].astype(x.dtype),
+                p[:, m.inner + m.conv_width:])
+
+
+def ssm_split(m: SSMDims, c):
+    """The convolution's output (N, conv_width) → (x (N, H, P), B, C
+    (N, G, N_state))."""
+    n, width = c.shape[0], m.blocks[2]
+    return (c[:, :m.inner].reshape(n, m.heads, m.head),
+            c[:, m.inner:m.inner + width].reshape(n, m.groups, m.state),
+            c[:, m.inner + width:].reshape(n, m.groups, m.state))
+
+
+def ssm_finish(cfg: LMConfig, w: Dict, y, z, dtype):
+    """``ssm_out · (norm(y ⊙ SiLU(z)) W_out)``: ``y`` (N, H, P) float32."""
+    m = cfg.ssm
+    with jax.named_scope("lm/ssm_out"):
+        r = ssm.gated_norm(y.reshape(y.shape[0], m.inner), z, w["norm"],
+                           m.groups, cfg.eps).astype(dtype)
+        return scaled("ni,id->nd", r, w["out_proj"], cfg.mup.ssm_out)
+
+
+def ssm_decode(cfg: LMConfig, w: Dict, x, slots, pos, states, conv):
+    """One token of each of B rows through a layer's mixer: ``x`` (B, d)
+    the normed input, ``states`` (n_slots, H, P, N) and ``conv``
+    (n_slots, K − 1, W) the layer's session states → (M (B, d), states,
+    conv).  A row at position 0 starts from zeros; a padding row (slot
+    −1) moves nothing."""
+    m = cfg.ssm
+    z, u, dt = ssm_project(cfg, w, x)
+    live = slots >= 0
+    carried = live & (pos > 0)         # the others start from zeros
+    with jax.named_scope("lm/ssm_conv"):
+        mine = jnp.where(carried[:, None, None],
+                         conv[jnp.maximum(slots, 0)], 0)
+        c, mine = ssm.conv_step(u, mine, w["conv_w"], w["conv_b"])
+        conv = conv.at[jnp.where(live, slots, conv.shape[0])].set(
+            mine, mode="drop")
+        xs, Bm, Cm = ssm_split(m, c)
+        delta, a = ssm.step_sizes(dt, w["dt_bias"], w["A_log"])
+    with jax.named_scope("lm/ssm_update"):
+        if pallas_ssm_decode.supported(m.heads, m.head, m.state, m.groups):
+            states, y = pallas_ssm_decode.ssm_decode_update(
+                states, slots, pos, xs, delta, a, Bm, Cm)
+            y = y + w["D"][None, :, None] * xs
+        else:
+            old = jnp.where(carried[:, None, None, None],
+                            states[jnp.maximum(slots, 0)], 0.0)
+            y, new = ssm.ssd_step(xs, delta, a, Bm, Cm, w["D"], old)
+            states = states.at[jnp.where(live, slots, states.shape[0])].set(
+                new, mode="drop")
+    return ssm_finish(cfg, w, y, z, x.dtype), states, conv
+
+
+def ssm_prefill(cfg: LMConfig, w: Dict, x, slot, start, n_valid, states,
+                conv):
+    """A chunk of one session through a layer's mixer: ``x`` (T, d) of
+    which the first ``n_valid`` rows are real, the first at position
+    ``start`` (0: the session's states start from zeros) → (M (T, d),
+    states, conv), the slot's states those at the last real token (as they
+    were where nothing is real)."""
+    m = cfg.ssm
+    z, u, dt = ssm_project(cfg, w, x)
+    fresh = (start == 0) & (n_valid > 0)
+    with jax.named_scope("lm/ssm_conv"):
+        c, tail = ssm.conv_chunk(u, jnp.where(fresh, 0, conv[slot]),
+                                 w["conv_w"], w["conv_b"], n_valid)
+        conv = conv.at[slot].set(tail)
+        xs, Bm, Cm = ssm_split(m, c)
+        delta, _ = ssm.step_sizes(dt, w["dt_bias"], w["A_log"])
+    with jax.named_scope("lm/ssm_scan"):
+        y, last = ssm.ssd_chunked(
+            xs, delta, w["A_log"], Bm, Cm, w["D"],
+            jnp.where(fresh, 0.0, states[slot]), m.chunk, n_valid)
+        states = states.at[slot].set(last)
+    return ssm_finish(cfg, w, y, z, x.dtype), states, conv
 
 
 def feed_forward(cfg: LMConfig, layer: Dict, x):
@@ -498,8 +737,9 @@ def feed_forward(cfg: LMConfig, layer: Dict, x):
     routed to (``None`` for a dense layer)."""
     if "mlp" in layer:
         with jax.named_scope("lm/dense_mlp"):
-            return gated_mlp(x, layer["mlp"]), jnp.zeros((cfg.held,),
-                                                         jnp.int32), None
+            return (gated_mlp(x, layer["mlp"], cfg.mup.mlp_gate,
+                              cfg.mup.mlp_down),
+                    jnp.zeros((cfg.held,), jnp.int32), None)
     y, chosen, counts = moe_held_experts(
         x, layer["moe"], cfg.first_held, cfg.per_tok, cfg.route_scale,
         shared=cfg.f_shared > 0, n_group=cfg.n_group,
@@ -508,15 +748,20 @@ def feed_forward(cfg: LMConfig, layer: Dict, x):
 
 
 def choices(selected, routed) -> Dict:
+    """``routed`` (MoE layers, tokens, k): empty for a model of dense
+    layers alone."""
+    routed = [c for c in routed if c is not None]
     return {"selected": selected,
-            "routed": jnp.stack([c for c in routed if c is not None])}
+            "routed": jnp.stack(routed) if routed
+            else jnp.zeros((0, 0, 0), jnp.int32)}
 
 
 def head(cfg: LMConfig, ends: Dict, h):
     with jax.named_scope("lm/head"):
         x = rms_norm(h, ends["final_norm"], cfg.eps)
-        return jnp.einsum("nd,dv->nv", x, ends["head"],
-                          preferred_element_type=F32)
+        logits = jnp.einsum("nd,dv->nv", x, ends["head"],
+                            preferred_element_type=F32)
+        return logits if cfg.mup.head == 1.0 else logits * cfg.mup.head
 
 
 # ---------------------------------------------------------------------------
@@ -573,12 +818,12 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     page = jnp.where(live, tables[jnp.arange(B), pos // geo.page], 0)
     off = pos % geo.page
     lengths = jnp.where(live, pos + 1, 0)
-    with jax.named_scope("lm/embed"):
-        h = params["ends"]["embed"][tokens]
-    kv, ik, ring = (list(cache[k]) for k in ("kv", "ik", "ring"))
+    h = embed(cfg, params["ends"], tokens)
+    kv, ik, ring, states, conv = (list(cache[k]) for k in CACHE_KEYS)
     counts, selected, routed = [], [], []
     i_pool = i_full = i_slide = 0
-    for layer, kind in zip(params["layers"], cfg.kinds):
+    for i_layer, (layer, kind) in enumerate(zip(params["layers"],
+                                                cfg.kinds)):
         a, w = cfg.dims(kind), layer["attn"]
         gqa = isinstance(a, GQADims)
         # a sibling of the attention scopes below, never around one: the
@@ -586,11 +831,15 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
         with jax.named_scope("lm/proj"):
             x = rms_norm(h, layer["attn_norm"], cfg.eps)
             if gqa:
-                q_plain, q_rot, c = gqa_project(a, w, x, pos)
+                q_plain, q_rot, c = gqa_project(a, w, x, pos, cfg.mup)
                 gate = None
             else:
                 c_q, c, gate = latents(cfg, a, w, x, pos)
                 q_nope, q_rope = queries(a, w, c_q, pos)
+        if cfg.ssm:
+            mixed, states[i_layer], conv[i_layer] = ssm_decode(
+                cfg, layer["ssm"], x, slots, pos, states[i_layer],
+                conv[i_layer])
         if kind == FULL:
             with jax.named_scope("lm/indexer"):
                 q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
@@ -608,7 +857,7 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
             with jax.named_scope("lm/mla_full"):
                 o = att.mla_selected(q_nope, q_rope, chosen, valid,
                                      w["wkv_b"], a.nope, a.rope, a.scale)
-                h = h + finish_attention(w, o, gate)
+                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
             i_pool, i_full = i_pool + 1, i_full + 1
         elif kind == CAUSAL:
             with jax.named_scope("lm/cache_write"):
@@ -621,7 +870,7 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                     o = att.mla_paged(q_nope, q_rope, kv[i_pool], tables,
                                       lengths, w["wkv_b"], a.nope, a.rope,
                                       a.scale)
-                h = h + finish_attention(w, o, gate)
+                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
             i_pool += 1
         else:
             with jax.named_scope("lm/gqa_window" if gqa else "lm/mla_window"):
@@ -639,16 +888,18 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                 else:
                     o = att.mla_absorbed(q_nope, q_rope, mine, valid,
                                          w["wkv_b"], a.nope, a.rope, a.scale)
-                h = h + finish_attention(w, o, gate)
+                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
             i_slide += 1
+        if cfg.ssm:
+            h = h + mixed
         x = rms_norm(h, layer["mlp_norm"], cfg.eps)
         y, n, chosen = feed_forward(cfg, layer, x)
         h = h + y
         counts.append(n)
         routed.append(chosen)
     logits = head(cfg, params["ends"], h)
-    return ({"kv": kv, "ik": ik, "ring": ring}, logits, jnp.stack(counts),
-            choices(selected, routed))
+    return (dict(zip(CACHE_KEYS, (kv, ik, ring, states, conv))), logits,
+            jnp.stack(counts), choices(selected, routed))
 
 
 # ---------------------------------------------------------------------------
@@ -669,13 +920,13 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     real = jnp.arange(T) < n_valid
     page = jnp.where(real, table[pos // geo.page], 0)
     off = pos % geo.page
-    with jax.named_scope("lm/embed"):
-        h = params["ends"]["embed"][tokens]
-    kv, ik, ring = (list(cache[k]) for k in ("kv", "ik", "ring"))
+    h = embed(cfg, params["ends"], tokens)
+    kv, ik, ring, states, conv = (list(cache[k]) for k in CACHE_KEYS)
     W = cfg.window
     counts, selected, routed = [], [], []
     i_pool = i_full = i_slide = 0
-    for layer, kind in zip(params["layers"], cfg.kinds):
+    for i_layer, (layer, kind) in enumerate(zip(params["layers"],
+                                                cfg.kinds)):
         a, w = cfg.dims(kind), layer["attn"]
         gqa = isinstance(a, GQADims)
         # a sibling of the attention scopes below, never around one: the
@@ -683,11 +934,15 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
         with jax.named_scope("lm/proj"):
             x = rms_norm(h, layer["attn_norm"], cfg.eps)
             if gqa:
-                q_plain, q_rot, c = gqa_project(a, w, x, pos)
+                q_plain, q_rot, c = gqa_project(a, w, x, pos, cfg.mup)
                 gate = None
             else:
                 c_q, c, gate = latents(cfg, a, w, x, pos)
                 q_nope, q_rope = queries(a, w, c_q, pos)
+        if cfg.ssm:
+            mixed, states[i_layer], conv[i_layer] = ssm_prefill(
+                cfg, layer["ssm"], x, slot, start, n_valid, states[i_layer],
+                conv[i_layer])
         if kind == FULL:
             with jax.named_scope("lm/indexer"):
                 q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
@@ -700,7 +955,7 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                     a.scale, cfg.topk, pages_per_step,
                     flash=PREFILL_HEADS_PER_STEP)
                 selected.append(sets)
-                h = h + finish_attention(w, o, gate)
+                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
             i_pool, i_full = i_pool + 1, i_full + 1
         elif kind == CAUSAL:
             with jax.named_scope("lm/cache_write"):
@@ -715,7 +970,7 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                         q_nope, q_rope, kv[i_pool], table, start, n_valid,
                         w["wkv_b"], a.nope, a.rope, a.scale, pages_per_step,
                         flash=PREFILL_HEADS_PER_STEP)
-                h = h + finish_attention(w, o, gate)
+                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
             i_pool += 1
         else:
             with jax.named_scope("lm/gqa_window" if gqa else "lm/mla_window"):
@@ -731,7 +986,7 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                         jnp.concatenate([q_nope, q_rope], -1), c, prev,
                         prev_pos, start, n_valid, w["wkv_b"], a.nope, a.rope,
                         a.scale, W, min(q_block, T))
-                h = h + finish_attention(w, o, gate)
+                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
                 # the chunk's last W real tokens go into the ring; the
                 # others (overwritten within the chunk, or padding) are
                 # dropped by an index past the end
@@ -739,6 +994,8 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                 ring[i_slide] = ring[i_slide].at[
                     slot, jnp.where(keep, pos % W, W)].set(c, mode="drop")
             i_slide += 1
+        if cfg.ssm:
+            h = h + mixed
         x = rms_norm(h, layer["mlp_norm"], cfg.eps)
         y, n, chosen = feed_forward(cfg, layer, x)
         h = h + y
@@ -746,8 +1003,8 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
         routed.append(chosen)
     last = jax.lax.dynamic_slice_in_dim(h, jnp.maximum(n_valid - 1, 0), 1, 0)
     logits = head(cfg, params["ends"], last)
-    return ({"kv": kv, "ik": ik, "ring": ring}, logits, jnp.stack(counts),
-            choices(selected, routed))
+    return (dict(zip(CACHE_KEYS, (kv, ik, ring, states, conv))), logits,
+            jnp.stack(counts), choices(selected, routed))
 
 
 #: the two step programs, jitted once for the process: configuration and

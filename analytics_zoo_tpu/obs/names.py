@@ -90,6 +90,19 @@ CATALOG: Dict[str, str] = {
         "gauge · entries the last decode step's live rows hold in a "
         "sliding layer's ring, one layer (sum of min(length, window)): "
         "the windows' work beside lm/paged_pages, the paged layers'",
+    "lm/ssm_slots_live":
+        "gauge · slots whose recurrent state belongs to a live session (a "
+        "model with a state-space mixer: every layer keeps one float32 "
+        "state and one convolution state a slot), set each decode step",
+    "lm/ssm_state_bytes":
+        "gauge · bytes of float32 recurrent state the live sessions hold "
+        "over all layers: what a decode step of all of them reads once "
+        "and writes once (ops/pallas_ssm_decode.py, in place)",
+    "lm/ssm_state_starts":
+        "counter · decode rows and prefill chunks at position 0: states "
+        "that started from zeros inside the step program, whatever the "
+        "slot held before (a recycled slot is never zeroed by a program "
+        "or a transfer of its own)",
     "lm/expert_tokens/stat=*":
         "histogram · tokens a held expert of an expert layer got in one "
         "decode step: stat=mean over the held experts of the step's "
@@ -393,6 +406,25 @@ SCOPES: Dict[str, str] = {
     "lm/gqa_window":
         "decode_rows / prefill_step · a window grouped-query layer: ring "
         "write, gather, attention with the sink, output projection",
+    "lm/ssm_proj":
+        "ssm_project · a state-space mixer's in-projection off the "
+        "layer's normed input, with its multipliers: the gate z, the "
+        "convolution's input u, dt",
+    "lm/ssm_conv":
+        "ssm_decode / ssm_prefill · the causal convolution with the "
+        "session's last inputs read and written, the split into x, B, C "
+        "and the step sizes",
+    "lm/ssm_update":
+        "ssm_decode · one token's state update of every row: the Pallas "
+        "kernel lm_decode_ssm_update over the slots' array in place (the "
+        "jax.numpy step with a gather and a scatter at widths off its "
+        "tiles), D x",
+    "lm/ssm_scan":
+        "ssm_prefill · a chunk's chunked scan from the session's state "
+        "to its state at the last real token, and the slot's write",
+    "lm/ssm_out":
+        "ssm_finish · the gate, the group norm and the out-projection "
+        "with its multiplier",
     "lm/dense_mlp":
         "feed_forward · a dense layer's gated MLP",
     "lm/route":

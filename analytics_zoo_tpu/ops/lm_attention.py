@@ -680,17 +680,26 @@ def gqa_gathered(q_plain, q_rot, entries, valid, sink, G: int, dv: int,
     return jnp.einsum("bgas,bsge->bgae", p, v).reshape(B, H, dv)
 
 
-def gqa_block_queries(q_plain, q_rot, G: int):
-    """The paged kernel's queries: (B, H, G (dk − r) + G r), a head's
+def gqa_block_queries(q_plain, q_rot, G: int, rows: int = 1):
+    """The paged kernel's queries: (B, H', G (dk − r) + G r), a head's
     dims standing against the keys of ITS KV head in an entry's ``[plain |
-    rotary]`` columns and zeros against the others'."""
+    rotary]`` columns and zeros against the others'.  A KV head's query
+    heads are padded with rows of zeros to a multiple of ``rows`` (the
+    kernel cuts a KV head's heads out on sublane tiles: 5 heads stand in 8
+    rows, and nobody reads the other 3), so H' = G · ceil(H / G / rows) ·
+    rows; a key with no unrotated part has no ``plain`` columns."""
     B, H, _ = q_plain.shape
-    own = jnp.eye(G, dtype=q_plain.dtype)
+    own = jnp.eye(G, dtype=q_rot.dtype)
+    pad = -(H // G) % rows
 
     def spread(q):
-        return jnp.einsum("bgae,gk->bgake", q.reshape(B, G, H // G, -1),
-                          own).reshape(B, H, -1)
-    return jnp.concatenate([spread(q_plain), spread(q_rot)], -1)
+        q = q.reshape(B, G, H // G, -1)
+        if pad:
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        return jnp.einsum("bgae,gk->bgake", q, own).reshape(
+            B, H + G * pad, -1)
+    return jnp.concatenate([spread(q) for q in (q_plain, q_rot)
+                            if q.shape[-1]], -1)
 
 
 def gqa_paged(q_plain, q_rot, kv_pool, tables, lengths, G: int, dv: int,
@@ -707,9 +716,12 @@ def gqa_paged(q_plain, q_rot, kv_pool, tables, lengths, G: int, dv: int,
         mine = kv_pool[tables].reshape(B, -1, entry)
         valid = jnp.arange(mine.shape[1])[None, :] < lengths[:, None]
         return gqa_gathered(q_plain, q_rot, mine, valid, None, G, dv, scale)
-    return pallas_lm_decode.paged_gqa_decode(
-        gqa_block_queries(q_plain, q_rot, G), kv_pool, tables, lengths,
-        kv_heads=G, v=dv, scale=scale)
+    o = pallas_lm_decode.paged_gqa_decode(
+        gqa_block_queries(q_plain, q_rot, G, pallas_lm_decode.SUBLANES),
+        kv_pool, tables, lengths, kv_heads=G, v=dv, scale=scale)
+    if o.shape[1] != H:       # the rows of zeros that padded a KV head's heads
+        o = o.reshape(B, G, -1, dv)[:, :, :H // G].reshape(B, H, dv)
+    return o
 
 
 def prefill_gqa_causal(q_plain, q_rot, kv_pool, table, start, n_valid,

@@ -55,6 +55,7 @@ from analytics_zoo_tpu.utils import engine
 F32 = jnp.float32
 NEG = -1e30
 LANES = 128
+SUBLANES = 8
 
 
 def supported(rank: int, entry: int, page: int) -> bool:
@@ -329,11 +330,11 @@ def gqa_supported(kv_heads: int, k: int, v: int, heads: int,
                   page: int) -> bool:
     """Whether :func:`paged_gqa_decode` takes these widths: the entry's
     keys ``[plain | rotary]`` of all KV heads end on a lane tile, a KV
-    head's values are whole lane tiles, a KV head's query heads whole
-    sublane tiles, a page whole sublane tiles."""
+    head's values are whole lane tiles, a page whole sublane tiles, and
+    every KV head has as many query heads (the caller pads them to whole
+    sublane tiles: ops/lm_attention.py ``gqa_block_queries``)."""
     return (kv_heads * k % LANES == 0 and v % LANES == 0
-            and heads % kv_heads == 0 and heads // kv_heads % 8 == 0
-            and page % 16 == 0)
+            and heads % kv_heads == 0 and page % 16 == 0)
 
 
 def gqa_declared_vmem_bytes(H: int, page: int, keys: int, values: int,
@@ -366,6 +367,7 @@ def paged_gqa_decode(q, kv_pool, tables, lengths, *, kv_heads: int, v: int,
     _, page, entry = kv_pool.shape
     values = kv_heads * v
     if keys + values != entry or keys % kv_heads \
+            or H // kv_heads % SUBLANES \
             or not gqa_supported(kv_heads, keys // kv_heads, v, H, page):
         raise ValueError(f"paged_gqa_decode: q {q.shape}, pool "
                          f"{kv_pool.shape}, {kv_heads} KV heads of {v} "

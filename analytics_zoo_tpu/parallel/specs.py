@@ -379,7 +379,12 @@ def _lm_specs(mesh: Mesh) -> SpecSet:
     without a router bias, headwise gates or a shared expert has fewer
     leaves; a grouped-query model's attention has ``wq``, ``wk``, ``wv``
     and ``sink`` for the latent one's leaves — replicated like them:
-    attention is data-parallel over the sessions)."""
+    attention is data-parallel over the sessions; a model with a
+    state-space mixer has an ``ssm`` group a layer — ``in_proj``,
+    ``conv_w``, ``conv_b``, ``dt_bias``, ``A_log``, ``D``, ``norm``,
+    ``out_proj`` — replicated too, and no ``moe`` at all; its sessions'
+    recurrent and convolution states are cache leaves of the replica
+    that serves them)."""
     from analytics_zoo_tpu.parallel import tensor as tensor_lib
     from analytics_zoo_tpu.parallel.expert import EXPERT_AXIS
 

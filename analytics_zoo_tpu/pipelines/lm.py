@@ -32,6 +32,22 @@ killed or closed session, the tier itself after a ``final`` chunk — and
 nothing of the session is read again: a page's stale tail is masked by
 its next owner's length, a ring's by its positions.
 
+**A recurrent state a session.**  A model with a state-space mixer
+(models/lm.py ``SSMDims``) keeps, beside the pages, a float32 state and
+the convolution's last inputs a layer in the session's SLOT
+(``cache["ssm"]``, ``cache["conv"]``: ``n_slots`` of each).  Unlike a ring
+such a state is a running sum, valid by nothing but its history: the slot
+of a new session has to start from zeros, a padding row or a padded
+position must not advance it, and nothing can be rolled back.  The books
+stay as they are — a slot's state dies with the slot — and the step
+programs take a token at position 0 as the start of a session: its state
+in is zeros whatever the slot held (counter ``lm/ssm_state_starts``), so
+a recycled slot needs no zeroing program.  ``tier.record_state(ids)``
+makes the tier keep those sessions' states when they leave;
+``tier.state_of(id)`` hands over a session's states of every layer as
+they stand (fetched when asked; of a recorded session that has left, as
+they stood then).  Nothing is fetched for a session nobody asked about.
+
 **One jitted call a batch.**  A batch at edge 1 (decode) is ONE call of
 ``decode_step`` over all ``max_batch`` rows, whatever sessions they belong
 to.  A batch at a prefill edge is one call of ``prefill_step`` a row (a
@@ -203,6 +219,8 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
     state = {"cache": None}
     recorded: set = set()
     choices: Dict[int, List] = {}
+    state_kept: set = set()
+    states_left: Dict[int, List[np.ndarray]] = {}
 
     def record_choices(session_ids) -> None:
         recorded.clear()
@@ -220,6 +238,33 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
         if state["cache"] is None:
             state["cache"] = lm.new_cache(cfg, geo)
         return state["cache"]
+
+    def record_state(session_ids) -> None:
+        state_kept.clear()
+        state_kept.update(int(s) for s in session_ids)
+
+    def state_of(sid: int) -> List[np.ndarray]:
+        """A layer's (heads, head, state) float32 recurrent state of
+        session ``sid``, every layer's, as they stand."""
+        slot = books.slot_of.get(int(sid))
+        if slot is None:
+            return states_left[int(sid)]
+        return [np.asarray(s[slot]) for s in cache()["ssm"]]
+
+    def release(sid: int) -> None:
+        """The session's pages and slot back to the pool (a recorded
+        session's recurrent states are kept first)."""
+        if sid in state_kept and sid in books.slot_of:
+            states_left[sid] = state_of(sid)
+        books.evict(sid)
+
+    def note_states(starts: int) -> None:
+        m = cfg.ssm
+        live = len(books.slot_of)
+        registry.gauge("lm/ssm_slots_live").set(live)
+        registry.gauge("lm/ssm_state_bytes").set(
+            live * len(cfg.kinds) * m.heads * m.head * m.state * 4)
+        registry.counter("lm/ssm_state_starts").inc(starts)
 
     def note_experts(counts: np.ndarray) -> None:
         moe = counts[cfg.dense_layers:]
@@ -290,6 +335,8 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
                 keep_choices(int(sessions[i]), int(pos[i]), 1, chosen,
                              slice(i, i + 1))
         note_experts(counts)
+        if cfg.ssm:
+            note_states(int((live & (pos == 0)).sum()))
         if paged:
             note_paged(np.where(live, pos + 1, 0))
         if cfg.n_sliding:
@@ -333,6 +380,8 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
                                  jax.device_get(chosen), slice(0, n_call),
                                  -(-(start + lo + n_call) // 8))
             outs.append(logits)
+        if cfg.ssm:
+            note_states(sum(start == 0 and n > 0 for _, _, start, n in plan))
         with stage("az/serve/result_wait"):
             outs = [np.asarray(o)[0] for o in outs]
         answers = [np.zeros(cfg.vocab, np.float32)] * len(sessions)
@@ -352,12 +401,12 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
                 answers = run_prefill(ids, np.asarray(batch["n_tokens"]),
                                       sessions)
             for sid in sessions[np.asarray(batch["final"]) > 0]:
-                books.evict(int(sid))
+                release(int(sid))
             note_cache()
         return answers
 
     def evict(sid: int) -> None:
-        books.evict(int(sid))
+        release(int(sid))
         note_cache()
 
     def device_program(edge: int, rows: Optional[int] = None):
@@ -392,4 +441,5 @@ def lm_serving_tiers(model: LMModel, cache_tokens: int = 1 << 16,
     # what the driver, the tests and the audit reach for
     tier.books, tier.registry = books, registry
     tier.record_choices, tier.choices = record_choices, choices
+    tier.record_state, tier.state_of = record_state, state_of
     return [tier]

@@ -561,6 +561,11 @@ class TestRepoClean:
         # and rings of two widths, sinks, no shared expert)
         assert {"lm-gqa/serve:bf16", "lm-gqa/serve:prefill4",
                 "lm-gqa/serve:prefill8"} <= names
+        # ISSUE 39: and over a model with a state-space mixer beside
+        # every attention (a recurrent state a session among the
+        # cache's leaves, no experts)
+        assert {"lm-ssm/serve:bf16", "lm-ssm/serve:prefill4",
+                "lm-ssm/serve:prefill8"} <= names
         assert {"ssd/serve:fp", "ssd/serve:int8"} <= names
         # ISSUE 13: the persistent-RNN TRAIN program (pallas engine,
         # transposed persistent backward) is audited alongside the

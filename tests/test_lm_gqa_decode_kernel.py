@@ -2,7 +2,9 @@
 (ops/pallas_lm_decode.py ``paged_gqa_decode``) in interpret mode against
 the XLA form — every row's pages gathered, then ``gqa_gathered`` — and
 against plain softmax attention a head, on seeded pools, tables and
-queries: ragged rows, padding rows, one page, every page a row may hold."""
+queries: ragged rows, padding rows, one page, every page a row may hold;
+since PR 39 also at 5 query heads a KV head (padded to the kernel's 8
+rows and cut off after it) and at keys with no unrotated part."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -17,11 +19,12 @@ N_PAGES, MAX_PAGES = 24, 6
 SCALE = (PLAIN + ROT) ** -0.5
 
 
-def case(lengths, seed=0, shuffle=True):
+def case(lengths, seed=0, shuffle=True, H=H, G=G, PLAIN=PLAIN, ROT=ROT):
     """Pool, tables (pages out of order unless told), queries."""
     rng = np.random.RandomState(seed)
     B = len(lengths)
-    pool = rng.normal(size=(N_PAGES, PAGE, ENTRY)).astype(np.float32)
+    pool = rng.normal(size=(N_PAGES, PAGE, G * (PLAIN + ROT + DV))
+                      ).astype(np.float32)
     free = list(range(1, N_PAGES))
     if shuffle:
         rng.shuffle(free)
@@ -62,6 +65,34 @@ def test_kernel_equals_gather_then_attend(name, shuffle, monkeypatch):
     assert not np.asarray(got)[~live].any()       # a padding row: zeros
 
 
+#: (heads, KV heads, unrotated dims, rotated dims): 5 heads a KV head as
+#: Falcon-H1 has them, with MiMo's split key and with a key that is rotary
+#: throughout; 3 a KV head (one tile short by five rows); 16 with no plain
+SHAPES = {"5_heads_a_kv_head": (10, 2, 64, 64),
+          "5_heads_rotary_throughout": (20, 4, 0, 128),
+          "3_heads_a_kv_head": (6, 2, 0, 128),
+          "16_heads_rotary_throughout": (16, 1, 0, 128)}
+
+
+@pytest.mark.parametrize("name", ["ragged", "padding_rows", "max_pages"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_kernel_takes_any_heads_a_kv_head_and_keys_without_a_plain_part(
+        name, shape, monkeypatch):
+    h, g, plain, rot = SHAPES[shape]
+    q_plain, q_rot, pool, tables, n = case(CASES[name], len(name), True, h,
+                                           g, plain, rot)
+    scale = (plain + rot) ** -0.5
+    assert pd.gqa_supported(g, plain + rot, DV, h, PAGE)
+    got = att.gqa_paged(q_plain, q_rot, pool, tables, n, g, DV, scale)
+    assert got.shape == (len(CASES[name]), h, DV)
+    monkeypatch.setattr(pd, "gqa_supported", lambda *a: False)
+    want = att.gqa_paged(q_plain, q_rot, pool, tables, n, g, DV, scale)
+    live = np.asarray(n) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=0)
+    assert not np.asarray(got)[~live].any()
+
+
 def test_gathered_form_is_plain_attention_a_head():
     """Head a against KV head a // (H / G), its key the entry's unrotated
     and rotated dims of that KV head, a sink column where there is one."""
@@ -92,7 +123,9 @@ def test_gathered_form_is_plain_attention_a_head():
 def test_widths_the_kernel_takes():
     assert pd.gqa_supported(4, 192, 128, 64, 512)       # the published
     assert not pd.gqa_supported(2, 12, 8, 4, 4)         # the toy's
-    assert not pd.gqa_supported(4, 192, 128, 16, 512)   # 4 heads a KV head
+    assert pd.gqa_supported(4, 128, 128, 20, 256)       # Falcon-H1's: 5 a KV
+    assert pd.gqa_supported(4, 192, 128, 16, 512)       # 4 heads a KV head
+    assert not pd.gqa_supported(4, 192, 128, 18, 512)   # heads not whole KVs
     assert not pd.gqa_supported(4, 192, 96, 64, 512)    # values off a tile
     q = jnp.zeros((2, 64, 768))
     with pytest.raises(ValueError, match="do not fit"):
@@ -100,6 +133,26 @@ def test_widths_the_kernel_takes():
                             jnp.zeros((2, 3), jnp.int32),
                             jnp.zeros((2,), jnp.int32), kv_heads=4, v=128,
                             scale=1.0)
+
+
+def test_block_queries_pad_a_kv_head_s_heads_to_whole_tiles():
+    """5 heads a KV head stand in 8 rows, the last 3 of zeros; a key with
+    no unrotated part has no plain columns; the kernel itself refuses
+    heads that are not whole tiles."""
+    q_plain, q_rot, *_ = case([3], 2, True, 10, 2, 0, 128)
+    q = np.asarray(att.gqa_block_queries(q_plain, q_rot, 2, 8))
+    assert q.shape == (1, 16, 2 * 128)
+    for g in range(2):
+        for j in range(8):
+            want = np.zeros(256, np.float32)
+            if j < 5:
+                want[g * 128:(g + 1) * 128] = np.asarray(q_rot)[0, g * 5 + j]
+            np.testing.assert_array_equal(q[0, g * 8 + j], want)
+    with pytest.raises(ValueError, match="do not fit"):
+        pd.paged_gqa_decode(
+            jnp.asarray(att.gqa_block_queries(q_plain, q_rot, 2)),
+            jnp.zeros((5, 16, 512)), jnp.zeros((1, 3), jnp.int32),
+            jnp.zeros((1,), jnp.int32), kv_heads=2, v=128, scale=1.0)
 
 
 def test_block_queries_stand_against_their_own_kv_head():

@@ -158,6 +158,55 @@ def test_paged_gqa_decode_kernel_compiles_for_a_v5e_at_the_published_widths(
         < 8 * (1 << 20)
 
 
+def test_paged_gqa_decode_kernel_compiles_for_a_v5e_at_5_heads_a_kv_head(
+        one_chip, monkeypatch):
+    """``gqa_paged`` at the Falcon-H1 cell's geometry: 128 rows x 20 heads
+    on 4 KV heads (5 a KV head, padded to 8 rows for the kernel and cut
+    off after it), keys with NO unrotated part (rotary = k = 128), a pool
+    of 1,476 pages of 256 x 1,024, tables of 21 pages."""
+    from analytics_zoo_tpu.ops import pallas_lm_decode as pd
+
+    monkeypatch.setattr(pd.engine, "on_tpu", lambda: True)
+    S = lambda s, d=jnp.bfloat16: jax.ShapeDtypeStruct(      # noqa: E731
+        s, d, sharding=one_chip)
+    fn = lambda qp, qr, pool, tables, n: att.gqa_paged(      # noqa: E731
+        qp, qr, pool, tables, n, 4, 128, 128 ** -0.5)
+    lowered = jax.jit(fn).lower(
+        S((128, 20, 0)), S((128, 20, 128)), S((1476, 256, 1024)),
+        S((128, 21), jnp.int32), S((128,), jnp.int32))
+    assert lowered.out_info.shape == (128, 20, 128)
+    text = lowered.compile().as_text()
+    # the page tables' words are gathered (work_items), never a page
+    assert "tpu_custom_call" in text \
+        and not re.search(r"bf16\[[\d,]+\]\S* gather\(", text)
+    assert pd.gqa_declared_vmem_bytes(32, 256, 512, 512, 128, jnp.bfloat16) \
+        < 8 * (1 << 20)
+
+
+def test_ssm_decode_kernel_compiles_for_a_v5e_in_place(one_chip, monkeypatch):
+    """ops/pallas_ssm_decode.py at the Falcon-H1 cell's geometry: 128 rows
+    into 128 slots of 32 x 128 x 256 float32 (537 MB a layer).  Mosaic
+    takes a slot's 4.19 MB block in and out inside the VMEM the kernel
+    asks for, and the compiled program neither gathers nor scatters nor
+    copies the slots' array: the output IS the donated input."""
+    from analytics_zoo_tpu.ops import pallas_ssm_decode as pk
+
+    monkeypatch.setattr(pk.engine, "on_tpu", lambda: True)
+    S = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(       # noqa: E731
+        s, d, sharding=one_chip)
+    B, H, P, N, G = 128, 32, 128, 256, 2
+    compiled = jax.jit(pk.ssm_decode_update, donate_argnums=0).lower(
+        S((128, H, P, N)), S((B,), jnp.int32), S((B,), jnp.int32),
+        S((B, H, P)), S((B, H)), S((B, H)), S((B, G, N)),
+        S((B, G, N))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    whole = r"f32\[128,32,128,256\]\S* (gather|scatter|copy|fusion)\("
+    assert not re.search(whole, text), re.search(whole, text).group(0)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * (1 << 20)
+    assert pk.declared_vmem_bytes(H, P, N, G) < 24 * (1 << 20)
+
+
 def test_selected_decode_kernel_compiles_for_a_v5e_at_the_published_widths(
         one_chip, monkeypatch):
     """``selected_mla_decode`` at the dots3 cell's geometry: 64 rows x 128
