@@ -357,13 +357,15 @@ SCOPES: Dict[str, str] = {
         "multibox_loss · match_priors: the IoU matrix, each prior's best "
         "ground truth, the bipartite scatter",
     "ssd/loss_loc":
-        "multibox_loss · smooth-L1 of the encoded deltas on the positives",
+        "multibox_loss · the matched ground truths' boxes by masked sums "
+        "over the G, smooth-L1 of the encoded deltas on the positives",
     "ssd/loss_conf":
-        "multibox_loss · the cross-entropy: log_softmax, the gather of "
-        "the matched class's log-probability, the masked sum",
+        "multibox_loss · the cross-entropy: the matched labels by a masked "
+        "sum over the G, log_softmax, the matched class's log-probability "
+        "by a masked sum over the C, the masked sum",
     "ssd/loss_mine":
-        "multibox_loss · hard-negative mining: the candidates' sort (or "
-        "top_k) and the scatter of the keep mask",
+        "multibox_loss · hard-negative mining: the threshold's 32 counting "
+        "passes over the candidates' ordered bits, the ties' running count",
     "ssd/normalize":
         "SSDPredictor._detect / _detect_yuv · the staging arithmetic "
         "before the forward: uint8 to float less the pixel means, the "

@@ -78,6 +78,7 @@ import numpy as np
 from jax import lax
 
 from analytics_zoo_tpu.ops import pallas_lm_decode, pallas_lm_prefill
+from analytics_zoo_tpu.ops.ranking import kth_largest, ordered_bits
 
 F32 = jnp.float32
 NEG = -1e30
@@ -182,23 +183,6 @@ def rope_half(x, pos, theta: float):
     x0, x1 = xf[..., :r // 2], xf[..., r // 2:]
     return jnp.concatenate([x0 * cos - x1 * sin, x1 * cos + x0 * sin],
                            -1).astype(x.dtype)
-
-
-def ordered_bits(x):
-    """float32 → uint32 whose unsigned order is the floats' order."""
-    b = lax.bitcast_convert_type(x.astype(F32), jnp.int32)
-    b = b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
-    return lax.bitcast_convert_type(b, jnp.uint32) ^ jnp.uint32(0x80000000)
-
-
-def kth_largest(count, k: int, rows: int):
-    """Per row the k-th largest of its uint32 values, exactly: 32 counting
-    passes build it bit by bit from the top.  ``count(above)`` → (rows,)
-    says for how many of a row's values ``above(values)`` holds."""
-    def bit(i, tau):
-        cand = tau | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
-        return jnp.where(count(lambda v: v >= cand[:, None]) >= k, cand, tau)
-    return lax.fori_loop(0, 32, bit, jnp.zeros((rows,), jnp.uint32))
 
 
 # ---------------------------------------------------------------------------

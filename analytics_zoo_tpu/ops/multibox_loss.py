@@ -8,12 +8,20 @@ SmoothL1(loc) + CrossEntropy(conf) normalized by match count
 program (SURVEY.md §7.3 hard part #1):
 
 - matching = IoU matrix + per-prior argmax, with each gt's best prior
-  force-matched (the bipartite phase) via scatter;
-- hard-negative mining = rank negatives by background conf loss (one
-  descending argsort — or a static ``lax.top_k`` window in
-  ``mining="topk"`` mode — plus a scatter of the keep mask) and select
-  the top ``neg_pos_ratio·num_pos``, count-exact;
-- losses are masked sums — no gather/boolean filtering, shapes stay static.
+  force-matched (the bipartite phase) via a scatter indexed by gt (G
+  indices an image);
+- every choice made a PRIOR at a time is a compare where the data lies,
+  never an index: the matched gt's label and box are sums over the G
+  under ``matched == arange(G)``, the matched class's log-probability a
+  sum over the C under ``label == arange(C)`` (its gradient a select);
+  an XLA gather costs a v5e 7–15 ns an index whatever it fetches, and
+  the two gathers of 558,848 indices a step were 11.7 ms (PERF.md §5);
+- hard-negative mining = the ``min(neg_pos_ratio·num_pos, #candidates)``
+  largest background losses an image, count-exact and ties to the lower
+  prior as a stable sort keeps them, by threshold and tie room
+  (``hard_negatives``: ``kth_largest``'s counting passes, a running
+  count) — no sort, no scatter of a keep mask;
+- losses are masked sums, shapes stay static.
 
 Gradient-explosion guard: the reference skips backward when loss > 50
 (``updateGradInput:546``); the equivalent lives in the train step's
@@ -23,7 +31,6 @@ Gradient-explosion guard: the reference skips backward when loss > 50
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
 
 import jax
 import numpy as np
@@ -31,6 +38,7 @@ import jax.numpy as jnp
 
 from analytics_zoo_tpu.core.criterion import Criterion, smooth_l1
 from analytics_zoo_tpu.ops.bbox import encode_bbox, iou_matrix
+from analytics_zoo_tpu.ops.ranking import kth_largest, ordered_bits
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,19 +52,6 @@ class MultiBoxLossParam:
     background_id: int = 0
     neg_pos_ratio: float = 3.0
     neg_overlap: float = 0.5
-    # Hard-negative selection engine (docs/MFU_CEILING.md: mining was
-    # ~20% of the SSD300 train step at 1.3% of its FLOPs on a v5e in
-    # round 4).  "sort": one value
-    # sort of the (P,) negative losses — exact reference semantics up to
-    # float ties (the former double-argsort rank trick cost two sorts
-    # for the same selection).  "topk": lax.top_k over a static window
-    # of ``mining_topk`` candidates — cheapest, and exact whenever
-    # ``num_neg = min(3·num_pos, #candidates) <= mining_topk`` (i.e.
-    # fewer than ~mining_topk/3 positive priors per image; beyond that
-    # the negative count is capped at mining_topk, a documented
-    # deviation).
-    mining: str = "sort"
-    mining_topk: int = 1024
 
 
 def match_priors(priors: jax.Array, gt_boxes: jax.Array, gt_mask: jax.Array,
@@ -89,6 +84,26 @@ def match_priors(priors: jax.Array, gt_boxes: jax.Array, gt_mask: jax.Array,
     return matched, positive, best_gt_iou
 
 
+def hard_negatives(loss: jax.Array, k: jax.Array) -> jax.Array:
+    """Of each row of ``loss`` (B, P) its ``k`` (B,) int32 largest: the
+    mask a stable descending sort's first ``k`` would keep — every value
+    over the row's ``k``-th largest and, of those equal to it, the lowest
+    indices that still fit.  No sort and no scatter: the threshold by
+    ``kth_largest``'s 32 counting passes over the values' ordered bits,
+    the ties' ranks by a running count.  −0.0 is counted as +0.0 (a sort
+    holds them equal, their bits do not)."""
+    bits = jnp.where(loss == 0, jnp.uint32(0x80000000), ordered_bits(loss))
+
+    def count(above):
+        return jnp.sum(above(bits), 1, dtype=jnp.int32)
+
+    tau = kth_largest(count, k, bits.shape[0])[:, None]
+    room = k - count(lambda v: v > tau)
+    tie = bits == tau
+    return (bits > tau) | (tie & (jnp.cumsum(tie, 1, dtype=jnp.int32)
+                                  <= room[:, None]))
+
+
 def multibox_loss(loc_pred: jax.Array, conf_logits: jax.Array,
                   priors: jax.Array, variances: jax.Array,
                   gt_boxes: jax.Array, gt_labels: jax.Array,
@@ -102,68 +117,64 @@ def multibox_loss(loc_pred: jax.Array, conf_logits: jax.Array,
     gt_labels (B,G) int (background = ``param.background_id``),
     gt_mask (B,G) 1.0=valid.  Scalar loss = (loc + conf) / total matches.
     """
+    G = gt_boxes.shape[1]
+    # four named sections of the compiled step, forward and backward
+    # (obs/names.py::SCOPES)
+    with jax.named_scope("ssd/loss_match"):
+        matched, positive, best_iou = jax.vmap(
+            lambda b, m: match_priors(priors, b, m, param.overlap_threshold)
+        )(gt_boxes, gt_mask)                                  # (B,P) each
+        pos_f = positive.astype(jnp.float32)
+        num_pos = jnp.sum(pos_f, -1)                          # (B,)
+        # the prior's matched ground truth among the G, (B,G,P): the priors
+        # stand on the minor axis, so a sum over the G adds whole vectors
+        own = matched[:, None, :] == jnp.arange(G)[None, :, None]
 
-    def per_image(loc_p, conf_l, boxes, labels, mask):
-        # four named sections of the compiled step, forward and backward
-        # (obs/names.py::SCOPES)
-        with jax.named_scope("ssd/loss_match"):
-            matched, positive, best_iou = match_priors(
-                priors, boxes, mask, param.overlap_threshold)
-            pos_f = positive.astype(jnp.float32)
-            num_pos = jnp.sum(pos_f)
+    def of_matched(v):
+        """``v[b, matched[b, p]]`` of a (B,G) ``v`` → (B,P): one term of
+        the sum is not zero, so it is exact (a ``where``, so a padded
+        ground truth's NaN stays out)."""
+        return jnp.sum(jnp.where(own, v[:, :, None], 0), 1)
 
-        # --- localization: smooth-L1 on encoded deltas, positives only
-        with jax.named_scope("ssd/loss_loc"):
-            matched_boxes = boxes[matched]                    # (P,4)
-            loc_target = encode_bbox(priors, variances, matched_boxes)
-            loc_loss = jnp.sum(
-                jnp.sum(smooth_l1(loc_p - loc_target), axis=-1) * pos_f)
+    # --- localization: smooth-L1 on encoded deltas, positives only
+    with jax.named_scope("ssd/loss_loc"):
+        matched_boxes = jnp.stack(
+            [of_matched(gt_boxes[..., c]) for c in range(4)], -1)
+        loc_target = encode_bbox(priors, variances, matched_boxes)
+        loc_loss = jnp.sum(
+            jnp.sum(smooth_l1(loc_pred - loc_target), axis=-1) * pos_f, -1)
 
-        # --- confidence: CE with matched label for positives, bg for rest
-        with jax.named_scope("ssd/loss_conf"):
-            matched_label = jnp.where(
-                positive, labels[matched].astype(jnp.int32),
-                param.background_id)
-            logp = jax.nn.log_softmax(conf_l, axis=-1)        # (P,C)
-            ce = -jnp.take_along_axis(logp, matched_label[:, None],
-                                      axis=1)[:, 0]
+    # --- confidence: CE with matched label for positives, bg for rest; the
+    # matched class's log-probability by a compare against an iota, so its
+    # gradient is a select
+    with jax.named_scope("ssd/loss_conf"):
+        matched_label = jnp.where(
+            positive, of_matched(gt_labels.astype(jnp.int32)),
+            param.background_id)
+        logp = jax.nn.log_softmax(conf_logits, axis=-1)       # (B,P,C)
+        ce = -jnp.sum(jnp.where(
+            jnp.arange(logp.shape[-1]) == matched_label[..., None], logp, 0),
+            -1)
 
-        # --- hard-negative mining (reference ``mineHardExamples:334``):
-        # candidates = non-positive priors whose best gt overlap is below
-        # negOverlap (near-matches are neither positive nor negative)
-        with jax.named_scope("ssd/loss_mine"):
-            neg_cand = (~positive) & (best_iou < param.neg_overlap)
-            neg_loss = jnp.where(neg_cand, -logp[:, param.background_id],
-                                 -jnp.inf)
-            num_neg = jnp.minimum(param.neg_pos_ratio * num_pos,
-                                  jnp.sum(neg_cand.astype(jnp.float32)))
-            # count-exact top-num_neg selection with ONE sort + a scatter
-            # (the former double-argsort rank trick paid a second full
-            # sort for the same mask; a value-threshold variant would be
-            # cheaper still but over-selects whole tie groups — e.g. the
-            # uniform logits of a fresh model — so the count contract
-            # would break)
-            if param.mining == "topk":
-                k = min(param.mining_topk, neg_loss.shape[0])
-                _, cand_idx = jax.lax.top_k(neg_loss, k)      # desc (k,)
-                num_neg = jnp.minimum(num_neg, float(k))
-            elif param.mining == "sort":
-                cand_idx = jnp.argsort(-neg_loss)             # desc (P,)
-            else:
-                raise ValueError(f"unknown mining mode {param.mining!r}")
-            take = jnp.arange(cand_idx.shape[0]) < num_neg
-            neg_selected = (jnp.zeros(neg_loss.shape[0], bool)
-                            .at[cand_idx].set(take)) & neg_cand
+    # --- hard-negative mining (reference ``mineHardExamples:334``):
+    # candidates = non-positive priors whose best gt overlap is below
+    # negOverlap (near-matches are neither positive nor negative); the
+    # min(ratio·num_pos, #candidates) hardest of them by background loss
+    with jax.named_scope("ssd/loss_mine"):
+        neg_cand = (~positive) & (best_iou < param.neg_overlap)
+        neg_loss = jnp.where(neg_cand, -logp[..., param.background_id],
+                             -jnp.inf)
+        num_neg = jnp.minimum(
+            jnp.ceil(param.neg_pos_ratio * num_pos).astype(jnp.int32),
+            jnp.sum(neg_cand, -1, dtype=jnp.int32))
+        neg_selected = hard_negatives(neg_loss, num_neg) & neg_cand
 
-        with jax.named_scope("ssd/loss_conf"):
-            conf_loss = jnp.sum(
-                ce * (pos_f + neg_selected.astype(jnp.float32)))
-        return param.loc_weight * loc_loss, conf_loss, num_pos
-
-    loc_l, conf_l, n_pos = jax.vmap(per_image)(
-        loc_pred, conf_logits, gt_boxes, gt_labels, gt_mask)
-    total_pos = jnp.maximum(jnp.sum(n_pos), 1.0)
-    return (jnp.sum(loc_l) + jnp.sum(conf_l)) / total_pos
+    with jax.named_scope("ssd/loss_conf"):
+        conf_loss = jnp.sum(
+            ce * (pos_f + neg_selected.astype(jnp.float32)), -1)
+    total_pos = jnp.maximum(jnp.sum(num_pos), 1.0)
+    return (jnp.sum(param.loc_weight * loc_loss)
+            + jnp.sum(conf_loss)) / total_pos
 
 
 class MultiBoxLoss(Criterion):

@@ -267,31 +267,143 @@ def test_multibox_loss_hard_negative_ratio():
     np.testing.assert_allclose(float(loss), 3 * np.log(21.0), rtol=1e-4)
 
 
-def test_multibox_loss_topk_mining_matches_sort():
-    """mining="topk" (static lax.top_k window) equals the exact sort
-    engine whenever num_neg fits the window — same loss bit-for-bit on
-    realistic (distinct-loss) data."""
-    rng = np.random.RandomState(11)
+def _oracle_loss_and_mask(loc_pred, conf_logits, priors, variances,
+                          gt_boxes, gt_labels, gt_mask, param):
+    """MultiBoxLoss as it was written up to PR 39: the matched gt's box and
+    label and the matched class's log-probability GATHERED a prior at a
+    time, the hard negatives by a stable descending argsort and a scatter
+    of the keep mask.  → (loss, mask of the mined negatives (B, P))."""
+    from analytics_zoo_tpu.core.criterion import smooth_l1
+
+    def per_image(loc_p, conf_l, boxes, labels, mask):
+        matched, positive, best_iou = match_priors(
+            priors, boxes, mask, param.overlap_threshold)
+        pos_f = positive.astype(jnp.float32)
+        num_pos = jnp.sum(pos_f)
+        loc_target = bbox.encode_bbox(priors, variances, boxes[matched])
+        loc_loss = jnp.sum(
+            jnp.sum(smooth_l1(loc_p - loc_target), axis=-1) * pos_f)
+        matched_label = jnp.where(
+            positive, labels[matched].astype(jnp.int32), param.background_id)
+        logp = jax.nn.log_softmax(conf_l, axis=-1)
+        ce = -jnp.take_along_axis(logp, matched_label[:, None], axis=1)[:, 0]
+        neg_cand = (~positive) & (best_iou < param.neg_overlap)
+        neg_loss = jnp.where(neg_cand, -logp[:, param.background_id],
+                             -jnp.inf)
+        num_neg = jnp.minimum(param.neg_pos_ratio * num_pos,
+                              jnp.sum(neg_cand.astype(jnp.float32)))
+        cand_idx = jnp.argsort(-neg_loss)
+        take = jnp.arange(cand_idx.shape[0]) < num_neg
+        neg_selected = (jnp.zeros(neg_loss.shape[0], bool)
+                        .at[cand_idx].set(take)) & neg_cand
+        conf_loss = jnp.sum(ce * (pos_f + neg_selected.astype(jnp.float32)))
+        return (param.loc_weight * loc_loss, conf_loss, num_pos,
+                neg_selected, neg_loss, num_neg)
+
+    loc_l, conf_l, n_pos, mined, neg_loss, num_neg = jax.vmap(per_image)(
+        loc_pred, conf_logits, gt_boxes, gt_labels, gt_mask)
+    loss = (jnp.sum(loc_l) + jnp.sum(conf_l)) / jnp.maximum(
+        jnp.sum(n_pos), 1.0)
+    return loss, mined, neg_loss, num_neg
+
+
+def _loss_case(name):
+    """Inputs of the loss for one case of the equivalence test: 36 priors,
+    two images, up to 12 ground truths."""
+    rng = np.random.RandomState(sum(map(ord, name)))
     priors = _grid_priors(6)
-    P = priors.shape[0]
+    P, B, G, C = priors.shape[0], 2, 4, 21
     var = np.tile([0.1, 0.1, 0.2, 0.2], (P, 1)).astype(np.float32)
-    gt_boxes = np.abs(rng.rand(2, 3, 4)).astype(np.float32)
-    gt_boxes[..., 2:] = np.clip(gt_boxes[..., :2] + 0.3, 0, 1)
-    gt_labels = rng.randint(1, 21, (2, 3)).astype(np.int32)
-    gt_mask = np.ones((2, 3), np.float32)
-    loc = rng.randn(2, P, 4).astype(np.float32) * 0.1
-    conf = rng.randn(2, P, 21).astype(np.float32)
-    a = multibox_loss(jnp.asarray(loc), jnp.asarray(conf),
-                      jnp.asarray(priors), jnp.asarray(var),
-                      jnp.asarray(gt_boxes), jnp.asarray(gt_labels),
-                      jnp.asarray(gt_mask),
-                      MultiBoxLossParam(mining="sort"))
-    b = multibox_loss(jnp.asarray(loc), jnp.asarray(conf),
-                      jnp.asarray(priors), jnp.asarray(var),
-                      jnp.asarray(gt_boxes), jnp.asarray(gt_labels),
-                      jnp.asarray(gt_mask),
-                      MultiBoxLossParam(mining="topk", mining_topk=32))
-    np.testing.assert_allclose(float(a), float(b), rtol=1e-6)
+    boxes = rng.rand(B, G, 4).astype(np.float32) * 0.6
+    boxes[..., 2:] = np.clip(boxes[..., :2] + 0.3, 0, 1)
+    labels = rng.randint(1, C, (B, G)).astype(np.int32)
+    mask = np.ones((B, G), np.float32)
+    loc = rng.randn(B, P, 4).astype(np.float32) * 0.1
+    conf = rng.randn(B, P, C).astype(np.float32)
+    param = MultiBoxLossParam()
+    if name == "uniform":                 # every candidate tied
+        conf[:] = 0.0
+    elif name == "no_positives":
+        mask[:] = 0.0
+    elif name == "capped":                # 3·num_pos > #candidates
+        G = 12
+        boxes = np.broadcast_to(priors[:G], (B, G, 4)).copy()
+        labels = rng.randint(1, C, (B, G)).astype(np.int32)
+        mask = np.ones((B, G), np.float32)
+    elif name == "negative_zero":         # saturated background: −0.0 loss
+        conf[:, np.arange(P) % 4 > 0, 0] = 100.0
+    elif name == "masked_gt":
+        mask[0, 1:] = 0.0
+        mask[1, ::2] = 0.0
+    elif name == "shared_prior":          # two gts claim one prior
+        boxes[:, 1] = boxes[:, 0]
+    elif name == "fractional_ratio":
+        param = MultiBoxLossParam(neg_pos_ratio=2.5)
+    args = tuple(jnp.asarray(x) for x in
+                 (loc, conf, priors, var, boxes, labels, mask))
+    return args, param
+
+
+@pytest.mark.parametrize("case", [
+    "uniform", "random", "no_positives", "capped", "negative_zero",
+    "masked_gt", "shared_prior", "fractional_ratio"])
+def test_multibox_loss_matches_gather_and_sort_oracle(case):
+    """The loss that selects a prior in place (compares against an iota,
+    the mining by threshold and tie room) equals the one that gathered a
+    prior at a time and sorted: in value and gradient to 1e-6, and the
+    mined mask bit for bit — also where zeros of both signs tie."""
+    from analytics_zoo_tpu.ops.multibox_loss import hard_negatives
+    args, param = _loss_case(case)
+
+    def new(loc, conf):
+        return multibox_loss(loc, conf, *args[2:], param)
+
+    def old(loc, conf):
+        return _oracle_loss_and_mask(loc, conf, *args[2:], param)[0]
+
+    for f_new, f_old in ((new, old), (jax.grad(new, (0, 1)),
+                                      jax.grad(old, (0, 1)))):
+        for a, b in zip(jax.tree.leaves(f_new(*args[:2])),
+                        jax.tree.leaves(f_old(*args[:2]))):
+            b = np.asarray(b)
+            np.testing.assert_allclose(np.asarray(a), b, rtol=1e-6,
+                                       atol=1e-6 * np.abs(b).max())
+
+    _, mined, neg_loss, num_neg = _oracle_loss_and_mask(*args, param)
+    k = jnp.ceil(num_neg).astype(jnp.int32)
+    np.testing.assert_array_equal(hard_negatives(neg_loss, k), mined)
+    # the sort holds −0.0 and +0.0 equal: flip every other zero's sign
+    flip = (neg_loss == 0) & (jnp.arange(neg_loss.shape[1]) % 2 == 0)
+    np.testing.assert_array_equal(
+        hard_negatives(jnp.where(flip, 0.0, neg_loss), k), mined)
+    if case == "negative_zero":
+        assert bool(jnp.any(flip & mined))
+    if case == "capped":
+        assert bool(jnp.all(k < 3 * jnp.sum(args[6] > 0, -1)))
+
+
+def test_multibox_loss_lowers_without_a_gather_of_priors():
+    """At the SSD300 geometry (8,732 priors, 21 classes, 100 padded gts)
+    the loss and its gradient lower with no sort and no gather or scatter
+    of B·P indices or more: only the bipartite scatter, G an image."""
+    import re
+    B, P, C, G = 4, 8732, 21, 100
+    S = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    loss = jax.value_and_grad(
+        lambda loc, conf, pr, va, bx, lb, mk: multibox_loss(
+            loc, conf, pr, va, bx, lb, mk), (0, 1))
+    text = jax.jit(loss).lower(
+        S((B, P, 4), f32), S((B, P, C), f32), S((P, 4), f32), S((P, 4), f32),
+        S((B, G, 4), f32), S((B, G), jnp.int32), S((B, G), f32)).as_text()
+    assert "stablehlo.sort" not in text
+    ops = re.findall(r'stablehlo\.(gather|scatter|dynamic_gather)"?'
+                     r'.*?: \(tensor<[^>]*>, tensor<([0-9x]*)x?[a-z]+[0-9]*>',
+                     text, re.S)
+    assert ops, "the bipartite scatter is lowered"
+    for op, dims in ops:
+        n = int(np.prod([int(d) for d in dims.split("x") if d]))
+        assert n < B * P, (op, dims)
 
 
 def test_multibox_loss_grad_flows():
