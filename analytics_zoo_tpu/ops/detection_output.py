@@ -218,7 +218,7 @@ def resolve_backend(param: DetectionOutputParam, n_priors: int,
     tpu = engine.on_tpu()
     name = param.backend
     need = pallas_detout.fused_vmem_bytes(n_priors, n_classes,
-                                          param.keep_topk)
+                                          param.keep_topk, param.nms_topk)
     fits = vmem.fits(need)
     if name == "auto":
         if not tpu:

@@ -18,33 +18,48 @@ stage boundary.  This module is the same math as ONE kernel over a
   score above ``conf_thresh`` (the pop ORDER is the sorted order, so
   no top_k materialization is needed), stop after ``nms_topk`` pops
   (the reference's nmsFast topk-400 pre-filter, reproduced exactly:
-  rank is the pop index), and for each still-active pop write its keep
-  bit and deactivate overlapping candidates with one VPU IoU row.
-  The background class never enters: only foreground rows are in the
-  grid, so the discard happens at selection, not by post-hoc masking;
-- **global cross-class top-K** runs at the last class step from the
-  accumulated per-class keep scores (a ``(C_fg, P)`` VMEM scratch):
-  pop the global max ``keep_topk`` times, tie-broken by flattened
-  (class, prior) index — exactly ``lax.top_k``'s stable order over the
-  reference's class-major candidate layout — and write ``(class_id,
-  score, x1, y1, x2, y2)`` rows directly into the output block.
+  rank is the pop index), and for each still-active pop append it to
+  the class's keep list and deactivate overlapping candidates with one
+  VPU IoU row.  The background class never enters: only foreground
+  rows are in the grid, so the discard happens at selection, not by
+  post-hoc masking;
+- **global cross-class top-K** runs at the last class step over the
+  per-class keep lists alone: pop the global max ``min(kept,
+  keep_topk)`` times, tie-broken by (class, slot) — within a class the
+  slot order is the pop order, score then prior, so this is exactly
+  ``lax.top_k``'s stable order over the reference's class-major
+  candidate layout — and write ``(class_id, score, x1, y1, x2, y2)``
+  rows directly into the output block.
 
 Candidates never leave VMEM between the stages; the only HBM traffic
 is streaming the inputs once and writing the (B, keep_topk, 6) result.
 
 **Layout.**  Mosaic tiles a buffer's last two dims in ``(8, 128)``
 registers, so every per-prior vector (scores, the four box rows, the
-sweep's two masks, each class's keeps) is a dense ``(P_pad / 128, 128)``
-tile with ``P_pad`` a multiple of 1,024: SSD512's 24,564 priors are 192
-rows, 24 full registers (as ``(1, P)`` rows they were 192 registers with
-one sublane of eight in use).  Prior ``p`` sits at ``[p // 128,
-p % 128]`` and ``p`` is the index every tie-break is stated on.  A pass
-over a vector (max, index of the max, the IoU row) costs 24 register
-operations; whatever concerns ONE candidate — clearing its ``remaining``
-bit, reading its ``active`` bit and its box, writing its keep score,
-zeroing it in the merge, writing an output row — loads, selects in and
-stores the one aligned register that holds it, and a class's keeps are
-written in place into its own ``allkeep[c]`` tile.
+sweep's two masks) is a dense ``(P_pad / 128, 128)`` tile with
+``P_pad`` a multiple of 1,024: SSD512's 24,564 priors are 192 rows, 24
+full registers (as ``(1, P)`` rows they were 192 registers with one
+sublane of eight in use).  Prior ``p`` sits at ``[p // 128, p % 128]``
+and ``p`` is the index every tie-break is stated on.  A pass over a
+vector (max, index of the max, the IoU row) costs 24 register
+operations; whatever concerns ONE candidate of the sweep — clearing its
+``remaining`` bit, reading its ``active`` bit and its box, appending it
+to its class's list — loads, selects in and stores the one aligned
+register that holds it.
+
+**The keep lists.**  A class keeps at most ``min(nms_topk, P)``
+candidates, so its list has that many slots, rounded up to 128 lanes:
+``(slots / 128, C_fg rounded up to 8, 128)`` scratches of the kept
+scores and of the kept boxes' four corners, class ``k``'s slot ``n`` at
+``[n // 128, k, n % 128]``.  The sweep writes its ``n``-th keep into
+slot ``n`` (the count is the loop's carry); it pops in descending
+order, so every list comes out sorted.  At SSD512 the scores are 12
+registers where one ``(P_pad / 128, 128)`` tile a class was 480.  A
+merge pop stays in vector registers: the max, the lowest tie key among
+the maxima, then the slot's class and box as masked sums over the
+lists, and the slot zeroed, all as whole-list passes — never a scalar
+read back from a vector to address one register (the pop is bound by
+its chain of reductions, not by the registers it scans).
 
 Semantics contract: bit-for-bit the same detections as
 ``detection_output_single`` (and therefore the xla/pallas backends) up
@@ -88,14 +103,24 @@ def _padded_priors(n_priors: int) -> int:
     return round_up(n_priors, 8 * 128)
 
 
-def fused_vmem_bytes(n_priors: int, n_classes: int, keep_topk: int) -> int:
+def _list_slots(n_priors: int, nms_topk: int) -> int:
+    """Slots of a class's keep list: it keeps at most ``min(nms_topk,
+    P)`` candidates; whole 128-lane rows."""
+    return round_up(max(min(nms_topk, n_priors), 1), 128)
+
+
+def fused_vmem_bytes(n_priors: int, n_classes: int, keep_topk: int,
+                     nms_topk: int = 400) -> int:
     """VMEM the fused program's buffers occupy, priced the way Mosaic
     lays them out (``ops.vmem.padded_bytes``).  Every per-prior vector is
     a dense ``(R, 128)`` tile with ``R`` a multiple of 8, so the figure is
-    the logical bytes of the padded priors; only the ``(keep_topk, 6)``
-    output block pads (6 lanes to 128).  Counted: the per-class keep
-    scratch (C_fg tiles), the decoded boxes (4) and the two sweep masks,
-    the double-buffered score and loc blocks, the single-buffered
+    the logical bytes of the padded priors; the keep lists pad their
+    class axis to 8 sublanes and their slots to 128 lanes, and the
+    ``(keep_topk, 6)`` output block pads 6 lanes to 128.  Counted: the
+    decoded boxes (4 vectors) and the two sweep masks, the five keep
+    lists (score and box corners, ``min(nms_topk, P)`` slots a
+    foreground class; ``nms_topk`` defaults to ``DetectionOutputParam``'s
+    400), the double-buffered score and loc blocks, the single-buffered
     prior/variance blocks (whole-array windows are not double-buffered)
     and the double-buffered output block.  ``detection_output`` selects on
     it and ``fused_detection_output`` hands it to Mosaic as the VMEM
@@ -104,20 +129,24 @@ def fused_vmem_bytes(n_priors: int, n_classes: int, keep_topk: int) -> int:
     n_fg = max(n_classes - 1, 1)
     vec = padded_bytes((rows, 128), np.float32)
     quad = padded_bytes((4, rows, 128), np.float32)
-    scratch = (n_fg + 2) * vec + quad
+    lists = padded_bytes(
+        (5, _list_slots(n_priors, nms_topk) // 128, n_fg, 128), np.float32)
+    scratch = 2 * vec + quad + lists
     blocks = 2 * vec + 2 * quad + 2 * quad
     return scratch + blocks + 2 * padded_bytes((keep_topk, 6), np.float32)
 
 
 def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
-                  boxes, active, remaining, allkeep,
-                  *, n_fg: int, n_priors: int, rows: int, kout: int,
-                  conf_thresh: float, nms_thresh: float, nms_topk: int,
-                  bg_id: int, clip: bool, stage: str):
+                  boxes, active, remaining, kscore, kbox,
+                  *, n_fg: int, n_priors: int, rows: int, slots: int,
+                  kout: int, conf_thresh: float, nms_thresh: float,
+                  nms_topk: int, bg_id: int, clip: bool, stage: str):
     """One (image, class) grid step.  A per-prior vector is an
-    ``(rows, 128)`` tile and prior ``p`` sits at ``[p // 128, p % 128]``.
-    Whole-vector work (max, index of the max, the IoU row) runs over
-    ``rows / 8`` full registers; everything that touches ONE candidate
+    ``(rows, 128)`` tile and prior ``p`` sits at ``[p // 128, p % 128]``;
+    class ``k``'s keep list slot ``n`` sits at ``[n // 128, k, n % 128]``
+    of ``kscore`` and of each corner of ``kbox``.  Whole-vector work
+    (max, index of the max, the IoU row) runs over ``rows / 8`` full
+    registers; whatever the sweep does to ONE candidate or list slot
     loads, selects in and stores the one aligned ``(8, 128)`` register
     that holds it (TPU VMEM has no scalar stores).  Scratch persists
     across the class grid, which is what lets decode run once per image
@@ -130,12 +159,22 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
     flat = (jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0) * 128
             + jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1))
     flat8 = flat[:8]
+    sub8 = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    lane8 = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
 
     def register_of(p):
         """The aligned 8-row window holding prior ``p`` and ``p``'s
         place in it."""
         r0 = pl.multiple_of((p // 1024) * 8, 8)
         return pl.ds(r0, 8), flat8 + r0 * 128 == p
+
+    def slot_of(k, n):
+        """The register of the keep lists holding class ``k``'s slot
+        ``n`` (a row of 128 slots, an aligned window of 8 classes) and
+        the slot's place in it."""
+        k0 = pl.multiple_of((k // 8) * 8, 8)
+        row = n // 128
+        return row, pl.ds(k0, 8), (sub8 == k - k0) & (lane8 == n - row * 128)
 
     def pick(reg, hit):
         return jnp.sum(jnp.where(hit, reg, 0.0))
@@ -167,12 +206,14 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
 
     # -- stage 2: per-class filter + selection + suppression, fused -------
     if stage in ("select", "full"):
-        keep_c = allkeep.at[c]                          # this class's keeps
+        @pl.when(c == 0)
+        def _clear_lists():                 # a score of 0 is an empty slot
+            kscore[:] = jnp.zeros(kscore.shape, f32)
+
         valid = ((flat < n_priors)
                  & (scores_ref[0, 0] > conf_thresh)).astype(f32)
         active[:] = valid
         remaining[:] = valid
-        keep_c[:] = jnp.zeros((rows, 128), f32)
         # pop order IS descending-score order (ties: lowest prior index,
         # lax.top_k's stable order), and the pop INDEX is the sorted
         # rank — so stopping at nms_topk pops reproduces the reference's
@@ -181,17 +222,24 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
         # (conf_thresh kills most priors) costs #valid pops, not K.
         bound = jnp.minimum(jnp.sum(valid).astype(jnp.int32), nms_topk)
 
-        def body(i, _):
+        def body(i, n_kept):
             vals = jnp.where(remaining[:] > 0, scores_ref[0, 0], -jnp.inf)
             m = jnp.max(vals)
             p = jnp.min(jnp.where(vals == m, flat, ppad))
             win, hit = register_of(p)
             remaining[win, :] = jnp.where(hit, 0.0, remaining[win, :])
+            keep = pick(active[win, :], hit) > 0.0
 
-            @pl.when(pick(active[win, :], hit) > 0.0)
+            @pl.when(keep)
             def _keep():
-                keep_c[win, :] = jnp.where(hit, m, keep_c[win, :])
+                # append (score, box) to the class's list: the pops
+                # come in descending order, so the list is sorted
+                row, kwin, at = slot_of(c, n_kept)
+                kscore[row, kwin, :] = jnp.where(at, m, kscore[row, kwin, :])
                 x1, y1, x2, y2 = box_of(win, hit)
+                for corner, v in enumerate((x1, y1, x2, y2)):
+                    kbox[corner, row, kwin, :] = jnp.where(
+                        at, v, kbox[corner, row, kwin, :])
                 bx1, by1, bx2, by2 = (boxes[i] for i in range(4))
                 ix1 = jnp.maximum(bx1, x1)
                 iy1 = jnp.maximum(by1, y1)
@@ -203,13 +251,13 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
                 area_p = (x2 - x1) * (y2 - y1)
                 union = jnp.maximum(area + area_p - inter, 1e-12)
                 # deactivate everything overlapping the kept box
-                # (including itself; its keep score is already written)
+                # (including itself; it is already on the list)
                 active[:] = jnp.where(inter / union >= nms_thresh, 0.0,
                                       active[:])
 
-            return 0
+            return n_kept + keep.astype(jnp.int32)
 
-        jax.lax.fori_loop(0, bound, body, 0)
+        jax.lax.fori_loop(0, bound, body, jnp.int32(0))
 
     # -- stage 3: global cross-class top-K, last class step ---------------
     if stage == "full":
@@ -220,26 +268,40 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
             out_ref[0] = jnp.where(             # empty rows
                 jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 1)
                 == 0, -1.0, 0.0)
-            ci = jax.lax.broadcasted_iota(jnp.int32, (n_fg, rows, 128), 0)
-            n_kept = jnp.sum((allkeep[:] > 0).astype(f32)).astype(jnp.int32)
+            # a slot's tie key: class-major, then slot — within a class
+            # the pop order, score then prior.  The lowest key among the
+            # maxima is lax.top_k's stable order over the reference's
+            # class-major candidate layout
+            key = (jax.lax.broadcasted_iota(jnp.int32, kscore.shape, 1)
+                   * slots
+                   + jax.lax.broadcasted_iota(jnp.int32, kscore.shape, 0)
+                   * 128
+                   + jax.lax.broadcasted_iota(jnp.int32, kscore.shape, 2))
+            no_key = kscore.shape[1] * slots
+            n_kept = jnp.sum((kscore[:] > 0).astype(f32)).astype(jnp.int32)
             npop = jnp.minimum(n_kept, kout)
 
+            # foreground row → original class id (the background column
+            # was dropped before the kernel)
+            cls_id = jax.lax.broadcasted_iota(jnp.int32, kscore.shape, 1)
+            cls_id = (cls_id + (cls_id >= bg_id).astype(jnp.int32)
+                      ).astype(f32)
+
+            def whole(x, op):
+                """``op`` over a whole list, kept a (1, 1) vector: the
+                pop never reads a scalar back from the vector unit."""
+                x = op(x, axis=0)
+                return op(op(x, axis=1, keepdims=True), axis=0, keepdims=True)
+
             def body(j, _):
-                ak = allkeep[:]
-                m = jnp.max(jnp.max(ak, axis=0))
-                # tie-break: lowest flattened (class, prior) index ==
-                # lax.top_k's stable order over the reference's
-                # class-major candidate layout; per prior the lowest
-                # class that holds the max, then the lowest such pair
-                cls_at = jnp.min(jnp.where(ak == m, ci, n_fg), axis=0)
-                idx = jnp.min(cls_at * ppad + flat)
-                cstar = idx // ppad
-                win, hit = register_of(idx - cstar * ppad)
-                # foreground row → original class id (the background
-                # column was dropped before the kernel)
-                cls = (cstar
-                       + (cstar >= bg_id).astype(jnp.int32)).astype(f32)
-                x1, y1, x2, y2 = box_of(win, hit)
+                ks = kscore[:]
+                m = whole(ks, jnp.max)
+                sel = key == whole(jnp.where(ks == m, key, no_key), jnp.min)
+                kscore[:] = jnp.where(sel, 0.0, ks)
+                cls = whole(jnp.where(sel, cls_id, 0.0), jnp.sum)
+                x1, y1, x2, y2 = [
+                    whole(jnp.where(sel, kbox[i], 0.0), jnp.sum)
+                    for i in range(4)]
                 vals = jnp.where(coli == 0, cls,
                        jnp.where(coli == 1, m,
                        jnp.where(coli == 2, x1,
@@ -248,8 +310,6 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
                 j0 = pl.multiple_of((j // 8) * 8, 8)
                 out_ref[0, pl.ds(j0, 8), :] = jnp.where(
                     rowi == j - j0, vals, out_ref[0, pl.ds(j0, 8), :])
-                keep_s = allkeep.at[cstar]
-                keep_s[win, :] = jnp.where(hit, 0.0, keep_s[win, :])
                 return 0
 
             jax.lax.fori_loop(0, npop, body, 0)
@@ -259,8 +319,11 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
         # interpret-mode emulation dead-code the measured work)
         @pl.when(c == n_fg - 1)
         def _touch():
-            probe = (jnp.sum(boxes[0]) + jnp.sum(boxes[3])
-                     + (jnp.sum(allkeep[:]) if stage == "select" else 0.0))
+            probe = jnp.sum(boxes[0]) + jnp.sum(boxes[3])
+            if stage == "select":
+                ks = kscore[:]
+                probe += jnp.sum(ks) + jnp.sum(
+                    jnp.where(ks > 0, kbox[0], 0.0))
             out_ref[:] = jnp.zeros(out_ref.shape, f32) + probe
 
 
@@ -291,6 +354,8 @@ def fused_detection_output(loc: jax.Array, conf: jax.Array,
     ppad = _padded_priors(P)
     rows = ppad // 128
     kout = int(param.keep_topk)
+    nms_topk = int(param.nms_topk)
+    slots = _list_slots(P, nms_topk)
     # the merge writes an answer's row through the aligned 8-row window
     # that holds it: the output block is whole registers, cut after
     kpad = round_up(kout, 8)
@@ -308,9 +373,9 @@ def fused_detection_output(loc: jax.Array, conf: jax.Array,
     vr = tiles(jnp.swapaxes(jnp.asarray(variances, jnp.float32), 0, 1))
 
     kernel = functools.partial(
-        _fused_kernel, n_fg=n_fg, n_priors=P, rows=rows, kout=kout,
-        conf_thresh=float(param.conf_thresh),
-        nms_thresh=float(param.nms_thresh), nms_topk=int(param.nms_topk),
+        _fused_kernel, n_fg=n_fg, n_priors=P, rows=rows, slots=slots,
+        kout=kout, conf_thresh=float(param.conf_thresh),
+        nms_thresh=float(param.nms_thresh), nms_topk=nms_topk,
         bg_id=int(param.background_id), clip=bool(param.clip_boxes),
         stage=stage)
     out = pl.pallas_call(
@@ -336,9 +401,13 @@ def fused_detection_output(loc: jax.Array, conf: jax.Array,
             pltpu.VMEM((4, rows, 128), jnp.float32),        # boxes
             pltpu.VMEM((rows, 128), jnp.float32),           # active
             pltpu.VMEM((rows, 128), jnp.float32),           # remaining
-            pltpu.VMEM((n_fg, rows, 128), jnp.float32),     # allkeep
+            # the keep lists: scores, box corners
+            pltpu.VMEM((slots // 128, round_up(n_fg, 8), 128), jnp.float32),
+            pltpu.VMEM((4, slots // 128, round_up(n_fg, 8), 128),
+                       jnp.float32),
         ],
-        compiler_params=compiler_params(fused_vmem_bytes(P, C, kout)),
+        compiler_params=compiler_params(
+            fused_vmem_bytes(P, C, kout, nms_topk)),
         interpret=interpret,
     )(scores, loc_t, pr, vr)
     return out[:, :kout]

@@ -291,8 +291,8 @@ def test_fused_detection_output_compiles_for_a_v5e(one_chip, batch,
     """``ops/pallas_detout.py`` at SSD300's and SSD512's priors (the
     serve cell's batch), each prefix program, and the ``int8_topk50``
     tier's ``keep_topk``: Mosaic takes the dense (rows, 128) tiles, the
-    dynamic aligned register windows and the dynamic first-axis class
-    index, inside the VMEM the kernel asks for."""
+    dynamic aligned register windows and the keep lists' dynamic row and
+    class window, inside the VMEM the kernel asks for."""
     from analytics_zoo_tpu.ops.detection_output import DetectionOutputParam
     from analytics_zoo_tpu.ops.pallas_detout import fused_detection_output
 

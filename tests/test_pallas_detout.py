@@ -198,6 +198,51 @@ class TestFusedParity:
         assert (got[..., 1] > 0).sum() < got.shape[0] * 120
 
 
+def _one_image_empty(loc, conf, priors, variances):
+    """The first image with no foreground score at all."""
+    conf = np.array(conf)
+    conf[0, :, 1:] = 0.0
+    return loc, jnp.asarray(conf), priors, variances
+
+
+class TestKeepLists:
+    """Each foreground class's keeps go to a list of min(nms_topk, P)
+    slots in pop order, and the merge pops over those lists alone: the
+    lists' edges, each against the xla backend."""
+
+    @pytest.mark.parametrize("inputs,param", [
+        # dense conf and suppression only of identical boxes: every
+        # class keeps all its 130 pops, a list full to its last slot and
+        # 130 not a multiple of 128; every slot of every list comes out
+        (dict(seed=3), dict(nms_topk=130, nms_thresh=1.0, keep_topk=700)),
+        # keep_topk over one class's capacity: the merge cuts across
+        # lists
+        (dict(seed=3), dict(nms_topk=130, nms_thresh=1.0, keep_topk=300)),
+        (dict(seed=4, bg_bias=4.0, hot_frac=0.1),
+         dict(nms_topk=20, keep_topk=70)),
+        # one image with no candidate, one with fewer keeps than keep_topk
+        (dict(seed=9, bg_bias=8.0, hot_frac=0.01, empty_first=True),
+         dict(nms_topk=64, keep_topk=120)),
+    ], ids=["full_list_130", "merge_crosses_lists", "capacity_20",
+            "no_candidate_and_few_keeps"])
+    def test_list_edges(self, inputs, param):
+        inputs = dict(inputs)
+        empty_first = inputs.pop("empty_first", False)
+        case = _inputs(priors_n=1300, **inputs)
+        if empty_first:
+            case = _one_image_empty(*case)
+        p = DetectionOutputParam(n_classes=6, **param)
+        got = _fused(*case, p)
+        ref = _reference(*case, p)
+        _assert_rows_match(got, ref)
+        rows = (ref[..., 1] > 0).sum(axis=1)
+        if empty_first:
+            assert rows[0] == 0 and 0 < rows[1] < p.keep_topk
+        elif p.nms_thresh == 1.0:
+            # every class's list full: 5 x 130 keeps, cut at keep_topk
+            assert (rows == min(5 * 130, p.keep_topk)).all()
+
+
 def _placed(priors_n, scores, overlaps=(), classes=4):
     """One image of hand-placed candidates.  Prior ``i`` is a small box in
     its own cell of a 64-wide grid and ``loc`` is zero, so a decoded box
@@ -256,6 +301,18 @@ class TestFlatIndexOrder:
                        overlaps=[(5, 389)])
         self._check(case, [(1, 0.9, 5), (2, 0.8, 700), (3, 0.8, 7),
                            (2, 0.7, 130), (2, 0.7, 900)])
+
+    @pytest.mark.parametrize("keep_topk", [2, 3, 4, 5])
+    def test_equal_scores_in_several_classes_at_the_cut(self, keep_topk):
+        """Four candidates of one score in three classes where keep_topk
+        ends among them: class-major, within a class by prior (class 2's
+        10 before its 700, popped in that order into its list), and the
+        cut takes exactly the first ``keep_topk``."""
+        case = _placed(1300, {(1, 5): 0.9, (3, 40): 0.6, (2, 700): 0.6,
+                              (1, 900): 0.6, (2, 10): 0.6, (3, 7): 0.3})
+        rows = [(1, 0.9, 5), (1, 0.6, 900), (2, 0.6, 10), (2, 0.6, 700),
+                (3, 0.6, 40), (3, 0.3, 7)]
+        self._check(case, rows[:keep_topk], keep_topk=keep_topk)
 
     def test_higher_prior_wins_on_score_not_on_place(self):
         """The same pair with the higher prior scoring higher: it is
@@ -363,10 +420,13 @@ class TestBackendResolution:
 
     def test_estimate_counts_tile_padding(self):
         """A per-prior vector is a dense (P_pad / 128, 128) tile, P padded
-        to whole (8, 128) registers: the scratch and the input blocks
-        cost their logical bytes, and only the (keep_topk, 6) output
-        block pads (6 lanes to 128).  The one-sublane layout before PR 30
-        cost 8x: 9.1 MiB at SSD300 and 24.9 MiB at SSD512."""
+        to whole (8, 128) registers: the sweep's scratch and the input
+        blocks cost their logical bytes.  The five keep lists (scores,
+        box corners) hold min(nms_topk, P) slots a foreground class,
+        padded to 128 lanes and the class axis to 8 sublanes; the
+        (keep_topk, 6) output block pads 6 lanes to 128.  Keeps held as
+        one prior tile a class cost 1.7 MiB at SSD300 and 4.3 MiB at
+        SSD512; the one-sublane layout before that 9.1 and 24.9 MiB."""
         from analytics_zoo_tpu.ops import vmem
         from analytics_zoo_tpu.ops.pallas_detout import fused_vmem_bytes
 
@@ -375,18 +435,28 @@ class TestBackendResolution:
         ssd512 = fused_vmem_bytes(24564, 21, 200)
         assert small < ssd300 < ssd512
         out = 2 * 200 * 128 * 4              # two (200, 6) blocks, padded
-        # 20 keep tiles + 4 box tiles + 2 masks of scratch; 2 score,
-        # 2 x 4 loc, 4 prior and 4 variance tiles of input blocks
-        vectors = (20 + 4 + 2) + (2 + 8 + 4 + 4)
-        assert ssd300 == vectors * 9216 * 4 + out       # 72 rows of 128
-        assert ssd512 == vectors * 24576 * 4 + out      # 192 rows
-        assert small == (5 + 6 + 18) * 1024 * 4 + 2 * 32 * 128 * 4
-        assert 1.74 < ssd300 / 2**20 < 1.75
-        assert 4.32 < ssd512 / 2**20 < 4.33
-        assert vmem.limit_bytes(ssd512) < 15 * 2**20
+        # 4 box tiles + 2 masks of scratch; 2 score, 2 x 4 loc, 4 prior
+        # and 4 variance tiles of input blocks
+        vectors = (4 + 2) + (2 + 8 + 4 + 4)
+        # min(400, P) = 400 slots -> 4 rows of 128; 20 classes -> 24
+        lists = 5 * 4 * 24 * 128 * 4
+        assert ssd300 == vectors * 9216 * 4 + lists + out   # 72 rows
+        assert ssd512 == vectors * 24576 * 4 + lists + out  # 192 rows
+        # 160 priors: 2 rows of slots, 5 classes -> 8
+        assert small == ((6 + 18) * 1024 * 4 + 5 * 2 * 8 * 128 * 4
+                         + 2 * 32 * 128 * 4)
+        # a list never holds more slots than there are priors
+        assert fused_vmem_bytes(160, 6, 32, nms_topk=2048) == small
+        assert fused_vmem_bytes(24564, 21, 200, nms_topk=130) == (
+            ssd512 - lists // 2)
+        assert 1.27 < ssd300 / 2**20 < 1.28
+        assert 2.67 < ssd512 / 2**20 < 2.68
+        assert vmem.limit_bytes(ssd512) < 13 * 2**20
         assert vmem.fits(ssd300) and vmem.fits(ssd512)
-        # a geometry four times SSD512's priors at 81 classes still fits
+        # four times SSD512's priors: 81 classes fit, and so do 201,
+        # which a prior tile of keeps a class did not
         assert vmem.fits(fused_vmem_bytes(4 * 24564, 81, 200))
+        assert vmem.fits(fused_vmem_bytes(4 * 24564, 201, 200))
 
     def test_param_is_static_arg_usable(self):
         p = DetectionOutputParam(backend="fused")
