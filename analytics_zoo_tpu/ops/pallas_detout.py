@@ -14,15 +14,18 @@ stage boundary.  This module is the same math as ONE kernel over a
   keeps them VMEM-resident; the corner boxes land in VMEM scratch that
   persists across the class grid — the ``pallas_rnn`` residency trick);
 - **confidence filter + candidate selection + suppression sweep** fuse
-  into a single greedy loop per (image, class): pop the max remaining
-  score above ``conf_thresh`` (the pop ORDER is the sorted order, so
-  no top_k materialization is needed), stop after ``nms_topk`` pops
-  (the reference's nmsFast topk-400 pre-filter, reproduced exactly:
-  rank is the pop index), and for each still-active pop append it to
-  the class's keep list and deactivate overlapping candidates with one
-  VPU IoU row.  The background class never enters: only foreground
-  rows are in the grid, so the discard happens at selection, not by
-  post-hoc masking;
+  into a single greedy loop per (image, class) over ONE pool of the
+  candidates still eligible.  The pool starts as the scores above
+  ``conf_thresh`` of rank under ``nms_topk`` (the reference's nmsFast
+  topk-400 pre-filter, reproduced exactly: a row with more candidates
+  finds its cut once, by counting passes over the scores' bits and
+  then over the priors tied at the cut).  Each pop takes the pool's max
+  (the pop ORDER is the sorted order, so no top_k materialization is
+  needed), appends it to the class's keep list and clears it and every
+  candidate it overlaps from the pool with one VPU IoU row: every pop
+  is a keep, and the loop runs while the pool holds a candidate.  The
+  background class never enters: only foreground rows are in the grid,
+  so the discard happens at selection, not by post-hoc masking;
 - **global cross-class top-K** runs at the last class step over the
   per-class keep lists alone: pop the global max ``min(kept,
   keep_topk)`` times, tie-broken by (class, slot) — within a class the
@@ -36,16 +39,15 @@ is streaming the inputs once and writing the (B, keep_topk, 6) result.
 
 **Layout.**  Mosaic tiles a buffer's last two dims in ``(8, 128)``
 registers, so every per-prior vector (scores, the four box rows, the
-sweep's two masks) is a dense ``(P_pad / 128, 128)`` tile with
+sweep's pool) is a dense ``(P_pad / 128, 128)`` tile with
 ``P_pad`` a multiple of 1,024: SSD512's 24,564 priors are 192 rows, 24
 full registers (as ``(1, P)`` rows they were 192 registers with one
 sublane of eight in use).  Prior ``p`` sits at ``[p // 128, p % 128]``
 and ``p`` is the index every tie-break is stated on.  A pass over a
-vector (max, index of the max, the IoU row) costs 24 register
-operations; whatever concerns ONE candidate of the sweep — clearing its
-``remaining`` bit, reading its ``active`` bit and its box, appending it
-to its class's list — loads, selects in and stores the one aligned
-register that holds it.
+vector (max, index of the max, the box of the popped prior as masked
+sums, the IoU row) costs 24 register operations; appending a keep to
+its class's list loads, selects in and stores the one aligned register
+of the list that holds its slot.
 
 **The keep lists.**  A class keeps at most ``min(nms_topk, P)``
 candidates, so its list has that many slots, rounded up to 128 lanes:
@@ -55,11 +57,12 @@ scores and of the kept boxes' four corners, class ``k``'s slot ``n`` at
 slot ``n`` (the count is the loop's carry); it pops in descending
 order, so every list comes out sorted.  At SSD512 the scores are 12
 registers where one ``(P_pad / 128, 128)`` tile a class was 480.  A
-merge pop stays in vector registers: the max, the lowest tie key among
-the maxima, then the slot's class and box as masked sums over the
-lists, and the slot zeroed, all as whole-list passes — never a scalar
-read back from a vector to address one register (the pop is bound by
-its chain of reductions, not by the registers it scans).
+pop, of the sweep or of the merge, stays in vector registers: the max,
+the lowest tie key among the maxima, then the popped entry as masked
+sums, all as whole-vector passes — never a scalar read back from a
+vector to address one register (a pop is bound by its chain of
+reductions, not by the registers it scans).  The sweep's one scalar
+read-back a pop is its loop's continue test.
 
 Semantics contract: bit-for-bit the same detections as
 ``detection_output_single`` (and therefore the xla/pallas backends) up
@@ -77,7 +80,9 @@ the kernel requests from Mosaic.
 ``stage`` builds prefix programs of the same kernel ("decode" →
 "select" → "full") so a caller can ladder the fused cost into parts
 that sum to the whole BY CONSTRUCTION (each rung is a prefix; rung
-deltas are stage costs; the chip's ladder: PERF.md, PR 30).
+deltas are stage costs; the chip's ladder: PERF.md, PR 30).  The
+"select" rung's probe also carries the sweep's trip count, its pops (=
+keeps) a picture, in column 1.
 """
 
 from __future__ import annotations
@@ -117,7 +122,7 @@ def fused_vmem_bytes(n_priors: int, n_classes: int, keep_topk: int,
     the logical bytes of the padded priors; the keep lists pad their
     class axis to 8 sublanes and their slots to 128 lanes, and the
     ``(keep_topk, 6)`` output block pads 6 lanes to 128.  Counted: the
-    decoded boxes (4 vectors) and the two sweep masks, the five keep
+    decoded boxes (4 vectors) and the sweep's pool, the five keep
     lists (score and box corners, ``min(nms_topk, P)`` slots a
     foreground class; ``nms_topk`` defaults to ``DetectionOutputParam``'s
     400), the double-buffered score and loc blocks, the single-buffered
@@ -131,42 +136,35 @@ def fused_vmem_bytes(n_priors: int, n_classes: int, keep_topk: int,
     quad = padded_bytes((4, rows, 128), np.float32)
     lists = padded_bytes(
         (5, _list_slots(n_priors, nms_topk) // 128, n_fg, 128), np.float32)
-    scratch = 2 * vec + quad + lists
+    scratch = vec + quad + lists
     blocks = 2 * vec + 2 * quad + 2 * quad
     return scratch + blocks + 2 * padded_bytes((keep_topk, 6), np.float32)
 
 
 def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
-                  boxes, active, remaining, kscore, kbox,
+                  boxes, pool, kscore, kbox,
                   *, n_fg: int, n_priors: int, rows: int, slots: int,
                   kout: int, conf_thresh: float, nms_thresh: float,
                   nms_topk: int, bg_id: int, clip: bool, stage: str):
     """One (image, class) grid step.  A per-prior vector is an
     ``(rows, 128)`` tile and prior ``p`` sits at ``[p // 128, p % 128]``;
     class ``k``'s keep list slot ``n`` sits at ``[n // 128, k, n % 128]``
-    of ``kscore`` and of each corner of ``kbox``.  Whole-vector work
-    (max, index of the max, the IoU row) runs over ``rows / 8`` full
-    registers; whatever the sweep does to ONE candidate or list slot
-    loads, selects in and stores the one aligned ``(8, 128)`` register
-    that holds it (TPU VMEM has no scalar stores).  Scratch persists
-    across the class grid, which is what lets decode run once per image
-    and the global merge see every class's keeps without an HBM
-    round-trip."""
+    of ``kscore`` and of each corner of ``kbox``.  A pop, of the sweep
+    over a tile or of the merge over the lists, is whole-vector work (the
+    max, the lowest tie key among the maxima, the popped entry as masked
+    sums) kept in (1, 1) vectors; a scalar addresses a register only by
+    a loop's count: the sweep's keep-list slot, the merge's output row
+    (TPU VMEM has no scalar stores).  Scratch persists across the class grid, which is what lets
+    decode run once per image and the global merge see every class's
+    keeps without an HBM round-trip."""
     c = pl.program_id(1)
     f32 = jnp.float32
     ppad = rows * 128
     # a prior's flat index: what every tie-break below is stated on
     flat = (jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0) * 128
             + jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1))
-    flat8 = flat[:8]
     sub8 = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
     lane8 = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
-
-    def register_of(p):
-        """The aligned 8-row window holding prior ``p`` and ``p``'s
-        place in it."""
-        r0 = pl.multiple_of((p // 1024) * 8, 8)
-        return pl.ds(r0, 8), flat8 + r0 * 128 == p
 
     def slot_of(k, n):
         """The register of the keep lists holding class ``k``'s slot
@@ -176,11 +174,15 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
         row = n // 128
         return row, pl.ds(k0, 8), (sub8 == k - k0) & (lane8 == n - row * 128)
 
-    def pick(reg, hit):
-        return jnp.sum(jnp.where(hit, reg, 0.0))
+    def whole(x, op):
+        """``op`` over a whole tile or all the lists, kept a (1, 1)
+        vector: a pop never reads a scalar back from the vector unit."""
+        while x.ndim > 2:
+            x = op(x, axis=0)
+        return op(op(x, axis=0, keepdims=True), axis=1, keepdims=True)
 
-    def box_of(win, hit):
-        return [pick(boxes[i, win, :], hit) for i in range(4)]
+    def count(mask):
+        return jnp.sum(mask.astype(f32))
 
     # -- stage 1: box decode, once per image (class-constant blocks) ------
     @pl.when(c == 0)
@@ -210,54 +212,79 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
         def _clear_lists():                 # a score of 0 is an empty slot
             kscore[:] = jnp.zeros(kscore.shape, f32)
 
-        valid = ((flat < n_priors)
-                 & (scores_ref[0, 0] > conf_thresh)).astype(f32)
-        active[:] = valid
-        remaining[:] = valid
-        # pop order IS descending-score order (ties: lowest prior index,
-        # lax.top_k's stable order), and the pop INDEX is the sorted
-        # rank — so stopping at nms_topk pops reproduces the reference's
-        # topk-400 pre-filter without materializing a sorted list.  The
-        # bound is dynamic (a while_loop), so the common sparse case
-        # (conf_thresh kills most priors) costs #valid pops, not K.
-        bound = jnp.minimum(jnp.sum(valid).astype(jnp.int32), nms_topk)
+        # the pool: the scores of the candidates still eligible, -inf
+        # elsewhere.  Rank is score descending, then prior ascending
+        # (lax.top_k's stable order), and only the first nms_topk by
+        # rank enter: the reference's topk-400 pre-filter
+        s = scores_ref[0, 0]
+        valid = (flat < n_priors) & (s > conf_thresh)
+        pool[:] = jnp.where(valid, s, -jnp.inf)
 
-        def body(i, n_kept):
-            vals = jnp.where(remaining[:] > 0, scores_ref[0, 0], -jnp.inf)
-            m = jnp.max(vals)
-            p = jnp.min(jnp.where(vals == m, flat, ppad))
-            win, hit = register_of(p)
-            remaining[win, :] = jnp.where(hit, 0.0, remaining[win, :])
-            keep = pick(active[win, :], hit) > 0.0
+        @pl.when(count(valid) > nms_topk)
+        def _cut():
+            # the nms_topk-th largest score exactly: its bits, in an
+            # order signed compares keep (-0.0 as +0.0, as the pops
+            # compare), built from the top by 32 counting passes
+            lowest = jnp.int32(-2 ** 31)
+            b = jax.lax.bitcast_convert_type(jnp.where(s == 0.0, 0.0, s),
+                                             jnp.int32)
+            key = jnp.where(valid, b ^ ((b >> 31) & 0x7FFFFFFF), lowest)
 
-            @pl.when(keep)
-            def _keep():
-                # append (score, box) to the class's list: the pops
-                # come in descending order, so the list is sorted
-                row, kwin, at = slot_of(c, n_kept)
-                kscore[row, kwin, :] = jnp.where(at, m, kscore[row, kwin, :])
-                x1, y1, x2, y2 = box_of(win, hit)
-                for corner, v in enumerate((x1, y1, x2, y2)):
-                    kbox[corner, row, kwin, :] = jnp.where(
-                        at, v, kbox[corner, row, kwin, :])
-                bx1, by1, bx2, by2 = (boxes[i] for i in range(4))
-                ix1 = jnp.maximum(bx1, x1)
-                iy1 = jnp.maximum(by1, y1)
-                ix2 = jnp.minimum(bx2, x2)
-                iy2 = jnp.minimum(by2, y2)
-                inter = (jnp.maximum(ix2 - ix1, 0.0)
-                         * jnp.maximum(iy2 - iy1, 0.0))
-                area = (bx2 - bx1) * (by2 - by1)
-                area_p = (x2 - x1) * (y2 - y1)
-                union = jnp.maximum(area + area_p - inter, 1e-12)
-                # deactivate everything overlapping the kept box
-                # (including itself; it is already on the list)
-                active[:] = jnp.where(inter / union >= nms_thresh, 0.0,
-                                      active[:])
+            def bit(i, tau):
+                cand = tau | (jnp.int32(1) << (31 - i))
+                return jnp.where(count(key >= (cand ^ lowest)) >= nms_topk,
+                                 cand, tau)
 
-            return n_kept + keep.astype(jnp.int32)
+            t = jax.lax.fori_loop(0, 32, bit, jnp.int32(0)) ^ lowest
+            # of the candidates tied at it, the lowest priors that fit:
+            # the last one's index, built from the top the same way
+            tie = key == t
+            room = nms_topk - count(key > t)
+            nbits = (ppad - 1).bit_length()
 
-        jax.lax.fori_loop(0, bound, body, jnp.int32(0))
+            def place(i, tau):
+                cand = tau | (jnp.int32(1) << (nbits - 1 - i))
+                return jnp.where(count(tie & (flat < cand)) < room, cand, tau)
+
+            last = jax.lax.fori_loop(0, nbits, place, jnp.int32(0))
+            pool[:] = jnp.where((key > t) | (tie & (flat <= last)),
+                                pool[:], -jnp.inf)
+
+        # greedy suppression: the pool's max (ties: lowest prior) is the
+        # next keep in rank order, since every candidate above it has
+        # been kept or suppressed.  Each pop appends it to the class's
+        # list (the pops come in descending order, so the list is
+        # sorted) and takes it and every candidate whose IoU with it
+        # reaches nms_thresh out of the pool — the popped one by its own
+        # mask, since a box of zero area has IoU 0 with itself.  So every
+        # pop is a keep and the loop runs while the pool holds one.
+        def pop(carry):
+            n, m = carry
+            pv = pool[:]
+            sel = flat == whole(jnp.where(pv == m, flat, ppad), jnp.min)
+            x1, y1, x2, y2 = [whole(jnp.where(sel, boxes[i], 0.0), jnp.sum)
+                              for i in range(4)]
+            row, kwin, at = slot_of(c, n)
+            kscore[row, kwin, :] = jnp.where(at, m, kscore[row, kwin, :])
+            for corner, v in enumerate((x1, y1, x2, y2)):
+                kbox[corner, row, kwin, :] = jnp.where(
+                    at, v, kbox[corner, row, kwin, :])
+            bx1, by1, bx2, by2 = (boxes[i] for i in range(4))
+            ix1 = jnp.maximum(bx1, x1)
+            iy1 = jnp.maximum(by1, y1)
+            ix2 = jnp.minimum(bx2, x2)
+            iy2 = jnp.minimum(by2, y2)
+            inter = jnp.maximum(ix2 - ix1, 0.0) * jnp.maximum(iy2 - iy1, 0.0)
+            area = (bx2 - bx1) * (by2 - by1)
+            area_p = (x2 - x1) * (y2 - y1)
+            union = jnp.maximum(area + area_p - inter, 1e-12)
+            pv = jnp.where(sel | (inter / union >= nms_thresh), -jnp.inf, pv)
+            pool[:] = pv
+            return n + 1, whole(pv, jnp.max)
+
+        n_kept, _ = jax.lax.while_loop(
+            lambda carry: jnp.max(carry[1]) > -jnp.inf, pop,
+            (jnp.int32(0), whole(pool[:], jnp.max)))
 
     # -- stage 3: global cross-class top-K, last class step ---------------
     if stage == "full":
@@ -278,20 +305,13 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
                    * 128
                    + jax.lax.broadcasted_iota(jnp.int32, kscore.shape, 2))
             no_key = kscore.shape[1] * slots
-            n_kept = jnp.sum((kscore[:] > 0).astype(f32)).astype(jnp.int32)
-            npop = jnp.minimum(n_kept, kout)
+            npop = jnp.minimum(count(kscore[:] > 0).astype(jnp.int32), kout)
 
             # foreground row → original class id (the background column
             # was dropped before the kernel)
             cls_id = jax.lax.broadcasted_iota(jnp.int32, kscore.shape, 1)
             cls_id = (cls_id + (cls_id >= bg_id).astype(jnp.int32)
                       ).astype(f32)
-
-            def whole(x, op):
-                """``op`` over a whole list, kept a (1, 1) vector: the
-                pop never reads a scalar back from the vector unit."""
-                x = op(x, axis=0)
-                return op(op(x, axis=1, keepdims=True), axis=0, keepdims=True)
 
             def body(j, _):
                 ks = kscore[:]
@@ -316,15 +336,28 @@ def _fused_kernel(scores_ref, loc_ref, priors_ref, var_ref, out_ref,
     else:
         # prefix stages for the profile ladder: the output must DEPEND
         # on the computed scratch (an all-constant write would let the
-        # interpret-mode emulation dead-code the measured work)
+        # interpret-mode emulation dead-code the measured work).  The
+        # "select" probe's column 1 is the sweep's engagement counter:
+        # its pops (= keeps) a picture, summed over the class steps
+        col = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 2)
+        if stage == "select":
+            @pl.when(c == 0)
+            def _zero():
+                out_ref[:] = jnp.zeros(out_ref.shape, f32)
+
+            out_ref[:] = out_ref[:] + jnp.where(col == 1,
+                                                n_kept.astype(f32), 0.0)
+
         @pl.when(c == n_fg - 1)
         def _touch():
             probe = jnp.sum(boxes[0]) + jnp.sum(boxes[3])
+            pops = 0.0
             if stage == "select":
                 ks = kscore[:]
                 probe += jnp.sum(ks) + jnp.sum(
                     jnp.where(ks > 0, kbox[0], 0.0))
-            out_ref[:] = jnp.zeros(out_ref.shape, f32) + probe
+                pops = out_ref[:]
+            out_ref[:] = jnp.where(col == 1, pops, probe)
 
 
 @functools.partial(jax.jit, static_argnames=("param", "interpret", "stage"))
@@ -399,8 +432,7 @@ def fused_detection_output(loc: jax.Array, conf: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, kpad, 6), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((4, rows, 128), jnp.float32),        # boxes
-            pltpu.VMEM((rows, 128), jnp.float32),           # active
-            pltpu.VMEM((rows, 128), jnp.float32),           # remaining
+            pltpu.VMEM((rows, 128), jnp.float32),           # pool
             # the keep lists: scores, box corners
             pltpu.VMEM((slots // 128, round_up(n_fg, 8), 128), jnp.float32),
             pltpu.VMEM((4, slots // 128, round_up(n_fg, 8), 128),

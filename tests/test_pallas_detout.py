@@ -368,6 +368,89 @@ class TestFlatIndexOrder:
                            _reference(loc, conf, priors, variances, p))
 
 
+class TestPoolSweep:
+    """The sweep pops from ONE pool of eligible candidates — the first
+    ``nms_topk`` by rank, each keep taking out of it what it suppresses —
+    so every pop is a keep.  Each case bit for bit against the xla
+    reference, and where the answer is known, against it written out."""
+
+    def _check(self, case, rows=None, **param):
+        p = DetectionOutputParam(**{"n_classes": 4, **param})
+        got = _fused(*case, p)
+        np.testing.assert_array_equal(got, _reference(*case, p))
+        if rows is not None:
+            _assert_rows_match(got, _expected_rows(case[2], rows,
+                                                   p.keep_topk))
+
+    def test_scores_tied_across_the_cut(self):
+        """506 candidates of one class and nms_topk 400: 390 distinct
+        scores, then 16 tied at 0.5 across rows and registers, then 100
+        below.  The cut takes the tied ones with the lowest priors that
+        fit (10 of 16), and counts one that a keep suppresses (401, by
+        prior 5) in the rank: 902 does not move up in its place."""
+        top = {(1, i): float(np.float32(0.9) - np.float32(i * 1e-4))
+               for i in range(390)}
+        tied = [1299, 1024, 1023, 400, 401, 900, 901, 902, 390, 391, 392,
+                393, 394, 395, 1100, 1101]
+        below = {(1, i): 0.3 for i in range(600, 700)}
+        case = _placed(1300, {**top, **{(1, i): 0.5 for i in tied},
+                              **below, (2, 7): 0.95},
+                       overlaps=[(5, 401)])
+        rows = ([(2, 0.95, 7)] + [(1, top[(1, i)], i) for i in range(390)]
+                + [(1, 0.5, i) for i in (390, 391, 392, 393, 394, 395,
+                                         400, 900, 901)])
+        self._check(case, rows, nms_topk=400, keep_topk=420)
+
+    @pytest.mark.parametrize("clip", [True, False])
+    def test_zero_area_candidates(self, clip):
+        """Priors past the picture's right edge clip to boxes of zero
+        width (IoU 0 with themselves, and with everything): the pop takes
+        each out by its own mask, the loop ends, and all are kept.
+        Unclipped, the same priors are ordinary boxes."""
+        case = _placed(1300, {(1, i): 0.5 + i * 1e-4 for i in range(12)})
+        loc, conf, priors, variances = case
+        priors = priors.copy()
+        priors[:6, [0, 2]] += 1.5           # six boxes past x = 1
+        priors[6:9] = priors[6]             # three identical ones
+        self._check((loc, conf, priors, variances), clip_boxes=clip,
+                    keep_topk=16)
+
+    @pytest.mark.parametrize("n", [300, 450])
+    def test_one_box_suppresses_all_the_others(self, n):
+        """Every candidate of the class overlaps the best one: one keep,
+        under the cut and over it."""
+        case = _placed(1300, {**{(1, i): 0.2 + i * 1e-4 for i in range(n)},
+                              (3, 1299): 0.1},
+                       overlaps=[(n - 1, i) for i in range(n - 1)])
+        self._check(case, [(1, 0.2 + (n - 1) * 1e-4, n - 1),
+                           (3, 0.1, 1299)], nms_topk=400, keep_topk=8)
+
+    @pytest.mark.parametrize("priors_n", PRIOR_COUNTS)
+    def test_select_rung_counts_pops_equal_to_keeps(self, priors_n):
+        """The ``select`` prefix's column 1 is the sweep's trip count, a
+        picture: equal to the keeps of every class (the xla reference's
+        answer with room for all of them), and fewer than the ranked
+        candidates a sweep that also pops discards would take."""
+        from analytics_zoo_tpu.ops.pallas_detout import (
+            fused_detection_output)
+
+        loc, conf, priors, variances = _inputs(
+            4, priors_n=priors_n, bg_bias=4.0, hot_frac=0.1)
+        p = DetectionOutputParam(**BASE)
+        probe = np.asarray(fused_detection_output(
+            loc, conf, priors, variances, param=p, interpret=True,
+            stage="select"))
+        pops = probe[:, 0, 1]
+        assert (probe[..., 1] == pops[:, None]).all()
+        every = dataclasses.replace(p, keep_topk=5 * p.nms_topk)
+        keeps = (_reference(loc, conf, priors, variances, every)[..., 0]
+                 >= 0).sum(axis=1)
+        np.testing.assert_array_equal(pops, keeps)
+        ranked = np.minimum((np.asarray(conf)[..., 1:] > p.conf_thresh)
+                            .sum(axis=1), p.nms_topk).sum(axis=1)
+        assert (pops < ranked).all()
+
+
 class TestBackendResolution:
     """``resolve_backend`` is the one place a backend is chosen, and what
     it returns is what ``detection_output`` runs: an explicitly named
@@ -420,8 +503,9 @@ class TestBackendResolution:
 
     def test_estimate_counts_tile_padding(self):
         """A per-prior vector is a dense (P_pad / 128, 128) tile, P padded
-        to whole (8, 128) registers: the sweep's scratch and the input
-        blocks cost their logical bytes.  The five keep lists (scores,
+        to whole (8, 128) registers: the sweep's scratch (the decoded
+        boxes and ONE pool of eligible candidates) and the input blocks
+        cost their logical bytes.  The five keep lists (scores,
         box corners) hold min(nms_topk, P) slots a foreground class,
         padded to 128 lanes and the class axis to 8 sublanes; the
         (keep_topk, 6) output block pads 6 lanes to 128.  Keeps held as
@@ -435,22 +519,22 @@ class TestBackendResolution:
         ssd512 = fused_vmem_bytes(24564, 21, 200)
         assert small < ssd300 < ssd512
         out = 2 * 200 * 128 * 4              # two (200, 6) blocks, padded
-        # 4 box tiles + 2 masks of scratch; 2 score, 2 x 4 loc, 4 prior
+        # 4 box tiles + 1 pool of scratch; 2 score, 2 x 4 loc, 4 prior
         # and 4 variance tiles of input blocks
-        vectors = (4 + 2) + (2 + 8 + 4 + 4)
+        vectors = (4 + 1) + (2 + 8 + 4 + 4)
         # min(400, P) = 400 slots -> 4 rows of 128; 20 classes -> 24
         lists = 5 * 4 * 24 * 128 * 4
         assert ssd300 == vectors * 9216 * 4 + lists + out   # 72 rows
         assert ssd512 == vectors * 24576 * 4 + lists + out  # 192 rows
         # 160 priors: 2 rows of slots, 5 classes -> 8
-        assert small == ((6 + 18) * 1024 * 4 + 5 * 2 * 8 * 128 * 4
+        assert small == ((5 + 18) * 1024 * 4 + 5 * 2 * 8 * 128 * 4
                          + 2 * 32 * 128 * 4)
         # a list never holds more slots than there are priors
         assert fused_vmem_bytes(160, 6, 32, nms_topk=2048) == small
         assert fused_vmem_bytes(24564, 21, 200, nms_topk=130) == (
             ssd512 - lists // 2)
-        assert 1.27 < ssd300 / 2**20 < 1.28
-        assert 2.67 < ssd512 / 2**20 < 2.68
+        assert 1.23 < ssd300 / 2**20 < 1.24
+        assert 2.58 < ssd512 / 2**20 < 2.59
         assert vmem.limit_bytes(ssd512) < 13 * 2**20
         assert vmem.fits(ssd300) and vmem.fits(ssd512)
         # four times SSD512's priors: 81 classes fit, and so do 201,
