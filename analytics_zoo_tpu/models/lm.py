@@ -1,47 +1,33 @@
 """A causal decoder LM for serving through device-resident session caches
-(pipelines/lm.py): RMS-norm pre-norm residual blocks; attention in one of
-two FORMS — multi-head latent attention (MLA: one latent + rotary key a
-token in the cache, shared by all heads) or grouped-query attention (GQA:
-real keys and values a KV head in the cache) — and three kinds of layer by
-REACH: FULL layers (MLA only) with a learned sparse indexer that selects
-the ``index_topk`` positions a token attends to, SLIDING layers over a
-window (a ring a session), CAUSAL layers over the whole context (every
-page a row holds, read by a paged decode kernel: ops/pallas_lm_decode.py);
-a gated dense MLP in the leading layers, then sparse experts (sigmoid
-router of the published width, top-k — group-limited where the config says
-so — a shared expert where the config has one) of which THIS CHIP HOLDS A
-SHARE (parallel/expert.py); embedding and head over a slice of the
-vocabulary.
+(pipelines/lm.py): RMS-norm pre-norm residual blocks, each an attention of
+one FORM and one KIND (with a state-space mixer beside it where the config
+has one, ops/ssm.py), then a gated dense MLP in the leading layers or
+sparse experts (sigmoid router of the published width, top-k) of which
+THIS CHIP HOLDS A SHARE (parallel/expert.py); embedding and head over a
+slice of the vocabulary.  WHICH model it is comes from the keys of a
+HuggingFace-style ``config.json`` alone (``LMConfig.from_dict``); the
+parameter tree's names are the benchmark reference's
+(benchmarks/reference/lm.py), which is how the same weights reach both.
 
-WHICH model it is comes from the config's keys alone
-(``LMConfig.from_dict``).  The FORM: a config with ``kv_lora_rank`` is
-latent (``MLADims``), one with ``num_key_value_heads`` and none is
-grouped-query (``GQADims``: heads and KV heads, key and value widths,
-partial rotary, value scale and a learned softmax sink a kind of layer).
-The KINDS: ``layer_types`` names FULL and SLIDING layers (the
-``dots3_note`` family: headwise sigmoid gates on the heads' outputs,
-rescaled latents, a bias-corrected router); else ``hybrid_layer_pattern``
-names CAUSAL (0) and SLIDING (1) layers (the ``mimo_v2`` family:
-grouped-query, KV heads and rotary base a kind, sinks in the window
-layers, no shared expert); a config with neither is CAUSAL throughout
-(the ``axk1`` family: YaRN rotary scaling, routing groups, no gate, no
-rescale, no router bias).  The leading dense layers are
-``first_k_dense_replace`` or the leading zeros of a ``moe_layer_freq``
-list.  ``param_shapes`` and ``cache_shapes`` follow: a model without
-sliding layers has no ring, one without an indexer no index keys, one
-without a shared expert no ``shared`` leaf; a grouped-query model's pools
-and rings are as wide as their kind's KV heads.
+The FORM is the type of a layer's attention sizes: multi-head latent
+attention (``MLADims``: one latent + rotary key a token in the cache,
+shared by all heads) or grouped-query attention (``GQADims``: real keys
+and values a KV head in the cache); the class holds the form's leaves,
+``project``, ``scope`` and attention calls (each op looked up on
+ops/lm_attention.py when it runs).  The KIND is a row of ``KINDS``: FULL
+(latent only: a learned sparse indexer selects the ``index_topk``
+positions a token attends to), CAUSAL (the whole context, read by a paged
+decode kernel: ops/pallas_lm_decode.py) or SLIDING (a window: a ring a
+session).  A row names its sizes, the cache lists a layer of it owns, its
+leaves beyond the form's and its two bodies; ``param_shapes``,
+``cache_shapes`` and each layer's place in the cache come from the rows,
+and :func:`walk` runs both step programs' layers.  A new kind costs one
+row, its two bodies and the shape of any list it brings; a new form one
+class.
 
 Functional, not flax: the step functions take the parameters and the
 cache and return the new cache, so one jitted call is a whole batch of
-rows and the cache is donated from call to call.
-
-``LMConfig.from_dict`` reads a HuggingFace-style ``config.json`` with the
-share beside it (``expert_share``); the parameter tree's names are the
-benchmark reference's (benchmarks/reference/lm.py), which is how the same
-weights reach both.
-
-Two step programs:
+rows and the cache is donated from call to call.  Two step programs:
 
 - :func:`decode_step` — B rows of any sessions, one token each (its
   integer arguments in one vector, :func:`pack_rows`: one transfer a
@@ -54,15 +40,15 @@ over the vocabulary slice at each row's last position, the tokens each held
 expert got, and the step's discrete CHOICES: ``{"selected": a full layer's
 selected positions (ops/lm_attention.py; an empty list without full
 layers), "routed": (MoE layers, tokens, k) expert ids}``.  They stay on
-the device unless somebody fetches them
-(pipelines/lm.py records them for the sessions it is asked to).
+the device unless somebody fetches them (pipelines/lm.py records them for
+the sessions it is asked to).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Optional, Tuple, Union
+from typing import Callable, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -73,11 +59,9 @@ from analytics_zoo_tpu.ops import pallas_ssm_decode, ssm
 from analytics_zoo_tpu.parallel.expert import moe_held_experts
 
 F32 = jnp.float32
-FULL, SLIDING = "full_attention", "sliding_attention"
-#: a third kind, for a config that names no ``layer_types``: causal MLA over
-#: the WHOLE context — no indexer, no index keys, no ring; decode reads every
-#: page a row holds (ops/pallas_lm_decode.py)
-CAUSAL = "causal_attention"
+#: the kinds of layer (``KINDS``)
+FULL, SLIDING, CAUSAL = ("full_attention", "sliding_attention",
+                         "causal_attention")
 #: a cache entry's width is rounded up to this many elements: the TPU
 #: tiles the minor axis by 128, and for a width of 576 its default layout
 #: puts the PAGE axis minor, so that every gather and scatter of a token's
@@ -112,6 +96,39 @@ class MLADims:
         """Width of a token's cache entry: latent, rotary key, padding."""
         return -(-(self.kv_rank + self.rope) // LANE) * LANE
 
+    def leaves(self, d: int, S) -> Dict:
+        return {"wq_a": S(d, self.q_rank), "q_norm": S(self.q_rank),
+                "wq_b": S(self.q_rank, self.heads, self.nope + self.rope),
+                "wkv_a": S(d, self.kv_rank + self.rope),
+                "kv_norm": S(self.kv_rank),
+                "wkv_b": S(self.kv_rank, self.heads, self.nope + self.v),
+                "wo": S(self.heads, self.v, d)}
+
+    def project(self, cfg: "LMConfig", w: Dict, x, pos):
+        return latents(cfg, self, w, x, pos)
+
+    def scope(self, window: bool):
+        return jax.named_scope("lm/mla_window" if window else "lm/mla_paged")
+
+    def paged(self, w, q, kv, tables, lengths):
+        return att.mla_paged(*q, kv, tables, lengths, w["wkv_b"], self.nope,
+                             self.rope, self.scale)
+
+    def gathered(self, w, q, mine, valid):
+        return att.mla_absorbed(*q, mine, valid, w["wkv_b"], self.nope,
+                                self.rope, self.scale)
+
+    def prefill_paged(self, w, q, kv, table, start, n_valid, pages_per_step):
+        return att.prefill_causal_attention(
+            *q, kv, table, start, n_valid, w["wkv_b"], self.nope, self.rope,
+            self.scale, pages_per_step, flash=PREFILL_HEADS_PER_STEP)
+
+    def prefill_window(self, w, q, c, prev, prev_pos, start, n_valid,
+                       window, q_block):
+        return att.prefill_window_attention(
+            jnp.concatenate(q, -1), c, prev, prev_pos, start, n_valid,
+            w["wkv_b"], self.nope, self.rope, self.scale, window, q_block)
+
 
 @dataclasses.dataclass(frozen=True)
 class GQADims:
@@ -139,6 +156,52 @@ class GQADims:
         """Width of a token's cache entry: every KV head's key and value
         (ops/lm_attention.py ``gqa_entry``), nothing else."""
         return self.kv_heads * (self.k + self.v)
+
+    def leaves(self, d: int, S) -> Dict:
+        sink = jax.ShapeDtypeStruct((self.heads,), F32)
+        return {"wq": S(d, self.heads, self.k),
+                "wk": S(d, self.kv_heads, self.k),
+                "wv": S(d, self.kv_heads, self.v),
+                "wo": S(self.heads, self.v, d),
+                **({"sink": sink} if self.sink else {})}
+
+    def project(self, cfg: "LMConfig", w: Dict, x, pos):
+        """((q_plain (N, H, k − rotary), q_rot (N, H, rotary) rotated), c
+        (N, entry) the rotated keys and scaled values (``gqa_entry``), no
+        gate, no c_q); with multipliers, of ``attn_in · x``, the keys times
+        ``key``."""
+        mup, r = cfg.mup, self.rotary
+        x = times(x, mup.attn_in)
+        q = jnp.einsum("nd,dhe->nhe", x, w["wq"])
+        k = jnp.einsum("nd,dge->nge", x, w["wk"]) if mup.key == 1.0 \
+            else scaled("nd,dge->nge", x, w["wk"], mup.key)
+        v = (jnp.einsum("nd,dge->nge", x, w["wv"], preferred_element_type=F32)
+             * self.value_scale).astype(x.dtype)
+        return (q[..., r:], att.rope_half(q[..., :r], pos, self.theta)), \
+            att.gqa_entry(k[..., r:], att.rope_half(k[..., :r], pos,
+                                                    self.theta), v), None, None
+
+    def scope(self, window: bool):
+        return jax.named_scope("lm/gqa_window" if window else "lm/gqa_paged")
+
+    def paged(self, w, q, kv, tables, lengths):
+        return att.gqa_paged(*q, kv, tables, lengths, self.kv_heads, self.v,
+                             self.scale)
+
+    def gathered(self, w, q, mine, valid):
+        return att.gqa_gathered(*q, mine, valid, w.get("sink"),
+                                self.kv_heads, self.v, self.scale)
+
+    def prefill_paged(self, w, q, kv, table, start, n_valid, pages_per_step):
+        return att.prefill_gqa_causal(*q, kv, table, start, n_valid,
+                                      self.kv_heads, self.v, self.scale,
+                                      pages_per_step)
+
+    def prefill_window(self, w, q, c, prev, prev_pos, start, n_valid,
+                       window, q_block):
+        return att.prefill_gqa_window(
+            *q, c, prev, prev_pos, start, n_valid, w.get("sink"),
+            self.kv_heads, self.v, self.scale, window, q_block)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -241,9 +304,14 @@ class LMConfig:
     @classmethod
     def from_dict(cls, cfg: Dict) -> "LMConfig":
         """A config's keys are the only source of what the model is.
-        ``layer_types`` (with the ``swa_*`` and ``index_*`` sizes its
-        kinds need) names FULL and SLIDING layers; a config without it is
-        CAUSAL MLA throughout.  ``attention_gate_type: "headwise"`` → the
+        ``kv_lora_rank`` → latent attention, else ``num_key_value_heads``
+        → grouped-query.  ``layer_types`` (with the ``swa_*`` and
+        ``index_*`` sizes its kinds need) names FULL and SLIDING layers
+        (``dots3_note``), else ``hybrid_layer_pattern`` CAUSAL (0) and
+        SLIDING (1) ones (``mimo_v2``), else all are CAUSAL (``axk1``,
+        ``falcon_h1``).  The leading dense layers are
+        ``first_k_dense_replace`` or the leading zeros of a
+        ``moe_layer_freq`` list.  ``attention_gate_type: "headwise"`` → the
         heads' sigmoid gate; ``apply_mla_qkv_lora_rescale`` → sqrt(hidden /
         rank) on the latents; ``rope_scaling`` → YaRN; ``topk_method:
         "noaux_tc"`` → the router's bias; ``n_group`` / ``topk_group`` →
@@ -363,19 +431,14 @@ class LMConfig:
 
     @property
     def n_full(self) -> int:
-        return sum(k == FULL for k in self.kinds)
+        return self.kinds.count(FULL)
 
     @property
     def n_sliding(self) -> int:
-        return sum(k == SLIDING for k in self.kinds)
-
-    @property
-    def n_pools(self) -> int:
-        """Layers whose entries live in a paged pool: FULL and CAUSAL."""
-        return len(self.kinds) - self.n_sliding
+        return self.kinds.count(SLIDING)
 
     def dims(self, kind: str) -> Union[MLADims, GQADims]:
-        return self.swa if kind == SLIDING else self.full
+        return getattr(self, KINDS[kind].sizes)
 
 
 # ---------------------------------------------------------------------------
@@ -394,28 +457,10 @@ def param_shapes(cfg: LMConfig) -> Dict:
     layers = []
     for i, kind in enumerate(cfg.kinds):
         a = cfg.dims(kind)
-        if isinstance(a, GQADims):
-            attn = {"wq": S(cfg.d, a.heads, a.k),
-                    "wk": S(cfg.d, a.kv_heads, a.k),
-                    "wv": S(cfg.d, a.kv_heads, a.v),
-                    "wo": S(a.heads, a.v, cfg.d)}
-            if a.sink:
-                attn["sink"] = jax.ShapeDtypeStruct((a.heads,), F32)
-        else:
-            attn = {"wq_a": S(cfg.d, a.q_rank), "q_norm": S(a.q_rank),
-                    "wq_b": S(a.q_rank, a.heads, a.nope + a.rope),
-                    "wkv_a": S(cfg.d, a.kv_rank + a.rope),
-                    "kv_norm": S(a.kv_rank),
-                    "wkv_b": S(a.kv_rank, a.heads, a.nope + a.v),
-                    "wo": S(a.heads, a.v, cfg.d)}
+        attn = a.leaves(cfg.d, S)
         if cfg.gate:
             attn["w_gate"] = S(cfg.d, a.heads)
-        if kind == FULL:
-            attn.update({"idx_wq_b": S(a.q_rank, cfg.idx_heads, cfg.idx_dim),
-                         "idx_wk": S(cfg.d, cfg.idx_dim),
-                         "idx_k_norm_w": S(cfg.idx_dim),
-                         "idx_k_norm_b": S(cfg.idx_dim),
-                         "idx_w": S(cfg.d, cfg.idx_heads)})
+        attn.update(KINDS[kind].leaves(cfg, a, S))
         layer = {"attn_norm": S(cfg.d), "mlp_norm": S(cfg.d), "attn": attn}
         if cfg.ssm:
             m = cfg.ssm
@@ -491,30 +536,29 @@ class CacheGeometry:
 
 
 def cache_shapes(cfg: LMConfig, geo: CacheGeometry) -> Dict:
-    """One array a layer (a layer's pool is never sliced out of a stack),
-    for the kinds of layer the model has: ``kv`` for the full and the
+    """One array a layer (a layer's pool is never sliced out of a stack)
+    in each list its kind owns (``KINDS``): ``kv`` for the full and the
     causal layers, ``ik`` for the full ones (the indexer's keys), ``ring``
     for the sliding ones; for a model with a state-space mixer, a layer's
     recurrent states ``ssm`` (float32, a slot a session) and the last
     ``conv − 1`` inputs of its convolution ``conv``."""
     dt = jnp.dtype(cfg.dtype)
     S = jax.ShapeDtypeStruct
-    m, mixers = cfg.ssm, len(cfg.kinds) if cfg.ssm else 0
-    return {
-        "kv": [S((geo.n_pages, geo.page, cfg.full.entry), dt)
-               for _ in range(cfg.n_pools)],
-        "ik": [S((geo.n_pages, geo.page, cfg.idx_dim), dt)
-               for _ in range(cfg.n_full)],
-        "ring": [S((geo.n_slots, cfg.window, cfg.swa.entry), dt)
-                 for _ in range(cfg.n_sliding)],
-        "ssm": [S((geo.n_slots, m.heads, m.head, m.state), F32)
-                for _ in range(mixers)],
-        "conv": [S((geo.n_slots, m.conv - 1, m.conv_width), dt)
-                 for _ in range(mixers)]}
-
-
-#: the cache's leaves in the order the step programs unpack them
-CACHE_KEYS = ("kv", "ik", "ring", "ssm", "conv")
+    out = {"kv": [], "ik": [], "ring": [], "ssm": [], "conv": []}
+    for kind in cfg.kinds:
+        a = cfg.dims(kind)
+        shape = {"kv": (geo.n_pages, geo.page, a.entry),
+                 "ik": (geo.n_pages, geo.page, cfg.idx_dim),
+                 "ring": (geo.n_slots, cfg.window, a.entry)}
+        for k in KINDS[kind].caches:
+            out[k].append(S(shape[k], dt))
+    if cfg.ssm:
+        m, n = cfg.ssm, len(cfg.kinds)
+        out["ssm"] = [S((geo.n_slots, m.heads, m.head, m.state), F32)
+                      for _ in range(n)]
+        out["conv"] = [S((geo.n_slots, m.conv - 1, m.conv_width), dt)
+                       for _ in range(n)]
+    return out
 
 
 def new_cache(cfg: LMConfig, geo: CacheGeometry) -> Dict:
@@ -542,9 +586,9 @@ def layer_norm(x, w, b, eps):
 
 def times(x, by: float):
     """``by · x`` through float32, rounded once; ``x`` itself at 1.  The
-    ``== 1.0`` forks here and in ``gated_mlp``, ``gqa_project`` and ``head``
-    are for one thing: a configuration without multipliers lowers to
-    the program it did before there were any (the three decoder cells'
+    ``== 1.0`` forks here, in ``gated_mlp``, ``GQADims.project`` and the
+    head are for one thing: a configuration without multipliers lowers
+    to the program it did before there were any (the three decoder cells'
     step programs are compared as text with their parent's)."""
     return x if by == 1.0 else (x.astype(F32) * by).astype(x.dtype)
 
@@ -567,9 +611,10 @@ def gated_mlp(x, w, gate: float = 1.0, down: float = 1.0):
 
 
 def latents(cfg: LMConfig, a: MLADims, w: Dict, x, pos):
-    """(c_q (N, q_rank), c (N, a.entry) the cache entry — latent, rotary
-    key, zeros up to the entry's width — gate (N, H), ``None`` without one)
-    of normed inputs ``x`` (N, d) at positions ``pos`` (N,).
+    """A latent layer's ``project``: ((q_nope (N, H, nope), q_rope (N, H,
+    rope) rotated), c (N, a.entry) the cache entry — latent, rotary key,
+    zeros up to the entry's width — gate (N, H), ``None`` without one, c_q
+    (N, q_rank)) of normed inputs ``x`` (N, d) at positions ``pos`` (N,).
     ``cfg.rescale``: sqrt(hidden/rank) after the norms."""
     c_q = rms_norm(x @ w["wq_a"], w["q_norm"], cfg.eps)
     kv = x @ w["wkv_a"]
@@ -581,32 +626,10 @@ def latents(cfg: LMConfig, a: MLADims, w: Dict, x, pos):
     gate = jax.nn.sigmoid((x @ w["w_gate"]).astype(F32)) if cfg.gate \
         else None
     pad = jnp.zeros((x.shape[0], a.entry - a.kv_rank - a.rope), x.dtype)
-    return c_q, jnp.concatenate([c_kv, k_r, pad], -1), gate
-
-
-def queries(a: MLADims, w: Dict, c_q, pos):
-    """(q_nope (N, H, nope), q_rope (N, H, rope) rotated)."""
+    c = jnp.concatenate([c_kv, k_r, pad], -1)
     q = jnp.einsum("nr,rhe->nhe", c_q, w["wq_b"])
-    return q[..., :a.nope], att.rope(q[..., a.nope:], pos, a.theta,
-                                     a.scaling)
-
-
-def gqa_project(a: GQADims, w: Dict, x, pos, mup: Multipliers = Multipliers()):
-    """A grouped-query layer's (q_plain (N, H, k − rotary), q_rot (N, H,
-    rotary) rotated, c (N, a.entry) the cache entry: the rotated keys and
-    the scaled values, ops/lm_attention.py ``gqa_entry``) of normed inputs
-    ``x`` (N, d) at positions ``pos`` (N,); with multipliers, of
-    ``attn_in · x``, the keys times ``key``."""
-    x = times(x, mup.attn_in)
-    q = jnp.einsum("nd,dhe->nhe", x, w["wq"])
-    k = jnp.einsum("nd,dge->nge", x, w["wk"]) if mup.key == 1.0 \
-        else scaled("nd,dge->nge", x, w["wk"], mup.key)
-    v = (jnp.einsum("nd,dge->nge", x, w["wv"], preferred_element_type=F32)
-         * a.value_scale).astype(x.dtype)
-    r = a.rotary
-    return (q[..., r:], att.rope_half(q[..., :r], pos, a.theta),
-            att.gqa_entry(k[..., r:], att.rope_half(k[..., :r], pos, a.theta),
-                          v))
+    return (q[..., :a.nope], att.rope(q[..., a.nope:], pos, a.theta,
+                                      a.scaling)), c, gate, c_q
 
 
 def indexer(cfg: LMConfig, w: Dict, x, c_q, pos):
@@ -631,11 +654,6 @@ def finish_attention(w: Dict, o, gate, out: float = 1.0):
     if out != 1.0:
         return scaled("nhv,hvd->nd", o, w["wo"], out)
     return jnp.einsum("nhv,hvd->nd", o, w["wo"])
-
-
-def embed(cfg: LMConfig, ends: Dict, tokens):
-    with jax.named_scope("lm/embed"):
-        return times(ends["embed"][tokens], cfg.mup.embed)
 
 
 # -- the state-space mixer (ops/ssm.py), off the same normed input as the
@@ -747,25 +765,173 @@ def feed_forward(cfg: LMConfig, layer: Dict, x):
     return y, counts, chosen
 
 
-def choices(selected, routed) -> Dict:
-    """``routed`` (MoE layers, tokens, k): empty for a model of dense
-    layers alone."""
-    routed = [c for c in routed if c is not None]
-    return {"selected": selected,
-            "routed": jnp.stack(routed) if routed
-            else jnp.zeros((0, 0, 0), jnp.int32)}
+# ---------------------------------------------------------------------------
+# the kinds of layer: one row each, and the walk over a model's layers
+# ---------------------------------------------------------------------------
+
+class Kind(NamedTuple):
+    """A kind of layer: ``sizes`` the ``LMConfig`` field of its attention's
+    sizes (their type is the form), ``caches`` the lists of the cache a
+    layer of it owns (``cache_shapes``), ``leaves`` its attention leaves
+    beyond the form's, and its two bodies.  A body takes by keyword what it
+    reads of the layer (``a`` the sizes, ``w`` the leaves, ``x``, and
+    ``q``, ``c``, ``c_q`` of the form's ``project``, its arrays of its
+    lists, ``out(o)`` the stream past the attention's output ``o``,
+    ``selected``) and of the step (:func:`walk`'s ``step``), opens its
+    ``lm/`` scopes and returns (the stream, its new arrays of its lists)."""
+    sizes: str
+    caches: Tuple[str, ...]
+    decode: Callable
+    prefill: Callable
+    leaves: Callable = lambda cfg, a, S: {}
 
 
-def head(cfg: LMConfig, ends: Dict, h):
+def full_decode(*, cfg, a, w, x, q, c, c_q, kv, ik, selected, out, geo, B,
+                pos, page, off, tables, owner, lengths, **_):
+    """The indexer scores every page, ``select_topk`` names a row's ``topk``
+    positions, ``selected_addresses`` their places in the pool, ONE gather
+    fetches them for ``mla_selected``; a slot past a row's count reads its
+    first entry, which the mask drops."""
+    with jax.named_scope("lm/indexer"):
+        q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
+        kv = kv.at[page, off].set(c)
+        ik = ik.at[page, off].set(k_idx)
+        by_page = att.index_scores_paged(q_idx, w_idx, ik, owner)
+        scores = by_page[tables].reshape(B, geo.max_len)
+    with jax.named_scope("lm/select"):
+        idx, valid = att.select_topk(scores, lengths, cfg.topk)
+        selected.append(jnp.where(valid, idx, -1))
+        phys = att.selected_addresses(tables, idx, geo.page)
+        chosen = kv.reshape(geo.n_pages * geo.page, -1)[phys]
+    with jax.named_scope("lm/mla_full"):
+        o = att.mla_selected(*q, chosen, valid, w["wkv_b"], a.nope, a.rope,
+                             a.scale)
+        return out(o), {"kv": kv, "ik": ik}
+
+
+def full_prefill(*, cfg, a, w, x, q, c, c_q, kv, ik, selected, out, pos,
+                 page, off, table, start, n_valid, pages_per_step, **_):
+    with jax.named_scope("lm/indexer"):
+        q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
+        kv = kv.at[page, off].set(c)
+        ik = ik.at[page, off].set(k_idx)
+    with jax.named_scope("lm/mla_full"):
+        o, sets = att.prefill_full_attention(
+            *q, q_idx, w_idx, kv, ik, table, start, n_valid, w["wkv_b"],
+            a.nope, a.rope, a.scale, cfg.topk, pages_per_step,
+            flash=PREFILL_HEADS_PER_STEP)
+        selected.append(sets)
+        return out(o), {"kv": kv, "ik": ik}
+
+
+def causal_decode(*, a, w, q, c, kv, out, page, off, tables, lengths, **_):
+    with jax.named_scope("lm/cache_write"):
+        kv = kv.at[page, off].set(c)
+    with a.scope(window=False):
+        return out(a.paged(w, q, kv, tables, lengths)), {"kv": kv}
+
+
+def causal_prefill(*, a, w, q, c, kv, out, page, off, table, start, n_valid,
+                   pages_per_step, **_):
+    with jax.named_scope("lm/cache_write"):
+        kv = kv.at[page, off].set(c)
+    with a.scope(window=False):
+        return out(a.prefill_paged(w, q, kv, table, start, n_valid,
+                                   pages_per_step)), {"kv": kv}
+
+
+def sliding_decode(*, cfg, a, w, q, c, ring, out, live, slot, pos, **_):
+    W = cfg.window
+    with a.scope(window=True):
+        ring = ring.at[slot, jnp.where(live, pos % W, W)].set(c, mode="drop")
+        mine = ring[slot]                                 # (B, W, entry)
+        age = (pos[:, None] - jnp.arange(W)[None, :]) % W
+        valid = (age <= pos[:, None]) & live[:, None]
+        return out(a.gathered(w, q, mine, valid)), {"ring": ring}
+
+
+def sliding_prefill(*, cfg, a, w, q, c, ring, out, slot, pos, real, start,
+                    n_valid, q_block, **_):
+    W, T = cfg.window, pos.shape[0]
+    with a.scope(window=True):
+        prev_pos = start - (W - 1) + jnp.arange(W - 1)
+        prev = ring[slot, prev_pos % W]
+        h = out(a.prefill_window(w, q, c, prev, prev_pos, start, n_valid, W,
+                                 min(q_block, T)))
+        # the chunk's last W real tokens go into the ring; the others
+        # (overwritten in the chunk, or padding) drop by an index past it
+        keep = real & (jnp.arange(T) >= n_valid - W)
+        ring = ring.at[slot, jnp.where(keep, pos % W, W)].set(c, mode="drop")
+        return h, {"ring": ring}
+
+
+#: every kind of layer the module knows: a new kind is a row here, its
+#: bodies, and the shape of any list it brings (``cache_shapes``)
+KINDS = {
+    FULL: Kind("full", ("kv", "ik"), full_decode, full_prefill,
+               lambda cfg, a, S: {  # the indexer's
+                   "idx_wq_b": S(a.q_rank, cfg.idx_heads, cfg.idx_dim),
+                   "idx_wk": S(cfg.d, cfg.idx_dim),
+                   "idx_k_norm_w": S(cfg.idx_dim),
+                   "idx_k_norm_b": S(cfg.idx_dim),
+                   "idx_w": S(cfg.d, cfg.idx_heads)}),
+    CAUSAL: Kind("full", ("kv",), causal_decode, causal_prefill),
+    SLIDING: Kind("swa", ("ring",), sliding_decode, sliding_prefill)}
+
+
+def walk(cfg: LMConfig, params: Dict, cache: Dict, tokens, phase: str,
+         mix: Callable, last: Callable, **step):
+    """Both step programs' layers: embed; a layer's ``lm/proj`` (its norm,
+    its form's ``project``), its mixer (``mix(w, x, states, conv)``), its
+    kind's ``phase`` body, the norm and :func:`feed_forward`; the head over
+    ``last(stream)``.  ``step``: what the bodies read of the step.
+    → the step's (cache, logits, expert tokens, choices)."""
+    with jax.named_scope("lm/embed"):
+        h = times(params["ends"]["embed"][tokens], cfg.mup.embed)
+    cache = {k: list(v) for k, v in cache.items()}
+    counts, selected, routed = [], [], []
+    for i, (layer, kind) in enumerate(zip(params["layers"], cfg.kinds)):
+        a, w = cfg.dims(kind), layer["attn"]
+        at = {k: sum(k in KINDS[j].caches for j in cfg.kinds[:i])
+              for k in KINDS[kind].caches}       # its place in each list
+        # a sibling of the attention scopes, never around one: the readers
+        # take the first lm/ name of an op_name (obs/names.py)
+        with jax.named_scope("lm/proj"):
+            x = rms_norm(h, layer["attn_norm"], cfg.eps)
+            q, c, gate, c_q = a.project(cfg, w, x, step["pos"])
+        if cfg.ssm:
+            mixed, cache["ssm"][i], cache["conv"][i] = mix(
+                layer["ssm"], x, cache["ssm"][i], cache["conv"][i])
+        h, new = getattr(KINDS[kind], phase)(
+            cfg=cfg, a=a, w=w, x=x, q=q, c=c, c_q=c_q, selected=selected,
+            out=lambda o: h + finish_attention(w, o, gate, cfg.mup.attn_out),
+            **{k: cache[k][j] for k, j in at.items()}, **step)
+        for k, j in at.items():
+            cache[k][j] = new[k]
+        if cfg.ssm:
+            h = h + mixed
+        x = rms_norm(h, layer["mlp_norm"], cfg.eps)
+        y, n, chosen = feed_forward(cfg, layer, x)
+        h = h + y
+        counts.append(n)
+        routed.append(chosen)
+    x = last(h)
     with jax.named_scope("lm/head"):
-        x = rms_norm(h, ends["final_norm"], cfg.eps)
-        logits = jnp.einsum("nd,dv->nv", x, ends["head"],
+        x = rms_norm(x, params["ends"]["final_norm"], cfg.eps)
+        logits = jnp.einsum("nd,dv->nv", x, params["ends"]["head"],
                             preferred_element_type=F32)
-        return logits if cfg.mup.head == 1.0 else logits * cfg.mup.head
+        if cfg.mup.head != 1.0:
+            logits = logits * cfg.mup.head
+    # ``routed`` (MoE layers, tokens, k): empty for dense layers alone
+    routed = [r for r in routed if r is not None]
+    return cache, logits, jnp.stack(counts), {
+        "selected": selected, "routed": jnp.stack(routed) if routed
+        else jnp.zeros((0, 0, 0), jnp.int32)}
 
 
 # ---------------------------------------------------------------------------
-# decode: B rows of any sessions, one token each
+# the step programs: decode (B rows of any sessions, one token each) and
+# prefill (one session's chunk of T tokens)
 # ---------------------------------------------------------------------------
 
 def pack_rows(tokens, slots, pos, tables, owner) -> np.ndarray:
@@ -800,111 +966,20 @@ def decode_rows(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     ``owner`` (n_pages,) which row owns each page (−1: none).
     → (cache, logits (B, vocab) float32, expert tokens (n_layers, held),
     choices: ``selected`` (B, topk) positions a full layer, −1 where a row
-    has fewer).
-
-    A full layer: the indexer scores every page once, ``select_topk``
-    names the row's ``topk`` positions (ascending), their addresses in the
-    pool come from the row's page table by a compare-and-sum
-    (``selected_addresses``: no lookup a position at a time), and ONE
-    gather a layer — the only one left between the selection and the
-    softmax — fetches the entries ``mla_selected`` attends to, reading the
-    copy once at lane-aligned widths (a Pallas program a layer) and as
-    ``mla_absorbed`` does at any other.  A slot past a row's count reads
-    the row's first entry, which the mask drops."""
+    has fewer)."""
     B = tokens.shape[0]
     live = slots >= 0
     slot = jnp.maximum(slots, 0)
     # a padding row writes to page 0 and to no ring slot
     page = jnp.where(live, tables[jnp.arange(B), pos // geo.page], 0)
-    off = pos % geo.page
-    lengths = jnp.where(live, pos + 1, 0)
-    h = embed(cfg, params["ends"], tokens)
-    kv, ik, ring, states, conv = (list(cache[k]) for k in CACHE_KEYS)
-    counts, selected, routed = [], [], []
-    i_pool = i_full = i_slide = 0
-    for i_layer, (layer, kind) in enumerate(zip(params["layers"],
-                                                cfg.kinds)):
-        a, w = cfg.dims(kind), layer["attn"]
-        gqa = isinstance(a, GQADims)
-        # a sibling of the attention scopes below, never around one: the
-        # readers take the first lm/ name of an op_name (obs/names.py)
-        with jax.named_scope("lm/proj"):
-            x = rms_norm(h, layer["attn_norm"], cfg.eps)
-            if gqa:
-                q_plain, q_rot, c = gqa_project(a, w, x, pos, cfg.mup)
-                gate = None
-            else:
-                c_q, c, gate = latents(cfg, a, w, x, pos)
-                q_nope, q_rope = queries(a, w, c_q, pos)
-        if cfg.ssm:
-            mixed, states[i_layer], conv[i_layer] = ssm_decode(
-                cfg, layer["ssm"], x, slots, pos, states[i_layer],
-                conv[i_layer])
-        if kind == FULL:
-            with jax.named_scope("lm/indexer"):
-                q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
-                kv[i_pool] = kv[i_pool].at[page, off].set(c)
-                ik[i_full] = ik[i_full].at[page, off].set(k_idx)
-                by_page = att.index_scores_paged(q_idx, w_idx, ik[i_full],
-                                                 owner)
-                scores = by_page[tables].reshape(B, geo.max_len)
-            with jax.named_scope("lm/select"):
-                idx, valid = att.select_topk(scores, lengths, cfg.topk)
-                selected.append(jnp.where(valid, idx, -1))
-                phys = att.selected_addresses(tables, idx, geo.page)
-                chosen = kv[i_pool].reshape(
-                    geo.n_pages * geo.page, -1)[phys]
-            with jax.named_scope("lm/mla_full"):
-                o = att.mla_selected(q_nope, q_rope, chosen, valid,
-                                     w["wkv_b"], a.nope, a.rope, a.scale)
-                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
-            i_pool, i_full = i_pool + 1, i_full + 1
-        elif kind == CAUSAL:
-            with jax.named_scope("lm/cache_write"):
-                kv[i_pool] = kv[i_pool].at[page, off].set(c)
-            with jax.named_scope("lm/gqa_paged" if gqa else "lm/mla_paged"):
-                if gqa:
-                    o = att.gqa_paged(q_plain, q_rot, kv[i_pool], tables,
-                                      lengths, a.kv_heads, a.v, a.scale)
-                else:
-                    o = att.mla_paged(q_nope, q_rope, kv[i_pool], tables,
-                                      lengths, w["wkv_b"], a.nope, a.rope,
-                                      a.scale)
-                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
-            i_pool += 1
-        else:
-            with jax.named_scope("lm/gqa_window" if gqa else "lm/mla_window"):
-                at = jnp.where(live, pos % cfg.window, cfg.window)
-                ring[i_slide] = ring[i_slide].at[slot, at].set(
-                    c, mode="drop")
-                mine = ring[i_slide][slot]                 # (B, W, entry)
-                age = (pos[:, None] - jnp.arange(cfg.window)[None, :]) \
-                    % cfg.window
-                valid = (age <= pos[:, None]) & live[:, None]
-                if gqa:
-                    o = att.gqa_gathered(q_plain, q_rot, mine, valid,
-                                         w.get("sink"), a.kv_heads, a.v,
-                                         a.scale)
-                else:
-                    o = att.mla_absorbed(q_nope, q_rope, mine, valid,
-                                         w["wkv_b"], a.nope, a.rope, a.scale)
-                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
-            i_slide += 1
-        if cfg.ssm:
-            h = h + mixed
-        x = rms_norm(h, layer["mlp_norm"], cfg.eps)
-        y, n, chosen = feed_forward(cfg, layer, x)
-        h = h + y
-        counts.append(n)
-        routed.append(chosen)
-    logits = head(cfg, params["ends"], h)
-    return (dict(zip(CACHE_KEYS, (kv, ik, ring, states, conv))), logits,
-            jnp.stack(counts), choices(selected, routed))
+    return walk(
+        cfg, params, cache, tokens, "decode",
+        lambda w, x, states, conv: ssm_decode(cfg, w, x, slots, pos, states,
+                                              conv), lambda h: h,
+        geo=geo, B=B, live=live, slot=slot, pos=pos, page=page,
+        off=pos % geo.page, lengths=jnp.where(live, pos + 1, 0),
+        tables=tables, owner=owner)
 
-
-# ---------------------------------------------------------------------------
-# prefill: one session's chunk of T tokens
-# ---------------------------------------------------------------------------
 
 def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
                  cache: Dict, tokens, slot, start, n_valid, table,
@@ -918,93 +993,15 @@ def prefill_step(cfg: LMConfig, geo: CacheGeometry, params: Dict,
     T = tokens.shape[0]
     pos = start + jnp.arange(T)
     real = jnp.arange(T) < n_valid
-    page = jnp.where(real, table[pos // geo.page], 0)
-    off = pos % geo.page
-    h = embed(cfg, params["ends"], tokens)
-    kv, ik, ring, states, conv = (list(cache[k]) for k in CACHE_KEYS)
-    W = cfg.window
-    counts, selected, routed = [], [], []
-    i_pool = i_full = i_slide = 0
-    for i_layer, (layer, kind) in enumerate(zip(params["layers"],
-                                                cfg.kinds)):
-        a, w = cfg.dims(kind), layer["attn"]
-        gqa = isinstance(a, GQADims)
-        # a sibling of the attention scopes below, never around one: the
-        # readers take the first lm/ name of an op_name (obs/names.py)
-        with jax.named_scope("lm/proj"):
-            x = rms_norm(h, layer["attn_norm"], cfg.eps)
-            if gqa:
-                q_plain, q_rot, c = gqa_project(a, w, x, pos, cfg.mup)
-                gate = None
-            else:
-                c_q, c, gate = latents(cfg, a, w, x, pos)
-                q_nope, q_rope = queries(a, w, c_q, pos)
-        if cfg.ssm:
-            mixed, states[i_layer], conv[i_layer] = ssm_prefill(
-                cfg, layer["ssm"], x, slot, start, n_valid, states[i_layer],
-                conv[i_layer])
-        if kind == FULL:
-            with jax.named_scope("lm/indexer"):
-                q_idx, k_idx, w_idx = indexer(cfg, w, x, c_q, pos)
-                kv[i_pool] = kv[i_pool].at[page, off].set(c)
-                ik[i_full] = ik[i_full].at[page, off].set(k_idx)
-            with jax.named_scope("lm/mla_full"):
-                o, sets = att.prefill_full_attention(
-                    q_nope, q_rope, q_idx, w_idx, kv[i_pool], ik[i_full],
-                    table, start, n_valid, w["wkv_b"], a.nope, a.rope,
-                    a.scale, cfg.topk, pages_per_step,
-                    flash=PREFILL_HEADS_PER_STEP)
-                selected.append(sets)
-                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
-            i_pool, i_full = i_pool + 1, i_full + 1
-        elif kind == CAUSAL:
-            with jax.named_scope("lm/cache_write"):
-                kv[i_pool] = kv[i_pool].at[page, off].set(c)
-            with jax.named_scope("lm/gqa_paged" if gqa else "lm/mla_paged"):
-                if gqa:
-                    o = att.prefill_gqa_causal(
-                        q_plain, q_rot, kv[i_pool], table, start, n_valid,
-                        a.kv_heads, a.v, a.scale, pages_per_step)
-                else:
-                    o = att.prefill_causal_attention(
-                        q_nope, q_rope, kv[i_pool], table, start, n_valid,
-                        w["wkv_b"], a.nope, a.rope, a.scale, pages_per_step,
-                        flash=PREFILL_HEADS_PER_STEP)
-                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
-            i_pool += 1
-        else:
-            with jax.named_scope("lm/gqa_window" if gqa else "lm/mla_window"):
-                prev_pos = start - (W - 1) + jnp.arange(W - 1)
-                prev = ring[i_slide][slot, prev_pos % W]
-                if gqa:
-                    o = att.prefill_gqa_window(
-                        q_plain, q_rot, c, prev, prev_pos, start, n_valid,
-                        w.get("sink"), a.kv_heads, a.v, a.scale, W,
-                        min(q_block, T))
-                else:
-                    o = att.prefill_window_attention(
-                        jnp.concatenate([q_nope, q_rope], -1), c, prev,
-                        prev_pos, start, n_valid, w["wkv_b"], a.nope, a.rope,
-                        a.scale, W, min(q_block, T))
-                h = h + finish_attention(w, o, gate, cfg.mup.attn_out)
-                # the chunk's last W real tokens go into the ring; the
-                # others (overwritten within the chunk, or padding) are
-                # dropped by an index past the end
-                keep = real & (jnp.arange(T) >= n_valid - W)
-                ring[i_slide] = ring[i_slide].at[
-                    slot, jnp.where(keep, pos % W, W)].set(c, mode="drop")
-            i_slide += 1
-        if cfg.ssm:
-            h = h + mixed
-        x = rms_norm(h, layer["mlp_norm"], cfg.eps)
-        y, n, chosen = feed_forward(cfg, layer, x)
-        h = h + y
-        counts.append(n)
-        routed.append(chosen)
-    last = jax.lax.dynamic_slice_in_dim(h, jnp.maximum(n_valid - 1, 0), 1, 0)
-    logits = head(cfg, params["ends"], last)
-    return (dict(zip(CACHE_KEYS, (kv, ik, ring, states, conv))), logits,
-            jnp.stack(counts), choices(selected, routed))
+    return walk(
+        cfg, params, cache, tokens, "prefill",
+        lambda w, x, states, conv: ssm_prefill(cfg, w, x, slot, start,
+                                               n_valid, states, conv),
+        lambda h: jax.lax.dynamic_slice_in_dim(
+            h, jnp.maximum(n_valid - 1, 0), 1, 0),
+        pos=pos, real=real, page=jnp.where(real, table[pos // geo.page], 0),
+        off=pos % geo.page, slot=slot, start=start, n_valid=n_valid,
+        table=table, pages_per_step=pages_per_step, q_block=q_block)
 
 
 #: the two step programs, jitted once for the process: configuration and

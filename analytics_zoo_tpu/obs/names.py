@@ -379,35 +379,35 @@ SCOPES: Dict[str, str] = {
         "SSDPredictor._forward_tail · scale_detections: the boxes to the "
         "pictures' sizes",
     "lm/embed":
-        "decode_rows / prefill_step · the embedding look-up",
+        "walk · the embedding look-up",
     "lm/proj":
-        "decode_rows / prefill_step · the top of each layer: rms_norm of "
-        "the stream, then gqa_project, or latents and queries",
+        "walk · the top of each layer: rms_norm of the stream, then the "
+        "form's project (latents, or GQADims.project)",
     "lm/cache_write":
-        "decode_rows / prefill_step · a CAUSAL layer's write of the new "
+        "causal_decode / causal_prefill · a CAUSAL layer's write of the new "
         "entries into the paged pool (a full layer's stands under "
         "lm/indexer, a window layer's under its window scope)",
     "lm/indexer":
-        "decode_rows / prefill_step · a full layer's index projections, "
+        "full_decode / full_prefill · a full layer's index projections, "
         "its cache writes and the paged index scores",
     "lm/select":
-        "decode_rows · a full layer's top-k of the index scores and the "
+        "full_decode · a full layer's top-k of the index scores and the "
         "gather of the selected entries",
     "lm/mla_full":
-        "decode_rows / prefill_step · a full layer's latent attention "
+        "full_decode / full_prefill · a full layer's latent attention "
         "over the selected entries, with its output projection",
     "lm/mla_paged":
-        "decode_rows / prefill_step · a causal latent layer's attention "
+        "causal_decode / causal_prefill · a causal latent layer's attention "
         "over the paged pool, with its output projection",
     "lm/gqa_paged":
-        "decode_rows / prefill_step · a global grouped-query layer's "
+        "causal_decode / causal_prefill · a global grouped-query layer's "
         "attention over the paged pool, with its output projection",
     "lm/mla_window":
-        "decode_rows / prefill_step · a sliding latent layer: ring write, "
-        "gather, attention, output projection",
+        "sliding_decode / sliding_prefill · a sliding latent layer: ring "
+        "write, gather, attention, output projection",
     "lm/gqa_window":
-        "decode_rows / prefill_step · a window grouped-query layer: ring "
-        "write, gather, attention with the sink, output projection",
+        "sliding_decode / sliding_prefill · a window grouped-query layer: "
+        "ring write, gather, attention with the sink, output projection",
     "lm/ssm_proj":
         "ssm_project · a state-space mixer's in-projection off the "
         "layer's normed input, with its multipliers: the gate z, the "
@@ -436,7 +436,7 @@ SCOPES: Dict[str, str] = {
     "lm/shared_mlp":
         "moe_held_experts · the shared expert's gated MLP",
     "lm/head":
-        "head · the final norm and the vocabulary projection",
+        "walk · the final norm and the vocabulary projection",
 }
 
 

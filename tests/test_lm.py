@@ -524,18 +524,24 @@ def test_config_reads_the_published_keys():
     assert shapes["layers"][1]["moe"]["router_w"].shape == (32, 8)
 
 
-@pytest.mark.parametrize("name,geo,leaves,count,tree,cache", [
-    ("dots3-note-prev-ep8", (2401, 512, 136, 64), 98, 4087154176,
-     "09edca6ccd04cd81", "c24b4c820c95c402"),
-    ("ax-k1-ep16", (1751, 512, 88, 64), 79, 3491257344,
+@pytest.mark.parametrize("name,geo,form,mixer,leaves,count,tree,cache", [
+    ("dots3-note-prev-ep8", (2401, 512, 136, 64), lm.MLADims, False, 98,
+     4087154176, "09edca6ccd04cd81", "c24b4c820c95c402"),
+    ("ax-k1-ep16", (1751, 512, 88, 64), lm.MLADims, False, 79, 3491257344,
      "b3901c3932d8e722", "8286b4e17aca8eb3"),
+    ("mimo-v25-ep16", (2101, 512, 136, 64), lm.GQADims, False, 83,
+     3429955392, "7096cdf2019b682e", "72a5ca82f52fb6bd"),
+    ("falcon-h1-34b-pp12", (1476, 256, 21, 128), lm.GQADims, True, 105,
+     2914958912, "729e18820c0eb113", "82ccb9af00e8a22a"),
 ])
-def test_the_benchmark_s_latent_models_are_what_they_were(name, geo, leaves,
-                                                          count, tree, cache):
-    """``LMConfig.from_dict`` of the two configuration files the benchmark
-    had before the grouped-query form joined the module: every leaf's
-    name, shape and dtype of the parameter tree and of the cache at the
-    cell's geometry, as a digest taken at PR 34."""
+def test_the_benchmark_s_decoder_models_are_what_they_were(
+        name, geo, form, mixer, leaves, count, tree, cache):
+    """``LMConfig.from_dict`` of the four configuration files the benchmark
+    serves: the form of attention, a mixer or none (no multiplier without
+    one), and every leaf's name, shape and dtype of the parameter tree and
+    of the cache at the cell's geometry as a digest — dots3's and A.X-K1's
+    taken at commit c199510, MiMo's at 008c4ee (neither cache has an
+    ``ssm`` or a ``conv`` leaf), Falcon-H1's at dde06de."""
     import hashlib
     import json
 
@@ -543,8 +549,10 @@ def test_the_benchmark_s_latent_models_are_what_they_were(name, geo, leaves,
     with open(os.path.join(root, "benchmarks", "configs",
                            name + ".json")) as f:
         cfg = lm.LMConfig.from_dict(json.load(f))
-    assert isinstance(cfg.full, lm.MLADims) \
-        and isinstance(cfg.swa, (lm.MLADims, type(None)))
+    assert isinstance(cfg.full, form) \
+        and isinstance(cfg.swa, (form, type(None)))
+    assert (cfg.ssm is not None) == mixer
+    assert mixer or cfg.mup == lm.Multipliers()
 
     def flat(shapes):
         return [(jax.tree_util.keystr(p), v.shape, str(v.dtype)) for p, v

@@ -372,40 +372,6 @@ def test_served_configuration_is_what_its_file_says():
     assert all(config[k] for k in ("deployment", "assumed", "left_out"))
 
 
-@pytest.mark.parametrize("name,geo,leaves,count,tree,cache", [
-    ("dots3-note-prev-ep8", (2401, 512, 136, 64), 98, 4087154176,
-     "09edca6ccd04cd81", "c24b4c820c95c402"),
-    ("ax-k1-ep16", (1751, 512, 88, 64), 79, 3491257344,
-     "b3901c3932d8e722", "8286b4e17aca8eb3"),
-    ("mimo-v25-ep16", (2101, 512, 136, 64), 83, 3429955392,
-     "7096cdf2019b682e", "72a5ca82f52fb6bd"),
-])
-def test_the_models_without_a_mixer_are_what_they_were(name, geo, leaves,
-                                                       count, tree, cache):
-    """The three decoder configurations the benchmark had before a mixer
-    joined the module: no multiplier, no mixer, and every leaf's name,
-    shape and dtype of the parameter tree and of the cache at the cell's
-    geometry as a digest taken at PR 38 (the cache has no ``ssm`` and no
-    ``conv`` leaf)."""
-    with open(os.path.join(ROOT, "benchmarks", "configs",
-                           name + ".json")) as f:
-        cfg = lm.LMConfig.from_dict(json.load(f))
-    assert cfg.ssm is None and cfg.mup == lm.Multipliers()
-
-    def flat(shapes):
-        return [(jax.tree_util.keystr(p), v.shape, str(v.dtype)) for p, v
-                in jax.tree_util.tree_flatten_with_path(shapes)[0]]
-
-    def digest(rows):
-        return hashlib.sha256(repr(rows).encode()).hexdigest()[:16]
-
-    params = flat(lm.param_shapes(cfg))
-    assert len(params) == leaves
-    assert sum(int(np.prod(s)) for _, s, _ in params) == count
-    assert digest(params) == tree
-    assert digest(flat(lm.cache_shapes(cfg, lm.CacheGeometry(*geo)))) == cache
-
-
 @pytest.mark.parametrize("change,message", [
     ({"attn_layer_indices": [0, 2]}, "state-space mixer"),
     ({"mamba_norm_before_gate": True}, "state-space mixer"),
@@ -430,20 +396,26 @@ def test_a_model_without_experts_chooses_nothing(weights):
         == jax.tree_util.tree_structure(lm.param_shapes(cfg))
 
 
-@pytest.mark.parametrize("toy,program,text", [
-    ("lm_toy", "decode", "691f70b026500a1f"),
-    ("lm_toy", "prefill", "27d7c7ecbe8983a3"),
-    ("lm_mla_toy", "decode", "dfb009b1ad9811c6"),
-    ("lm_mla_toy", "prefill", "b7376527b5f9763e"),
-    ("lm_gqa_toy", "decode", "493ebd5061cd864e"),
-    ("lm_gqa_toy", "prefill", "3e34b80840374f97"),
+@pytest.mark.parametrize("toy,program,text,scopes", [
+    ("lm_toy", "decode", "691f70b026500a1f", "d663be26cdd6d859"),
+    ("lm_toy", "prefill", "27d7c7ecbe8983a3", "3610de390ec14d5b"),
+    ("lm_mla_toy", "decode", "dfb009b1ad9811c6", "2f79f68be408fbca"),
+    ("lm_mla_toy", "prefill", "b7376527b5f9763e", "97a8a984d281daee"),
+    ("lm_gqa_toy", "decode", "493ebd5061cd864e", "46227f1397096846"),
+    ("lm_gqa_toy", "prefill", "3e34b80840374f97", "4045a9966d42e5fa"),
+    ("lm_ssm_toy", "decode", "c7beb0bd68165361", "09d40f5afd66d751"),
+    ("lm_ssm_toy", "prefill", "38df50ed73fd6947", "24ff44bad2e266fa"),
 ])
-def test_the_other_models_step_programs_lower_as_they_did(toy, program, text):
-    """The three toys' step programs as lowered text (locations cut out),
-    a digest taken on the parent of PR 39: no multiplier of 1 is
-    multiplied in, no empty list of states is an argument, and the
-    grouped-query kernel's wider layout pads nothing at MiMo's 16 heads a
-    KV head."""
+def test_the_other_models_step_programs_lower_as_they_did(toy, program, text,
+                                                          scopes):
+    """The four toys' step programs as lowered text (locations cut out),
+    a digest taken at commit 008c4ee (the state-space toy's at dde06de):
+    no multiplier of 1 is multiplied in, no empty list of states is an
+    argument, and the grouped-query kernel's wider layout pads nothing at
+    MiMo's 16 heads a KV head.  And the ``lm/`` scope of
+    every op, in program order (what the decoder cells' device time by
+    scope reads, benchmarks/lm_scopes.py), as a digest taken at commit
+    dde06de: an op moved from one scope to another fails."""
     import importlib
     import re
 
@@ -463,3 +435,16 @@ def test_the_other_models_step_programs_lower_as_they_did(toy, program, text):
             S((geo.max_pages,), i32))
     got = re.sub(r"loc\(.*?\)", "", lowered.as_text())
     assert hashlib.sha256(got.encode()).hexdigest()[:16] == text
+    # an op's location is an alias; a named one reads
+    # ``#locN = loc("jit(..)/lm/<scope>/../<op>"(#locM))``, #locM its file
+    # and line, which are left out
+    debug = lowered.as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"\(', debug, re.M))
+    seq = []
+    for line in debug.splitlines():
+        op = re.search(r"loc\((#loc\d+)\)$", line)
+        if op and not line.startswith("#loc"):
+            scope = re.search(r"(?:^|/)(lm/\w+)", names.get(op.group(1), ""))
+            seq.append(scope.group(1) if scope else "")
+    assert any(seq)
+    assert hashlib.sha256("\n".join(seq).encode()).hexdigest()[:16] == scopes
